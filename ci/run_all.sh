@@ -804,7 +804,6 @@ os.environ["XLA_FLAGS"] = re.sub(
     r"--xla_force_host_platform_device_count=\d+", "",
     os.environ.get("XLA_FLAGS", "")).strip()   # one device per rank
 import jax
-jax.config.update("jax_platforms", "cpu")
 import numpy as np
 import mxnet_tpu as mx
 from mxnet_tpu import gluon, telemetry
@@ -1137,7 +1136,6 @@ run_chaos_dist() {
 import os, sys
 os.environ["JAX_PLATFORMS"] = "cpu"
 import jax
-jax.config.update("jax_platforms", "cpu")
 import numpy as np
 import mxnet_tpu as mx
 from mxnet_tpu import chaos, telemetry

@@ -16,78 +16,13 @@ __version__ = "0.1.0"
 
 import os as _os
 
-# Persistent XLA compilation cache: the imperative NDArray surface compiles
-# one tiny XLA program per (op, shape) pair; caching them on disk makes
-# every process after the first start hot.  (The reference's analog is
-# cuDNN autotune caching, MXNET_CUDNN_AUTOTUNE_DEFAULT.)
-if _os.environ.get("MXNET_TPU_COMPILATION_CACHE", "1") != "0":
+# Persistent XLA compilation cache: wherever JAX_COMPILATION_CACHE_DIR
+# says, else inside the checkout (base.compile_cache_dir).
+from .base import compile_cache_dir as _compile_cache_dir
+_cache_dir = _compile_cache_dir()
+if _cache_dir is not None:
     import jax as _jax
-
-    def _cache_fingerprint():
-        # AOT artifacts are only valid for the exact compiler build and
-        # host ISA that produced them.  A home directory shared across
-        # machines (or across a rolling libtpu upgrade) serving stale
-        # executables is a startup SIGILL / libtpu-version-mismatch
-        # crash, not a warm start -- so the cache dir is keyed on
-        # jax/jaxlib/libtpu versions plus the host CPU model+flags.
-        import hashlib
-        import platform as _plat
-        parts = [_jax.__version__, _plat.machine()]
-        try:
-            import jaxlib as _jaxlib
-            parts.append(getattr(_jaxlib, "__version__", ""))
-        except Exception:
-            pass
-        from importlib import metadata as _md
-        for _pkg in ("libtpu", "libtpu-nightly"):
-            try:
-                parts.append(_pkg + "=" + _md.version(_pkg))
-            except Exception:
-                pass
-        try:
-            model = flags = ""
-            with open("/proc/cpuinfo") as _f:
-                for _line in _f:
-                    if not model and _line.startswith("model name"):
-                        model = _line.strip()
-                    elif not flags and _line.startswith("flags"):
-                        flags = _line.strip()
-                    if model and flags:
-                        break
-            parts += [model, flags]
-        except OSError:
-            pass
-        return hashlib.sha256("|".join(parts).encode()).hexdigest()[:16]
-
-    _cache_dir = _os.environ.get("MXNET_TPU_COMPILATION_CACHE_DIR")
-    if _cache_dir is None:
-        _cache_root = _os.path.expanduser("~/.cache/mxnet_tpu/xla")
-        _cache_dir = _os.path.join(_cache_root, _cache_fingerprint())
-        # best-effort GC: prune sibling fingerprint dirs untouched for
-        # 30+ days (each rolling jaxlib/libtpu bump orphans one).  Every
-        # import touches its OWN dir's mtime first, so a cache that is
-        # still in use anywhere (even read-only warm) stays fresh as
-        # long as its processes restart within the window.
-        try:
-            import shutil as _shutil
-            import time as _time
-            if _os.path.isdir(_cache_dir):
-                _os.utime(_cache_dir, None)
-            _cutoff = _time.time() - 30 * 86400
-            for _d in _os.listdir(_cache_root):
-                _p = _os.path.join(_cache_root, _d)
-                if (_p != _cache_dir and len(_d) == 16
-                        and _os.path.isdir(_p)
-                        and _os.path.getmtime(_p) < _cutoff):
-                    _shutil.rmtree(_p, ignore_errors=True)
-        except OSError:
-            pass
-    try:
-        _os.makedirs(_cache_dir, exist_ok=True)
-        _jax.config.update("jax_compilation_cache_dir", _cache_dir)
-        _jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.1)
-    except Exception:
-        pass
+    _jax.config.update("jax_compilation_cache_dir", _cache_dir)
 
 # Transfer guard (sharding sanitizer runtime wiring): with
 # MXNET_TPU_TRANSFER_GUARD=disallow, an IMPLICIT host<->device transfer

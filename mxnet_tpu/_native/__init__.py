@@ -21,8 +21,9 @@ _SRC = os.path.join(os.path.dirname(os.path.abspath(__file__)),
 
 
 def _cache_dir():
-    d = os.environ.get("MXNET_TPU_NATIVE_CACHE",
-                       os.path.expanduser("~/.cache/mxnet_tpu/native"))
+    from ..base import CACHE_ROOT
+    d = os.environ.get("MXNET_TPU_NATIVE_CACHE") \
+        or os.path.join(CACHE_ROOT, "native")
     os.makedirs(d, exist_ok=True)
     return d
 

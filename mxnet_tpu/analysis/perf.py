@@ -638,7 +638,7 @@ def _kernel_remedy(kind: str) -> Optional[str]:
 
 
 def _advisories_for(label: str, metrics: Dict, counters: Dict,
-                    ridge: float, thresholds: Dict) -> List[Dict]:
+                    ridge: Optional[float], thresholds: Dict) -> List[Dict]:
     adv = []
     top_transpose = sorted(counters["transpose_ops"].items(),
                            key=lambda kv: -kv[1])[:3]
@@ -684,7 +684,8 @@ def _advisories_for(label: str, metrics: Dict, counters: Dict,
                        % (100 * metrics["pad_waste"], label),
         })
     factor = thresholds["membound_ridge_factor"]
-    if metrics["bytes"] and metrics["intensity"] < ridge / factor:
+    if ridge is not None and metrics["bytes"] \
+            and metrics["intensity"] < ridge / factor:
         adv.append({
             "kind": "memory-bound",
             "category": "elementwise_fusion",
@@ -717,8 +718,10 @@ def perf_audit(thresholds=None, peaks=None) -> Dict:
 
     ``thresholds`` overrides :data:`THRESHOLDS`; ``peaks`` is an
     optional ``(peak_flops, peak_bytes_per_s)`` pair pinning the ridge
-    (tests; CI boxes use the assumed-peaks fallback, recorded in
-    ``peaks_assumed``).
+    (tests).  Without it the ridge is the device's own
+    (``profiling.roofline.DEVICE_PEAKS``); a CPU has none, so there
+    ``ridge_intensity`` is ``None`` and no ``memory-bound`` advisory is
+    judged.
     """
     import jax
     from ..profiling import roofline, store
@@ -726,11 +729,9 @@ def perf_audit(thresholds=None, peaks=None) -> Dict:
     th = dict(THRESHOLDS)
     if thresholds:
         th.update(thresholds)
-    if peaks is not None:
-        fl, bw, assumed = peaks[0], peaks[1], False
-    else:
-        fl, bw, assumed = roofline.device_peaks()
-    ridge = fl / bw
+    if peaks is None:
+        peaks = roofline.device_peaks()
+    ridge = peaks[0] / peaks[1] if peaks is not None else None
 
     merged: Dict[str, Dict] = {}
     totals: Dict[str, List[float]] = {}
@@ -742,11 +743,9 @@ def perf_audit(thresholds=None, peaks=None) -> Dict:
         counters = audit_hlo_text(text)
         xf = xb = 0.0
         try:
-            ca = compiled.cost_analysis()
-            if isinstance(ca, (list, tuple)):
-                ca = ca[0] if ca else {}
-            xf = float((ca or {}).get("flops", 0.0))
-            xb = float((ca or {}).get("bytes accessed", 0.0))
+            ca = compiled.cost_analysis() or {}
+            xf = float(ca.get("flops", 0.0))
+            xb = float(ca.get("bytes accessed", 0.0))
         except Exception:
             pass
         if label in merged:
@@ -776,8 +775,7 @@ def perf_audit(thresholds=None, peaks=None) -> Dict:
     return {
         "schema": AUDIT_SCHEMA,
         "backend": backend,
-        "ridge_intensity": round(ridge, 3),
-        "peaks_assumed": assumed,
+        "ridge_intensity": None if ridge is None else round(ridge, 3),
         "thresholds": th,
         "executables": execs,
         "advisories": ranked,
@@ -873,7 +871,7 @@ def diff_audit(baseline: Dict, current: Dict,
       "A registered executable's efficiency metrics (transpose share, "
       "unfused elementwise bytes, MXU pad waste, intensity) drifted "
       "past the committed ci/perf_baseline.json -- a named, gated "
-      "regression instead of a number drifting in BENCH_r0x.  Gate: "
-      "mxlint --perf-diff.")
+      "regression instead of a number drifting between bench rounds.  "
+      "Gate: mxlint --perf-diff.")
 def _rule_perf_drift(baseline, current):
     return diff_audit(baseline, current)

@@ -19,9 +19,8 @@ any of this; this pass does, in two layers:
   resolved best-effort (string literals, parameter defaults,
   ``self._axis``-style attributes bound in ``__init__``).
 - ``shard-map-spec-arity``: ``shard_map`` ``in_specs``/``out_specs``
-  tuple arity vs the body's signature/returns (covers the
-  ``parallel._shard_map`` compat wrapper and ``functools.partial``
-  bodies).
+  tuple arity vs the body's signature/returns (covers
+  ``functools.partial`` bodies).
 - ``undonated-train-state``: a ``jax.jit`` of a train-step-shaped
   function (name contains train/step, or positional params carry
   param/optimizer-state names) without ``donate_argnums`` -- each
@@ -73,7 +72,7 @@ __all__ = [
 # constructors that build a partition spec / mesh, by their usual names
 _P_FUNCS = {"P", "PartitionSpec"}
 _MESH_FUNCS = {"Mesh", "make_mesh"}
-_SHARD_MAP_FUNCS = {"shard_map", "_shard_map"}
+_SHARD_MAP_FUNCS = {"shard_map"}
 # module-level assignment targets that declare an axis vocabulary
 _AXIS_DECL_RE = re.compile(r"(AXIS|AXES)")
 # function names that read as a compiled train step
@@ -423,9 +422,9 @@ def _spec_arity(expr) -> Optional[int]:
 
 @rule("shard-map-spec-arity", "ast",
       "shard_map in_specs/out_specs tuple arity disagrees with the "
-      "body's positional signature / returned tuple (including the "
-      "parallel._shard_map compat wrapper and functools.partial "
-      "bodies); jax raises a cryptic tree-mismatch at trace time.")
+      "body's positional signature / returned tuple (including "
+      "functools.partial bodies); jax raises a cryptic tree-mismatch "
+      "at trace time.")
 def _lint_shard_map_arity(tree, path, ctx):
     defs, assigns = _file_defs_and_assigns(tree)
     for node in ast.walk(tree):

@@ -8,7 +8,27 @@ Python exceptions raised at op-call or sync points.
 """
 from __future__ import annotations
 
+import os
 import re
+
+# Everything the program builds or caches at run time (compiled XLA
+# programs, serving export artifacts, the native .so) goes under this
+# one git-ignored directory of the checkout, never under ``~``: what
+# runs is then built from the files git tracks and nothing else.
+CACHE_ROOT = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    ".mxnet_tpu_cache")
+
+
+def compile_cache_dir(environ=os.environ):
+    """Where this checkout keeps JAX's persistent compile cache, or
+    ``None`` where ``JAX_COMPILATION_CACHE_DIR`` is exported: JAX reads
+    that itself and nothing is set in code.  One fixed path otherwise --
+    the path is part of JAX's cache key, so a directory that moves
+    between runs never hits."""
+    if environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return None
+    return os.path.join(CACHE_ROOT, "xla")
 
 
 class MXNetError(RuntimeError):

@@ -49,24 +49,12 @@ _VARS = [
     EnvVar("MXNET_TPU_EAGER_JIT", bool, True,
            "Per-op persistent jit cache for eager NDArray ops.  '0' "
            "falls back to uncached dispatch (debugging)."),
-    EnvVar("MXNET_TPU_COMPILATION_CACHE", bool, True,
-           "Persist compiled XLA programs to disk so later processes "
-           "start hot (the reference's analog is cuDNN autotune "
-           "caching).  '0' disables."),
-    EnvVar("MXNET_TPU_COMPILATION_CACHE_DIR", str,
-           "~/.cache/mxnet_tpu/xla/<fingerprint>",
-           "Directory for the persistent compilation cache.  When unset, "
-           "a per-build subdirectory of ~/.cache/mxnet_tpu/xla keyed on "
-           "the jax/jaxlib/libtpu versions and host CPU model+flags is "
-           "used, so a home directory shared across machines or compiler "
-           "upgrades never serves stale AOT executables (SIGILL / "
-           "libtpu-version-mismatch hazard).  Setting the var explicitly "
-           "bypasses the fingerprinting."),
     EnvVar("MXNET_TPU_NATIVE", bool, True,
            "Build/load the native C++ components (recordio engine, "
            "predict runtime).  '0' forces the pure-Python paths."),
-    EnvVar("MXNET_TPU_NATIVE_CACHE", str, "~/.cache/mxnet_tpu/native",
-           "Directory where on-demand native builds are cached."),
+    EnvVar("MXNET_TPU_NATIVE_CACHE", str, "",
+           "Directory where on-demand native builds are cached.  "
+           "Unset: .mxnet_tpu_cache/native inside the checkout."),
     EnvVar("MXNET_OPTIMIZER_AGGREGATION_SIZE", int, 60,
            "Max tensors fused into one multi-tensor optimizer update "
            "(reference: same knob)."),
@@ -88,12 +76,6 @@ _VARS = [
            "pending region as ONE jitted program at the next sync point "
            "(the reference's MXNET_EXEC_BULK_EXEC_TRAIN analog).  '0' "
            "dispatches each eager op individually."),
-    EnvVar("MXNET_TPU_TEST_PLATFORM", str, "cpu",
-           "Backend the test suite pins via jax.config (tests/"
-           "conftest.py).  The suite's contract is 8 virtual CPU "
-           "devices; set e.g. 'tpu' for a deliberate on-device run.  "
-           "A dedicated var because JAX_PLATFORMS itself is forced by "
-           "some environments and cannot carry user intent."),
     EnvVar("MXNET_TPU_BENCH_BUDGET_S", float, 1500.0,
            "Wall-clock budget (seconds) for bench.py: headline metrics "
            "emit first, and optional configs that would exceed the "
@@ -250,9 +232,9 @@ _VARS = [
            "= longest admissible prompt), one warmed executable per "
            "bucket.  Per-model override: register_generative("
            "prefill_buckets=...)."),
-    EnvVar("MXNET_TPU_SERVING_CACHE_DIR", str,
-           "~/.cache/mxnet_tpu/serving",
-           "Directory of the persistent serving compile cache: "
+    EnvVar("MXNET_TPU_SERVING_CACHE_DIR", str, "",
+           "Directory of the persistent serving compile cache "
+           "(unset: .mxnet_tpu_cache/serving inside the checkout): "
            "per-bucket servable programs serialized via jax.export, "
            "keyed on the normalized-StableHLO fingerprint, so a new "
            "serving process warms registration from disk.  Disable "

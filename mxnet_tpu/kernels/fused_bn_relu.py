@@ -27,6 +27,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..ops.pallas.tiling import row_block
 from .registry import KernelSpec, choose, register_kernel
 
 try:  # pallas import kept lazy-safe: CPU-only builds fall back to XLA
@@ -36,11 +37,7 @@ except Exception:  # pragma: no cover
     _HAS_PALLAS = False
 
 
-def _best_block(rows, want):
-    b = max(1, min(want, rows))
-    while rows % b:
-        b -= 1          # largest divisor <= requested block
-    return b
+BLOCK_ROWS = 256
 
 
 # ----------------------------------------------------------------------
@@ -54,13 +51,13 @@ def _apply_fwd_kernel(x_ref, s_ref, o_ref, out_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
-def bn_relu_apply_pallas(x2d, scale, offset, block_rows=256,
+def bn_relu_apply_pallas(x2d, scale, offset, block_rows=BLOCK_ROWS,
                          interpret=False):
     """``relu(x2d * scale + offset)`` over (rows, C); ``scale``/
     ``offset`` are the folded per-channel (1, C) fp32 vectors
     ``gamma*rsqrt(var+eps)`` and ``beta - mean*gamma*rsqrt(var+eps)``."""
     rows, c = x2d.shape
-    block_rows = _best_block(rows, block_rows)
+    block_rows = row_block(rows, block_rows)
     vec = pl.BlockSpec((1, c), lambda i: (0, 0))
     return pl.pallas_call(
         _apply_fwd_kernel,
@@ -91,13 +88,13 @@ def _apply_bwd_kernel(x_ref, dy_ref, y_ref, a_ref, m_ref, i_ref,
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "interpret"))
 def bn_relu_bwd_pallas(x2d, dy2d, y2d, a, mean, inv, c1, c2,
-                       block_rows=256, interpret=False):
+                       block_rows=BLOCK_ROWS, interpret=False):
     """dx of fused BN+ReLU over (rows, C).  Per-channel (1, C) fp32
     vectors: ``a = gamma*inv``; ``c1``/``c2`` the mean-reduced
     ``dyr`` / ``dyr*xhat`` (zeros in inference mode, where the batch
     statistics are constants)."""
     rows, c = x2d.shape
-    block_rows = _best_block(rows, block_rows)
+    block_rows = row_block(rows, block_rows)
     row_spec = pl.BlockSpec((block_rows, c), lambda i: (i, 0))
     vec = pl.BlockSpec((1, c), lambda i: (0, 0))
     return pl.pallas_call(
@@ -195,7 +192,8 @@ def fused_bn_relu(data, gamma, beta, moving_mean, moving_var, eps=1e-5,
     with the same functional contract as the ``BatchNorm`` op plus the
     relu epilogue.  Kernel-vs-fallback is decided ONCE here through the
     registry (``choose('fused_bn_relu')``)."""
-    ch = choose("fused_bn_relu", axis=axis, ndim=data.ndim)
+    ch = choose("fused_bn_relu", axis=axis, ndim=data.ndim,
+                rows=data.size // max(data.shape[axis], 1))
     if not ch.use_pallas:
         return xla_reference(data, gamma, beta, moving_mean, moving_var,
                              eps=eps, momentum=momentum,
@@ -236,13 +234,17 @@ def fused_bn_relu(data, gamma, beta, moving_mean, moving_var, eps=1e-5,
             lax.stop_gradient(new_var))
 
 
-def _supports(axis=1, ndim=4, **_kw):
-    if axis in (-1, ndim - 1):
-        return True, ""
-    return False, ("fused_bn_relu is NHWC-native (channels-last); "
-                   "axis=%d of a %d-d input falls back to XLA -- "
-                   "moving the channel axis would pay the transpose "
-                   "traffic the kernel removes" % (axis, ndim))
+def _supports(axis=1, ndim=4, rows=None, **_kw):
+    if axis not in (-1, ndim - 1):
+        return False, ("fused_bn_relu is NHWC-native (channels-last); "
+                       "axis=%d of a %d-d input falls back to XLA -- "
+                       "moving the channel axis would pay the transpose "
+                       "traffic the kernel removes" % (axis, ndim))
+    if rows is not None and row_block(rows, BLOCK_ROWS) is None:
+        return False, ("fused_bn_relu tiles N*H*W in row blocks that "
+                       "are a multiple of 8; %d rows has no such "
+                       "divisor <= %d" % (rows, BLOCK_ROWS))
+    return True, ""
 
 
 register_kernel(KernelSpec(

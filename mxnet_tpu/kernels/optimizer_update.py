@@ -45,22 +45,19 @@ except Exception:  # pragma: no cover
 LANE = 128
 
 
-def _pad2d(v, lane=LANE):
-    """Flat (n,) -> (rows, lane) zero-padded, for the 2-D tiling the
-    TPU vector memory wants."""
+def _pad2d(v, block_rows, lane=LANE):
+    """Flat (n,) -> (rows, lane) zero-padded to a whole number of
+    ``block_rows``-row grid steps (the TPU lowering takes row blocks
+    that are a multiple of 8 or the whole extent, and a flat parameter
+    buffer's row count has no useful divisors -- ResNet-50: 199,665)."""
     n = v.shape[0]
     rows = -(-n // lane)
+    if rows > block_rows:
+        rows = -(-rows // block_rows) * block_rows
     pad = rows * lane - n
     if pad:
         v = jnp.pad(v, (0, pad))
     return v.reshape(rows, lane)
-
-
-def _best_block(rows, want):
-    b = max(1, min(want, rows))
-    while rows % b:
-        b -= 1
-    return b
 
 
 def _expand(per_tensor, sizes, total):
@@ -116,9 +113,9 @@ def lars_flat_pallas(w, g, m, lr, wd, sign, rescale, momentum=0.9,
     """The flat momentum update as ONE Pallas kernel over the padded
     (rows, 128) view of the concatenated buffer."""
     n = w.shape[0]
-    ops2d = [_pad2d(v) for v in (w, g, m, lr, wd, sign)]
+    ops2d = [_pad2d(v, block_rows) for v in (w, g, m, lr, wd, sign)]
     rows, lane = ops2d[0].shape
-    block_rows = _best_block(rows, block_rows)
+    block_rows = min(block_rows, rows)
     row = pl.BlockSpec((block_rows, lane), lambda i: (i, 0))
     scalar = pl.BlockSpec((1, 1), lambda i: (0, 0))
     rs = jnp.asarray(rescale, jnp.float32).reshape(1, 1)
@@ -259,9 +256,9 @@ def lamb_phase1_pallas(w, g, m, v, wd, scalars, beta1=0.9, beta2=0.999,
                        eps=1e-6, clip=0.0, block_rows=64,
                        interpret=False):
     n = w.shape[0]
-    ops2d = [_pad2d(x) for x in (w, g, m, v, wd)]
+    ops2d = [_pad2d(x, block_rows) for x in (w, g, m, v, wd)]
     rows, lane = ops2d[0].shape
-    block_rows = _best_block(rows, block_rows)
+    block_rows = min(block_rows, rows)
     row = pl.BlockSpec((block_rows, lane), lambda i: (i, 0))
     sc = pl.BlockSpec((1, 3), lambda i: (0, 0))
     gw, nm, nv = pl.pallas_call(

@@ -49,11 +49,10 @@ def _has_pallas() -> bool:
 
 
 def _backend() -> str:
-    try:
-        import jax
-        return jax.default_backend()
-    except Exception:  # pragma: no cover
-        return "cpu"
+    # a backend that fails to initialise (chip held by another process)
+    # raises here: answering "cpu" would turn it into "XLA path chosen"
+    import jax
+    return jax.default_backend()
 
 
 @dataclass(frozen=True)

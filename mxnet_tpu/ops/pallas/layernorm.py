@@ -15,6 +15,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from .tiling import row_block
+
 try:
     from jax.experimental import pallas as pl
     _HAS_PALLAS = True
@@ -41,9 +43,11 @@ def layernorm_fwd_pallas(x, gamma, beta, eps=1e-5, block_rows=128,
     rows, dim = x.shape
     if rows == 0:
         return x
-    block_rows = min(block_rows, rows)
-    while rows % block_rows != 0:
-        block_rows -= 1          # largest divisor <= requested block
+    block_rows = row_block(rows, block_rows)
+    if block_rows is None:
+        raise ValueError(
+            "layernorm_fwd_pallas tiles rows in blocks that are a "
+            "multiple of 8; %d rows has no such divisor" % rows)
     grid = (rows // block_rows,)
     kernel = functools.partial(_ln_kernel, eps=eps)
     return pl.pallas_call(

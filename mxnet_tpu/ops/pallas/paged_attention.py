@@ -15,9 +15,10 @@ Layout: q ``(slots, heads, head_dim)``; per-layer cache slabs
 ``(num_blocks, block_size, heads, head_dim)``; ``block_tables``
 ``(slots, max_blocks)`` int32; ``context_lens`` ``(slots, 1)`` int32
 (tokens 0..ctx-1 are live).  fp32 accumulation regardless of cache
-dtype.  The whole slab pair is presented to each program (VMEM-bounded
-on real hardware -- sized for the serving tier's preallocated caches;
-interpret mode has no such bound).
+dtype.  The block table and context lengths are scalar-prefetched into
+SMEM and the grid is ``(slots, max_blocks)``: each step's K/V block is
+the ONE cache block the table names, copied HBM->VMEM by the pipeline,
+so VMEM holds two blocks per operand whatever the cache size.
 """
 from __future__ import annotations
 
@@ -30,7 +31,7 @@ NEG_INF = -1e30
 
 try:  # pallas import kept lazy-safe: CPU-only builds fall back to XLA
     from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu  # noqa: F401
+    from jax.experimental.pallas import tpu as pltpu
     _HAS_PALLAS = True
 except Exception:  # pragma: no cover
     _HAS_PALLAS = False
@@ -66,21 +67,28 @@ def paged_attention_reference(q, k_cache, v_cache, block_tables,
 
 
 # ----------------------------------------------------------------------
-# Pallas kernel: grid over slots, online softmax across table blocks
+# Pallas kernel: grid (slots, table blocks), online softmax carried in
+# VMEM scratch across a slot's blocks
 # ----------------------------------------------------------------------
 
-def _decode_kernel(q_ref, k_ref, v_ref, bt_ref, ctx_ref, o_ref, *,
-                   block_size, scale, max_blocks):
-    q = q_ref[0].astype(jnp.float32)              # (heads, d)
-    heads, d = q.shape
-    ctx = ctx_ref[0, 0]
-    num_blocks = jax.lax.div(ctx + block_size - 1, block_size)
+def _decode_kernel(bt_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
+                   m_ref, l_ref, acc_ref, *, block_size, scale):
+    slot = pl.program_id(0)
+    j = pl.program_id(1)
+    ctx = ctx_ref[slot]
 
-    def body(j, carry):
-        m, l, acc = carry
-        blk = bt_ref[0, j]
-        k = k_ref[blk].astype(jnp.float32)        # (bs, heads, d)
-        v = v_ref[blk].astype(jnp.float32)
+    @pl.when(j == 0)
+    def _():
+        m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
+        l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
+        acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
+
+    @pl.when(j * block_size < ctx)
+    def _():
+        q = q_ref[0].astype(jnp.float32)          # (heads, d)
+        heads = q.shape[0]
+        k = k_ref[0].astype(jnp.float32)          # (bs, heads, d)
+        v = v_ref[0].astype(jnp.float32)
         # (heads, 1, d) x (heads, bs, d) -> (heads, 1, bs): one query
         # row per head against the block's keys
         s = jax.lax.dot_general(
@@ -90,22 +98,24 @@ def _decode_kernel(q_ref, k_ref, v_ref, bt_ref, ctx_ref, o_ref, *,
         tpos = j * block_size + jax.lax.broadcasted_iota(
             jnp.int32, (heads, block_size), 1)
         s = jnp.where(tpos < ctx, s, NEG_INF)     # (heads, bs)
+        m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
         p = jnp.exp(s - m_new)
         alpha = jnp.exp(m - m_new)
-        l_new = alpha * l + jnp.sum(p, axis=-1, keepdims=True)
+        l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1,
+                                                  keepdims=True)
         # (heads, 1, bs) x (heads, bs, d) -> (heads, d)
         pv = jax.lax.dot_general(
             p[:, None, :], v.transpose(1, 0, 2),
             (((2,), (1,)), ((0,), (0,))),
             preferred_element_type=jnp.float32)[:, 0, :]
-        return m_new, l_new, acc * alpha + pv
+        acc_ref[...] = acc_ref[...] * alpha + pv
+        m_ref[...] = m_new
 
-    m0 = jnp.full((heads, 1), NEG_INF, jnp.float32)
-    l0 = jnp.zeros((heads, 1), jnp.float32)
-    acc0 = jnp.zeros((heads, d), jnp.float32)
-    _m, l, acc = jax.lax.fori_loop(0, num_blocks, body, (m0, l0, acc0))
-    o_ref[0] = (acc / jnp.maximum(l, 1e-30)).astype(o_ref.dtype)
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
+                    ).astype(o_ref.dtype)
 
 
 @functools.partial(jax.jit, static_argnames=("scale", "interpret"))
@@ -115,23 +125,32 @@ def paged_attention_pallas(q, k_cache, v_cache, block_tables,
     (slots, mb) int32; context_lens (slots, 1) int32 -> (slots, heads,
     d)."""
     slots, heads, d = q.shape
-    nb, bs, _, _ = k_cache.shape
+    _nb, bs, _, _ = k_cache.shape
     mb = block_tables.shape[1]
-    kernel = functools.partial(_decode_kernel, block_size=bs,
-                               scale=scale, max_blocks=mb)
-    cache_spec = pl.BlockSpec((nb, bs, heads, d),
-                              lambda s: (0, 0, 0, 0))
+
+    def kv_block(s, j, bt, ctx):
+        # steps past the slot's last live block name that block again:
+        # an unchanged block index is not re-fetched, so dead steps
+        # cost no DMA (the body skips them)
+        last = jnp.maximum(ctx[s] - 1, 0) // bs
+        return (bt[s, jnp.minimum(j, last)], 0, 0, 0)
+
+    def q_block(s, j, bt, ctx):
+        return (s, 0, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
+        grid=(slots, mb),
+        in_specs=[pl.BlockSpec((1, heads, d), q_block),
+                  pl.BlockSpec((1, bs, heads, d), kv_block),
+                  pl.BlockSpec((1, bs, heads, d), kv_block)],
+        out_specs=pl.BlockSpec((1, heads, d), q_block),
+        scratch_shapes=[pltpu.VMEM((heads, 1), jnp.float32),
+                        pltpu.VMEM((heads, 1), jnp.float32),
+                        pltpu.VMEM((heads, d), jnp.float32)])
     return pl.pallas_call(
-        kernel,
+        functools.partial(_decode_kernel, block_size=bs, scale=scale),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
-        grid=(slots,),
-        in_specs=[
-            pl.BlockSpec((1, heads, d), lambda s: (s, 0, 0)),
-            cache_spec,
-            cache_spec,
-            pl.BlockSpec((1, mb), lambda s: (s, 0)),
-            pl.BlockSpec((1, 1), lambda s: (s, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, heads, d), lambda s: (s, 0, 0)),
+        grid_spec=grid_spec,
         interpret=interpret,
-    )(q, k_cache, v_cache, block_tables, context_lens)
+    )(block_tables, context_lens.reshape(slots), q, k_cache, v_cache)

@@ -283,8 +283,13 @@ def _flash_attention_op(q, k, v, causal=False, scale=-1.0, use_pallas=None,
     block_q, block_k = int(block_q), int(block_k)
     ch = _kernel_choice(q.shape[1], block_q, block_k, use_pallas)
     if ch.use_pallas:
-        return _flash(q, k, v, causal, scale, block_q, block_k, True,
-                      ch.interpret)
+        # (batch*heads, seq, d) is batch-major: under a dp-sharded
+        # batch the kernels run per shard (XLA cannot partition them)
+        from ..parallel.mesh import shard_over_batch
+        return shard_over_batch(
+            lambda q, k, v: _flash(q, k, v, causal, scale, block_q,
+                                   block_k, True, ch.interpret),
+            q, k, v)
     return _attention_reference(q, k, v, causal, scale)
 
 
@@ -303,7 +308,13 @@ def _flash_attention_masked_op(q, k, v, mask, scale=-1.0, use_pallas=None,
     maskf = mask.astype(jnp.float32)
     ch = _kernel_choice(q.shape[1], block_q, block_k, use_pallas)
     if ch.use_pallas:
-        return _flash_masked(q, k, v, maskf, scale, block_q, block_k,
-                             True, heads, ch.interpret)
+        # a shard's rows map onto its OWN slice of the (batch, seq,
+        # seq) mask: local bh // heads is the local batch index
+        from ..parallel.mesh import shard_over_batch
+        return shard_over_batch(
+            lambda q, k, v, m: _flash_masked(q, k, v, m, scale, block_q,
+                                             block_k, True, heads,
+                                             ch.interpret),
+            q, k, v, maskf)
     return _attention_reference_masked(
         q, k, v, jnp.repeat(maskf, heads, axis=0), scale)

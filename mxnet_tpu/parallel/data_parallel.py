@@ -32,7 +32,8 @@ from ..base import MXNetError
 from ..ndarray import NDArray
 from .. import profiling as _profiling
 from .. import random as _random_mod
-from .mesh import global_mesh, put_replicated, stage_process_local
+from .mesh import (batch_sharded, global_mesh, put_replicated,
+                   stage_process_local)
 
 __all__ = ["replicate_block", "shard_batch", "split_and_load", "TrainStep"]
 
@@ -257,15 +258,6 @@ class TrainStep:
         self._mesh = mesh
         self._batch_axis = batch_axis
         self._axis_name = axis_name
-        if donate and mesh is not None and jax.process_count() > 1 \
-                and jax.default_backend() == "cpu":
-            # jaxlib 0.4.x gloo CPU collectives + donated buffers
-            # corrupt the heap after a few dispatches (glibc "corrupted
-            # double-linked list" abort, reproduced in-suite); donation
-            # is an HBM optimization with no meaning for host memory,
-            # so the multi-process CPU/gloo path runs undonated.  TPU
-            # pods (ICI collectives) keep donation.
-            donate = False
         self._donate = donate
         self._cache = {}
         if mesh is not None:
@@ -340,6 +332,12 @@ class TrainStep:
         idxs = self._diff_indices()
         pure_fn, pnames, pmap = block.functionalize(training=training)
         name_by_idx = {i: tr._params[i].name for i in idxs}
+        # entered by the traced bodies themselves, so every (re)trace
+        # -- the dispatch, an AOT lowering for cost analysis -- tells
+        # kernels XLA cannot partition which axis the batch is split over
+        scope = batch_sharded(self._mesh, self._axis_name)
+
+        @scope
         def step_fn(pvals, svals, data, label, rng, t, lrs, wds, rescale,
                     loss_scale):
             def loss_of(diff_pvals):
@@ -410,6 +408,7 @@ class TrainStep:
                                                  svals.get(i))
             return new_w, new_s, aux, mean_loss, all_finite
 
+        @scope
         def probe_fn(pvals, data, label, rng, loss_scale):
             # failure-path attribution (numerics sentinel): recompute
             # the gradients from the SAME params/batch/rng -- on a
@@ -631,10 +630,7 @@ class TrainStep:
             return None
         fn, arg_shapes = self._last_call
         try:
-            ca = fn.lower(*arg_shapes).compile().cost_analysis()
-            if isinstance(ca, list):
-                ca = ca[0]
-            return dict(ca)
+            return dict(fn.lower(*arg_shapes).compile().cost_analysis())
         except Exception:
             return None
 

@@ -8,6 +8,8 @@ picking mesh axes (SURVEY.md §2.4).
 """
 from __future__ import annotations
 
+import contextlib
+import threading
 from collections import OrderedDict
 
 import numpy as np
@@ -19,7 +21,8 @@ from ..base import MXNetError
 
 __all__ = ["make_mesh", "Mesh", "NamedSharding", "PartitionSpec",
            "local_devices", "default_mesh", "global_mesh", "AXIS_ROLES",
-           "put_replicated", "stage_process_local"]
+           "put_replicated", "stage_process_local", "batch_sharded",
+           "shard_over_batch"]
 
 # Canonical mesh-axis vocabulary.  Axis names are arbitrary strings to
 # XLA, but the parallel layers, the docs, and the sharding sanitizer
@@ -149,3 +152,42 @@ def stage_process_local(x, sharding):
     # explicit staging primitive: see put_replicated's guard note
     with jax.transfer_guard("allow"):
         return jax.make_array_from_process_local_data(sharding, x)
+
+
+# ----------------------------------------------------------------------
+# Kernels under a sharded batch
+# ----------------------------------------------------------------------
+# XLA's SPMD partitioner splits ordinary HLO over the mesh by itself,
+# but not a Mosaic (Pallas/TPU) custom call: the TPU lowering refuses
+# one inside a partitioned program ("Mosaic kernels cannot be
+# automatically partitioned").  ``TrainStep`` therefore says, while its
+# step is traced, which mesh axis the batch is split over, and a kernel
+# whose leading dimension is batch-major runs itself per shard.
+
+_batch_scope = threading.local()
+
+
+@contextlib.contextmanager
+def batch_sharded(mesh, axis_name):
+    """Trace-time scope: the batch of the program being traced is
+    sharded over ``axis_name`` of ``mesh`` (no-op for ``mesh=None``)."""
+    prev = getattr(_batch_scope, "value", None)
+    _batch_scope.value = (mesh, axis_name) if mesh is not None else None
+    try:
+        yield
+    finally:
+        _batch_scope.value = prev
+
+
+def shard_over_batch(fn, *arrays):
+    """``fn(*arrays)`` -- run per batch shard through ``jax.shard_map``
+    when the program being traced shards its batch over more than one
+    device (:func:`batch_sharded`), plainly otherwise.  Every array's
+    leading dimension must be batch-major; so must the result's."""
+    scope = getattr(_batch_scope, "value", None)
+    if scope is None or scope[0].shape[scope[1]] == 1:
+        return fn(*arrays)
+    mesh, axis = scope
+    spec = PartitionSpec(axis)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * len(arrays),
+                         out_specs=spec, check_vma=False)(*arrays)

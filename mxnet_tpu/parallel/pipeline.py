@@ -49,8 +49,7 @@ def pipeline_apply(stage_fn, stacked_params, microbatches, mesh,
     (M, mb, ...) outputs.  Differentiable end-to-end (ppermute
     transposes to the reverse rotation).
     """
-    from ._shard_map import shard_map as _sm
-    shard_map = functools.partial(_sm, check_vma=False)
+    shard_map = functools.partial(jax.shard_map, check_vma=False)
 
     S = mesh.shape[axis]
     M = microbatches.shape[0]

@@ -19,9 +19,8 @@ import math
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
-
-from ._shard_map import shard_map
 
 from ..base import MXNetError
 

@@ -136,9 +136,7 @@ def _render_report(comb):
         bound = ""
         rl = rep.get("roofline")
         if rl and top in rl["categories"]:
-            bound = " (%s-bound%s)" % (
-                rl["categories"][top]["bound"],
-                ", peaks assumed" if rl["peaks_assumed"] else "")
+            bound = " (%s-bound)" % rl["categories"][top]["bound"]
         lines.append("  %-36s %-16s %12s %12s %12s  %s%s"
                      % (rep["label"][:36], rep["fingerprint"],
                         _fmt_flops(rep["totals"]["flops"]),

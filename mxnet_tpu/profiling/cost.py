@@ -59,10 +59,7 @@ def analyze_compiled(compiled, label="executable", kind="jit", **meta):
 
     totals = {"flops": 0.0, "bytes_accessed": 0.0, "transcendentals": 0.0}
     try:
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):
-            ca = ca[0] if ca else {}
-        ca = dict(ca or {})
+        ca = dict(compiled.cost_analysis() or {})
         totals["flops"] = float(ca.get("flops", 0.0))
         totals["bytes_accessed"] = float(ca.get("bytes accessed", 0.0))
         totals["transcendentals"] = float(ca.get("transcendentals", 0.0))

@@ -9,51 +9,60 @@ number decomposes into "the convs are compute-bound at X%, the
 layout ops are pure bandwidth": the ceiling analysis ROADMAP item 2
 asks for.
 
-Peaks come from a device-kind table (TPU generations) or conservative
-assumed defaults (CPU/dev boxes) -- ``peaks_assumed`` in the output
-says which, so a CI roofline is never mistaken for chip truth.
+Peaks come from ONE table keyed by ``device_kind``.  A CPU has no
+entry and gets no roofline (a host's peak is not assumed); an
+accelerator kind the table does not hold is an error, not a default.
 """
 from __future__ import annotations
 
-# (peak bf16 FLOP/s, peak HBM bytes/s) by device-kind prefix.  Sources:
-# published TPU spec sheets; the bench's MFU table uses the same FLOPs.
-_DEVICE_PEAKS = (
-    ("TPU v5 lite", 197e12, 819e9),
-    ("TPU v5e", 197e12, 819e9),
-    ("TPU v5", 459e12, 2765e9),
-    ("TPU v4", 275e12, 1228e9),
-    ("TPU v3", 123e12, 900e9),
-    ("TPU v2", 45e12, 700e9),
-)
+from ..base import MXNetError
 
-# dev-box fallback so the roofline SECTION always renders (CI runs on
-# CPU); flagged assumed=True and sized for a generic server core
-_ASSUMED_PEAKS = (5e11, 5e10)
+# device_kind (as jax reports it) -> (peak bf16 FLOP/s, peak HBM
+# bytes/s) of one chip.  Source: Google Cloud TPU documentation, the
+# system-architecture page of each generation ("TPU v5e": 197 TFLOP/s
+# bf16, 819 GB/s; "TPU v5p": 459 TFLOP/s, 2,765 GB/s; "TPU v4": 275
+# TFLOP/s, 1,228 GB/s; "TPU v3": 123 TFLOP/s, 900 GB/s; "TPU v2": 45
+# TFLOP/s, 700 GB/s).  jax names a v5e chip "TPU v5 lite" and a v5p
+# chip "TPU v5".
+DEVICE_PEAKS = {
+    "TPU v5 lite": (197e12, 819e9),
+    "TPU v5e": (197e12, 819e9),
+    "TPU v5": (459e12, 2765e9),
+    "TPU v4": (275e12, 1228e9),
+    "TPU v3": (123e12, 900e9),
+    "TPU v2": (45e12, 700e9),
+}
 
 
 def device_peaks(device_kind=None):
-    """(peak_flops, peak_bytes_per_s, assumed) for the current (or
-    named) device kind."""
+    """``(peak_flops, peak_bytes_per_s)`` of the current (or named)
+    device kind; ``None`` on a CPU; raises for an accelerator the table
+    does not know."""
     if device_kind is None:
-        try:
-            import jax
-            device_kind = jax.devices()[0].device_kind
-        except Exception:
-            device_kind = ""
-    for prefix, fl, bw in _DEVICE_PEAKS:
-        if device_kind.startswith(prefix):
-            return fl, bw, False
-    return _ASSUMED_PEAKS[0], _ASSUMED_PEAKS[1], True
+        import jax
+        device_kind = jax.devices()[0].device_kind
+    if device_kind.lower() == "cpu":
+        return None
+    try:
+        return DEVICE_PEAKS[device_kind]
+    except KeyError:
+        raise MXNetError(
+            "no published peak for device kind %r; add it to "
+            "profiling.roofline.DEVICE_PEAKS with its source (known: %s)"
+            % (device_kind, ", ".join(sorted(DEVICE_PEAKS)))) from None
 
 
 def build(report, step_time_s, peak_flops=None, peak_bytes_per_s=None,
           items_per_step=None):
-    """Roofline section dict for ``report`` at ``step_time_s``."""
-    fl, bw, assumed = device_peaks(report.get("device"))
+    """Roofline section dict for ``report`` at ``step_time_s``, or
+    ``None`` where the device has no peak (CPU) and none is passed."""
+    fl, bw = device_peaks(report.get("device")) or (None, None)
     if peak_flops is not None:
-        fl, assumed = peak_flops, False
+        fl = peak_flops
     if peak_bytes_per_s is not None:
         bw = peak_bytes_per_s
+    if fl is None or bw is None:
+        return None
     step_time_s = max(float(step_time_s), 1e-12)
     tot_f = report["totals"]["flops"]
     tot_b = report["totals"]["bytes_accessed"]
@@ -82,7 +91,6 @@ def build(report, step_time_s, peak_flops=None, peak_bytes_per_s=None,
         "step_time_s": step_time_s,
         "peak_flops": fl,
         "peak_bytes_per_s": bw,
-        "peaks_assumed": assumed,
         "ridge_intensity": round(ridge, 3),
         "achieved_flops_per_s": achieved_f,
         "achieved_bytes_per_s": achieved_b,

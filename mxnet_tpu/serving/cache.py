@@ -7,8 +7,9 @@ stripped), fingerprints the program.  The serialized ``jax.export``
 artifact is committed under that fingerprint, so the *next* process
 that registers the same model/bucket deserializes a portable program
 instead of re-tracing Python -- and, stacked on the framework-wide
-persistent XLA compilation cache (``MXNET_TPU_COMPILATION_CACHE``),
-its warm-up compile is served from disk too.
+persistent XLA compilation cache (``JAX_COMPILATION_CACHE_DIR``, or
+``.mxnet_tpu_cache/xla`` in the checkout), its warm-up compile is
+served from disk too.
 
 Artifacts are committed through ``checkpoint.core.atomic_write_bytes``
 (tmp+fsync+rename), so a process killed mid-store can never leave a
@@ -21,7 +22,7 @@ import re
 
 from .. import telemetry as _telemetry
 
-__all__ = ["CompileCache", "stablehlo_fingerprint"]
+__all__ = ["CompileCache", "compile_through", "stablehlo_fingerprint"]
 
 # StableHLO normalization: jax stamps every op line with a loc(#locN)
 # reference and appends a #locN = loc("file":line:col) table; the module
@@ -48,7 +49,32 @@ def stablehlo_fingerprint(text):
 
 def default_cache_dir():
     from .. import env as _env
-    return os.path.expanduser(_env.get("MXNET_TPU_SERVING_CACHE_DIR"))
+    from ..base import CACHE_ROOT
+    return os.path.expanduser(_env.get("MXNET_TPU_SERVING_CACHE_DIR")) \
+        or os.path.join(CACHE_ROOT, "serving")
+
+
+def compile_through(cache, key, jfn, lowered, specs):
+    """AOT-compile one servable program whose lowering is ``lowered``
+    (``jfn.lower(*specs)``, fingerprint ``key``).  With a cache, the
+    program compiled is the ``jax.export`` wrapper of the artifact --
+    the SAME wrapper whether the artifact was just made or read back,
+    so the process that reads it back finds this compile in the
+    persistent XLA cache; it is compiled here, not at the first
+    request.  A program that cannot be exported is compiled as is."""
+    import jax
+    if cache is not None:
+        exported = cache.get(key)
+        if exported is None:
+            try:
+                from jax import export as jexport
+                exported = jexport.export(jfn)(*specs)
+                cache.put(key, exported)
+            except Exception:
+                exported = None
+        if exported is not None:
+            return jax.jit(exported.call).lower(*specs).compile()
+    return lowered.compile()
 
 
 class CompileCache:

@@ -50,7 +50,7 @@ from ... import sync as _sync
 from ... import telemetry as _telemetry
 from ...base import MXNetError
 from ..batcher import RequestTimeout, ServableClosed, ServingQueueFull
-from ..cache import stablehlo_fingerprint
+from ..cache import compile_through, stablehlo_fingerprint
 from ..loop import RegistryWatcher as _RegistryWatcher
 from .kvcache import SCRATCH_BLOCK, KVCacheExhausted, PagedKVCache
 
@@ -178,19 +178,9 @@ class _AotPrograms:
         jfn = jax.jit(fn)
         lowered = jfn.lower(*specs)
         fp = stablehlo_fingerprint(lowered.as_text())
-        call = None
-        if self._cache is not None:
-            exported = self._cache.get(fp)
-            if exported is not None:
-                call = jax.jit(exported.call)
-        if call is None:
-            call = lowered.compile()
-            if self._cache is not None:
-                try:
-                    from jax import export as jexport
-                    self._cache.put(fp, jexport.export(jfn)(*specs))
-                except Exception:
-                    pass        # a cold next process, not an error now
+        # compiled here, never at the first request: warmup() promises
+        # that no request pays a compile
+        call = compile_through(self._cache, fp, jfn, lowered, specs)
         self._programs[key] = call
         self.fingerprints[key] = fp
         return call

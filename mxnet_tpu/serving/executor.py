@@ -21,7 +21,7 @@ import numpy as np
 
 from .. import telemetry as _telemetry
 from ..base import MXNetError
-from .cache import stablehlo_fingerprint
+from .cache import compile_through, stablehlo_fingerprint
 
 __all__ = ["BucketExecutorPool"]
 
@@ -127,23 +127,8 @@ class BucketExecutorPool:
         jfn = jax.jit(self._fn)
         lowered = jfn.lower(pspecs, xspec)
         key = stablehlo_fingerprint(lowered.as_text())
-        call = None
-        if self._cache is not None:
-            exported = self._cache.get(key)
-            if exported is not None:
-                # cache hit: the portable artifact replaces re-tracing;
-                # jit-wrap so XLA compiles it once (persistent XLA cache
-                # makes that compile itself warm across processes)
-                call = jax.jit(exported.call)
-        if call is None:
-            call = lowered.compile()
-            if self._cache is not None:
-                try:
-                    from jax import export as jexport
-                    self._cache.put(key,
-                                    jexport.export(jfn)(pspecs, xspec))
-                except Exception:
-                    pass        # a cold next process, not an error now
+        call = compile_through(self._cache, key, jfn, lowered,
+                               (pspecs, xspec))
         self._compiled[bucket] = call
         self._fingerprints[bucket] = key
         self._register_profiling(bucket, jfn, (pspecs, xspec))

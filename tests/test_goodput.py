@@ -209,7 +209,7 @@ def test_env_guard_degraded_env_not_regression(telem):
     for _ in range(4):
         _window(led, {"profiling.step_time": 0.004})
     base_before = led.baseline()["device_compute"]["mean"]
-    # the bench health probe's gauge says the tunnel collapsed
+    # the bench health probe's gauge says the environment degraded
     telemetry.gauge("env.dispatch_roundtrip_us").set(90000.0)
     w = _window(led, {"profiling.step_time": 0.015})  # ~4x slower
     assert w["env_degraded"] is True
@@ -219,7 +219,7 @@ def test_env_guard_degraded_env_not_regression(telem):
     assert ev["dispatch_roundtrip_us"] == 90000.0
     assert led.baseline()["device_compute"]["mean"] == \
         pytest.approx(base_before)
-    # tunnel recovers: the same slowdown now IS a regression
+    # environment recovers: the same slowdown now IS a regression
     telemetry.gauge("env.dispatch_roundtrip_us").set(2.0)
     w2 = _window(led, {"profiling.step_time": 0.015})
     assert w2["env_degraded"] is False
@@ -244,17 +244,26 @@ def test_env_degraded_threshold_matches_bench_flag():
 
 # -- MFU ---------------------------------------------------------------
 
-def test_mfu_from_flops_per_step(telem):
-    from mxnet_tpu.profiling import roofline
+def test_no_mfu_without_a_device_peak(telem):
+    """A CPU has no published peak and none is assumed: the window
+    carries its flops and no MFU."""
     led = StepLedger(window_steps=4, flops_per_step=1e9)
     w = _window(led, {"profiling.step_time": 0.005})
-    peak, _bw, _assumed = roofline.device_peaks()
     assert w["flops"] == pytest.approx(4e9)
-    assert w["mfu"] == pytest.approx(4e9 / w["wall_s"] / peak, rel=0.01)
+    assert w["mfu"] is None
+
+
+def test_mfu_from_flops_per_step(telem, v5e_peaks):
+    from mxnet_tpu.profiling import roofline
+    led = StepLedger(window_steps=4, flops_per_step=1e12)
+    w = _window(led, {"profiling.step_time": 0.005})
+    peak, _bw = roofline.device_peaks()
+    assert w["flops"] == pytest.approx(4e12)
+    assert w["mfu"] == pytest.approx(4e12 / w["wall_s"] / peak, rel=0.01)
     assert telemetry.gauge("goodput.mfu").value == w["mfu"]
 
 
-def test_mfu_from_profiling_store(telem):
+def test_mfu_from_profiling_store(telem, v5e_peaks):
     """flops_per_step resolves from the captured TrainStep's CostReport
     (the 'executable's cost report' MFU source the issue names)."""
     import mxnet_tpu as mx

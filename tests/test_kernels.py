@@ -337,6 +337,50 @@ def test_causal_flash_op_pallas_matches_xla(kernels_on):
         np.testing.assert_allclose(a, b, rtol=2e-3, atol=2e-3)
 
 
+def test_flash_kernels_run_per_shard_under_a_sharded_batch(kernels_on):
+    """XLA cannot partition a Mosaic custom call (the TPU lowering
+    refuses one inside a partitioned program), so while a program whose
+    batch is dp-sharded is traced the flash ops run their kernels per
+    shard through shard_map -- plain and masked, forward and backward,
+    equal to the unsharded result."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from mxnet_tpu.ops.transformer import (_flash_attention_masked_op,
+                                           _flash_attention_op)
+    from mxnet_tpu.parallel import make_mesh
+    from mxnet_tpu.parallel.mesh import batch_sharded
+    rng = np.random.RandomState(6)
+    q, k, v = (jnp.asarray(rng.randn(BH, SEQ, D), jnp.float32)
+               for _ in range(3))
+    mask = jnp.asarray(_mask_np())
+
+    def loss(q, k, v, mask):
+        a = _flash_attention_op.fcompute(q, k, v, block_q=32, block_k=32)
+        b = _flash_attention_masked_op.fcompute(
+            q, k, v, mask, heads=HEADS, block_q=32, block_k=32)
+        return jnp.sum(a * a) + jnp.sum(b * b)
+
+    grad = jax.value_and_grad(loss, argnums=(0, 1, 2))
+    want = grad(q, k, v, mask)
+
+    mesh = make_mesh({"dp": B})          # one batch row per device
+    rows = NamedSharding(mesh, P("dp"))
+
+    def sharded(q, k, v, mask):
+        with batch_sharded(mesh, "dp"):
+            return grad(q, k, v, mask)
+
+    fn = jax.jit(sharded, in_shardings=(rows,) * 4)
+    got = fn(q, k, v, mask)
+    assert "manual_computation" in fn.lower(q, k, v, mask).as_text()
+    for a, b in zip(jax.tree_util.tree_leaves(got),
+                    jax.tree_util.tree_leaves(want)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)
+    # outside the scope (or on a one-device axis) nothing is wrapped
+    assert "manual_computation" not in jax.jit(grad).lower(
+        q, k, v, mask).as_text()
+
+
 def test_flash_selection_is_the_registry(monkeypatch):
     """One selection point: monkeypatching the registry's choose drives
     the op -- no residual per-call-site use_pallas branching."""

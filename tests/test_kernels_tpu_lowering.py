@@ -1,0 +1,107 @@
+"""Cross-lower every Pallas kernel for TPU from the CPU.
+
+``jax.export`` with ``platforms=["tpu"]`` runs the Pallas->Mosaic
+lowering without a chip, so a block shape the TPU lowering refuses
+(second-to-last dim neither a multiple of 8 nor the whole extent, last
+dim likewise for 128) fails here, in CPU CI, at the shapes
+``chip_smoke.py``'s census runs on the chip.  Whether Mosaic then
+compiles the kernel within VMEM only the chip can say.
+"""
+import jax
+import jax.numpy as jnp
+import pytest
+from jax import export
+
+from mxnet_tpu.kernels.fused_bn_relu import (bn_relu_apply_pallas,
+                                             bn_relu_bwd_pallas)
+from mxnet_tpu.kernels.optimizer_update import (lamb_phase1_pallas,
+                                                lars_flat_pallas)
+from mxnet_tpu.ops.pallas.flash_attention import (
+    flash_attention_bwd_pallas, flash_attention_fwd_pallas)
+from mxnet_tpu.ops.pallas.layernorm import layernorm_fwd_pallas
+from mxnet_tpu.ops.pallas.paged_attention import paged_attention_pallas
+
+S = jax.ShapeDtypeStruct
+BF16, F32, I32 = jnp.bfloat16, jnp.float32, jnp.int32
+
+
+def _lowers_to_mosaic(fn, *specs):
+    text = export.export(jax.jit(fn), platforms=["tpu"])(*specs) \
+        .mlir_module()
+    assert "tpu_custom_call" in text
+
+
+# BERT-base, batch 32 x seq 512: (batch*heads, seq, head_dim)
+_QKV = S((32 * 12, 512, 64), BF16)
+_LSE = S((32 * 12, 512), F32)
+_MASK = S((32, 512, 512), F32)
+
+
+def test_flash_attention_forward():
+    _lowers_to_mosaic(
+        lambda q, k, v: flash_attention_fwd_pallas(q, k, v, scale=0.125),
+        _QKV, _QKV, _QKV)
+    _lowers_to_mosaic(
+        lambda q, k, v, m: flash_attention_fwd_pallas(
+            q, k, v, m, scale=0.125, heads=12),
+        _QKV, _QKV, _QKV, _MASK)
+
+
+def test_flash_attention_backward():
+    _lowers_to_mosaic(
+        lambda q, k, v, lse, do, delta: flash_attention_bwd_pallas(
+            q, k, v, lse, do, delta, scale=0.125),
+        _QKV, _QKV, _QKV, _LSE, _QKV, _LSE)
+    _lowers_to_mosaic(
+        lambda q, k, v, lse, do, delta, m: flash_attention_bwd_pallas(
+            q, k, v, lse, do, delta, m, scale=0.125, heads=12),
+        _QKV, _QKV, _QKV, _LSE, _QKV, _LSE, _MASK)
+
+
+@pytest.mark.parametrize("slots", [1, 2, 8])
+def test_paged_attention_every_decode_bucket(slots):
+    # the serve phase's cache: 512 blocks of 16 tokens, BERT-base heads,
+    # tables wide enough for max_seq 512
+    slab = S((512, 16, 12, 64), F32)
+    _lowers_to_mosaic(
+        lambda q, k, v, bt, cl: paged_attention_pallas(
+            q, k, v, bt, cl, scale=0.125),
+        S((slots, 12, 64), F32), slab, slab,
+        S((slots, 32), I32), S((slots, 1), I32))
+
+
+@pytest.mark.parametrize("channels", [64, 256])
+def test_fused_bn_relu_resnet50_stage1(channels):
+    x = S((128 * 56 * 56, channels), BF16)       # NHWC rows x C
+    vec = S((1, channels), F32)
+    _lowers_to_mosaic(bn_relu_apply_pallas, x, vec, vec)
+    _lowers_to_mosaic(bn_relu_bwd_pallas, x, x, x, vec, vec, vec, vec,
+                      vec)
+
+
+def test_bucket_optimizer_resnet50_flat_buffer():
+    flat = S((25557032,), F32)                   # 199,665 rows of 128
+    _lowers_to_mosaic(lars_flat_pallas, flat, flat, flat, flat, flat,
+                      flat, S((), F32))
+    _lowers_to_mosaic(lamb_phase1_pallas, flat, flat, flat, flat, flat,
+                      S((3,), F32))
+
+
+def test_layernorm_bert_base_rows():
+    vec = S((768,), F32)
+    _lowers_to_mosaic(layernorm_fwd_pallas, S((16384, 768), BF16), vec,
+                      vec)
+
+
+def test_row_blocks_are_a_multiple_of_8_or_the_whole_extent():
+    from mxnet_tpu import kernels
+    from mxnet_tpu.ops.pallas.tiling import row_block
+    assert row_block(128 * 56 * 56, 256) == 256
+    assert row_block(50, 256) == 50              # fits one block
+    assert row_block(1000, 256) == 200           # not 250: 250 % 8 != 0
+    assert row_block(199665, 64) is None         # odd: caller pads
+    # a shape with no such block is declined by name, not refused by
+    # the lowering later
+    ch = kernels.choose("fused_bn_relu", force=True, axis=3, ndim=4,
+                        rows=2 * 15 * 15)
+    assert not ch.use_pallas and "multiple of 8" in ch.reason

@@ -3,7 +3,7 @@ byte lock (reference: ``src/ndarray/ndarray.cc :: NDArray::Save/Load``,
 magics ``kMXAPINDArrayListMagic=0x112`` / ``NDARRAY_V2_MAGIC=
 0xF993FAC9``).
 
-The point of these tests (VERDICT r3 #9 / r4 #9): the format must be
+The point of these tests (an early review finding): the format must be
 demonstrated, not asserted.  ``_spec_write`` below is an INDEPENDENT
 implementation of the documented binary layout -- written from the
 spec, byte by byte with ``struct``, sharing no code with

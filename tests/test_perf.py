@@ -413,10 +413,11 @@ def _bench_mod():
     return bench
 
 
-def test_subprocess_pair_failure_raises_with_stderr_tail():
+def test_cpu_subprocess_failure_raises_with_stderr_tail():
     bench = _bench_mod()
     with pytest.raises(RuntimeError) as ei:
-        bench._subprocess_pair("bench.no_such_function()", timeout=120)
+        bench._cpu_subprocess_value("bench.no_such_function()",
+                                    timeout=120)
     msg = str(ei.value)
     assert "exited" in msg and "AttributeError" in msg
 

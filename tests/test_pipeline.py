@@ -151,7 +151,7 @@ def test_process_pool_decode_matches_serial(tmp_path):
     SharedMemory slab; batches must match the serial path exactly
     (deterministic augs).  The pool must NOT fork this
     (JAX-multithreaded) process: the os.fork RuntimeWarning is
-    escalated to an error here (VERDICT r4 #5 -- the fork-based pool
+    escalated to an error here (the fork-based pool
     was a deadlock time bomb)."""
     import warnings
     p = str(tmp_path / "procjpg")

@@ -89,10 +89,19 @@ def test_hlo_parser_attributes_conv_and_layout():
         assert p["flops"] > 0 and p["category"] in hlo.CATEGORIES
 
 
+def test_no_peak_is_assumed():
+    rep = cost.analyze_jit(jax.jit(_tiny_fn(32)), _tiny_args(32))
+    assert roofline.device_peaks() is None               # CPU: absent
+    assert roofline.build(rep, step_time_s=1e-3) is None
+    assert roofline.device_peaks("TPU v5 lite") == (197e12, 819e9)
+    with pytest.raises(mx.MXNetError, match="no published peak"):
+        roofline.device_peaks("TPU v9 imaginary")
+
+
 def test_roofline_labels_every_category():
     rep = cost.analyze_jit(jax.jit(_tiny_fn(32)), _tiny_args(32))
-    rl = roofline.build(rep, step_time_s=1e-3)
-    assert rl["peaks_assumed"] is True          # CPU dev box
+    rl = roofline.build(rep, step_time_s=1e-3, peak_flops=197e12,
+                        peak_bytes_per_s=819e9)
     assert rl["mfu"] >= 0
     assert rl["categories"], "empty roofline category section"
     for cat, v in rl["categories"].items():
@@ -105,7 +114,7 @@ def test_roofline_labels_every_category():
                                         "instructions": 1}},
             "memory": {"peak_hbm_bytes": 0}}
     rl2 = roofline.build(fake, 1.0)
-    assert rl2["peaks_assumed"] is False
+    assert rl2["peak_flops"] == 197e12
     assert rl2["categories"]["conv_dot"]["bound"] == "compute"
 
 
@@ -144,7 +153,7 @@ def test_executor_path_captured(prof):
     assert any(r["label"] == "executor.eval" for r in reps)
 
 
-def test_train_step_captured_with_step_and_roofline(prof):
+def test_train_step_captured_with_step_and_roofline(prof, v5e_peaks):
     from mxnet_tpu.parallel import TrainStep
     net = gluon.nn.Dense(4)
     net.initialize()
@@ -311,7 +320,7 @@ def test_runtime_features_profiling_row(prof):
 
 
 @pytest.mark.slow
-def test_resnet_bf16_train_step_cost_report():
+def test_resnet_bf16_train_step_cost_report(v5e_peaks):
     """Acceptance shape (ISSUE 6): a bf16 ResNet train step's
     CostReport has conv/dot-dominated per-category FLOPs/bytes summing
     to the executable totals, and the roofline labels every category
@@ -345,7 +354,7 @@ def test_resnet_bf16_train_step_cost_report():
         assert v["bound"] in ("compute", "memory"), (cat, v)
 
 
-def test_report_for_train_step_helper():
+def test_report_for_train_step_helper(v5e_peaks):
     """bench.py's artifact path: report_for on a dispatched TrainStep
     works without the store (profiling disabled)."""
     from mxnet_tpu.parallel import TrainStep

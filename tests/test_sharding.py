@@ -103,7 +103,7 @@ def test_parallel_package_axes_all_declared():
 
 def test_shard_map_arity_fires_and_clean_twin_silent():
     bad = (
-        "from mxnet_tpu.parallel._shard_map import shard_map\n"
+        "from jax import shard_map\n"
         "def body(q, k):\n"
         "    return q\n"
         "def run(mesh, spec):\n"
@@ -122,7 +122,7 @@ def test_shard_map_arity_resolves_partial_bodies():
     # must NOT reduce the positional arity
     src = (
         "import functools\n"
-        "from mxnet_tpu.parallel._shard_map import shard_map\n"
+        "from jax import shard_map\n"
         "def body(q, k, v, *, scale):\n"
         "    return q\n"
         "def run(mesh, spec):\n"
@@ -138,7 +138,7 @@ def test_shard_map_arity_resolves_partial_bodies():
 
 def test_shard_map_out_specs_tuple_arity():
     bad = (
-        "from mxnet_tpu.parallel._shard_map import shard_map\n"
+        "from jax import shard_map\n"
         "def body(q, k):\n"
         "    return q, k, q\n"
         "def run(mesh, spec):\n"
@@ -155,8 +155,7 @@ def test_shard_map_arity_real_parallel_files_clean():
     """The in-repo shard_map call sites (ring attention, pipeline) must
     satisfy their own arity rule."""
     for rel in ("mxnet_tpu/parallel/sequence.py",
-                "mxnet_tpu/parallel/pipeline.py",
-                "mxnet_tpu/parallel/_shard_map.py"):
+                "mxnet_tpu/parallel/pipeline.py"):
         diags = an.lint_file(os.path.join(REPO, rel))
         assert [d for d in diags if d.rule == "shard-map-spec-arity"] \
             == [], rel

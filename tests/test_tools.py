@@ -57,6 +57,24 @@ def test_launch_local_env():
     assert prefixes == ["[0]", "[1]"]
 
 
+def test_launch_local_refuses_a_tpu_host(monkeypatch):
+    """A chip belongs to one process: on a host with TPU device nodes
+    local mode starts nothing unless the workers are pinned to the CPU
+    (the launcher looks at /dev, never at JAX)."""
+    from tools import launch
+    monkeypatch.setattr(
+        launch.glob, "glob",
+        lambda pat: ["/dev/vfio/0", "/dev/vfio/1"] if "vfio" in pat else [])
+    monkeypatch.setenv("JAX_PLATFORMS", "tpu,cpu")
+    assert "one process" in launch._refuse_local_on_tpu_host()
+    with pytest.raises(SystemExit) as exc:
+        launch.main(["-n", "2", sys.executable, "-c", "pass"])
+    assert exc.value.code == 2
+    monkeypatch.setenv("JAX_PLATFORMS", "cpu")
+    assert launch._refuse_local_on_tpu_host() is None
+    assert "jax" not in launch.__dict__          # holds no chip itself
+
+
 def test_opperf_runs():
     from benchmark import opperf
     results = opperf.run(ops=["relu", "dot"], warmup=1, runs=2)

@@ -8,9 +8,17 @@ an XLA collective over ICI/DCN (see ``mxnet_tpu/kvstore.py``).  The
 launcher therefore only has to start N identical processes with the
 coordinator's address and each process's index:
 
-  local mode:   ``launch.py -n 4 python train.py``      (one host)
+  local mode:   ``launch.py -n 4 python train.py``      (one host, CPU)
   ssh mode:     ``launch.py -n 8 -H hostfile python train.py``
   supervised:   ``launch.py -n 4 --supervise python train.py``
+
+Local mode is the CPU/gloo test path.  A TPU chip belongs to one
+process, and one process drives every chip of its host (``python
+train.py`` over ``parallel.make_mesh``); N local workers with one
+environment would each open every chip.  On a host with TPU device
+nodes local mode therefore refuses to start unless the workers are
+pinned to the CPU (``JAX_PLATFORMS=cpu``).  This launcher imports no
+JAX and holds no chip itself.
 
 Each worker gets MXNET_TPU_COORDINATOR / MXNET_TPU_NUM_PROCS /
 MXNET_TPU_PROC_ID; ``mxnet_tpu.distributed_init()`` (or user code) maps
@@ -26,6 +34,7 @@ bounded ``--max-restarts`` budget.
 from __future__ import annotations
 
 import argparse
+import glob
 import os
 import shlex
 import socket
@@ -122,6 +131,23 @@ def _wait_all(procs):
         raise
 
 
+def _refuse_local_on_tpu_host():
+    """Why local mode must not start here, or None.  Observed without
+    JAX: the device nodes libtpu opens (``/dev/accel*`` up to v4,
+    ``/dev/vfio/<n>`` from v5e on)."""
+    if os.environ.get("JAX_PLATFORMS", "").strip().lower() == "cpu":
+        return None
+    nodes = glob.glob("/dev/accel*") + glob.glob("/dev/vfio/[0-9]*")
+    if not nodes:
+        return None
+    return ("local mode would start N workers that each open every TPU "
+            "chip of this host (%s): a chip belongs to one process.  Run "
+            "ONE process per host -- it drives all local chips through "
+            "parallel.make_mesh -- or export JAX_PLATFORMS=cpu for the "
+            "CPU/gloo test path (docs/distributed.md)."
+            % ", ".join(sorted(nodes)))
+
+
 def launch_local(args, command):
     coord = "127.0.0.1:%d" % _free_port()
     procs = []
@@ -192,6 +218,10 @@ def main(argv=None):
     args = p.parse_args(argv)
     if not args.command:
         p.error("no command given")
+    if not args.hostfile:
+        why = _refuse_local_on_tpu_host()
+        if why:
+            p.error(why)
     if args.supervise:
         if args.hostfile:
             p.error("--supervise is local-mode only (ssh worlds need "
