@@ -1,0 +1,2 @@
+"""The device's idle share in a training cell."""
+from perfbench.harness.readers import device_idle_share as read  # noqa: F401

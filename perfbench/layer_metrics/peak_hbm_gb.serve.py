@@ -1,0 +1,2 @@
+"""Peak device memory of a serving cell."""
+from perfbench.harness.readers import peak_hbm_gb as read  # noqa: F401
