@@ -18,11 +18,19 @@ import os as _os
 
 # Persistent XLA compilation cache: wherever JAX_COMPILATION_CACHE_DIR
 # says, else inside the checkout (base.compile_cache_dir).
+from .base import CACHE_ROOT as _CACHE_ROOT
 from .base import compile_cache_dir as _compile_cache_dir
+import jax as _jax
 _cache_dir = _compile_cache_dir()
 if _cache_dir is not None:
-    import jax as _jax
     _jax.config.update("jax_compilation_cache_dir", _cache_dir)
+# Source paths in HLO metadata are relative to the checkout, so that the
+# programs whose cache key holds their metadata (base.scopes_in_cache_key)
+# hit wherever the checkout lies.
+import re as _re
+_jax.config.update(
+    "jax_hlo_source_file_canonicalization_regex",
+    "^" + _re.escape(_os.path.dirname(_CACHE_ROOT) + _os.sep))
 
 # Transfer guard (sharding sanitizer runtime wiring): with
 # MXNET_TPU_TRANSFER_GUARD=disallow, an IMPLICIT host<->device transfer

@@ -8,6 +8,7 @@ Python exceptions raised at op-call or sync points.
 """
 from __future__ import annotations
 
+import contextlib
 import os
 import re
 
@@ -29,6 +30,27 @@ def compile_cache_dir(environ=os.environ):
     if environ.get("JAX_COMPILATION_CACHE_DIR"):
         return None
     return os.path.join(CACHE_ROOT, "xla")
+
+
+@contextlib.contextmanager
+def scopes_in_cache_key():
+    """Compile what runs inside with its metadata in the persistent
+    cache's key.  A cached executable carries the op names
+    (``jax.named_scope`` paths) of whichever process compiled it, and
+    JAX leaves them out of the key by default, so a device trace may read
+    another version's scopes, or none.  The programs whose scopes a trace
+    is read by (``obs.note_program``: the compiled train step, the decode
+    and prefill programs) are compiled under this; everything else keeps
+    JAX's default, under which the per-layer programs of an eager pass
+    share one cache entry."""
+    import jax
+    name = "jax_compilation_cache_include_metadata_in_key"
+    before = getattr(jax.config, name)
+    jax.config.update(name, True)
+    try:
+        yield
+    finally:
+        jax.config.update(name, before)
 
 
 class MXNetError(RuntimeError):

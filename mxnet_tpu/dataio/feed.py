@@ -45,7 +45,7 @@ import numpy as np
 import jax
 from jax.sharding import NamedSharding, PartitionSpec
 
-from .. import profiling as _profiling
+from .. import obs as _obs
 from .. import sync as _sync
 from .. import telemetry as _telemetry
 from ..base import MXNetError
@@ -275,18 +275,21 @@ class DeviceFeed:
                     # put below is backpressure, not work, and stays
                     # outside it
                     t0 = time.perf_counter()
-                    try:
-                        arrays, pad = next_batch()
-                    except StopIteration:
-                        break
-                    feed = wself()
-                    if feed is None:         # consumer GC'd mid-epoch
-                        return
-                    staged, nbytes = [], 0
-                    for a in arrays:
-                        d, nb = feed._stage(a)
-                        staged.append(d)
-                        nbytes += nb
+                    stage = _obs.span("mx.feed.stage")
+                    with stage:
+                        try:
+                            arrays, pad = next_batch()
+                        except StopIteration:
+                            break
+                        feed = wself()
+                        if feed is None:     # consumer GC'd mid-epoch
+                            return
+                        staged, nbytes = [], 0
+                        for a in arrays:
+                            d, nb = feed._stage(a)
+                            staged.append(d)
+                            nbytes += nb
+                        stage.set(bytes=nbytes)
                     busy = time.perf_counter() - t0
                     with feed._stats_lock:
                         feed._stats["producer_busy"] += busy
@@ -298,12 +301,6 @@ class DeviceFeed:
                     feed = None
                     if _telemetry._ENABLED:
                         _telemetry.hooks.feed_produce(busy, nbytes)
-                    if _profiling._ENABLED:
-                        # host->device transfer span on the step
-                        # timeline (mx.profiling)
-                        from ..profiling import timeline
-                        timeline.record("feed.stage", t0, busy,
-                                        {"bytes": nbytes})
                     if not DeviceFeed._producer_put(
                             q, stop, (tuple(staged), pad)):
                         return
@@ -330,7 +327,11 @@ class DeviceFeed:
         if self._error is not None:
             raise self._error
         t0 = time.perf_counter()
-        item = self._queue.get()
+        try:
+            item = self._queue.get_nowait()
+        except queue.Empty:
+            with _obs.span("mx.feed.wait"):
+                item = self._queue.get()
         wait = time.perf_counter() - t0
         with self._stats_lock:
             self._stats["consumer_wait"] += wait

@@ -151,9 +151,8 @@ _VARS = [
            "at import: every compiled executable (eager-jit cache, "
            "hybridize cache, Executor, TrainStep) is captured for "
            "lazy XLA cost/memory analysis with a per-HLO-category "
-           "breakdown, TrainStep dispatch walls feed the roofline, "
-           "and host spans land on the Chrome-trace step timeline.  "
-           "Off (the default), every hook is a single module-flag "
+           "breakdown, and TrainStep dispatch walls feed the "
+           "roofline.  Off (the default), every hook is a single module-flag "
            "check.  Runtime toggle: mx.profiling.enable()/disable(); "
            "render with the mxprof CLI."),
     EnvVar("MXNET_TPU_PROFILING_DIR", str, "",

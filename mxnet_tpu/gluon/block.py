@@ -68,11 +68,27 @@ def _active_trace():
     return getattr(_trace_tls, "trace", None)
 
 
-class _TraceContext:
-    """Collects aux-state writes made while tracing a hybrid graph."""
+def _child_scope_names(block, out=None):
+    """``{id(child): its name in its parent}`` for every block under
+    ``block`` (a shared child keeps the first name it was found by)."""
+    out = {} if out is None else out
+    for name, child in block._children.items():
+        if id(child) not in out:
+            out[id(child)] = name
+            _child_scope_names(child, out)
+    return out
 
-    def __init__(self):
+
+class _TraceContext:
+    """Collects aux-state writes made while tracing a hybrid graph, and
+    names each child block's ops after the child (``jax.named_scope``:
+    ``op_name`` metadata of the HLO, so a device trace of the compiled
+    program reads ``.../encoder/cell3/attention/...``)."""
+
+    def __init__(self, block=None):
         self.aux_updates = OrderedDict()  # Parameter -> NDArray(tracer)
+        self.scope_names = _child_scope_names(block) \
+            if block is not None else {}
 
     def record_aux(self, param, data):
         self.aux_updates[param] = data
@@ -269,7 +285,15 @@ class Block:
     def __call__(self, *args):
         for hook in self._forward_pre_hooks:
             hook(self, args)
-        out = self.forward(*args)
+        tr = _active_trace()
+        scope = tr.scope_names.get(id(self)) if tr is not None else None
+        if scope is not None:
+            # traced into a compiled program: the child's ops carry its
+            # name.  An eager call never gets here.
+            with jax.named_scope(scope):
+                out = self.forward(*args)
+        else:
+            out = self.forward(*args)
         for hook in self._forward_hooks:
             hook(self, args, out)
         import sys
@@ -471,7 +495,7 @@ class HybridBlock(Block):
         block = self
 
         def pure_fn(pvals, ivals, rng_key):
-            tr = _TraceContext()
+            tr = _TraceContext(block)
             with tr, _random_mod.traced_stream(rng_key), \
                     autograd.pause(train_mode=training):
                 for name, p in pmap.items():
@@ -534,7 +558,7 @@ class HybridBlock(Block):
         block = self
 
         def pure_fn(pvals, ivals, rng_key):
-            tr = _TraceContext()
+            tr = _TraceContext(block)
             with tr, _random_mod.traced_stream(rng_key), \
                     autograd.pause(train_mode=training):
                 for name, p in pmap.items():

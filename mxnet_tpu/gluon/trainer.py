@@ -10,7 +10,7 @@ from __future__ import annotations
 import time
 
 from .. import optimizer as opt
-from .. import profiling as _profiling
+from .. import obs as _obs
 from .. import telemetry as _telemetry
 from ..base import MXNetError
 from .parameter import Parameter, ParameterDict
@@ -111,19 +111,14 @@ class Trainer:
     def step(self, batch_size, ignore_stale_grad=False):
         """allreduce (via kvstore/collectives) + optimizer update
         (reference: ``Trainer.step``)."""
-        t0 = time.perf_counter() \
-            if _telemetry._ENABLED or _profiling._ENABLED else None
+        t0 = time.perf_counter() if _telemetry._ENABLED else None
         try:
-            self._step_impl(batch_size, ignore_stale_grad)
+            with _obs.span("mx.trainer.step", batch=batch_size):
+                self._step_impl(batch_size, ignore_stale_grad)
         finally:
             if t0 is not None:
-                dt = time.perf_counter() - t0
-                if _telemetry._ENABLED:
-                    _telemetry.hooks.trainer_step(dt, batch_size)
-                if _profiling._ENABLED:
-                    from ..profiling import timeline
-                    timeline.record("trainer.step", t0, dt,
-                                    {"batch": batch_size})
+                _telemetry.hooks.trainer_step(time.perf_counter() - t0,
+                                              batch_size)
 
     def _step_impl(self, batch_size, ignore_stale_grad):
         if not self._kv_initialized:

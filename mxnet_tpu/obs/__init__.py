@@ -44,7 +44,8 @@ import os
 
 from . import alerts, flight, goodput, status, trace
 from .trace import (TraceContext, begin_span, current, end_span,
-                    export_chrome_trace, record_span, span, spans)
+                    export_chrome_trace, note_program, program_scopes,
+                    record_span, span, spans)
 from .trace import trace as start_trace
 
 __all__ = [
@@ -52,6 +53,7 @@ __all__ = [
     "enable_goodput", "disable_goodput", "goodput_enabled",
     "start_trace", "span", "begin_span", "end_span", "record_span",
     "current", "spans", "export_chrome_trace", "TraceContext",
+    "note_program", "program_scopes",
     "flight", "goodput", "status", "server", "serve",
     "install_blackbox", "fleet", "alerts",
 ]

@@ -9,25 +9,32 @@ batcher assembling many requests into one compiled dispatch) is modeled
 as **span links** -- the batch span names every request span it serves,
 exactly the Dapper/OpenTelemetry shape.
 
-Two recording surfaces:
+Recording surfaces:
 
-- :func:`span` / :func:`trace` -- context managers for code that OWNS
-  its scope (user code, tests);
+- :class:`span` -- THE span call of the instrumented framework paths
+  (``TrainStep``, ``DeviceFeed``, ``DecodeEngine``, ``mx.profiler.scope``)
+  and of user code.  It always enters a
+  ``jax.profiler.TraceAnnotation``: the native ``TraceMe`` is a no-op
+  while no profiler session runs, and while one runs -- whoever started
+  it -- the span is in its trace, on the device trace's clock.  While
+  ``obs._TRACE_ENABLED`` it additionally records into the ring below
+  (parent id, trace id, links).  A site calls it and tests no switch.
+- :func:`trace` -- context manager that opens a root trace;
 - :func:`begin_span` / :func:`end_span` and :func:`record_span` -- the
-  hook surface the instrumented framework paths use, so a disabled
-  tracer costs exactly one module-flag check per site
-  (``obs._TRACE_ENABLED``, the same zero-overhead contract as
-  ``telemetry._ENABLED``, proven by tests/test_obs.py).
+  ring-only hook surface of the older sites (call sites guard with
+  ``obs._TRACE_ENABLED``, the same zero-overhead contract as
+  ``telemetry._ENABLED``, proven by tests/test_obs.py) and of
+  cross-thread spans with explicit timing.
 
-Every finished span lands in (1) a bounded in-process ring (the flight
-recorder and :func:`export_chrome_trace` read it), (2) the attached
-telemetry sinks as a streamed ``{"kind": "span", ...}`` JSONL record
-(``mxtelemetry summarize`` folds them), and (3) the profiling timeline
-ring when ``mx.profiling`` is enabled, so traces overlay the existing
-Chrome-trace step timeline.
+Every finished ring span lands in (1) a bounded in-process ring (the
+flight recorder and :func:`export_chrome_trace`, the one Chrome
+exporter, read it) and (2) the attached telemetry sinks as a streamed
+``{"kind": "span", ...}`` JSONL record (``mxtelemetry summarize`` folds
+them).
 """
 from __future__ import annotations
 
+import collections
 import contextlib
 import contextvars
 import os
@@ -35,12 +42,16 @@ import threading
 import time
 import uuid
 
+import jax
+from jax.profiler import TraceAnnotation as _Annotation
+
+from .. import obs as _obs
 from .. import sync as _sync
 
 __all__ = [
     "TraceContext", "current", "new_id", "trace", "span",
     "begin_span", "end_span", "record_span", "spans", "clear",
-    "export_chrome_trace",
+    "export_chrome_trace", "note_program", "program_scopes",
 ]
 
 # bounded span ring: a multi-hour run must not grow host memory
@@ -92,7 +103,7 @@ def fresh_context():
 
 class _OpenSpan:
     __slots__ = ("name", "ctx", "parent_id", "t0", "t_wall", "attrs",
-                 "token")
+                 "token", "links")
 
     def __init__(self, name, ctx, parent_id, attrs, token):
         self.name = name
@@ -102,6 +113,7 @@ class _OpenSpan:
         self.t_wall = time.time()
         self.attrs = attrs
         self.token = token
+        self.links = None
 
 
 def begin_span(name, **attrs):
@@ -131,18 +143,69 @@ def end_span(open_span, **extra_attrs):
                 parent_id=open_span.parent_id,
                 t0=open_span.t0,
                 dur=time.perf_counter() - open_span.t0,
-                t_wall=open_span.t_wall, attrs=attrs)
+                t_wall=open_span.t_wall, attrs=attrs,
+                links=open_span.links)
     return open_span.ctx
 
 
-@contextlib.contextmanager
-def span(name, **attrs):
-    """``with obs.span("phase"): ...`` -- scoped child span."""
-    sp = begin_span(name, **attrs)
-    try:
-        yield sp.ctx
-    finally:
-        end_span(sp)
+class span:
+    """``with obs.span("mx.layer.what", n=3): ...`` -- the one span call.
+
+    Always a ``jax.profiler.TraceAnnotation(name, **attrs)`` (nothing
+    while no profiler session runs); while ``obs._TRACE_ENABLED`` also a
+    child span of the current context in the ring, whose context the
+    ``with`` yields (None otherwise).  ``links`` are span ids this span
+    serves without being their child (a decode step and its requests);
+    ``since`` is a ``perf_counter`` reading from which the ring's span
+    counts where the work began earlier, on another thread (a queue
+    wait: the annotation then carries ``waited_us``); ``hlo=True`` also
+    enters ``jax.named_scope(name)``, so ops traced inside carry the
+    name in their ``op_name`` (``mx.profiler.scope``)."""
+
+    __slots__ = ("_name", "_attrs", "_links", "_since", "_ann", "_hlo",
+                 "_open")
+
+    def __init__(self, name, links=None, since=None, hlo=False, **attrs):
+        self._name = name
+        self._attrs = attrs
+        self._links = links
+        self._since = since
+        if since is not None:
+            attrs = dict(attrs, waited_us=int(
+                1e6 * (time.perf_counter() - since)))
+        self._ann = _Annotation(name, **attrs)
+        self._hlo = jax.named_scope(name) if hlo else None
+        self._open = None
+
+    def __enter__(self):
+        self._ann.__enter__()
+        if self._hlo is not None:
+            self._hlo.__enter__()
+        if not _obs._TRACE_ENABLED:
+            return None
+        sp = self._open = begin_span(self._name, **self._attrs)
+        if self._links:
+            sp.links = list(self._links)
+        if self._since is not None:
+            sp.t_wall -= sp.t0 - self._since
+            sp.t0 = self._since
+        return sp.ctx
+
+    def set(self, **attrs):
+        """Attributes known only once the work is under way (bytes
+        staged): onto the annotation and, in the ring, the span."""
+        self._ann.set_metadata(**attrs)
+        if self._open is not None:
+            self._open.attrs = dict(self._open.attrs or {}, **attrs)
+
+    def __exit__(self, *exc):
+        if self._open is not None:
+            end_span(self._open)
+            self._open = None
+        if self._hlo is not None:
+            self._hlo.__exit__(*exc)
+        self._ann.__exit__(*exc)
+        return False
 
 
 @contextlib.contextmanager
@@ -198,14 +261,52 @@ def record_span(name, ctx, parent_id=None, t0=None, dur=0.0,
     # require telemetry to be enabled, so tracing stands alone
     from .. import telemetry as _telemetry
     _telemetry.registry()._stream(rec)
-    # overlay on the profiling step timeline when cost accounting is on
-    from .. import profiling as _profiling
-    if _profiling.enabled():
-        from ..profiling import timeline as _timeline
-        _timeline.record(name, rec["t0"], rec["dur"],
-                         args={"trace": ctx.trace_id,
-                               "span": ctx.span_id})
     return rec
+
+
+# ---------------------------------------------------------------------
+# scopes of the compiled programs, for a reader of a device trace
+# ---------------------------------------------------------------------
+# A device trace names an executed instruction (``fusion.810``) and not
+# the ``jax.named_scope`` path it was traced under; that lives in the
+# compiled module's metadata.  The paths that compile a program of their
+# own (TrainStep, the decode engine) leave a way to its compiled text
+# here, and whoever holds a trace of the process asks for the map.
+# Bounded, newest kept: a process that builds programs without end (hot
+# swaps) does not keep them all alive.
+_MAX_PROGRAMS = 32
+_programs = collections.OrderedDict()     # label -> () -> compiled HLO text
+
+
+def note_program(label, text_fn):
+    """Remember how to get the compiled HLO text of the program called
+    ``label`` (one dict insert; nothing is computed here)."""
+    with _lock:
+        _programs.pop(label, None)
+        _programs[label] = text_fn
+        while len(_programs) > _MAX_PROGRAMS:
+            _programs.popitem(last=False)
+
+
+def program_scopes():
+    """``{label: {"module": HLO module name, "scopes": {instruction
+    name: op_name}}}`` for the noted programs (``profiling.hlo.scope_map``
+    of each one's compiled text; the module name is what a device trace
+    calls the program's executions).  Computed when asked -- after a
+    traced window, never inside one; a program whose text cannot be had
+    is left out."""
+    from ..profiling.hlo import module_name, scope_map
+    with _lock:
+        noted = list(_programs.items())
+    out = {}
+    for label, text_fn in noted:
+        try:
+            text = text_fn()
+            out[label] = {"module": module_name(text),
+                          "scopes": scope_map(text)}
+        except Exception:
+            continue
+    return out
 
 
 def spans():

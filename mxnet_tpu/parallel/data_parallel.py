@@ -28,8 +28,9 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from ..base import MXNetError
+from ..base import MXNetError, scopes_in_cache_key
 from ..ndarray import NDArray
+from .. import obs as _obs
 from .. import profiling as _profiling
 from .. import random as _random_mod
 from .mesh import (batch_sharded, global_mesh, put_replicated,
@@ -345,15 +346,17 @@ class TrainStep:
                 merged.update(diff_pvals)
                 outs, aux = pure_fn(merged, [data], rng)
                 out_nd = [NDArray(o) for o in outs]
-                l = loss_fn(out_nd[0] if len(out_nd) == 1 else out_nd,
-                            NDArray(label))
-                ldata = l._data if isinstance(l, NDArray) else l
-                # Sum (not mean): the reference seeds backward with ones
-                # over the batch loss and rescales by 1/batch_size in the
-                # optimizer (Trainer.step semantics).  loss_scale is the
-                # fp16 AMP scale (1.0 otherwise); rescale folds in its
-                # inverse.
-                return jnp.sum(ldata) * loss_scale, (jnp.mean(ldata), aux)
+                with jax.named_scope("mx.loss"):
+                    l = loss_fn(out_nd[0] if len(out_nd) == 1 else out_nd,
+                                NDArray(label))
+                    ldata = l._data if isinstance(l, NDArray) else l
+                    # Sum (not mean): the reference seeds backward with
+                    # ones over the batch loss and rescales by
+                    # 1/batch_size in the optimizer (Trainer.step
+                    # semantics).  loss_scale is the fp16 AMP scale (1.0
+                    # otherwise); rescale folds in its inverse.
+                    return (jnp.sum(ldata) * loss_scale,
+                            (jnp.mean(ldata), aux))
 
             diff_pvals = {name_by_idx[i]: pvals[name_by_idx[i]] for i in idxs}
             grads_and_aux = jax.value_and_grad(loss_of, has_aux=True)(
@@ -367,18 +370,21 @@ class TrainStep:
             # set (the numerics sentinel's in-graph form) -- one boolean
             # output, no extra host sync on the clean path.
             from ..analysis import numerics as _numerics
-            all_finite = _numerics.finite_tree(
-                jax.tree_util.tree_leaves(grads))
+            with jax.named_scope("mx.finite_check"):
+                all_finite = _numerics.finite_tree(
+                    jax.tree_util.tree_leaves(grads))
 
-            lr_map = {i: lrs[k] for k, i in enumerate(idxs)}
-            wd_map = {i: wds[k] for k, i in enumerate(idxs)}
+            with jax.named_scope("mx.optimizer"):
+                lr_map = {i: lrs[k] for k, i in enumerate(idxs)}
+                wd_map = {i: wds[k] for k, i in enumerate(idxs)}
             # Start from the full pvals: every parameter buffer is donated,
             # so every one must come back out (unchanged ones alias
             # through), or frozen params would be left deleted.
             new_w = dict(pvals)
             new_s = {}
             from ..kernels import optimizer_update as _kopt
-            with _scalar_feed(opt, t, lr_map, wd_map, rescale):
+            with _scalar_feed(opt, t, lr_map, wd_map, rescale), \
+                    jax.named_scope("mx.optimizer"):
                 if _kopt.bucket_active(opt):
                     # kernel tier (MXNET_TPU_KERNELS=1): the LARS/LAMB
                     # update runs over ONE concatenated per-dtype buffer
@@ -511,8 +517,6 @@ class TrainStep:
         BatchNorm running stats, optimizer state, and the step counter all
         thread through the on-device loop.
         """
-        from .. import amp as _amp
-        from ..ndarray import bulk as _bulk
         tr = self._trainer
         opt = tr._optimizer
         if getattr(tr, "_amp_loss_scaler", None) is not None:
@@ -520,106 +524,138 @@ class TrainStep:
                 "run_steps does not support fp16 dynamic loss scaling "
                 "(the scaler's growth/backoff counters live on the host); "
                 "use bf16 AMP or per-step __call__ for fp16")
-        for p in tr._params:
-            if p._data is not None and p.dtype is not None \
-                    and p._data._data.dtype != p.dtype:
-                p.cast(p.dtype)
-        self._ensure_states()
-        # leading axis is the step index; batch axis shifts right by 1
-        data, label = self._stage_io(data, label, shift=1)
-        if any(p._deferred_init is not None
-               for p in self._block._all_params()):
-            from .. import autograd as _ag
-            with _ag.pause():
-                self._block(NDArray(data._data[0]))
-            self._ensure_states()
-        k = data.shape[0]
-        key = ("scan", tuple(data.shape), str(data.dtype),
-               tuple(label.shape), str(label.dtype), _amp.policy_token())
-        entry = self._cache.get(key)
-        if entry is None:
-            entry = self._build_scan([data, label], True)
-            self._cache[key] = entry
-        fn, idxs, pnames, pmap = entry
+        step = _obs.span("mx.train_step", step=opt.num_update + 1)
+        with step:
+            return self._run_steps(step, data, label, batch_size)
 
-        t_start = opt._index_update_count.get(
-            idxs[0], opt.begin_num_update) + 1 if idxs else opt.num_update
-        # lr/wd are read from the schedule at the BLOCK START and held for
-        # the K in-scan steps (the schedule is host-side Python, so it
-        # cannot be traced per step); callers with fast-moving schedules
-        # should pick K accordingly
-        num_update_at_start = max(opt.num_update, t_start)
-        saved_num_update = opt.num_update
-        opt.num_update = num_update_at_start
-        rep = _replicated(self._mesh) if self._mesh is not None else None
-        lrs = _feed_scalar([opt._get_lr(i) for i in idxs], np.float32, rep)
-        wds = _feed_scalar([opt._get_wd(i) for i in idxs], np.float32, rep)
-        opt.num_update = saved_num_update
-        for i in idxs:
-            opt._index_update_count[i] = \
-                opt._index_update_count.get(i, opt.begin_num_update) + k
-            opt.num_update = max(opt._index_update_count[i], opt.num_update)
-        t = _feed_scalar(t_start, np.int32, rep)
-        bs = batch_size if batch_size is not None \
-            else data.shape[self._batch_axis + 1]
-        rescale = _feed_scalar(tr._scale / bs, np.float32, rep)
-        loss_scale = _feed_scalar(1.0, np.float32, rep)
-        upd = tr._updater
-        pvals = {n: pmap[n]._data._data for n in pnames}
-        svals = {i: jax.tree_util.tree_map(
-            lambda x: x._data if isinstance(x, NDArray) else x,
-            upd.states.get(i),
-            is_leaf=lambda x: isinstance(x, NDArray) or x is None)
-            for i in idxs}
-        rng = _random_mod.next_key()
-        args = (pvals, svals, data._data, label._data, rng, t, lrs, wds,
-                rescale, loss_scale)
-        self._last_call = (fn, jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args))
-        # the jit donates the param/state buffers; any still-pending
-        # bulked-eager region referencing them must execute first
-        _bulk.flush()
-        t0p = time.perf_counter() if _profiling._ENABLED else None
-        new_w, new_s, _t, losses = fn(*args)
-        if t0p is not None:
-            label = "train_scan:%s" % type(self._block).__name__
-            self._profiling_hook(label, fn, t0p,
-                                 time.perf_counter() - t0p, k * bs)
-        for n in pnames:
-            pmap[n]._data._data = new_w[n]
-        for i in idxs:
-            s = upd.states.get(i)
-            flat_new = jax.tree_util.tree_leaves(new_s[i])
-            for leaf, nv in zip(_state_leaves(s), flat_new):
-                leaf._data = nv
-        # aux (running stats) were threaded inside new_w; rebind Parameters
-        for p in self._block._all_params():
-            if p.name in pnames and p.grad_req == "null" \
-                    and p._data is not None:
-                grad = p._data._grad
-                p._data = NDArray(new_w[p.name])
-                p._data._grad = grad
+    def _run_steps(self, step, data, label, batch_size):
+        from .. import amp as _amp
+        from ..ndarray import bulk as _bulk
+        tr = self._trainer
+        opt = tr._optimizer
+        with _obs.span("mx.train_step.prep"):
+            for p in tr._params:
+                if p._data is not None and p.dtype is not None \
+                        and p._data._data.dtype != p.dtype:
+                    p.cast(p.dtype)
+            self._ensure_states()
+            # leading axis is the step index; batch axis shifts right by 1
+            data, label = self._stage_io(data, label, shift=1)
+            if any(p._deferred_init is not None
+                   for p in self._block._all_params()):
+                from .. import autograd as _ag
+                with _ag.pause():
+                    self._block(NDArray(data._data[0]))
+                self._ensure_states()
+            k = data.shape[0]
+            key = ("scan", tuple(data.shape), str(data.dtype),
+                   tuple(label.shape), str(label.dtype), _amp.policy_token())
+            entry = self._cache.get(key)
+            built = entry is None
+            if built:
+                entry = self._build_scan([data, label], True)
+                self._cache[key] = entry
+            fn, idxs, pnames, pmap = entry
+
+            t_start = opt._index_update_count.get(
+                idxs[0], opt.begin_num_update) + 1 if idxs else opt.num_update
+            # lr/wd are read from the schedule at the BLOCK START and held for
+            # the K in-scan steps (the schedule is host-side Python, so it
+            # cannot be traced per step); callers with fast-moving schedules
+            # should pick K accordingly
+            num_update_at_start = max(opt.num_update, t_start)
+            saved_num_update = opt.num_update
+            opt.num_update = num_update_at_start
+            rep = _replicated(self._mesh) if self._mesh is not None else None
+            lrs = _feed_scalar([opt._get_lr(i) for i in idxs], np.float32,
+                               rep)
+            wds = _feed_scalar([opt._get_wd(i) for i in idxs], np.float32,
+                               rep)
+            opt.num_update = saved_num_update
+            for i in idxs:
+                opt._index_update_count[i] = \
+                    opt._index_update_count.get(i, opt.begin_num_update) + k
+                opt.num_update = max(opt._index_update_count[i],
+                                     opt.num_update)
+            t = _feed_scalar(t_start, np.int32, rep)
+            bs = batch_size if batch_size is not None \
+                else data.shape[self._batch_axis + 1]
+            step.set(items=k * bs)
+            rescale = _feed_scalar(tr._scale / bs, np.float32, rep)
+            loss_scale = _feed_scalar(1.0, np.float32, rep)
+            upd = tr._updater
+            pvals = {n: pmap[n]._data._data for n in pnames}
+            svals = {i: jax.tree_util.tree_map(
+                lambda x: x._data if isinstance(x, NDArray) else x,
+                upd.states.get(i),
+                is_leaf=lambda x: isinstance(x, NDArray) or x is None)
+                for i in idxs}
+            rng = _random_mod.next_key()
+            args = (pvals, svals, data._data, label._data, rng, t, lrs, wds,
+                    rescale, loss_scale)
+            self._last_call = (fn, jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args))
+            if built:
+                self._note_program("train_scan", fn, args)
+        with _obs.span("mx.train_step.dispatch"):
+            # the jit donates the param/state buffers; any still-pending
+            # bulked-eager region referencing them must execute first
+            _bulk.flush()
+            t0p = time.perf_counter() if _profiling._ENABLED else None
+            with scopes_in_cache_key() if built \
+                    else contextlib.nullcontext():
+                new_w, new_s, _t, losses = fn(*args)
+            if t0p is not None:
+                label = "train_scan:%s" % type(self._block).__name__
+                self._profiling_hook(label, fn,
+                                     time.perf_counter() - t0p, k * bs)
+        with _obs.span("mx.train_step.rebind"):
+            for n in pnames:
+                pmap[n]._data._data = new_w[n]
+            for i in idxs:
+                s = upd.states.get(i)
+                flat_new = jax.tree_util.tree_leaves(new_s[i])
+                for leaf, nv in zip(_state_leaves(s), flat_new):
+                    leaf._data = nv
+            # aux (running stats) were threaded inside new_w; rebind
+            # Parameters
+            for p in self._block._all_params():
+                if p.name in pnames and p.grad_req == "null" \
+                        and p._data is not None:
+                    grad = p._data._grad
+                    p._data = NDArray(new_w[p.name])
+                    p._data._grad = grad
         return NDArray(losses)
 
-    def _profiling_hook(self, label, fn, t0, dispatch_s, items):
+    def _profiling_hook(self, label, fn, dispatch_s, items):
         """mx.profiling capture for one dispatched step: register the
-        compiled program for lazy cost analysis, feed the roofline's
-        step clock, and drop a timeline span.  On a synchronous backend
-        (CPU CI) the dispatch wall IS the step time; on async TPU
-        dispatch the steady-state loop is back-pressured by buffer
-        donation, so per-call wall converges to step time -- callers
-        with externally synced windows can refine via
-        ``profiling.record_step``."""
-        from ..profiling import timeline
+        compiled program for lazy cost analysis and feed the roofline's
+        step clock.  On a synchronous backend (CPU CI) the dispatch wall
+        IS the step time; on async TPU dispatch the steady-state loop is
+        back-pressured by buffer donation, so per-call wall converges to
+        step time -- callers with externally synced windows can refine
+        via ``profiling.record_step``.  (The step's host spans are
+        ``mx.train_step`` and its children, through ``obs.span``.)"""
         _profiling.capture_jit(label, fn, self._last_call[1],
                                key=("train_step", id(fn)),
                                kind="train_step")
         _profiling.record_step(label, dispatch_s, items=items)
-        timeline.record(label, t0, dispatch_s,
-                        {"items": items, "donated": self._donate})
-        if self._donate:
-            timeline.instant(label + ".donate",
-                             {"buffers": "params+opt_state"})
+
+    def _note_program(self, kind, fn, args):
+        """Leave ``obs.program_scopes`` a way to this program's compiled
+        text: lowered for the arguments' own shardings, it is the program
+        that runs, so the lowering hits the jit's own compile or the
+        persistent cache."""
+        specs = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                           sharding=a.sharding), args)
+
+        def text():
+            with scopes_in_cache_key():     # the key it was compiled under
+                return fn.lower(*specs).compile().as_text()
+
+        _obs.note_program("%s:%s" % (kind, type(self._block).__name__),
+                          text)
 
     def cost_analysis(self):
         """XLA's cost analysis of the most recently dispatched compiled
@@ -636,8 +672,6 @@ class TrainStep:
 
     # -- call ----------------------------------------------------------
     def __call__(self, data, label=None, batch_size=None):
-        from .. import autograd as _ag
-        from ..ndarray import bulk as _bulk
         if label is None:
             # a fed batch (dataio.DeviceFeed) carries device-resident
             # data+label; unpack without any re-transfer
@@ -648,88 +682,109 @@ class TrainStep:
                 raise MXNetError(
                     "TrainStep needs (data, label) or a DeviceBatch "
                     "with a label component")
+        step = _obs.span("mx.train_step",
+                         step=self._trainer._optimizer.num_update + 1)
+        with step:
+            return self._step(step, data, label, batch_size)
+
+    def _step(self, step, data, label, batch_size):
+        from .. import autograd as _ag
+        from ..ndarray import bulk as _bulk
         tr = self._trainer
         opt = tr._optimizer
-        # value dtype must match the declared Parameter dtype BEFORE
-        # optimizer states are created from it (a drifted value would
-        # bake mismatched state dtypes in for the whole run);
-        # Parameter.cast also reallocates the grad buffer
-        for p in tr._params:
-            if p._data is not None and p.dtype is not None \
-                    and p._data._data.dtype != p.dtype:
-                p.cast(p.dtype)
-        self._ensure_states()
-        data, label = self._stage_io(data, label)
-        if any(p._deferred_init is not None
-               for p in self._block._all_params()):
-            # materialize deferred shapes with one eager forward;
-            # Parameter._sharding (set by replicate_block) places them
-            # replicated on the mesh
-            with _ag.pause():
-                self._block(data)
+        with _obs.span("mx.train_step.prep"):
+            # value dtype must match the declared Parameter dtype BEFORE
+            # optimizer states are created from it (a drifted value would
+            # bake mismatched state dtypes in for the whole run);
+            # Parameter.cast also reallocates the grad buffer
+            for p in tr._params:
+                if p._data is not None and p.dtype is not None \
+                        and p._data._data.dtype != p.dtype:
+                    p.cast(p.dtype)
             self._ensure_states()
+            data, label = self._stage_io(data, label)
+            if any(p._deferred_init is not None
+                   for p in self._block._all_params()):
+                # materialize deferred shapes with one eager forward;
+                # Parameter._sharding (set by replicate_block) places them
+                # replicated on the mesh
+                with _ag.pause():
+                    self._block(data)
+                self._ensure_states()
 
-        from ..analysis import numerics as _numerics
-        from .. import chaos as _chaos
-        # numerics.nonfinite chaos point: poison_action marks the box
-        # and THIS step injects the NaN into its own batch, so the
-        # fault flows through forward/backward and must be caught by
-        # the sentinel, not the injector (docs/numerics.md)
-        _box = {}
-        _chaos.fail_point("numerics.nonfinite", box=_box,
-                          step=opt.num_update + 1)
-        if _box.get("poison"):
-            data = _numerics.poison_nd(data)
+            from ..analysis import numerics as _numerics
+            from .. import chaos as _chaos
+            # numerics.nonfinite chaos point: poison_action marks the box
+            # and THIS step injects the NaN into its own batch, so the
+            # fault flows through forward/backward and must be caught by
+            # the sentinel, not the injector (docs/numerics.md)
+            _box = {}
+            _chaos.fail_point("numerics.nonfinite", box=_box,
+                              step=opt.num_update + 1)
+            if _box.get("poison"):
+                data = _numerics.poison_nd(data)
 
-        training = True
-        from .. import amp as _amp
-        key = (tuple(data.shape), str(data.dtype), tuple(label.shape),
-               str(label.dtype), training, _amp.policy_token())
-        entry = self._cache.get(key)
-        if entry is None:
-            entry = self._build([data, label], training)
-            self._cache[key] = entry
-        fn, probe, idxs, pnames, pmap = entry
+            training = True
+            from .. import amp as _amp
+            key = (tuple(data.shape), str(data.dtype), tuple(label.shape),
+                   str(label.dtype), training, _amp.policy_token())
+            entry = self._cache.get(key)
+            built = entry is None
+            if built:
+                entry = self._build([data, label], training)
+                self._cache[key] = entry
+            fn, probe, idxs, pnames, pmap = entry
 
-        # host-side per-step bookkeeping (matches Optimizer._update_count)
-        for i in idxs:
-            opt._index_update_count[i] = \
-                opt._index_update_count.get(i, opt.begin_num_update) + 1
-            opt.num_update = max(opt._index_update_count[i], opt.num_update)
-        rep = _replicated(self._mesh) if self._mesh is not None else None
-        t = _feed_scalar(opt._index_update_count[idxs[0]] if idxs else
-                         opt.num_update, np.int32, rep)
-        lrs = _feed_scalar([opt._get_lr(i) for i in idxs], np.float32, rep)
-        wds = _feed_scalar([opt._get_wd(i) for i in idxs], np.float32, rep)
-        bs = batch_size if batch_size is not None \
-            else data.shape[self._batch_axis]
-        scaler = getattr(tr, "_amp_loss_scaler", None)
-        ls = scaler.loss_scale if scaler is not None else 1.0
-        rescale = _feed_scalar(tr._scale / bs / ls, np.float32, rep)
-        loss_scale = _feed_scalar(ls, np.float32, rep)
+            # host-side per-step bookkeeping (matches Optimizer._update_count)
+            for i in idxs:
+                opt._index_update_count[i] = \
+                    opt._index_update_count.get(i, opt.begin_num_update) + 1
+                opt.num_update = max(opt._index_update_count[i],
+                                     opt.num_update)
+            rep = _replicated(self._mesh) if self._mesh is not None else None
+            t = _feed_scalar(opt._index_update_count[idxs[0]] if idxs else
+                             opt.num_update, np.int32, rep)
+            lrs = _feed_scalar([opt._get_lr(i) for i in idxs], np.float32,
+                               rep)
+            wds = _feed_scalar([opt._get_wd(i) for i in idxs], np.float32,
+                               rep)
+            bs = batch_size if batch_size is not None \
+                else data.shape[self._batch_axis]
+            step.set(items=bs)
+            scaler = getattr(tr, "_amp_loss_scaler", None)
+            ls = scaler.loss_scale if scaler is not None else 1.0
+            rescale = _feed_scalar(tr._scale / bs / ls, np.float32, rep)
+            loss_scale = _feed_scalar(ls, np.float32, rep)
 
-        upd = tr._updater
-        pvals = {n: pmap[n]._data._data for n in pnames}
-        svals = {i: jax.tree_util.tree_map(
-            lambda x: x._data if isinstance(x, NDArray) else x,
-            upd.states.get(i),
-            is_leaf=lambda x: isinstance(x, NDArray) or x is None)
-            for i in idxs}
-        rng = _random_mod.next_key()
+            upd = tr._updater
+            pvals = {n: pmap[n]._data._data for n in pnames}
+            svals = {i: jax.tree_util.tree_map(
+                lambda x: x._data if isinstance(x, NDArray) else x,
+                upd.states.get(i),
+                is_leaf=lambda x: isinstance(x, NDArray) or x is None)
+                for i in idxs}
+            rng = _random_mod.next_key()
 
-        args = (pvals, svals, data._data, label._data, rng, t, lrs, wds,
-                rescale, loss_scale)
-        self._last_call = (fn, jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args))
-        # the jit donates the param/state buffers; any still-pending
-        # bulked-eager region referencing them must execute first
-        _bulk.flush()
-        t0p = time.perf_counter() if _profiling._ENABLED else None
-        new_w, new_s, aux, mean_loss, all_finite = fn(*args)
-        if t0p is not None:
-            label = "train_step:%s" % type(self._block).__name__
-            self._profiling_hook(label, fn, t0p,
-                                 time.perf_counter() - t0p, bs)
+            args = (pvals, svals, data._data, label._data, rng, t, lrs, wds,
+                    rescale, loss_scale)
+            self._last_call = (fn, jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), args))
+            if built:
+                self._note_program("train_step", fn, args)
+        with _obs.span("mx.train_step.dispatch"):
+            # the jit donates the param/state buffers; any still-pending
+            # bulked-eager region referencing them must execute first
+            _bulk.flush()
+            t0p = time.perf_counter() if _profiling._ENABLED else None
+            # a program's first dispatch compiles it: with its scopes in
+            # the cache key, so that a trace reads this version's names
+            with scopes_in_cache_key() if built \
+                    else contextlib.nullcontext():
+                new_w, new_s, aux, mean_loss, all_finite = fn(*args)
+            if t0p is not None:
+                label = "train_step:%s" % type(self._block).__name__
+                self._profiling_hook(label, fn,
+                                     time.perf_counter() - t0p, bs)
         finite_host = None
         if scaler is not None:
             # host sync only in fp16 mode: the scaler's growth/backoff
@@ -737,20 +792,22 @@ class TrainStep:
             finite_host = bool(np.asarray(all_finite))
             scaler.update_scale(not finite_host)
 
-        # rebind updated weights/states/aux into the framework objects
-        # (ALL params: buffers were donated, unchanged ones aliased through)
-        for n in pnames:
-            pmap[n]._data._data = new_w[n]
-        for i in idxs:
-            s = upd.states.get(i)
-            flat_new = jax.tree_util.tree_leaves(new_s[i])
-            for leaf, nv in zip(_state_leaves(s), flat_new):
-                leaf._data = nv
-        for p in self._block._all_params():
-            if p.name in aux:
-                grad = p._data._grad if p._data is not None else None
-                p._data = NDArray(aux[p.name])
-                p._data._grad = grad
+        with _obs.span("mx.train_step.rebind"):
+            # rebind updated weights/states/aux into the framework objects
+            # (ALL params: buffers were donated, unchanged ones aliased
+            # through)
+            for n in pnames:
+                pmap[n]._data._data = new_w[n]
+            for i in idxs:
+                s = upd.states.get(i)
+                flat_new = jax.tree_util.tree_leaves(new_s[i])
+                for leaf, nv in zip(_state_leaves(s), flat_new):
+                    leaf._data = nv
+            for p in self._block._all_params():
+                if p.name in aux:
+                    grad = p._data._grad if p._data is not None else None
+                    p._data = NDArray(aux[p.name])
+                    p._data._grad = grad
 
         if _numerics.check_enabled():
             # the sentinel reads the ONE boolean the compiled step

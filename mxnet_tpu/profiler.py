@@ -155,28 +155,19 @@ def state():
     return _state
 
 
-@contextlib.contextmanager
 def scope(name):
-    """Named region.  Shows up in the XLA device trace (reference:
-    profiler scope in ``MXNET_PROFILER_SCOPE``), AND -- via
-    ``jax.named_scope`` -- in the ``op_name`` metadata of any HLO
-    traced inside it, which is how framework provenance reaches the
-    mx.profiling CostReport's per-scope attribution.  With
-    mx.profiling enabled it also lands as a span on the step
-    timeline."""
-    from . import profiling as _profiling
-    if not _scopes_enabled and not _profiling._ENABLED:
-        yield
-        return
-    with contextlib.ExitStack() as stack:
-        if _scopes_enabled:
-            import jax
-            stack.enter_context(jax.profiler.TraceAnnotation(name))
-            stack.enter_context(jax.named_scope(name))
-        if _profiling._ENABLED:
-            from .profiling import timeline
-            stack.enter_context(timeline.span(name))
-        yield
+    """Named region, through the one span call (``obs.span``): a
+    ``jax.profiler.TraceAnnotation`` in the device trace (reference:
+    profiler scope in ``MXNET_PROFILER_SCOPE``) AND -- via
+    ``jax.named_scope`` -- the ``op_name`` metadata of any HLO traced
+    inside it, which is how framework provenance reaches the
+    mx.profiling CostReport's per-scope attribution.  With obs tracing
+    armed it is also a span in the ``obs`` ring.  A no-op while the
+    profiler is stopped or paused."""
+    if not _scopes_enabled:
+        return contextlib.nullcontext()
+    from . import obs as _obs
+    return _obs.span(name, hlo=True)
 
 
 class Profiler:
@@ -225,8 +216,8 @@ class _NamedRegion:
 
     def start(self):
         if _scopes_enabled:
-            import jax
-            self._cm = jax.profiler.TraceAnnotation(self.name)
+            from . import obs as _obs
+            self._cm = _obs.span(self.name)
             self._cm.__enter__()
 
     def stop(self):
@@ -303,8 +294,8 @@ def marker(name, scope="process"):
     """Instant event (reference: ``profiler.Marker``/``set_marker``):
     recorded as a zero-length annotation."""
     if _scopes_enabled:
-        import jax
-        with jax.profiler.TraceAnnotation("marker:" + name):
+        from . import obs as _obs
+        with _obs.span("marker:" + name):
             pass
 
 

@@ -17,9 +17,6 @@ per-op stats, rebuilt on XLA's own cost model:
 - An analytic roofline turns measured step time + CostReport into
   achieved-vs-peak compute and bandwidth per category, labeling each
   category compute- or memory-bound -- MFU decomposed.
-- A lightweight always-available step timeline (host spans +
-  transfer/donation events) exports as Chrome-trace JSON
-  (``chrome://tracing`` / Perfetto) without TensorBoard.
 - The ``mxprof`` CLI (``report`` / ``diff``) renders report artifacts
   and names the categories whose FLOPs/bytes/peak-HBM drifted between
   two runs -- the regression-attribution contract of ROADMAP item 2.
@@ -144,11 +141,10 @@ def save_reports(dirpath=None):
 
 
 def reset():
-    """Drop captured reports, pending specs, step times, and timeline
-    events (test isolation)."""
-    from . import store, timeline
+    """Drop captured reports, pending specs and step times (test
+    isolation)."""
+    from . import store
     store.clear()
-    timeline.clear()
 
 
 def report_for(obj, label=None, step_time_s=None, items_per_step=None):

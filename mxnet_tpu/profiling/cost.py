@@ -1,8 +1,8 @@
 """CostReport: XLA cost/memory analysis + per-category attribution.
 
 One report per compiled executable, keyed by a fingerprint of the
-post-optimization HLO (normalized: trace metadata and the module name
-are stripped, so identical programs recompiled -- or retraced from a
+post-optimization HLO (normalized: trace metadata, the source-position
+tables and the module name are stripped, so identical programs recompiled -- or retraced from a
 fresh ``jax.jit`` of the same code -- fingerprint identically).
 
 The per-category numbers are the ``hlo.py`` analytic estimates
@@ -23,13 +23,20 @@ SCHEMA = "mxprof.cost_report.v1"
 
 _NORM_METADATA = re.compile(r",?\s*metadata=\{[^}]*\}")
 _NORM_MODULE = re.compile(r"^HloModule\s+\S+", re.MULTILINE)
+# the source-position tables HLO text carries outside metadata={...}:
+# a header line, then numbered entries up to a blank line
+_NORM_TABLES = re.compile(
+    r"^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n"
+    r"(?:\d+ .*\n)*\n*", re.MULTILINE)
 
 
 def fingerprint(text):
     """Stable identity of a compiled program: sha256 of the HLO text
-    with volatile parts (module name, source-location metadata)
-    normalized away."""
+    with volatile parts (module name, ``metadata={...}`` with its
+    ``op_name`` scopes, the source-position tables) normalized away, so
+    neither a moved line nor a ``jax.named_scope`` changes it."""
     norm = _NORM_METADATA.sub("", text)
+    norm = _NORM_TABLES.sub("", norm)
     norm = _NORM_MODULE.sub("HloModule <norm>", norm)
     return hashlib.sha256(norm.encode()).hexdigest()[:16]
 

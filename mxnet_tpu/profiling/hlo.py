@@ -55,7 +55,7 @@ _DTYPE_BYTES = {
 }
 
 _SHAPE_RE = re.compile(r"\b([a-z][a-z0-9]*)\[([0-9,<=\s]*)\]")
-_INSTR_RE = re.compile(r"^\s+(?:ROOT\s+)?%[\w.\-]+\s*=\s*(.*)$")
+_INSTR_RE = re.compile(r"^\s+(?:ROOT\s+)?%([\w.\-]+)\s*=\s*(.*)$")
 _COMP_RE = re.compile(r"^(?:ENTRY\s+)?%([\w.\-]+)\s*\(.*\)\s*->")
 _METADATA_RE = re.compile(r",?\s*metadata=\{[^}]*\}")
 _STRING_RE = re.compile(r'"[^"]*"')
@@ -129,11 +129,12 @@ def _nbytes(shapes):
 
 
 class Instr:
-    __slots__ = ("opcode", "out_shapes", "operand_shapes", "attrs",
+    __slots__ = ("name", "opcode", "out_shapes", "operand_shapes", "attrs",
                  "op_name")
 
-    def __init__(self, opcode, out_shapes, operand_shapes, attrs,
+    def __init__(self, name, opcode, out_shapes, operand_shapes, attrs,
                  op_name):
+        self.name = name
         self.opcode = opcode
         self.out_shapes = out_shapes
         self.operand_shapes = operand_shapes
@@ -165,7 +166,7 @@ def parse_module(text):
         m = _INSTR_RE.match(line)
         if not m:
             continue
-        rhs = m.group(1)
+        name, rhs = m.group(1), m.group(2)
         op_name_m = _OPNAME_RE.search(rhs)
         op_name = op_name_m.group(1) if op_name_m else None
         clean = _METADATA_RE.sub("", rhs)
@@ -209,7 +210,7 @@ def parse_module(text):
                     break
         operands = rest[start + 1:end]
         attrs = rest[end + 1:]
-        instr = Instr(opcode, _parse_shapes(out_txt),
+        instr = Instr(name, opcode, _parse_shapes(out_txt),
                       _parse_shapes(operands), attrs, op_name)
         comps[cur].append(instr)
         for rx in (_CALLS_RE, _BODY_RE, _COND_RE, _TRUE_RE, _FALSE_RE):
@@ -221,6 +222,26 @@ def parse_module(text):
         if opcode == "call":
             refs[cur].extend(_TOAPPLY_RE.findall(clean_noquote))
     return entry, comps, refs
+
+
+_MODULE_NAME_RE = re.compile(r"^HloModule\s+([^\s,]+)", re.MULTILINE)
+
+
+def module_name(text):
+    """The compiled module's name (``jit_step_fn``), or None."""
+    m = _MODULE_NAME_RE.search(text)
+    return m.group(1) if m else None
+
+
+def scope_map(text):
+    """``{instruction name: op_name}`` of a compiled module's text: the
+    ``jax.named_scope`` path each instruction was traced under, for a
+    reader of a device trace, whose events carry the instruction's name
+    and not its metadata.  An instruction the compiler made itself (a
+    layout copy, a rematerialised fusion) has none and is left out."""
+    _entry, comps, _refs = parse_module(text)
+    return {ins.name: ins.op_name for instrs in comps.values()
+            for ins in instrs if ins.op_name}
 
 
 def category_of(instr):

@@ -29,6 +29,8 @@ __all__ = ["CompileCache", "compile_through", "stablehlo_fingerprint"]
 # name carries the traced function's name.  None of those affect the
 # program, all of them vary across processes/refactors.
 _LOC_REF = re.compile(r"\s*loc\(#loc\d*\)")
+# an argument's inline location: loc("x") -- a name, never nested
+_LOC_INLINE = re.compile(r"\s*loc\(\"[^\"]*\"\)")
 _LOC_DEF = re.compile(r"^#loc\d*\s*=\s*loc\(.*\)\s*$", re.MULTILINE)
 _LOC_BARE = re.compile(r"^#loc\s*=\s*loc\(.*\)\s*$", re.MULTILINE)
 _MODULE = re.compile(r"^module @\S+", re.MULTILINE)
@@ -40,9 +42,10 @@ def stablehlo_fingerprint(text):
     and the module name are normalized away, then the profiling
     subsystem's fingerprint hashes the rest."""
     from ..profiling.cost import fingerprint
-    norm = _LOC_REF.sub("", text)
-    norm = _LOC_DEF.sub("", norm)
+    norm = _LOC_DEF.sub("", text)
     norm = _LOC_BARE.sub("", norm)
+    norm = _LOC_REF.sub("", norm)
+    norm = _LOC_INLINE.sub("", norm)
     norm = _MODULE.sub("module @<norm>", norm)
     return fingerprint(norm)
 

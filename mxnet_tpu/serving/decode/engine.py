@@ -24,10 +24,14 @@ whose batch membership changes every step.  This module is the loop:
   a running sequence can never die for cache space.
 - **token streaming**: :meth:`DecodeEngine.submit` returns a
   :class:`GenerationStream` iterator; every token lands there as it is
-  decoded, with ``serving.decode_step`` trace spans recorded as
-  children of the request's ``serving.request`` root, so TTFT and
-  inter-token latency are product-layer measurements
-  (``decode.ttft`` / ``decode.inter_token`` timers).
+  decoded, so TTFT and inter-token latency are product-layer
+  measurements (``decode.ttft`` / ``decode.inter_token`` timers).
+- **spans**: the worker's time is named through ``obs.span`` --
+  ``mx.decode.idle``, ``.admit`` (``.queue_wait``), ``.prefill`` and
+  ``.step``, the last two split into ``.build`` / ``.call`` / ``.emit``.
+  ONE ``mx.decode.step`` a step (``n``, ``bucket``, ``max_slots``) whose
+  ring record links the ``serving.request`` root of every sequence it
+  served (docs/observability.md).
 
 Hot swap (the PR-12 contract extended mid-decode): re-registering a
 :class:`GenerativeServable` installs the replacement for NEW requests
@@ -48,7 +52,7 @@ from ... import chaos as _chaos
 from ... import obs as _obs
 from ... import sync as _sync
 from ... import telemetry as _telemetry
-from ...base import MXNetError
+from ...base import MXNetError, scopes_in_cache_key
 from ..batcher import RequestTimeout, ServableClosed, ServingQueueFull
 from ..cache import compile_through, stablehlo_fingerprint
 from ..loop import RegistryWatcher as _RegistryWatcher
@@ -160,6 +164,13 @@ class _GenRequest:
         return len(self.prompt) + self.generated - 1
 
 
+def _request_links(reqs):
+    """Span ids of the requests' ``serving.request`` roots: what a step,
+    a prefill or a queue wait serves without being its child.  Empty
+    unless tracing was armed when the requests were submitted."""
+    return [r.tctx.span_id for r in reqs if r.tctx is not None]
+
+
 class _AotPrograms:
     """lower -> fingerprint -> CompileCache -> compile, per static
     shape key (the BucketExecutorPool discipline generalized to
@@ -179,10 +190,15 @@ class _AotPrograms:
         lowered = jfn.lower(*specs)
         fp = stablehlo_fingerprint(lowered.as_text())
         # compiled here, never at the first request: warmup() promises
-        # that no request pays a compile
-        call = compile_through(self._cache, fp, jfn, lowered, specs)
+        # that no request pays a compile; with its scopes in the XLA
+        # cache's key, so that a trace reads this version's names
+        with scopes_in_cache_key():
+            call = compile_through(self._cache, fp, jfn, lowered, specs)
         self._programs[key] = call
         self.fingerprints[key] = fp
+        # for a reader of a device trace: instruction name -> scope
+        _obs.note_program("%s:%s:%s" % ((self._label,) + tuple(key)),
+                          call.as_text)
         return call
 
     def get(self, key):
@@ -263,18 +279,21 @@ class DecodeEngine:
     # -- AOT build ------------------------------------------------------
     def _prefill_impl(self, params, kv_k, kv_v, tokens, table,
                       true_len):
+        import jax
         import jax.numpy as jnp
         bs = self.cache.block_size
         logits, ks, vs = self.model.prefill_kv(params, tokens)
         lb = tokens.shape[1]
-        pos = jnp.arange(lb, dtype=jnp.int32)
-        blk = jnp.where(pos < true_len,
-                        jnp.take(table, pos // bs), SCRATCH_BLOCK)
-        off = pos % bs
-        kv_k = kv_k.at[:, blk, off].set(ks.astype(kv_k.dtype))
-        kv_v = kv_v.at[:, blk, off].set(vs.astype(kv_v.dtype))
-        last = jnp.take(logits[0], true_len - 1, axis=0)
-        first_token = jnp.argmax(last).astype(jnp.int32)
+        with jax.named_scope("mx.kv_scatter"):
+            pos = jnp.arange(lb, dtype=jnp.int32)
+            blk = jnp.where(pos < true_len,
+                            jnp.take(table, pos // bs), SCRATCH_BLOCK)
+            off = pos % bs
+            kv_k = kv_k.at[:, blk, off].set(ks.astype(kv_k.dtype))
+            kv_v = kv_v.at[:, blk, off].set(vs.astype(kv_v.dtype))
+        with jax.named_scope("mx.lm_head"):
+            last = jnp.take(logits[0], true_len - 1, axis=0)
+            first_token = jnp.argmax(last).astype(jnp.int32)
         return first_token, kv_k, kv_v
 
     def _decode_impl(self, params, kv_k, kv_v, tokens, positions,
@@ -400,16 +419,21 @@ class DecodeEngine:
     def _worker(self):
         while True:
             with self._cond:
-                while not self._pending and not self._active \
+                if not self._pending and not self._active \
                         and not self._closed:
-                    self._cond.wait(_IDLE_WAIT_S)
+                    with _obs.span("mx.decode.idle"):
+                        while not self._pending and not self._active \
+                                and not self._closed:
+                            self._cond.wait(_IDLE_WAIT_S)
                 if self._closed:
                     if not self._drain:
                         self._abort_locked()
                         return
                     if not self._pending and not self._active:
                         return
-            self._admit()
+            if self._pending:
+                with _obs.span("mx.decode.admit"):
+                    self._admit()
             if self._active:
                 self._step()
 
@@ -434,6 +458,9 @@ class DecodeEngine:
                         or len(self._active) >= self.max_slots:
                     return
                 req = self._pending.popleft()
+            with _obs.span("mx.decode.queue_wait", since=req.t_submit,
+                           links=_request_links((req,))):
+                pass
             now = time.perf_counter()
             if req.stream.cancelled:
                 self._finish(req, "cancel")
@@ -449,62 +476,81 @@ class DecodeEngine:
             self._prefill(req)
 
     def _prefill(self, req):
-        import jax
         bucket = self._bucket(self.prefill_buckets, len(req.prompt),
                               "prefill")
-        tokens = np.zeros((1, bucket), np.int32)
-        tokens[0, :len(req.prompt)] = req.prompt
-        table = self.cache.padded_table(req.table,
-                                        self.max_blocks_per_seq)
+        with _obs.span("mx.decode.prefill", bucket=bucket,
+                       prompt=len(req.prompt),
+                       links=_request_links((req,))):
+            self._prefill_spanned(req, bucket)
+
+    def _prefill_spanned(self, req, bucket):
+        import jax
+        with _obs.span("mx.decode.prefill.build"):
+            tokens = np.zeros((1, bucket), np.int32)
+            tokens[0, :len(req.prompt)] = req.prompt
+            table = self.cache.padded_table(req.table,
+                                            self.max_blocks_per_seq)
         t0 = time.perf_counter()
         call = self._programs.get(("prefill", bucket))
         try:
-            _chaos.fail_point("serving.decode.prefill",
-                              model=self._label, bucket=bucket)
-            first, kv_k, kv_v = call(
-                self.params, self.cache.keys, self.cache.values,
-                tokens, table, np.int32(len(req.prompt)))
-            first = int(jax.device_get(first))
+            with _obs.span("mx.decode.prefill.call"):
+                _chaos.fail_point("serving.decode.prefill",
+                                  model=self._label, bucket=bucket)
+                first, kv_k, kv_v = call(
+                    self.params, self.cache.keys, self.cache.values,
+                    tokens, table, np.int32(len(req.prompt)))
+                first = int(jax.device_get(first))
         except Exception as e:
             if _telemetry._ENABLED:
                 _telemetry.hooks.serving_error(self._label)
             self.cache.free(req.table)
             req.stream._finish("error", error=e)
             return
-        self.cache.keys, self.cache.values = kv_k, kv_v
-        self.cache.note_tokens(req.table, len(req.prompt) + 1)
-        now = time.perf_counter()
-        if _telemetry._ENABLED:
-            _telemetry.hooks.decode_prefill(self._label, bucket,
-                                            len(req.prompt), now - t0)
-            _telemetry.hooks.decode_ttft(now - req.t_submit)
-        self._emit(req, first, t0, now)
-        if not self._maybe_finish(req):
-            self._active.append(req)
+        with _obs.span("mx.decode.prefill.emit"):
+            self.cache.keys, self.cache.values = kv_k, kv_v
+            self.cache.note_tokens(req.table, len(req.prompt) + 1)
+            now = time.perf_counter()
+            if _telemetry._ENABLED:
+                _telemetry.hooks.decode_prefill(self._label, bucket,
+                                                len(req.prompt), now - t0)
+                _telemetry.hooks.decode_ttft(now - req.t_submit)
+            self._emit(req, first, now)
+            if not self._maybe_finish(req):
+                self._active.append(req)
 
     def _step(self):
-        """ONE decode iteration for every live slot."""
-        import jax
+        """ONE decode iteration for every live slot, under ONE
+        ``mx.decode.step`` span."""
         n = len(self._active)
         bucket = self._bucket(self.decode_buckets, n, "decode")
-        tokens = np.zeros((bucket,), np.int32)
-        positions = np.zeros((bucket,), np.int32)
-        tables = np.full((bucket, self.max_blocks_per_seq),
-                         SCRATCH_BLOCK, np.int32)
-        for i, req in enumerate(self._active):
-            tokens[i] = req.last_token
-            positions[i] = req.position
-            tables[i] = self.cache.padded_table(
-                req.table, self.max_blocks_per_seq)
+        with _obs.span("mx.decode.step", n=n, bucket=bucket,
+                       max_slots=self.max_slots,
+                       links=_request_links(self._active)):
+            self._step_spanned(n, bucket)
+
+    def _step_spanned(self, n, bucket):
+        import jax
+        with _obs.span("mx.decode.step.build"):
+            tokens = np.zeros((bucket,), np.int32)
+            positions = np.zeros((bucket,), np.int32)
+            tables = np.full((bucket, self.max_blocks_per_seq),
+                             SCRATCH_BLOCK, np.int32)
+            for i, req in enumerate(self._active):
+                tokens[i] = req.last_token
+                positions[i] = req.position
+                tables[i] = self.cache.padded_table(
+                    req.table, self.max_blocks_per_seq)
         t0 = time.perf_counter()
         call = self._programs.get(("decode", bucket))
         try:
-            _chaos.fail_point("serving.decode.step", model=self._label,
-                              occupancy=n, bucket=bucket)
-            out, kv_k, kv_v = call(self.params, self.cache.keys,
-                                   self.cache.values, tokens,
-                                   positions, tables)
-            out = jax.device_get(out)
+            with _obs.span("mx.decode.step.call"):
+                _chaos.fail_point("serving.decode.step",
+                                  model=self._label, occupancy=n,
+                                  bucket=bucket)
+                out, kv_k, kv_v = call(self.params, self.cache.keys,
+                                       self.cache.values, tokens,
+                                       positions, tables)
+                out = jax.device_get(out)
         except Exception as e:
             if _telemetry._ENABLED:
                 _telemetry.hooks.serving_error(self._label)
@@ -513,36 +559,31 @@ class DecodeEngine:
                 req.stream._finish("error", error=e)
             del self._active[:]
             return
-        self.cache.keys, self.cache.values = kv_k, kv_v
-        now = time.perf_counter()
-        if _telemetry._ENABLED:
-            _telemetry.hooks.decode_step(self._label, n, bucket,
-                                         now - t0)
-        finished = []
-        for i, req in enumerate(self._active):
-            self._emit(req, int(out[i]), t0, now)
-            self.cache.note_tokens(req.table,
-                                   len(req.prompt) + req.generated)
-            if self._maybe_finish(req):
-                finished.append(req)
-        if finished:
-            # finished sequences vacate their slot IMMEDIATELY: the
-            # next iteration packs the survivors into a smaller bucket
-            self._active = [r for r in self._active
-                            if r not in finished]
+        with _obs.span("mx.decode.step.emit"):
+            self.cache.keys, self.cache.values = kv_k, kv_v
+            now = time.perf_counter()
+            if _telemetry._ENABLED:
+                _telemetry.hooks.decode_step(self._label, n, bucket,
+                                             now - t0)
+            finished = []
+            for i, req in enumerate(self._active):
+                self._emit(req, int(out[i]), now)
+                self.cache.note_tokens(req.table,
+                                       len(req.prompt) + req.generated)
+                if self._maybe_finish(req):
+                    finished.append(req)
+            if finished:
+                # finished sequences vacate their slot IMMEDIATELY: the
+                # next iteration packs the survivors into a smaller
+                # bucket
+                self._active = [r for r in self._active
+                                if r not in finished]
 
-    def _emit(self, req, token, t_step0, now):
+    def _emit(self, req, token, now):
         req.generated += 1
         req.last_token = token
         if _telemetry._ENABLED and req.t_last_emit is not None:
             _telemetry.hooks.decode_inter_token(now - req.t_last_emit)
-        if _obs._TRACE_ENABLED and req.tctx is not None:
-            _obs.record_span(
-                "serving.decode_step", req.tctx.child(),
-                parent_id=req.tctx.span_id, t0=t_step0,
-                dur=now - t_step0,
-                attrs={"model": self._label,
-                       "token_index": req.generated - 1})
         req.t_last_emit = now
         req.stream._push(token, now)
 
@@ -561,7 +602,7 @@ class DecodeEngine:
     def _finish(self, req, reason):
         self.cache.free(req.table)
         now = time.perf_counter()
-        if _obs._TRACE_ENABLED and req.tctx is not None:
+        if req.tctx is not None:        # tracing was armed at submit
             _obs.record_span(
                 "serving.request", req.tctx, t0=req.t_submit,
                 dur=now - req.t_submit,

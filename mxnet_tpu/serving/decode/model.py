@@ -111,35 +111,45 @@ class TinyGPT:
 
     # -- full causal forward (reference + prefill) ----------------------
     def _forward(self, params, tokens, collect_kv):
+        import jax
         import jax.numpy as jnp
+        scope = jax.named_scope
         b, t = tokens.shape
-        x = jnp.take(params["embed"], tokens, axis=0) \
-            + params["pos_embed"][:t][None]
+        with scope("mx.embed"):
+            x = jnp.take(params["embed"], tokens, axis=0) \
+                + params["pos_embed"][:t][None]
         causal = jnp.tril(jnp.ones((t, t), bool))
         kvs = []
         for i in range(self.num_layers):
             pre = "h%d_" % i
-            h = self._ln(x, params[pre + "ln1_g"], params[pre + "ln1_b"])
-            qkv = jnp.dot(h, params[pre + "wqkv"])
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = self._split_heads(q)               # (b, t, H, D)
-            k = self._split_heads(k)
-            v = self._split_heads(v)
+            layer = "h%d/" % i
+            with scope(layer + "qkv"):
+                h = self._ln(x, params[pre + "ln1_g"],
+                             params[pre + "ln1_b"])
+                qkv = jnp.dot(h, params[pre + "wqkv"])
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                q = self._split_heads(q)               # (b, t, H, D)
+                k = self._split_heads(k)
+                v = self._split_heads(v)
             if collect_kv:
                 kvs.append((k, v))
-            s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * self.scale
-            s = jnp.where(causal[None, None], s, -1e30)
-            w = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
-            w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True),
-                                1e-30)
-            att = jnp.einsum("bhqk,bkhd->bqhd", w, v)
-            att = att.reshape(b, t, self.units)
-            x = x + jnp.dot(att, params[pre + "wo"])
-            h2 = self._ln(x, params[pre + "ln2_g"],
-                          params[pre + "ln2_b"])
-            x = x + self._mlp(params, pre, h2)
-        x = self._ln(x, params["lnf_g"], params["lnf_b"])
-        logits = jnp.dot(x, params["embed"].T)     # tied unembedding
+            with scope(layer + "attention"):
+                s = jnp.einsum("bqhd,bkhd->bhqk", q, k) * self.scale
+                s = jnp.where(causal[None, None], s, -1e30)
+                w = jnp.exp(s - jnp.max(s, axis=-1, keepdims=True))
+                w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True),
+                                    1e-30)
+                att = jnp.einsum("bhqk,bkhd->bqhd", w, v)
+                att = att.reshape(b, t, self.units)
+            with scope(layer + "proj"):
+                x = x + jnp.dot(att, params[pre + "wo"])
+            with scope(layer + "mlp"):
+                h2 = self._ln(x, params[pre + "ln2_g"],
+                              params[pre + "ln2_b"])
+                x = x + self._mlp(params, pre, h2)
+        with scope("mx.lm_head"):
+            x = self._ln(x, params["lnf_g"], params["lnf_b"])
+            logits = jnp.dot(x, params["embed"].T)     # tied unembedding
         return (logits, kvs) if collect_kv else logits
 
     def full_logits(self, params, tokens):
@@ -150,10 +160,12 @@ class TinyGPT:
     def prefill_kv(self, params, tokens):
         """tokens (1, t) -> (logits (1, t, vocab), keys, values) with
         keys/values stacked per layer: (layers, t, heads, head_dim)."""
+        import jax
         import jax.numpy as jnp
         logits, kvs = self._forward(params, tokens, collect_kv=True)
-        ks = jnp.stack([k[0] for k, _v in kvs])    # (L, t, H, D)
-        vs = jnp.stack([v[0] for _k, v in kvs])
+        with jax.named_scope("mx.kv_scatter"):
+            ks = jnp.stack([k[0] for k, _v in kvs])    # (L, t, H, D)
+            vs = jnp.stack([v[0] for _k, v in kvs])
         return logits, ks, vs
 
     # -- decode step over the paged cache -------------------------------
@@ -167,41 +179,52 @@ class TinyGPT:
         max_blocks) int32.  Returns (next_token (s,) int32, logits
         (s, vocab), kv_keys', kv_values').
         """
+        import jax
         import jax.numpy as jnp
         from ...kernels.paged_attention import paged_attention
+        scope = jax.named_scope
         s = token_ids.shape[0]
-        blk = jnp.take_along_axis(
-            block_tables, (positions // block_size)[:, None],
-            axis=1)[:, 0]                           # (s,)
-        off = positions % block_size
-        ctx = (positions + 1).astype(jnp.int32).reshape(s, 1)
-        x = jnp.take(params["embed"], token_ids, axis=0) \
-            + jnp.take(params["pos_embed"], positions, axis=0)
+        with scope("mx.embed"):
+            blk = jnp.take_along_axis(
+                block_tables, (positions // block_size)[:, None],
+                axis=1)[:, 0]                           # (s,)
+            off = positions % block_size
+            ctx = (positions + 1).astype(jnp.int32).reshape(s, 1)
+            x = jnp.take(params["embed"], token_ids, axis=0) \
+                + jnp.take(params["pos_embed"], positions, axis=0)
         for i in range(self.num_layers):
             pre = "h%d_" % i
-            h = self._ln(x, params[pre + "ln1_g"], params[pre + "ln1_b"])
-            qkv = jnp.dot(h, params[pre + "wqkv"])
-            q, k, v = jnp.split(qkv, 3, axis=-1)
-            q = self._split_heads(q)                # (s, H, D)
-            k = self._split_heads(k)
-            v = self._split_heads(v)
+            layer = "h%d/" % i
+            with scope(layer + "qkv"):
+                h = self._ln(x, params[pre + "ln1_g"],
+                             params[pre + "ln1_b"])
+                qkv = jnp.dot(h, params[pre + "wqkv"])
+                q, k, v = jnp.split(qkv, 3, axis=-1)
+                q = self._split_heads(q)                # (s, H, D)
+                k = self._split_heads(k)
+                v = self._split_heads(v)
             # scatter the new token's K/V into its cache position;
             # padded slots carry all-scratch tables so their writes
             # land in the reserved scratch block
-            kv_keys = kv_keys.at[i, blk, off].set(
-                k.astype(kv_keys.dtype))
-            kv_values = kv_values.at[i, blk, off].set(
-                v.astype(kv_values.dtype))
-            att = paged_attention(q, kv_keys[i], kv_values[i],
-                                  block_tables, ctx, scale=self.scale)
-            att = att.reshape(s, self.units).astype(x.dtype)
-            x = x + jnp.dot(att, params[pre + "wo"])
-            h2 = self._ln(x, params[pre + "ln2_g"],
-                          params[pre + "ln2_b"])
-            x = x + self._mlp(params, pre, h2)
-        x = self._ln(x, params["lnf_g"], params["lnf_b"])
-        logits = jnp.dot(x, params["embed"].T)
-        next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with scope(layer + "kv_write"):
+                kv_keys = kv_keys.at[i, blk, off].set(
+                    k.astype(kv_keys.dtype))
+                kv_values = kv_values.at[i, blk, off].set(
+                    v.astype(kv_values.dtype))
+            with scope(layer + "attention"):
+                att = paged_attention(q, kv_keys[i], kv_values[i],
+                                      block_tables, ctx, scale=self.scale)
+                att = att.reshape(s, self.units).astype(x.dtype)
+            with scope(layer + "proj"):
+                x = x + jnp.dot(att, params[pre + "wo"])
+            with scope(layer + "mlp"):
+                h2 = self._ln(x, params[pre + "ln2_g"],
+                              params[pre + "ln2_b"])
+                x = x + self._mlp(params, pre, h2)
+        with scope("mx.lm_head"):
+            x = self._ln(x, params["lnf_g"], params["lnf_b"])
+            logits = jnp.dot(x, params["embed"].T)
+            next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return next_token, logits, kv_keys, kv_values
 
     # -- single-shot oracle ---------------------------------------------

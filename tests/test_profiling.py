@@ -1,7 +1,8 @@
 """Compiled-step cost accounting (ISSUE 6): CostReport capture across
 the compiled dispatch paths, category attribution summing to XLA
 totals, stable fingerprints, roofline bound labels, the mxprof CLI's
-report/diff contract, the step timeline, and the satellite surfaces
+report/diff contract, host spans through the one span call (the step
+timeline's successor), and the satellite surfaces
 (profiler.dumps, telemetry instruments, Features row)."""
 import json
 import os
@@ -13,8 +14,18 @@ import jax
 import jax.numpy as jnp
 
 import mxnet_tpu as mx
-from mxnet_tpu import gluon, profiling
-from mxnet_tpu.profiling import cli, cost, hlo, roofline, timeline
+from mxnet_tpu import gluon, obs, profiling
+from mxnet_tpu.profiling import cli, cost, hlo, roofline
+
+
+@pytest.fixture()
+def ring():
+    """The one span ring armed and empty; disarmed and emptied after."""
+    obs.trace.clear()
+    obs.enable_tracing()
+    yield obs
+    obs.disable_tracing()
+    obs.trace.clear()
 
 
 @pytest.fixture()
@@ -69,6 +80,35 @@ def test_fingerprint_stable_across_identical_recompiles():
     # and a different program must not
     r3 = cost.analyze_jit(jax.jit(_tiny_fn(64)), _tiny_args(64))
     assert r3["fingerprint"] != r1["fingerprint"]
+
+
+def test_fingerprints_ignore_named_scopes():
+    """A ``jax.named_scope`` changes ``op_name`` metadata and the
+    source-position tables, nothing the chip runs: the compiled-HLO
+    fingerprint and the serving layer's StableHLO fingerprint of the
+    same function with and without one are alike, so a scope cannot turn
+    a compile-cache hit into a miss."""
+    from mxnet_tpu.serving.cache import stablehlo_fingerprint
+
+    def plain(x, w):
+        return jnp.tanh(x @ w).sum()
+
+    def scoped(x, w):
+        with jax.named_scope("mx.loss"):
+            return jnp.tanh(x @ w).sum()
+
+    args = (jnp.ones((8, 16)), jnp.ones((16, 4)))
+    lowered = [jax.jit(f).lower(*args) for f in (plain, scoped)]
+    assert "mx.loss" in lowered[1].as_text(debug_info=True)
+    texts = [lo.compile().as_text() for lo in lowered]
+    assert cost.fingerprint(texts[0]) == cost.fingerprint(texts[1])
+    for debug_info in (False, True):
+        fps = {stablehlo_fingerprint(lo.as_text(debug_info=debug_info))
+               for lo in lowered}
+        assert len(fps) == 1, debug_info
+    other = jax.jit(scoped).lower(jnp.ones((8, 16)), jnp.ones((16, 8)))
+    assert cost.fingerprint(other.compile().as_text()) \
+        != cost.fingerprint(texts[1])
 
 
 def test_hlo_parser_attributes_conv_and_layout():
@@ -183,7 +223,6 @@ def test_disabled_mode_captures_nothing():
     x = mx.nd.ones((3, 3))
     (x * 2 + 1).asnumpy()
     assert profiling.reports() == []
-    assert timeline.events() == []
 
 
 # -- CLI: report + diff ------------------------------------------------
@@ -258,27 +297,30 @@ def test_mxprof_diff_self_zero_with_repeated_labels(tmp_path, prof,
     assert "no drift" in capsys.readouterr().out
 
 
-# -- timeline ----------------------------------------------------------
+# -- host spans: the one span call and the one exporter ----------------
 
-def test_timeline_records_and_exports_chrome_trace(tmp_path, prof):
-    with timeline.span("phase1", detail="x"):
+def test_span_records_and_exports_chrome_trace(tmp_path, ring):
+    """What the step timeline did, through ``obs.span`` and
+    ``obs.export_chrome_trace``: a span with its attributes lands in the
+    ring and in the Chrome JSON."""
+    with obs.span("phase1", detail="x"):
         pass
-    timeline.instant("marker")
-    evs = timeline.events()
-    names = [e["name"] for e in evs]
+    with obs.span("marker"):
+        pass
+    names = [r["name"] for r in obs.spans()]
     assert "phase1" in names and "marker" in names
     path = tmp_path / "trace.json"
-    trace = timeline.export_chrome_trace(str(path))
+    trace = obs.export_chrome_trace(str(path))
     assert trace["traceEvents"]
     loaded = json.loads(path.read_text())
-    assert loaded["traceEvents"][0]["ph"] in ("X", "i")
+    assert loaded["traceEvents"][0]["ph"] == "X"
     span_ev = next(e for e in loaded["traceEvents"]
                    if e["name"] == "phase1")
     assert span_ev["ph"] == "X" and span_ev["dur"] >= 0
-    assert span_ev["args"] == {"detail": "x"}
+    assert span_ev["args"]["detail"] == "x"
 
 
-def test_timeline_train_step_span(prof):
+def test_train_step_lands_as_a_span(ring):
     from mxnet_tpu.parallel import TrainStep
     net = gluon.nn.Dense(2)
     net.initialize()
@@ -287,9 +329,13 @@ def test_timeline_train_step_span(prof):
                        {"learning_rate": 0.1}, kvstore=None)
     step = TrainStep(net, gluon.loss.L2Loss(), tr, mesh=None)
     step(mx.nd.ones((4, 3)), mx.nd.ones((4, 2)))
-    names = [e["name"] for e in timeline.events()]
-    assert "train_step:Dense" in names
-    assert "train_step:Dense.donate" in names
+    by = {r["name"]: r for r in obs.spans()}
+    assert by["mx.train_step"]["attrs"] == {"step": 1, "items": 4}
+    for part in ("prep", "dispatch", "rebind"):
+        assert by["mx.train_step." + part]["parent"] \
+            == by["mx.train_step"]["span"]
+    names = [e["name"] for e in obs.export_chrome_trace()["traceEvents"]]
+    assert "mx.train_step" in names and "mx.train_step.dispatch" in names
 
 
 # -- satellites wired through ------------------------------------------

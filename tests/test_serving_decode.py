@@ -348,7 +348,7 @@ def test_close_without_drain_resolves_streams(make_engine):
 # tracing
 # ---------------------------------------------------------------------
 
-def test_decode_step_spans_under_request_root(make_engine):
+def test_one_step_span_a_step_links_the_request_root(make_engine):
     obs.enable_tracing()
     try:
         eng = make_engine()
@@ -358,13 +358,30 @@ def test_decode_step_spans_under_request_root(make_engine):
         obs.disable_tracing()
     roots = [s for s in spans if s["name"] == "serving.request"
              and s["attrs"].get("generative")]
-    steps = [s for s in spans if s["name"] == "serving.decode_step"]
     assert len(roots) == 1
     assert roots[0]["attrs"]["tokens"] == 5
-    assert len(steps) == 5
-    assert {s["parent"] for s in steps} == {roots[0]["span"]}
-    assert {s["trace"] for s in steps} == {roots[0]["trace"]}
-    assert [s["attrs"]["token_index"] for s in steps] == list(range(5))
+    # one prefill and four decode steps made the five tokens: ONE span
+    # each, none per token, every one linking the request it served
+    by = {}
+    for s in spans:
+        by.setdefault(s["name"], []).append(s)
+    assert "serving.decode_step" not in by
+    assert len(by["mx.decode.prefill"]) == 1
+    steps = by["mx.decode.step"]
+    assert len(steps) == 4
+    for s in steps + by["mx.decode.prefill"] + by["mx.decode.queue_wait"]:
+        assert s["links"] == [roots[0]["span"]]
+    assert {(s["attrs"]["n"], s["attrs"]["bucket"]) for s in steps} \
+        == {(1, eng.decode_buckets[0])}
+    assert steps[0]["attrs"]["max_slots"] == eng.max_slots
+    for part in ("build", "call", "emit"):
+        kids = by["mx.decode.step." + part]
+        assert len(kids) == 4
+        assert {k["parent"] for k in kids} == {s["span"] for s in steps}
+    assert by["mx.decode.queue_wait"][0]["parent"] \
+        == by["mx.decode.admit"][0]["span"]
+    assert by["mx.decode.prefill"][0]["parent"] \
+        == by["mx.decode.admit"][0]["span"]
 
 
 # ---------------------------------------------------------------------
