@@ -591,6 +591,12 @@ def phase_serve(smoke, cfg):
             for b in buckets:
                 check(engine.fingerprint(kind, b) is not None,
                       "%s bucket %d not warmed" % (kind, b))
+                # in place: the program writes into its donated slabs
+                mem = engine.program_memory(kind, b)
+                check(mem is None or mem["aliased_bytes"]
+                      == engine.cache.slab_bytes(),
+                      "%s bucket %d copies its K/V slabs: %r of %d bytes "
+                      "aliased" % (kind, b, mem, engine.cache.slab_bytes()))
         warmed = smoke.compiles
         streams = [reg.generate("smoke_gpt", p, max_new) for p in prompts]
         outs = [s.tokens() for s in streams]
@@ -632,6 +638,7 @@ def phase_serve(smoke, cfg):
             "prefill_buckets": list(engine.prefill_buckets),
             "decode_buckets": list(engine.decode_buckets),
             "compiles_after_warmup": late_compiles,
+            "kv_slab_bytes": engine.cache.slab_bytes(),
             "logit_gap_default_vs_float32": round(gap, 4),
             "logit_tolerance": LOGIT_TOL,
             "tokens_equal_reference_argmax": "%d/%d"

@@ -57,14 +57,18 @@ def default_cache_dir():
         or os.path.join(CACHE_ROOT, "serving")
 
 
-def compile_through(cache, key, jfn, lowered, specs):
+def compile_through(cache, key, jfn, lowered, specs, donate_argnums=()):
     """AOT-compile one servable program whose lowering is ``lowered``
     (``jfn.lower(*specs)``, fingerprint ``key``).  With a cache, the
     program compiled is the ``jax.export`` wrapper of the artifact --
     the SAME wrapper whether the artifact was just made or read back,
     so the process that reads it back finds this compile in the
     persistent XLA cache; it is compiled here, not at the first
-    request.  A program that cannot be exported is compiled as is."""
+    request.  A program that cannot be exported is compiled as is.
+
+    ``donate_argnums`` are the arguments ``jfn`` donates: the wrapper
+    is a jit of its own, so it has to be told too, or the program the
+    cache path compiles copies what the plain path writes in place."""
     import jax
     if cache is not None:
         exported = cache.get(key)
@@ -76,7 +80,8 @@ def compile_through(cache, key, jfn, lowered, specs):
             except Exception:
                 exported = None
         if exported is not None:
-            return jax.jit(exported.call).lower(*specs).compile()
+            return jax.jit(exported.call, donate_argnums=donate_argnums
+                           ).lower(*specs).compile()
     return lowered.compile()
 
 

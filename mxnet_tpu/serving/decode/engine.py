@@ -56,7 +56,8 @@ from ...base import MXNetError, scopes_in_cache_key
 from ..batcher import RequestTimeout, ServableClosed, ServingQueueFull
 from ..cache import compile_through, stablehlo_fingerprint
 from ..loop import RegistryWatcher as _RegistryWatcher
-from .kvcache import SCRATCH_BLOCK, KVCacheExhausted, PagedKVCache
+from .kvcache import (SCRATCH_BLOCK, KVCacheExhausted, PagedKVCache,
+                      slab_rows)
 
 __all__ = ["DecodeEngine", "GenerationStream", "GenerativeServable",
            "GenerativeWatcher"]
@@ -171,31 +172,60 @@ def _request_links(reqs):
     return [r.tctx.span_id for r in reqs if r.tctx is not None]
 
 
+def _memory_of(compiled):
+    """{"aliased_bytes", "temp_bytes"} of a compiled executable, or
+    None where the backend's ``memory_analysis()`` reports nothing."""
+    try:
+        stats = compiled.memory_analysis()
+        return {"aliased_bytes": int(stats.alias_size_in_bytes),
+                "temp_bytes": int(stats.temp_size_in_bytes)}
+    except Exception:
+        return None
+
+
 class _AotPrograms:
     """lower -> fingerprint -> CompileCache -> compile, per static
     shape key (the BucketExecutorPool discipline generalized to
-    multi-argument decode/prefill signatures)."""
+    multi-argument decode/prefill signatures).
+
+    ``donate_argnums`` of :meth:`build` reach BOTH jits: the one that
+    is lowered and fingerprinted, and the ``jax.export`` wrapper that
+    ``compile_through`` compiles when a ``CompileCache`` is present
+    (the path a registry's servable runs).  A call of the built
+    program consumes the donated arguments: they are deleted when it
+    returns and the outputs that alias them are the arrays to keep.
+    ``memory[key]`` holds what the compiled executable's
+    ``memory_analysis()`` says of that: ``aliased_bytes`` (outputs
+    written into donated arguments) and ``temp_bytes``; None where the
+    backend reports nothing."""
 
     def __init__(self, cache=None, label="decode"):
         self._cache = cache
         self._label = label
         self._programs = {}
         self.fingerprints = {}
+        self.memory = {}
 
-    def build(self, key, fn, specs):
+    def build(self, key, fn, specs, donate_argnums=()):
         import jax
         if key in self._programs:
             return self._programs[key]
-        jfn = jax.jit(fn)
+        jfn = jax.jit(fn, donate_argnums=donate_argnums)
         lowered = jfn.lower(*specs)
         fp = stablehlo_fingerprint(lowered.as_text())
         # compiled here, never at the first request: warmup() promises
         # that no request pays a compile; with its scopes in the XLA
         # cache's key, so that a trace reads this version's names
         with scopes_in_cache_key():
-            call = compile_through(self._cache, fp, jfn, lowered, specs)
+            call = compile_through(self._cache, fp, jfn, lowered, specs,
+                                   donate_argnums=donate_argnums)
         self._programs[key] = call
         self.fingerprints[key] = fp
+        self.memory[key] = _memory_of(call)
+        aliased = [m["aliased_bytes"] for m in self.memory.values() if m]
+        if aliased and _telemetry._ENABLED:
+            # the least of the programs built: one that copies shows
+            _telemetry.hooks.decode_kv_aliased(self._label, min(aliased))
         # for a reader of a device trace: instruction name -> scope
         _obs.note_program("%s:%s:%s" % ((self._label,) + tuple(key)),
                           call.as_text)
@@ -277,6 +307,11 @@ class DecodeEngine:
         self._thread = None
 
     # -- AOT build ------------------------------------------------------
+    # argument numbers of (params, kv_k, kv_v, ...) that every prefill
+    # and decode program donates: both slab tuples, whole.  Never
+    # ``params``, which every call and every engine of a swap share.
+    _DONATED = (1, 2)
+
     def _prefill_impl(self, params, kv_k, kv_v, tokens, table,
                       true_len):
         import jax
@@ -289,8 +324,11 @@ class DecodeEngine:
             blk = jnp.where(pos < true_len,
                             jnp.take(table, pos // bs), SCRATCH_BLOCK)
             off = pos % bs
-            kv_k = kv_k.at[:, blk, off].set(ks.astype(kv_k.dtype))
-            kv_v = kv_v.at[:, blk, off].set(vs.astype(kv_v.dtype))
+            # each layer's prompt rows into that layer's own slab
+            kv_k = tuple(slab.at[blk, off].set(slab_rows(k, slab))
+                         for slab, k in zip(kv_k, ks))
+            kv_v = tuple(slab.at[blk, off].set(slab_rows(v, slab))
+                         for slab, v in zip(kv_v, vs))
         with jax.named_scope("mx.lm_head"):
             last = jnp.take(logits[0], true_len - 1, axis=0)
             first_token = jnp.argmax(last).astype(jnp.int32)
@@ -306,8 +344,8 @@ class DecodeEngine:
     def _specs(self):
         import jax
         i32 = np.int32
-        kv = jax.ShapeDtypeStruct(self.cache.keys.shape,
-                                  self.cache.keys.dtype)
+        kv = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
+                   for a in self.cache.keys)
         pspec = {n: jax.ShapeDtypeStruct(v.shape, v.dtype)
                  for n, v in self.params.items()}
         mb = self.max_blocks_per_seq
@@ -327,16 +365,17 @@ class DecodeEngine:
 
     def warmup(self):
         """Compile every prefill and decode bucket (compile-cache
-        checked first); returns total warm-up seconds.  After this no
-        request can trigger a compile."""
+        checked first), each with the K/V slabs donated; returns total
+        warm-up seconds.  After this no request can trigger a
+        compile."""
         t0 = time.perf_counter()
         prefill, decode = self._specs()
         for b, specs in prefill.items():
             self._programs.build(("prefill", b), self._prefill_impl,
-                                 specs)
+                                 specs, donate_argnums=self._DONATED)
         for s, specs in decode.items():
             self._programs.build(("decode", s), self._decode_impl,
-                                 specs)
+                                 specs, donate_argnums=self._DONATED)
         dt = time.perf_counter() - t0
         if _telemetry._ENABLED:
             _telemetry.hooks.serving_warmup(
@@ -492,22 +531,24 @@ class DecodeEngine:
                                             self.max_blocks_per_seq)
         t0 = time.perf_counter()
         call = self._programs.get(("prefill", bucket))
+        cache = self.cache
+        dispatched = False
         try:
             with _obs.span("mx.decode.prefill.call"):
                 _chaos.fail_point("serving.decode.prefill",
                                   model=self._label, bucket=bucket)
-                first, kv_k, kv_v = call(
-                    self.params, self.cache.keys, self.cache.values,
+                # the slabs that go in are donated: dead once the call
+                # returns, so the outputs are bound before anything
+                # else can read the cache
+                first, cache.keys, cache.values = call(
+                    self.params, cache.keys, cache.values,
                     tokens, table, np.int32(len(req.prompt)))
+                dispatched = True
                 first = int(jax.device_get(first))
         except Exception as e:
-            if _telemetry._ENABLED:
-                _telemetry.hooks.serving_error(self._label)
-            self.cache.free(req.table)
-            req.stream._finish("error", error=e)
+            self._call_failed(e, [req], dispatched)
             return
         with _obs.span("mx.decode.prefill.emit"):
-            self.cache.keys, self.cache.values = kv_k, kv_v
             self.cache.note_tokens(req.table, len(req.prompt) + 1)
             now = time.perf_counter()
             if _telemetry._ENABLED:
@@ -542,25 +583,23 @@ class DecodeEngine:
                     req.table, self.max_blocks_per_seq)
         t0 = time.perf_counter()
         call = self._programs.get(("decode", bucket))
+        cache = self.cache
+        dispatched = False
         try:
             with _obs.span("mx.decode.step.call"):
                 _chaos.fail_point("serving.decode.step",
                                   model=self._label, occupancy=n,
                                   bucket=bucket)
-                out, kv_k, kv_v = call(self.params, self.cache.keys,
-                                       self.cache.values, tokens,
-                                       positions, tables)
+                # donated slabs in, the same memory out: rebind at once
+                out, cache.keys, cache.values = call(
+                    self.params, cache.keys, cache.values, tokens,
+                    positions, tables)
+                dispatched = True
                 out = jax.device_get(out)
         except Exception as e:
-            if _telemetry._ENABLED:
-                _telemetry.hooks.serving_error(self._label)
-            for req in self._active:
-                self.cache.free(req.table)
-                req.stream._finish("error", error=e)
-            del self._active[:]
+            self._call_failed(e, list(self._active), dispatched)
             return
         with _obs.span("mx.decode.step.emit"):
-            self.cache.keys, self.cache.values = kv_k, kv_v
             now = time.perf_counter()
             if _telemetry._ENABLED:
                 _telemetry.hooks.decode_step(self._label, n, bucket,
@@ -578,6 +617,27 @@ class DecodeEngine:
                 # bucket
                 self._active = [r for r in self._active
                                 if r not in finished]
+
+    def _call_failed(self, error, served, dispatched):
+        """A prefill or decode call raised.  Before the call took its
+        arguments (the chaos fail points, a bad argument) the cache is
+        whole and only the requests the call ``served`` end with the
+        error.  After it -- the slabs are deleted, or are the outputs
+        of a program that failed -- no live stream has a cache left:
+        every one ends with the error, and fresh zeroed slabs serve the
+        next request."""
+        if _telemetry._ENABLED:
+            _telemetry.hooks.serving_error(self._label)
+        lost = dispatched or self.cache.slabs_deleted()
+        failed = list(served)
+        if lost:
+            failed += [r for r in self._active if r not in failed]
+        for req in failed:
+            self.cache.free(req.table)
+            req.stream._finish("error", error=error)
+        self._active = [r for r in self._active if r not in failed]
+        if lost:
+            self.cache.reset_slabs()
 
     def _emit(self, req, token, now):
         req.generated += 1
@@ -629,6 +689,13 @@ class DecodeEngine:
 
     def fingerprint(self, kind, bucket):
         return self._programs.fingerprints.get((kind, bucket))
+
+    def program_memory(self, kind, bucket):
+        """``{"aliased_bytes", "temp_bytes"}`` of one compiled program
+        (``kind`` "prefill" or "decode"), from its executable's
+        ``memory_analysis()``; None where the backend reports nothing.
+        In place means ``aliased_bytes == cache.slab_bytes()``."""
+        return self._programs.memory.get((kind, bucket))
 
     # -- lifecycle ------------------------------------------------------
     def close(self, drain=True):

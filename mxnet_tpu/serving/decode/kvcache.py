@@ -2,14 +2,44 @@
 discipline).
 
 The generative engine never allocates per-request device memory: at
-construction it carves ONE preallocated per-layer slab pair -- keys and
-values, shape ``(layers, num_blocks, block_size, heads, head_dim)`` --
-into fixed-size blocks, and a request is admitted by handing it a
-**block table** (the ordered list of block ids its tokens map onto).
-Token position ``p`` of a request lives at
+construction it preallocates one slab pair PER LAYER -- ``keys[i]`` and
+``values[i]``, each ``(num_blocks, block_size, heads, lanes)`` with the
+first ``head_dim`` lanes in use -- carved into fixed-size blocks, and a
+request is admitted by handing it a **block table** (the ordered list
+of block ids its tokens map onto; one table names the same blocks in
+every layer's slab).  Token position ``p`` of a request lives at
 ``(table[p // block_size], p % block_size)``; the decode-step attention
 kernel gathers K/V through the table, so sequences share the slabs
 without ever being contiguous.
+
+The slabs are **donated**: ``keys`` / ``values`` are tuples the
+engine's compiled prefill and decode programs take as one pytree
+argument each and write in place (``DecodeEngine`` compiles them with
+``donate_argnums``; each layer's scatter and its attention kernel work
+on that layer's own array, so no program ever copies a slab).  After a
+call the arrays that went in are deleted and the engine rebinds
+``keys`` / ``values`` to the call's outputs.  So nobody but the
+engine's loop may hold on to a slab across a call: read
+``cache.keys[i]`` afresh, and copy (``np.asarray``) what must outlive
+the next step.
+
+In place also needs the slabs to LIE in memory the way the programs
+work on them, and that is why a slab's last dimension is ``lanes``,
+``head_dim`` rounded up to whole 128-lane tiles, with the lanes past
+``head_dim`` never read.  A TPU tiles the two minor dimensions of an
+array in (8, 128) tiles, so a 64-wide head occupies 128 lanes however
+it is declared; but for an array DECLARED 64 wide the device's default
+layout is a compact one with ``num_blocks`` minor, while the scatter
+and the Mosaic kernel work row-major -- every layer then converts its
+slab on the way in and on the way out, two slab copies a layer whatever
+is donated.  Declared in whole tiles, the default layout IS the
+row-major one, ``slab[..., :head_dim]`` is a view of the same tiles (the
+compiler makes it a bitcast), and nothing is copied.  (Asking for a
+row-major layout of the 64-wide array through ``jax.experimental.
+layout`` compiles to the same program, but an executable read back
+from JAX's persistent compilation cache hands its outputs back tagged
+with the default layout and the next call refuses them; my chip runs,
+PR 26.)  On a backend without tiles the padding is real memory.
 
 Admission-time sizing is the backpressure contract: a request's whole
 budget -- ``prompt_len + max_new_tokens`` -- is allocated **at
@@ -37,11 +67,28 @@ from ... import telemetry as _telemetry
 from ...base import MXNetError
 
 __all__ = ["PagedKVCache", "BlockTable", "KVCacheExhausted",
-           "SCRATCH_BLOCK"]
+           "SCRATCH_BLOCK", "slab_rows"]
+
+# a TPU's lane count: the minor dimension of its (8, 128) memory tiles
+LANE_TILE = 128
 
 # block id 0 is the write sink for padded slots/positions; never
 # allocated to a request (see module doc)
 SCRATCH_BLOCK = 0
+
+
+def slab_rows(rows, slab):
+    """``rows`` (..., heads, head_dim) as whole rows of ``slab``
+    (num_blocks, block_size, heads, lanes >= head_dim): the slab's
+    dtype, zeros in the lanes past head_dim.  What a program scatters
+    into a slab: a whole-row update is one scatter, a window of some
+    lanes a loop of slot-sized updates on the TPU."""
+    import jax.numpy as jnp
+    pad = slab.shape[-1] - rows.shape[-1]
+    rows = rows.astype(slab.dtype)
+    if pad:
+        rows = jnp.pad(rows, [(0, 0)] * (rows.ndim - 1) + [(0, pad)])
+    return rows
 
 
 class KVCacheExhausted(MXNetError):
@@ -82,7 +129,6 @@ class PagedKVCache:
 
     def __init__(self, layers, heads, head_dim, block_size, num_blocks,
                  dtype="float32"):
-        import jax.numpy as jnp
         import numpy as np
         if block_size < 1 or num_blocks < 2:
             raise MXNetError(
@@ -95,16 +141,42 @@ class PagedKVCache:
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
         self.dtype = np.dtype(dtype)
-        shape = (self.layers, self.num_blocks, self.block_size,
-                 self.heads, self.head_dim)
-        # THE slabs: functional jax values the compiled prefill/decode
-        # programs consume and replace (the engine swaps the references
-        # after every step; on TPU donation makes that in-place)
-        self.keys = jnp.zeros(shape, self.dtype)
-        self.values = jnp.zeros(shape, self.dtype)
+        # whole 128-lane tiles, so that the device's default layout is
+        # the row-major one the programs work in (module doc)
+        self.lanes = -(-self.head_dim // LANE_TILE) * LANE_TILE
+        self.slab_shape = (self.num_blocks, self.block_size,
+                           self.heads, self.lanes)
+        self.reset_slabs()
         self._lock = _sync.Lock(name="serving.kvcache")
         self._free = list(range(1, self.num_blocks))  # 0 = scratch
         self._used_tokens = {}          # id(table) -> tokens written
+
+    # -- the slabs ------------------------------------------------------
+    def reset_slabs(self):
+        """Allocate fresh zeroed slabs: one ``keys[i]`` / ``values[i]``
+        per layer.  The engine's programs are compiled with both tuples
+        donated (``DecodeEngine.warmup``), so a call consumes the arrays
+        it is given and writes the new rows in place; the engine binds
+        the call's outputs here again.  Also the way back after a call
+        that failed with its arguments already consumed."""
+        import jax.numpy as jnp
+        # let go of the old slabs first: two sets may not fit the device
+        self.keys = self.values = ()
+        self.keys = tuple(jnp.zeros(self.slab_shape, self.dtype)
+                          for _ in range(self.layers))
+        self.values = tuple(jnp.zeros(self.slab_shape, self.dtype)
+                            for _ in range(self.layers))
+
+    def slab_bytes(self):
+        """Bytes the K and V slabs of all layers hold on their device
+        (unused lanes included)."""
+        return sum(a.on_device_size_in_bytes()
+                   for a in self.keys + self.values)
+
+    def slabs_deleted(self):
+        """Whether a call consumed the slabs without handing new ones
+        back (a donating call that raised after it took them)."""
+        return any(a.is_deleted() for a in self.keys + self.values)
 
     # -- sizing ---------------------------------------------------------
     def blocks_for(self, n_tokens):

@@ -15,8 +15,9 @@ model here is a plain params-dict + pure functions, the
   returning every layer's per-position K/V so the engine can scatter
   the prompt into cache blocks inside ONE compiled program.
 - :meth:`TinyGPT.decode_logits` -- one token per slot: project q/k/v,
-  scatter the new K/V into the slot's block-table position, attend over
-  the paged cache through the ``paged_attention`` kernel-registry entry.
+  scatter the new K/V into the slot's block-table position of THAT
+  LAYER's slab, attend over it through the ``paged_attention``
+  kernel-registry entry.
 
 Everything is fp32-accumulated and greedy-decodable: the engine's
 continuous-batching tests hold decode tokens bit-identical between a
@@ -159,14 +160,10 @@ class TinyGPT:
 
     def prefill_kv(self, params, tokens):
         """tokens (1, t) -> (logits (1, t, vocab), keys, values) with
-        keys/values stacked per layer: (layers, t, heads, head_dim)."""
-        import jax
-        import jax.numpy as jnp
+        keys/values one array per layer: (t, heads, head_dim) each."""
         logits, kvs = self._forward(params, tokens, collect_kv=True)
-        with jax.named_scope("mx.kv_scatter"):
-            ks = jnp.stack([k[0] for k, _v in kvs])    # (L, t, H, D)
-            vs = jnp.stack([v[0] for _k, v in kvs])
-        return logits, ks, vs
+        return (logits, tuple(k[0] for k, _v in kvs),
+                tuple(v[0] for _k, v in kvs))
 
     # -- decode step over the paged cache -------------------------------
     def decode_logits(self, params, kv_keys, kv_values, token_ids,
@@ -174,16 +171,30 @@ class TinyGPT:
         """One decode step for a slot batch.
 
         token_ids (s,) int32; positions (s,) int32 (where each new
-        token is written, = its context length - 1); kv slabs (layers,
-        num_blocks, block_size, heads, head_dim); block_tables (s,
-        max_blocks) int32.  Returns (next_token (s,) int32, logits
-        (s, vocab), kv_keys', kv_values').
+        token is written, = its context length - 1); ``kv_keys`` /
+        ``kv_values`` one slab per layer, each (num_blocks, block_size,
+        heads, lanes >= head_dim) of which the first head_dim lanes are
+        used (``PagedKVCache`` pads them to whole tiles, a plain array
+        need not); block_tables (s, max_blocks) int32.  Returns
+        (next_token (s,) int32, logits (s, vocab), kv_keys',
+        kv_values'), the slabs as tuples in layer order.
+
+        Layer ``i`` writes its ``s`` new rows into ``kv_keys[i]`` and
+        hands that array's head_dim lanes to the attention kernel (a
+        view of the same tiles on a TPU): no slab is cut out of another
+        or stacked, so a caller that donates both tuples (the
+        engine does) gets a step that updates the cache in place.  The
+        padded slots of a bucket all write (scratch block, offset 0):
+        the scatter's indices are not unique and nothing is promised
+        to XLA about them.
         """
         import jax
         import jax.numpy as jnp
         from ...kernels.paged_attention import paged_attention
+        from .kvcache import slab_rows
         scope = jax.named_scope
-        s = token_ids.shape[0]
+        s, d = token_ids.shape[0], self.head_dim
+        kv_keys, kv_values = list(kv_keys), list(kv_values)
         with scope("mx.embed"):
             blk = jnp.take_along_axis(
                 block_tables, (positions // block_size)[:, None],
@@ -207,12 +218,13 @@ class TinyGPT:
             # padded slots carry all-scratch tables so their writes
             # land in the reserved scratch block
             with scope(layer + "kv_write"):
-                kv_keys = kv_keys.at[i, blk, off].set(
-                    k.astype(kv_keys.dtype))
-                kv_values = kv_values.at[i, blk, off].set(
-                    v.astype(kv_values.dtype))
+                kv_keys[i] = kv_keys[i].at[blk, off].set(
+                    slab_rows(k, kv_keys[i]))
+                kv_values[i] = kv_values[i].at[blk, off].set(
+                    slab_rows(v, kv_values[i]))
             with scope(layer + "attention"):
-                att = paged_attention(q, kv_keys[i], kv_values[i],
+                att = paged_attention(q, kv_keys[i][..., :d],
+                                      kv_values[i][..., :d],
                                       block_tables, ctx, scale=self.scale)
                 att = att.reshape(s, self.units).astype(x.dtype)
             with scope(layer + "proj"):
@@ -225,7 +237,7 @@ class TinyGPT:
             x = self._ln(x, params["lnf_g"], params["lnf_b"])
             logits = jnp.dot(x, params["embed"].T)
             next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return next_token, logits, kv_keys, kv_values
+        return next_token, logits, tuple(kv_keys), tuple(kv_values)
 
     # -- single-shot oracle ---------------------------------------------
     def reference_decode(self, params, prompt, max_new_tokens,

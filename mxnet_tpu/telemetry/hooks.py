@@ -45,6 +45,7 @@ __all__ = [
     "fleet_round", "fleet_alert", "fleet_alerts_firing",
     "decode_request", "decode_shed", "decode_prefill", "decode_step",
     "decode_ttft", "decode_inter_token", "decode_finish",
+    "decode_kv_aliased",
     "kvcache_alloc", "kvcache_free", "kvcache_alloc_failure",
 ]
 
@@ -388,6 +389,12 @@ def decode_finish(model, reason, tokens):
     reg.counter("decode.finished").inc()
     reg.event("decode.finish").emit(model=model, reason=reason,
                                     tokens=int(tokens))
+
+
+def decode_kv_aliased(model, aliased_bytes):
+    """A decode engine compiled a program: the least bytes any of its
+    prefill and decode programs writes into donated arguments."""
+    _registry().gauge("decode.kv_aliased_bytes").set(aliased_bytes)
 
 
 def kvcache_alloc(in_use, fragmentation):
@@ -1001,6 +1008,10 @@ INSTRUMENTS = [
     _ii("decode.finish", "event", "serving", 18,
         "one finished generation; payload carries reason (eos/length/"
         "cancel/timeout/error/closed) + token count"),
+    _ii("decode.kv_aliased_bytes", "gauge", "serving", 26,
+        "least bytes any compiled prefill/decode program of an engine "
+        "writes into its donated K/V slabs (memory_analysis alias "
+        "size); in place means it equals the bytes of both slabs"),
     _ii("kvcache.allocs", "counter", "serving", 18,
         "block-table allocations (one per admitted request)"),
     _ii("kvcache.frees", "counter", "serving", 18,

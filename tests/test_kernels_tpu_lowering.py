@@ -105,3 +105,74 @@ def test_row_blocks_are_a_multiple_of_8_or_the_whole_extent():
     ch = kernels.choose("fused_bn_relu", force=True, axis=3, ndim=4,
                         rows=2 * 15 * 15)
     assert not ch.use_pallas and "multiple of 8" in ch.reason
+
+
+# ----------------------------------------------------------------------
+# compiled for a described chip: the decode step writes its K/V in place
+# ----------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def one_chip():
+    """A v5e chip that is described, not attached (the TPU compiler is
+    installed; nothing runs).  Described inside a fixture: only the
+    worker that is given this file loads the TPU's library."""
+    import os
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture()
+def no_compile_cache():
+    # a compile for a described chip is written to the persistent cache
+    # and cannot be read back without one: keep it out
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_the_decode_programs_write_the_cache_in_place_on_the_chip(
+        one_chip, no_compile_cache, monkeypatch, kind):
+    """GPT-2-medium's heads (16 x 64) and block size, two layers: every
+    byte of both slabs is aliased, no temporary is slab-sized and no
+    instruction copies a slab -- the layout a 64-wide slab would have by
+    default is why the slabs are declared in whole 128-lane tiles."""
+    import re
+
+    from mxnet_tpu.kernels import registry
+    from mxnet_tpu.serving.decode import DecodeEngine, TinyGPT
+    monkeypatch.setattr(registry, "_backend", lambda: "tpu")
+    model = TinyGPT(vocab_size=512, units=1024, num_layers=2,
+                    num_heads=16, max_seq=1024)
+    params = jax.eval_shape(model.init_params, 0)
+    eng = DecodeEngine(model, params, prefill_buckets=(128,),
+                       decode_buckets=(16,), block_size=16, num_blocks=513)
+    prefill, decode = eng._specs()
+    impl, specs = (eng._decode_impl, decode[16]) if kind == "decode" \
+        else (eng._prefill_impl, prefill[128])
+    specs = jax.tree.map(
+        lambda s: S(s.shape, s.dtype, sharding=one_chip), specs)
+    compiled = jax.jit(impl, donate_argnums=eng._DONATED).lower(
+        *specs).compile()
+    slab = 513 * 16 * 16 * 128 * 4
+    assert eng.cache.slab_shape == (513, 16, 16, 128)
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes == 2 * model.num_layers * slab
+    assert stats.temp_size_in_bytes < slab // 2
+    text = compiled.as_text()
+    assert text.count("tpu_custom_call") \
+        == (model.num_layers if kind == "decode" else 0)
+    moved = re.findall(r"= f32\[513,16,16,\d+\]\S* (?:copy|slice)\(", text)
+    assert not moved, moved[:3]
+    assert "remat_" not in text

@@ -345,6 +345,290 @@ def test_close_without_drain_resolves_streams(make_engine):
 
 
 # ---------------------------------------------------------------------
+# the cache is written in place: per-layer slabs, donated (ISSUE 26)
+# ---------------------------------------------------------------------
+
+def test_kvcache_holds_one_slab_per_layer_and_resets_to_zeros():
+    c = PagedKVCache(3, 2, 8, block_size=4, num_blocks=16)
+    assert len(c.keys) == len(c.values) == 3
+    # head_dim 8 in whole 128-lane tiles: the device's default layout
+    # is then the row-major one the programs work in
+    assert c.lanes == 128 and c.head_dim == 8
+    for slab in c.keys + c.values:
+        assert slab.shape == (16, 4, 2, 128) and slab.dtype == np.float32
+    assert c.slab_bytes() == 2 * 3 * 16 * 4 * 2 * 128 * 4
+    assert PagedKVCache(1, 2, 256, block_size=4, num_blocks=4).lanes == 256
+    assert PagedKVCache(1, 2, 130, block_size=4, num_blocks=4).lanes == 256
+    old = c.keys
+    t = c.allocate(6)
+    c.reset_slabs()                      # the allocator is untouched
+    assert c.blocks_in_use() == len(t.blocks)
+    assert all(new is not o for new, o in zip(c.keys, old))
+    assert not c.slabs_deleted()
+    assert all(not np.asarray(a).any() for a in c.keys + c.values)
+    old[0].delete()
+    c.keys = old
+    assert c.slabs_deleted()
+
+
+def _slab_args(text, slab_type):
+    """The ``@main`` arguments of a lowered program that have the slab's
+    tensor type, each with whether it is marked as donated."""
+    sig = text[text.index("@main("):]
+    sig = sig[:sig.index(") -> ")]
+    args = [a for a in sig.split("%arg")[1:] if slab_type in a]
+    return [("tf.aliasing_output" in a or "jax.buffer_donor" in a)
+            for a in args], sig
+
+
+@pytest.mark.parametrize("with_compile_cache", [False, True],
+                         ids=["plain", "compile_cache"])
+def test_every_program_writes_both_slabs_in_place(params, ccache,
+                                                  with_compile_cache):
+    """Aliased bytes = the bytes of both slabs in every prefill and
+    decode program, on the plain path and on the ``jax.export`` wrapper
+    a CompileCache compiles (the path a registry's servable runs)."""
+    eng = DecodeEngine(MODEL, params, **dict(
+        ENGINE_KW, cache=ccache if with_compile_cache else None))
+    eng.warmup()
+    both = eng.cache.slab_bytes()
+    assert both == 2 * MODEL.num_layers * 64 * 4 * 2 * 128 * 4
+    keys = [("prefill", b) for b in eng.prefill_buckets] \
+        + [("decode", b) for b in eng.decode_buckets]
+    slabs = 2 * MODEL.num_layers
+    for kind, bucket in keys:
+        mem = eng.program_memory(kind, bucket)
+        if mem is not None:              # the backend reports it
+            assert mem["aliased_bytes"] == both, (kind, bucket, mem)
+            assert mem["temp_bytes"] < both
+        # the compiled module aliases one output to each slab argument
+        head = eng._programs.get((kind, bucket)).as_text().split("\n")[0]
+        assert head.count("-alias)") == slabs, (kind, bucket, head)
+    # and the lowering marks the cache arguments, and only them
+    prefill, decode = eng._specs()
+    for impl, specs in ((eng._prefill_impl, prefill[8]),
+                        (eng._decode_impl, decode[2])):
+        import jax
+        text = jax.jit(impl, donate_argnums=eng._DONATED).lower(
+            *specs).as_text()
+        marked, sig = _slab_args(text, "tensor<64x4x2x128xf32>")
+        assert marked == [True] * slabs
+        assert sig.count("tf.aliasing_output") \
+            + sig.count("jax.buffer_donor") == slabs
+
+
+def test_compile_through_donates_only_when_told(ccache):
+    """``BucketExecutorPool`` shares ``compile_through`` and keeps its
+    behaviour: no donation unless the caller names the arguments, and
+    then on the export wrapper too."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.serving.cache import (compile_through,
+                                         stablehlo_fingerprint)
+
+    def bump(state, x):
+        return state.at[0].add(x), x * 2
+
+    specs = (jax.ShapeDtypeStruct((8, 4), jnp.float32),
+             jax.ShapeDtypeStruct((4,), jnp.float32))
+    for donate in ((), (0,)):
+        jfn = jax.jit(bump, donate_argnums=donate)
+        lowered = jfn.lower(*specs)
+        key = stablehlo_fingerprint(lowered.as_text())
+        for cache in (None, ccache):
+            kw = {"donate_argnums": donate} if donate else {}
+            call = compile_through(cache, key, jfn, lowered, specs, **kw)
+            state = jnp.zeros((8, 4), jnp.float32)
+            new, _ = call(state, jnp.ones((4,), jnp.float32))
+            assert state.is_deleted() == bool(donate)
+            assert float(new[0, 0]) == 1.0
+        assert key in ccache
+
+
+def test_slabs_are_rebound_and_the_old_ones_deleted(make_engine, params):
+    eng = make_engine()
+    before = eng.cache.keys + eng.cache.values
+    # one token comes from the prefill alone: no decode step runs
+    assert eng.submit([3, 7, 1], 1).tokens() \
+        == _reference(params, [3, 7, 1], 1)
+    after_prefill = eng.cache.keys + eng.cache.values
+    assert all(a.is_deleted() for a in before)
+    assert not any(a.is_deleted() for a in after_prefill)
+    seen = []
+    with chaos.scenario(seed=0):
+        # fires before each step's call: what the step is about to take
+        chaos.on("serving.decode.step", action=lambda ctx: seen.append(
+            eng.cache.keys + eng.cache.values))
+        assert eng.submit([5, 5, 6], 3).tokens() \
+            == _reference(params, [5, 5, 6], 3)
+    assert len(seen) == 2                # two steps made tokens 2 and 3
+    for taken in seen:
+        assert all(a.is_deleted() for a in taken)
+    assert all(a.is_deleted() for a in after_prefill)
+    assert not eng.cache.slabs_deleted()
+    assert len(eng.cache.keys) == MODEL.num_layers
+
+
+class _ConsumesThenFails:
+    """A compiled program that takes its donated slabs and then fails:
+    what a device error after dispatch looks like to the engine."""
+
+    def __init__(self, real):
+        self.real = real
+        self.calls = 0
+
+    def __call__(self, *args):
+        self.calls += 1
+        self.real(*args)
+        raise RuntimeError("device lost after the slabs were taken")
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_a_call_that_fails_with_the_slabs_taken_ends_every_stream(
+        make_engine, params, kind, counters):
+    eng = make_engine()
+    programs = eng._programs._programs
+    real = dict(programs)
+    with chaos.scenario(seed=0):
+        chaos.on("serving.decode.step",
+                 action=lambda ctx: time.sleep(0.02))
+        running = eng.submit([3, 7, 1, 9, 2], 20)
+        first = next(running)            # decoding from here on
+        assert first == _reference(params, [3, 7, 1, 9, 2], 1)[0]
+        for key in real:
+            if key[0] == kind:
+                programs[key] = _ConsumesThenFails(real[key])
+        joiner = eng.submit([5, 5, 6], 10)
+        for stream in (running, joiner):
+            if stream is joiner and kind == "decode":
+                next(stream)             # its prefill still worked
+            with pytest.raises(RuntimeError, match="device lost"):
+                list(stream)
+            assert stream.finish_reason == "error"
+    assert sum(p.calls for p in programs.values()
+               if isinstance(p, _ConsumesThenFails)) >= 1
+    assert eng.cache.blocks_in_use() == 0
+    assert counters.gauge("kvcache.blocks_in_use").value == 0
+    assert eng.active_sequences() == 0
+    # fresh slabs, and the engine serves on
+    programs.update(real)
+    assert not eng.cache.slabs_deleted()
+    for prompt in ([3, 7, 1, 9, 2], [1]):
+        assert eng.submit(prompt, 8).tokens() \
+            == _reference(params, prompt, 8)
+    assert eng.cache.blocks_in_use() == 0
+
+
+def test_a_prefill_fail_point_fails_only_that_request(make_engine,
+                                                      params):
+    """The chaos fail points fire BEFORE the call takes the slabs: the
+    cache is whole, the running stream never notices."""
+    eng = make_engine()
+    with chaos.scenario(seed=0):
+        chaos.on("serving.decode.step",
+                 action=lambda ctx: time.sleep(0.02))
+        running = eng.submit([3, 7, 1, 9, 2], 12)
+        first = next(running)
+        chaos.on("serving.decode.prefill", action=chaos.RAISE, times=1)
+        doomed = eng.submit([5, 5, 6], 4)
+        with pytest.raises(chaos.ChaosInjected):
+            doomed.tokens()
+        assert [first] + list(running) \
+            == _reference(params, [3, 7, 1, 9, 2], 12)
+        assert eng.submit([5, 5, 6], 4).tokens() \
+            == _reference(params, [5, 5, 6], 4)
+    assert eng.cache.blocks_in_use() == 0
+
+
+def test_a_step_fail_point_leaves_the_slabs_alone(make_engine, params):
+    eng = make_engine()
+    with chaos.scenario(seed=0):
+        chaos.on("serving.decode.step", action=chaos.RAISE, times=1)
+        stream = eng.submit([3, 7, 1], 6)
+        slabs = None
+        with pytest.raises(chaos.ChaosInjected):
+            next(stream)                 # the prefill's token
+            slabs = eng.cache.keys
+            list(stream)
+    assert stream.finish_reason == "error"
+    assert eng.cache.blocks_in_use() == 0
+    assert slabs is not None and eng.cache.keys is slabs  # not reset
+    assert eng.submit([3, 7, 1], 6).tokens() \
+        == _reference(params, [3, 7, 1], 6)
+
+
+@pytest.mark.parametrize("lanes", [MODEL.head_dim, 128],
+                         ids=["plain", "whole_tiles"])
+def test_padded_slots_write_the_scratch_block_only(params, lanes):
+    """A bucket's padded slots all write (block 0, offset 0): indices
+    that are not unique.  Every other block of every layer keeps what
+    it held, and the live slot's row lands where its table says --
+    in a slab as wide as a head and in one padded to whole tiles."""
+    import jax.numpy as jnp
+    rng = np.random.default_rng(0)
+    shape = (8, 4, MODEL.num_heads, lanes)
+    keys = tuple(jnp.asarray(rng.normal(size=shape).astype(np.float32))
+                 for _ in range(MODEL.num_layers))
+    values = tuple(jnp.asarray(rng.normal(size=shape).astype(np.float32))
+                   for _ in range(MODEL.num_layers))
+    tables = np.full((4, 8), SCRATCH_BLOCK, np.int32)
+    tables[0, :2] = [5, 3]
+    tokens = np.array([7, 0, 0, 0], np.int32)
+    positions = np.array([6, 0, 0, 0], np.int32)     # block 3, offset 2
+    _next, _logits, new_k, new_v = MODEL.decode_logits(
+        params, keys, values, jnp.asarray(tokens), jnp.asarray(positions),
+        jnp.asarray(tables), 4)
+    assert isinstance(new_k, tuple) and len(new_k) == MODEL.num_layers
+    for old, new in list(zip(keys, new_k)) + list(zip(values, new_v)):
+        old, new = np.asarray(old), np.asarray(new)
+        changed = {(b, o) for b in range(8) for o in range(4)
+                   if not np.array_equal(old[b, o], new[b, o])}
+        assert changed == {(SCRATCH_BLOCK, 0), (3, 2)}
+        assert not new[3, 2, :, MODEL.head_dim:].any()    # dead lanes
+
+
+def test_the_padded_lanes_never_reach_the_tokens(params):
+    """Garbage in the lanes past head_dim changes no token: the
+    programs read the first head_dim lanes only."""
+    import jax.numpy as jnp
+    eng = DecodeEngine(MODEL, params, **ENGINE_KW)
+    eng.warmup()
+    d = MODEL.head_dim
+    junk = np.zeros(eng.cache.slab_shape, np.float32)
+    junk[..., d:] = 1e9
+    eng.cache.keys = tuple(jnp.asarray(junk) for _ in eng.cache.keys)
+    eng.cache.values = tuple(jnp.asarray(junk) for _ in eng.cache.values)
+    eng.start()
+    try:
+        assert eng.submit([3, 7, 1, 9, 2], 8).tokens() \
+            == _reference(params, [3, 7, 1, 9, 2], 8)
+    finally:
+        eng.close(drain=False)
+
+
+def test_three_streams_in_a_bucket_of_four_match_the_oracle(make_engine,
+                                                            params):
+    eng = make_engine()
+    prompts = [[3, 7, 1, 9, 2], [5, 5, 6], [1, 2, 3, 4]]
+    with chaos.scenario(seed=0):
+        chaos.on("serving.decode.step",
+                 action=lambda ctx: time.sleep(0.01))
+        streams = [eng.submit(p, 9) for p in prompts]
+        got = [s.tokens() for s in streams]
+    assert got == [_reference(params, p, 9) for p in prompts]
+    assert eng.cache.blocks_in_use() == 0
+
+
+def test_the_aliased_bytes_gauge_is_catalogued_and_set(params, counters):
+    from mxnet_tpu.telemetry import hooks
+    assert "decode.kv_aliased_bytes" in {i.name for i in hooks.INSTRUMENTS}
+    eng = DecodeEngine(MODEL, params, **ENGINE_KW)
+    eng.warmup()
+    assert counters.gauge("decode.kv_aliased_bytes").value \
+        == eng.cache.slab_bytes()
+
+
+# ---------------------------------------------------------------------
 # tracing
 # ---------------------------------------------------------------------
 
