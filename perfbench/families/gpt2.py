@@ -139,3 +139,22 @@ def paged_attention_cost(cfg, context_tokens):
     The query and output rows are 1/context of that and left out."""
     flops = 4 * cfg["n_layer"] * cfg["n_embd"] * context_tokens
     return flops, kv_bytes_per_token(cfg) * context_tokens
+
+
+def served_flops(cfg, decode_tokens, decode_context_tokens, prompt_lens):
+    """FLOPs the model needs for what a window served: ``decode_tokens``
+    decode steps' tokens over ``decode_context_tokens`` of live context in
+    total, and one prefill for each of ``prompt_lens``.  A token's pass
+    through a layer is 12 x n_embd^2 multiply-adds in its four matmuls (qkv
+    3, proj 1, MLP 4 + 4) and 4 FLOPs per context token per channel in
+    attention; the unembedding is counted once per emitted token, the only
+    position whose logits are needed.  What a program computes beyond that
+    (a prefill bucket's padding, logits of every position) does not count."""
+    e, layers = cfg["n_embd"], cfg["n_layer"]
+    per_token = layers * 24 * e * e
+    head = 2 * e * cfg["vocab_size"]
+    prompt_tokens = sum(prompt_lens)
+    causal_pairs = sum(n * (n + 1) // 2 for n in prompt_lens)
+    return ((decode_tokens + prompt_tokens) * per_token
+            + (decode_tokens + len(prompt_lens)) * head
+            + 4 * layers * e * (decode_context_tokens + causal_pairs))

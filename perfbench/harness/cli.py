@@ -86,6 +86,9 @@ def main(argv, t_start, root):
                 if run.trace is not None else None
         else:
             metrics, parts = report.end_to_end_metrics(run), None
+    except SpecError as e:
+        print("perfbench: %s" % e, file=sys.stderr)
+        return EXIT_SPEC
     except Exception:
         traceback.print_exc()
         return EXIT_FAILED

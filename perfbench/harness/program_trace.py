@@ -198,6 +198,13 @@ def subtract(intervals, cover):
     return out
 
 
+def as_events(spans):
+    """The spans as the ``xplane.Event`` tuples that ``xplane.idle_gaps``
+    takes."""
+    return [xplane.Event(xplane.HOST_PLANE, s.line, s.name, s.start_ns,
+                         s.dur_ns, "") for s in spans]
+
+
 def exposed_ns(collectives, others):
     """Nanoseconds in which a collective ran and none of ``others`` did."""
     bare = subtract(xplane.union_intervals(collectives),
@@ -429,12 +436,10 @@ def _print(run, view):
             unscoped_largest_ms=largest_unscoped(timed, n))
     if view.spans and run.trace is not None:
         device_ops = run.trace.ops(run.trace.devices[0])
-        as_events = [xplane.Event(xplane.HOST_PLANE, s.line, s.name,
-                                  s.start_ns, s.dur_ns, "")
-                     for s in view.spans]
         run.log.measurement(
             "idle_by_program_span",
-            idle_gaps=xplane.idle_gaps(device_ops, view.window, as_events,
+            idle_gaps=xplane.idle_gaps(device_ops, view.window,
+                                       as_events(view.spans),
                                        IDLE_DEFAULT, 12),
             span_ms_median={name: stats.median(
                 [s.dur_ns for s in view.named(name)]) / 1e6

@@ -1,9 +1,11 @@
 """The last line of a run: one JSON object with ``correct``, ``attempted``,
 ``failed``, ``metrics`` and ``device`` (and ``breakdown`` in a traced
-run).  Values are printed as measured, with all their digits."""
+run), and last ``compared``: each number that decided ``correct`` beside
+its limit.  Values are printed as measured, with all their digits."""
 import json
+import sys
 
-from . import xplane
+from . import program_trace, xplane
 
 
 class MissingMetric(Exception):
@@ -39,12 +41,17 @@ def per_layer_metrics(run):
 def breakdown(run, idle_default, extra_spans=()):
     """The traced run's ``breakdown``: the ten device operations that took
     most time, under the names the trace prints, and the device's idle
-    time by what the host was doing.  ``extra_spans`` are host intervals
-    the driver knows from its own records, on the trace's clock."""
+    time by what the host was doing: under the program's own ``mx.`` spans
+    and the benchmark's ``perfbench.`` ones, the innermost winning where
+    they nest.  ``extra_spans`` are host intervals the driver knows from
+    its own records, on the trace's clock."""
     tr = run.trace
     ops = tr.ops(tr.devices[0])
     spans = [e for e in xplane.host_spans(tr.events, "perfbench.")
              if e.name != "perfbench.window"] + list(extra_spans)
+    view = program_trace.load(run)
+    if view is not None:
+        spans += program_trace.as_events(view.spans)
     return {"device_ops": xplane.top_ops(ops, 10),
             "idle_gaps": xplane.idle_gaps(ops, tr.window, spans,
                                           idle_default, 10)}
@@ -61,8 +68,16 @@ def result(run, metrics, breakdown=None):
            "failed": int(run.failed), "metrics": metrics, "device": device}
     if breakdown is not None:
         out["breakdown"] = breakdown
+    out["compared"] = {name: {"value": value, "limit": limit}
+                       for name, (value, limit) in run.compared.items()}
     return out
 
 
 def print_result(obj):
+    """The compared numbers as the last lines of standard error, the
+    result as the last line of standard output."""
+    for name, pair in obj["compared"].items():
+        print("compared %s %r limit %r" % (name, pair["value"],
+                                           pair["limit"]), file=sys.stderr)
+    sys.stderr.flush()
     print(json.dumps(obj), flush=True)

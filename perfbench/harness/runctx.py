@@ -93,6 +93,7 @@ class Run:
         self.end_to_end = {}
         self.trace = None
         self.correct = True
+        self.compared = {}      # name -> (number, its limit): ``compare``
         self.attempted = 0
         self.failed = 0
         self.memory_peak_bytes = None
@@ -113,6 +114,14 @@ class Run:
     def incorrect(self, why):
         self.correct = False
         self.log.line(event="incorrect", why=why)
+
+    def compare(self, name, value, limit, why):
+        """Hold ``value`` to ``limit`` (at most) and keep the pair for the
+        result's ``compared``; ``why`` says what it means to exceed it."""
+        self.compared[name] = (float(value), float(limit))
+        if not value <= limit:
+            self.incorrect("%s: %.4g against a limit of %.4g" % (why, value,
+                                                                limit))
 
     # -- tracing ---------------------------------------------------------
     def span(self, name):

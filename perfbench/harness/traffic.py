@@ -8,6 +8,19 @@ the stated distribution, so the set has the distribution's shape exactly
 -- and the seed permutes it and draws the token ids.  A run cycles through
 the permuted set, so two seeds that complete the same number of requests
 have done the same work.
+
+A mix with a ``cycle`` fixes the order too: ``cycle`` arrivals, each a gap
+with its sizes, in one sequence that the file's numbers decide, and the
+seed chooses where in it the run starts (and draws the token ids).  A
+queue's tail is made by the few moments at which short gaps meet long
+requests; permuted anew by every seed, those moments differ from seed to
+seed, and the share of arrivals with a first token inside the mix's limit
+(``ttft_ok_share``, the open loop's end-to-end metric) differs with them
+(PERF.md, PR 27).  A window of ``cycle / rate_per_s`` seconds (the
+benchmark's ``run_seconds``; the mixes' test holds the file to it) sees
+each arrival of the sequence exactly once, whatever the seed; a shorter
+``--seconds`` sees a consecutive part of it, a longer one some arrivals
+twice.
 """
 import itertools
 import math
@@ -62,13 +75,21 @@ class ServeTraffic:
     threads."""
 
     def __init__(self, mix, vocab_size, seed):
-        count = POPULATION
-        prompts = length_population(mix["prompt_len"])
-        outputs = length_population(mix["output_len"])
+        count = int(mix.get("cycle", POPULATION))
+        prompts = length_population(mix["prompt_len"], count)
+        outputs = length_population(mix["output_len"], count)
         # pair the two independently
-        np.random.RandomState(_POPULATION_SEED).shuffle(outputs)
+        fixed = np.random.RandomState(_POPULATION_SEED)
+        fixed.shuffle(outputs)
         rng = np.random.RandomState(_seed32(seed))
-        order = rng.permutation(count)
+        if "cycle" in mix:
+            fixed.shuffle(prompts)
+            start = int(rng.randint(count))
+            order = gap_order = [(start + i) % count for i in range(count)]
+        else:
+            order = rng.permutation(count)
+            gap_order = rng.permutation(count) if "rate_per_s" in mix \
+                else None
         self.sizes = [(prompts[i], outputs[i]) for i in order]
         self._vocab = int(vocab_size)
         self._rng = rng
@@ -76,8 +97,8 @@ class ServeTraffic:
         self._index = itertools.count()
         self.gaps = None
         if "rate_per_s" in mix:
-            gaps = gap_population(mix["rate_per_s"])
-            self.gaps = [gaps[i] for i in rng.permutation(count)]
+            gaps = gap_population(mix["rate_per_s"], count)
+            self.gaps = [gaps[i] for i in gap_order]
 
     def next_request(self):
         with self._lock:

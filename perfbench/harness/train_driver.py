@@ -32,10 +32,10 @@ def check_forward(run, built):
                  gap_mean=gap_mean, gap_token_max=gap_token,
                  tol_mean=chk["tol_mean_loss"],
                  tol_token=chk["tol_token_loss"])
-    if not (gap_mean <= chk["tol_mean_loss"]
-            and gap_token <= chk["tol_token_loss"]):
-        run.incorrect("forward loss is %.4g (mean) / %.4g (worst token) "
-                      "from the float32 reference" % (gap_mean, gap_token))
+    run.compare("loss_gap_mean", gap_mean, chk["tol_mean_loss"],
+                "forward loss (mean) from the float32 reference")
+    run.compare("loss_gap_token", gap_token, chk["tol_token_loss"],
+                "forward loss (worst token) from the float32 reference")
 
 
 def run_cell(run, compile_log):
@@ -91,8 +91,8 @@ def run_cell(run, compile_log):
     run.attempted, run.failed = steps, 0
     after = compile_log.snapshot()
     in_window = after["requests"] - setup["requests"]
-    if in_window:
-        run.incorrect("%d compile(s) inside the measured window" % in_window)
+    run.compare("compiles_in_window", in_window, 0,
+                "compiles inside the measured window")
     if not all(np.isfinite(losses)):
         run.incorrect("non-finite loss among %r" % (losses,))
     if len(shard_devices) != run.chips:

@@ -39,7 +39,7 @@ def _records(out):
 
 
 @pytest.mark.parametrize("cell,trace", [(name, 1) for name in CELLS] + [
-    ("bert_train_1chip", 0), ("gpt2m_serve_closed16", 0)])
+    (name, 0) for name, w in CELLS.items() if w["chips"] == 1])
 def test_rehearsal_runs_the_whole_command(cell, trace):
     out = _run(["--workload", cell, "--seed", "3000000001", "--seconds", "1",
                 "--trace", str(trace), "--rehearse"])
@@ -58,8 +58,8 @@ def test_rehearsal_runs_the_whole_command(cell, trace):
     done = by["rehearsed"]
     assert done["correct"] is True and done["failed"] == 0
     assert done["attempted"] > 0
-    assert done["keys"] == ["attempted", "correct", "device", "failed",
-                            "metrics"]
+    assert done["keys"] == ["attempted", "compared", "correct", "device",
+                            "failed", "metrics"]
     mine = [m["name"] for m in BENCH["end_to_end" if not trace
                                      else "per_layer"]
             if cell in m.get("workloads", CELLS)]
@@ -92,6 +92,17 @@ def _copy_of_the_benchmark(tmp_path):
     return root
 
 
+def _files_under(root):
+    """Every file of the copy but BENCHMARK.json, with its bytes."""
+    out = {}
+    for base, _dirs, files in os.walk(root):
+        for f in files:
+            p = os.path.join(base, f)
+            if not p.endswith("BENCHMARK.json"):
+                out[p] = open(p, "rb").read()
+    return out
+
+
 def test_alone_in_a_directory_the_command_fails_and_prints_nothing(tmp_path):
     root = _copy_of_the_benchmark(tmp_path)
     out = _run(["--workload", "bert_train_1chip", "--seed", "1",
@@ -111,12 +122,7 @@ def read(run):
 
 def test_new_cell_config_mix_and_metric_are_files_and_entries(tmp_path):
     root = _copy_of_the_benchmark(tmp_path)
-    before = {}
-    for base, _dirs, files in os.walk(root):
-        for f in files:
-            p = os.path.join(base, f)
-            if not p.endswith("BENCHMARK.json"):
-                before[p] = open(p, "rb").read()
+    before = _files_under(root)
 
     # a configuration: BERT's family at another (tiny) size, its own file
     cfg = json.load(open(root / "perfbench/configs/bert-base-mlm-s512.json"))
@@ -166,37 +172,110 @@ def test_new_cell_config_mix_and_metric_are_files_and_entries(tmp_path):
         assert open(p, "rb").read() == content, p
 
 
-def test_an_open_loop_cell_and_its_tail_metric_are_entries_only(tmp_path):
-    """The open-loop mix has no cell yet (PERF.md, open questions).  A
-    later PR adds one, and the end-to-end tail it reports, as entries of
-    BENCHMARK.json alone: the mix, the generator and the driver are here."""
-    root = _copy_of_the_benchmark(tmp_path)
+def _add_a_throwaway_serving_cell(root, tail=None, prefix="throwaway"):
+    """To the copy at ``root``: a serving configuration, an open-loop mix
+    and a cell of the two as new files and entries, the cell listed on
+    every metric that lists ``gpt2m_serve_closed16`` (and, with ``tail``,
+    on a new end-to-end metric of that name).  Returns the cell's name."""
+    config, traffic, cell = (prefix + "-gpt2", prefix + "_open",
+                             prefix + "_serve")
+    cfg = json.load(open(root / "perfbench/configs/gpt2-medium.json"))
+    cfg["source"] = "https://example.org/" + config
+    cfg["rehearse"]["n_layer"] = 1
+    with open(root / ("perfbench/configs/%s.json" % config), "w") as f:
+        json.dump(cfg, f)
+    mix = json.load(open(root / "perfbench/traffic/open_loop.json"))
+    mix["rate_per_s"] = 0.5 * mix["rate_per_s"]
+    mix["cycle"] = int(mix["rate_per_s"] * BENCH["run_seconds"])
+    mix["rehearse"]["rate_per_s"] = 30.0
+    with open(root / ("perfbench/traffic/%s.json" % traffic), "w") as f:
+        json.dump(mix, f)
     bench = json.load(open(root / "BENCHMARK.json"))
+    bench["configs"].append({
+        "name": config, "source": cfg["source"],
+        "file": "perfbench/configs/%s.json" % config,
+        "reduced": cfg["reduced"], "why": "test"})
     bench["workloads"].append({
-        "name": "gpt2m_serve_open", "config": "gpt2-medium",
-        "traffic": "open_loop", "chips": 1, "why": "test"})
-    bench["end_to_end"].append({
-        "name": "ttft_p95_ms", "unit": "ms", "better": "lower",
-        "bound": 0.1, "source": "host_clock",
-        "workloads": ["gpt2m_serve_open"]})
+        "name": cell, "config": config, "traffic": traffic, "chips": 1,
+        "why": "test"})
+    if tail:
+        bench["end_to_end"].append({
+            "name": tail, "unit": "ms", "better": "lower", "bound": 0.1,
+            "source": "host_clock", "workloads": [cell]})
     for m in bench["end_to_end"] + bench["per_layer"]:
         if "gpt2m_serve_closed16" in m.get("workloads", []):
-            m["workloads"].append("gpt2m_serve_open")
+            m["workloads"].append(cell)
     with open(root / "BENCHMARK.json", "w") as f:
         json.dump(bench, f)
-    for trace, want in ((0, ["serve_tokens_per_s", "setup_s", "ttft_p95_ms"]),
-                        (1, None)):
-        out = _run(["--workload", "gpt2m_serve_open", "--seed", "9",
+    return cell
+
+
+def test_an_open_loop_cell_and_its_tail_metric_are_entries_only(tmp_path):
+    """A serving configuration, an open-loop mix, a cell of the two and an
+    end-to-end tail that no cell reports yet (the gap between tokens) are
+    new files and entries: the generator and the driver are here, and the
+    command runs the cell with no edit to a file that was there."""
+    root = _copy_of_the_benchmark(tmp_path)
+    before = _files_under(root)
+    cell = _add_a_throwaway_serving_cell(root, tail="itl_p95_ms")
+    for trace, want in ((0, ["itl_p95_ms", "serve_tokens_per_s",
+                             "setup_s"]), (1, None)):
+        out = _run(["--workload", cell, "--seed", "9",
                     "--seconds", "1", "--trace", str(trace), "--rehearse"],
                    root=str(root), pythonpath=REPO)
         assert out.returncode == 0, out.stderr[-3000:]
         done = {r["event"]: r for r in _records(out)}["rehearsed"]
         assert done["correct"] is True and done["attempted"] > 0
         if want:
-            assert done["metrics"] == want
+            assert sorted(done["metrics"]) == want
         else:
             assert {"decode_step_ms.serve", "prefill_ms.serve",
-                    "itl_p95_ms.closed"} <= set(done["metrics"])
+                    "itl_p95_ms.closed", "host_loop_ms.serve",
+                    "slot_occupancy.serve"} <= set(done["metrics"])
+    for p, content in before.items():
+        assert open(p, "rb").read() == content, p
+
+
+STATIC_TESTS = [
+    "test_perfbench_units.py",
+    "test_perfbench_program_trace.py::"
+    "test_the_new_metrics_and_the_four_chip_cell_are_declared",
+    "test_perfbench_program_rehearsal.py::"
+    "test_every_cell_reads_the_programs_names_or_says_why_not"]
+
+
+def test_the_benchmarks_own_tests_take_a_new_serving_cell_as_data(tmp_path):
+    """The serving twin of the test above it, for the tests themselves: a
+    later PR may add files and entries and may not edit ``tests/perfbench``
+    (it is one of ``paths``).  So with a throw-away serving configuration,
+    mix and cell added to a copy, the static tests of this directory (the
+    letter of BENCHMARK.json, the declarations, the cell enumeration) pass
+    from the copy, and no file that was there differs."""
+    root = _copy_of_the_benchmark(tmp_path)
+    shutil.copytree(os.path.dirname(os.path.abspath(__file__)),
+                    root / "tests" / "perfbench",
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    before = _files_under(root)
+    _add_a_throwaway_serving_cell(root)
+    env = {k: v for k, v in os.environ.items()
+           if not k.startswith("PYTEST_")}
+    # ``perfbench`` from the copy (the working directory), the program
+    # from the repo
+    env.update(JAX_PLATFORMS="cpu", PYTHONPATH=REPO)
+    out = subprocess.run(
+        [sys.executable, "-m", "pytest", "-v", "-p", "no:cacheprovider",
+         "-p", "no:xdist", "-p", "no:randomly"]
+        + [os.path.join("tests", "perfbench", t) for t in STATIC_TESTS],
+        env=env, cwd=str(root), capture_output=True, text=True, timeout=600)
+    assert out.returncode == 0, out.stdout[-4000:] + out.stderr[-2000:]
+    # the copy was looked at: its cell and its mix are among the cases
+    for case in ("test_every_cell_finds_its_files[throwaway_serve]",
+                 "test_a_traffic_mix_is_a_data_file[throwaway_open.json]",
+                 "test_every_cell_reads_the_programs_names_or_says_why_not"
+                 "[throwaway_serve]"):
+        assert case + " PASSED" in out.stdout, case
+    for p, content in before.items():
+        assert open(p, "rb").read() == content, p
 
 
 @pytest.mark.parametrize("precision", ["highest", "bfloat16"])

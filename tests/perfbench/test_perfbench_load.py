@@ -12,7 +12,7 @@ import types
 import pytest
 
 from perfbench.harness import serve_driver
-from perfbench.harness.spec import sized
+from perfbench.harness.spec import SpecError, sized
 from perfbench.harness.traffic import ServeTraffic
 
 REPO = os.path.dirname(os.path.dirname(os.path.dirname(
@@ -154,11 +154,13 @@ def test_a_shed_request_counts_as_failed_and_has_no_latency():
     assert run.end_to_end["itl_p95_ms"] >= 1e3 * reg.gap * 0.9
 
 
-def _fake_run():
+def _fake_run(mix=None):
     run = types.SimpleNamespace(
-        end_to_end={}, counters={}, attempted=0, failed=0, window_s=None,
-        setup_seconds=lambda now: 1.0, why_incorrect=[],
-        log=types.SimpleNamespace(measurement=lambda event, **kw: None))
+        mix=mix or {}, end_to_end={}, counters={}, attempted=0, failed=0,
+        window_s=None, setup_seconds=lambda now: 1.0, why_incorrect=[],
+        printed={})
+    run.log = types.SimpleNamespace(
+        measurement=lambda event, **kw: run.printed.update(kw))
     run.incorrect = run.why_incorrect.append
     return run
 
@@ -208,6 +210,35 @@ def test_window_counts_the_context_every_decode_step_read():
     assert run.counters["in_flight_intervals"] == [(0.0, 0.4)]
     assert run.end_to_end["ttft_p95_ms"] == pytest.approx(100.0)
     assert run.end_to_end["itl_p95_ms"] == pytest.approx(100.0)
+    # no stream got a token from 0.4 s to the close: the window's hole
+    assert run.printed["longest_silence_ms"] == pytest.approx(600.0)
+    assert run.printed["longest_silence_at_s"] == pytest.approx(0.4)
+    assert run.printed["itl_max_ms"] == pytest.approx(100.0)
+
+
+def test_the_share_within_the_mixs_limit_counts_every_arrival_of_the_window():
+    """``ttft_ok_share``: of the requests due in the window, those whose
+    first token came within the mix's ``ttft_limit_ms`` of when they were
+    due.  A shed request and one still waiting miss; a mix without the
+    limit has no such metric."""
+    def rec(i, t_due, first, failed=None):
+        r = serve_driver.StreamRecord(
+            types.SimpleNamespace(index=i, prompt=[1] * 4, max_new=2), t_due)
+        r.t_submit = t_due
+        r.token_times = [] if first is None else [first, first + 0.01]
+        r.failed = failed
+        r.t_end = t_due + 1.0
+        r.done.set()
+        return r
+    records = [rec(0, 0.10, 0.15), rec(1, 0.20, 0.2999), rec(2, 0.30, 0.45),
+               rec(3, 0.40, None, "shed"), rec(4, 0.50, None),
+               rec(5, 1.50, 1.51)]           # due after the window
+    run = _fake_run({"ttft_limit_ms": 100})
+    serve_driver._window_metrics(run, records, 0.0, 1.0)
+    assert run.end_to_end["ttft_ok_share"] == pytest.approx(100.0 * 2 / 5)
+    run = _fake_run()
+    serve_driver._window_metrics(run, records[:3], 0.0, 1.0)
+    assert "ttft_ok_share" not in run.end_to_end
 
 
 @pytest.mark.parametrize("shift", [0.0, 0.3, 0.5, 0.77])
@@ -226,3 +257,20 @@ def test_the_rate_does_not_depend_on_where_the_window_cuts_a_step(shift):
     serve_driver._window_metrics(run, records, 5.0 + shift, 35.0 + shift)
     assert run.end_to_end["serve_tokens_per_s"] \
         == pytest.approx(16 / step, rel=1e-4)
+
+
+def test_a_checked_sequence_longer_than_the_checks_width_is_a_spec_error():
+    """Cut to ``check.width`` a longer sequence was judged on empty slices
+    and passed; now the run fails before a stream is sent, naming both."""
+    reg = FakeRegistry()
+    traffic = ServeTraffic(_mix("open_loop"), 50, 1)
+    longest = max(n for n, _out in traffic.sizes[:8])
+    cfg = {"check": {"streams": 8, "max_new": 6, "width": longest + 5,
+                     "chunk": 2, "references": []}}
+    run = types.SimpleNamespace(family=None, cfg=cfg)
+    load = serve_driver.Load(reg, traffic, _span)
+    with pytest.raises(SpecError) as err:
+        serve_driver.check_streams(run, None, None, load, traffic)
+    assert "%d tokens long" % (longest + 6) in str(err.value)
+    assert "check.width is %d" % (longest + 5) in str(err.value)
+    assert reg.calls == 0

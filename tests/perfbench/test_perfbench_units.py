@@ -103,6 +103,28 @@ def test_arrival_offsets_cycle_without_end():
     assert offs[len(t.gaps) - 1] == pytest.approx(sum(t.gaps))
 
 
+def test_a_mix_with_a_cycle_is_one_sequence_that_the_seed_only_starts():
+    """Gaps and sizes stay paired in the file's order; two seeds differ by
+    where they start; any window of ``cycle / rate_per_s`` seconds holds
+    each arrival of the sequence exactly once."""
+    mix = dict(sized(_mix("open_loop"), rehearse=False),
+               rate_per_s=8.0, cycle=240)
+    a, b = (ServeTraffic(mix, 50257, s) for s in (3000000001, 7))
+    seq_a, seq_b = list(zip(a.sizes, a.gaps)), list(zip(b.sizes, b.gaps))
+    assert len(seq_a) == 240 and seq_a != seq_b
+    start = seq_b.index(seq_a[0])
+    assert seq_b[start:] + seq_b[:start] == seq_a
+    assert sum(a.gaps) == pytest.approx(30.0)
+    offsets = a.arrival_offsets()
+    due = [next(offsets) for _ in range(3 * 240)]
+    for t0 in (0.001, 3.7, 11.1, 29.99):
+        inside = [i % 240 for i, t in enumerate(due) if t0 <= t < t0 + 30.0]
+        assert sorted(inside) == list(range(240))
+    # the lengths are the stated distributions' own quantiles
+    assert sorted(p for p, _o in a.sizes) \
+        == sorted(length_population(mix["prompt_len"], 240))
+
+
 def test_requests_are_handed_out_once_across_threads():
     t = ServeTraffic(sized(_mix("closed_loop"), True), 100, 1)
     got = []
@@ -156,6 +178,36 @@ def test_gpt2_medium_token_holds_196608_bytes_of_kv():
     assert gpt2.kv_bytes_per_token(cfg) == 196608 == 2 * 24 * 1024 * 4
     flops, nbytes = gpt2.paged_attention_cost(cfg, 1000)
     assert nbytes == 196608000 and flops == 4 * 24 * 1024 * 1000
+
+
+def test_gpt2_medium_served_flops_by_hand():
+    """One decode token over a context of 300 and one prompt of 100."""
+    from perfbench.families import gpt2
+    cfg = _config("gpt2-medium")
+    layer = 24 * 1024 * 1024            # 12 x n_embd^2 multiply-adds
+    head = 2 * 1024 * 50257
+    assert gpt2.served_flops(cfg, 1, 300, []) \
+        == 24 * layer + head + 4 * 24 * 1024 * 300
+    assert gpt2.served_flops(cfg, 0, 0, [100]) \
+        == 100 * 24 * layer + head + 4 * 24 * 1024 * (100 * 101 // 2)
+    assert gpt2.served_flops(cfg, 0, 0, []) == 0
+
+
+def test_a_compared_number_is_kept_beside_its_limit():
+    from perfbench.harness.runctx import Run
+    lines = []
+    run = Run.__new__(Run)
+    run.correct, run.compared = True, {}
+    run.log = types.SimpleNamespace(line=lambda **kw: lines.append(kw))
+    run.compare("logit_gap.bfloat16", 0.0, 0.3, "logits")
+    assert run.correct and not lines
+    run.compare("token_margin.bfloat16", 0.7, 0.6, "a token's margin")
+    run.compare("nan_is_over", float("nan"), 1.0, "not a number")
+    assert not run.correct and len(lines) == 2
+    assert "a token's margin" in lines[0]["why"] and "0.6" in lines[0]["why"]
+    assert run.compared["token_margin.bfloat16"] == (0.7, 0.6)
+    assert list(run.compared) == ["logit_gap.bfloat16",
+                                  "token_margin.bfloat16", "nan_is_over"]
 
 
 def test_peaks_are_the_programs_and_unknown_kinds_are_errors():
@@ -331,6 +383,7 @@ def _fake_run(trace=None):
         cell=cell, stamp={"platform": "tpu", "kind": "TPU v5 lite",
                           "count": 1},
         correct=True, attempted=50, failed=0, trace=trace,
+        compared={"loss_gap_mean": (0.001, 0.02)},
         memory_peak_bytes=9000000000,
         end_to_end={"train_tokens_per_s": 91234.5678, "setup_s": 41.25,
                     "something_else": 1.0})
@@ -340,8 +393,10 @@ def _fake_run(trace=None):
 def test_last_line_has_the_contracts_keys():
     run = _fake_run()
     out = report.result(run, report.end_to_end_metrics(run))
-    assert sorted(out) == ["attempted", "correct", "device", "failed",
-                           "metrics"]
+    assert list(out) == ["correct", "attempted", "failed", "metrics",
+                         "device", "compared"]     # ``compared`` comes last
+    assert out["compared"] == {"loss_gap_mean": {"value": 0.001,
+                                                 "limit": 0.02}}
     assert out["metrics"] == {
         "train_tokens_per_s": {"value": 91234.5678, "unit": "tokens/s"},
         "setup_s": {"value": 41.25, "unit": "s"}}
@@ -495,12 +550,23 @@ def test_every_file_under_paths_is_named_from_a_names_characters():
                 assert re.match(r"^[A-Za-z0-9_.\-/]+$", rel), rel
 
 
-@pytest.mark.parametrize("name", ["train_stream", "closed_loop",
-                                  "open_loop"])
-def test_a_traffic_mix_is_a_data_file(name):
+MIX_SUFFIXES = (".json", ".jsonl", ".toml", ".txt", ".csv")
+
+
+@pytest.mark.parametrize("file", sorted(os.listdir(
+    os.path.join(REPO, "perfbench", "traffic"))))
+def test_a_traffic_mix_is_a_data_file(file):
+    name, suffix = os.path.splitext(file)
+    assert suffix in MIX_SUFFIXES and NAME.match(name), file
+    assert suffix == ".json", "the one generator reads JSON: %s" % file
     mix = _mix(name)
     assert mix["kind"] in ("train_stream", "closed_loop", "open_loop")
     assert isinstance(mix["what"], str)
+    # a traced window of the whole 30 s is millions of events and tens of
+    # GB of host memory once a step takes 11 ms (PERF.md, PR 26)
+    assert 0 < mix["trace_seconds"] <= 6
     if mix["kind"] == "open_loop":
         assert isinstance(mix["rate_per_s"], (int, float))
         assert not math.isnan(mix["rate_per_s"])
+    if "cycle" in mix:      # a window holds the whole sequence once
+        assert mix["cycle"] == mix["rate_per_s"] * BENCH["run_seconds"]
