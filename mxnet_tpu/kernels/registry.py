@@ -101,7 +101,8 @@ def _ensure_registered():
     # importing the kernel modules registers their specs; lazy so that
     # `import mxnet_tpu` does not pull pallas machinery upfront
     from . import (flash_attention, fused_bn_relu,  # noqa: F401
-                   optimizer_update, paged_attention)
+                   mla_paged_attention, optimizer_update,
+                   paged_attention)
 
 
 def get(name: str) -> KernelSpec:

@@ -14,7 +14,8 @@ from .tensor_parallel import (ColumnParallelDense, RowParallelDense,
                               TensorParallelMLP, shard_block_tp)
 from .pipeline import (pipeline_apply, shard_stacked_params,
                        stack_stage_params)
-from .moe import MixtureOfExperts, moe_load_balancing_loss
+from .moe import (MixtureOfExperts, moe_load_balancing_loss,
+                  route_top_k, routed_experts)
 
 __all__ = ["Mesh", "NamedSharding", "PartitionSpec", "default_mesh",
            "global_mesh", "local_devices", "make_mesh", "put_replicated",
@@ -24,4 +25,4 @@ __all__ = ["Mesh", "NamedSharding", "PartitionSpec", "default_mesh",
            "RowParallelDense", "TensorParallelMLP", "shard_block_tp",
            "pipeline_apply", "shard_stacked_params",
            "stack_stage_params", "MixtureOfExperts",
-           "moe_load_balancing_loss"]
+           "moe_load_balancing_loss", "route_top_k", "routed_experts"]

@@ -112,3 +112,102 @@ def moe_load_balancing_loss(x, gate_w):
     frac = jnp.mean(jax.nn.one_hot(expert, E, dtype=probs.dtype), axis=0)
     prob_mean = jnp.mean(probs, axis=0)
     return E * jnp.sum(frac * prob_mean)
+
+
+# ----------------------------------------------------------------------
+# routed experts of an expert-parallel deployment (DeepSeek-V3 style)
+# ----------------------------------------------------------------------
+
+def route_top_k(x, gate_w, selection_bias, top_k, scale=1.0):
+    """The router of a DeepSeek-V3-style expert layer over ALL experts.
+
+    ``x`` (tokens, d); ``gate_w`` (d, experts); ``selection_bias``
+    (experts,), added to the scores for the choice only
+    (``e_score_correction_bias``).  Returns ``(chosen (tokens, top_k)
+    int32, weights (tokens, top_k) float32)``: ``scores = sigmoid(x
+    gate_w)``, ``chosen = top_k(scores + bias)``, ``weights =
+    scores[chosen] / (sum + 1e-20) * scale``.  float32 throughout and at
+    matmul precision ``highest``: the choice is discontinuous, so a
+    score is not to differ from another implementation's by more than
+    float32 rounding.
+    """
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), gate_w.astype(jnp.float32),
+        precision=jax.lax.Precision.HIGHEST))
+    _, chosen = jax.lax.top_k(scores + selection_bias.astype(jnp.float32),
+                              int(top_k))
+    picked = jnp.take_along_axis(scores, chosen, axis=-1)
+    weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    return chosen.astype(jnp.int32), weights * scale
+
+
+def routed_experts(x, chosen, weights, w_gate, w_up, w_down, first_expert,
+                   live=None, chunk_rows=2048):
+    """The part of an expert layer's output that THIS chip's experts
+    give, for an expert layer that is told which experts it holds.
+
+    ``x`` (tokens, d); ``chosen`` / ``weights`` (tokens, top_k) from
+    :func:`route_top_k` over all experts; ``w_gate`` / ``w_up``
+    (n_held, d, f) and ``w_down`` (n_held, f, d) the stacked weights of
+    experts ``first_expert .. first_expert + n_held - 1``, each a gated
+    SiLU MLP ``w_down(silu(x w_gate) * (x w_up))``; ``live`` (tokens,)
+    bool masks padding tokens out.  Returns ``(y (tokens, d) float32,
+    counts (n_held,) int32)``: ``y[t] = sum over the chosen experts held
+    here of weights * Expert(x[t])`` and ``counts`` the tokens each held
+    expert was given.  What the experts held elsewhere would add is
+    left out; on one chip there is no exchange and nothing stands in
+    for one.
+
+    No capacity and no dropped token: the assignments that fall on held
+    experts are sorted by expert and go through a grouped matmul
+    (``jax.lax.ragged_dot``) ``chunk_rows`` sorted rows at a time, in a
+    loop that runs as many chunks as there ARE such assignments -- one
+    for a decode step, ``tokens * top_k / chunk_rows`` if every token
+    chose only experts held here.
+    """
+    n_held, d, _f = w_gate.shape
+    tokens, top_k = chosen.shape
+    n_assign = tokens * top_k
+    local = chosen - first_expert
+    held = (local >= 0) & (local < n_held)
+    if live is not None:
+        held = held & live[:, None]
+    # an assignment's expert here, n_held for one that is not ours
+    expert = jnp.where(held, local, n_held).reshape(n_assign)
+    # a compare-and-sum, not a scatter-add: every update of a scatter
+    # into n_held bins collides, and a TPU runs those one by one
+    counts = jnp.sum(expert[:, None] == jnp.arange(n_held, dtype=jnp.int32),
+                     axis=0, dtype=jnp.int32)
+    ends = jnp.cumsum(counts)
+    starts, total = ends - counts, ends[-1]
+    chunk = min(int(chunk_rows), n_assign)
+    n_chunks = -(-n_assign // chunk)
+    # ours first and grouped by expert; padded to whole chunks
+    order = jnp.argsort(expert, stable=True).astype(jnp.int32)
+    order = jnp.pad(order, (0, n_chunks * chunk - n_assign))
+    flat_w = weights.reshape(n_assign)
+
+    def one_chunk(c, y):
+        at = c * chunk
+        rows = jax.lax.dynamic_slice(order, (at,), (chunk,))
+        ours = at + jnp.arange(chunk, dtype=jnp.int32) < total
+        token = rows // top_k
+        # this chunk's share of every expert's group
+        sizes = jnp.clip(ends - at, 0, chunk) - jnp.clip(starts - at, 0,
+                                                          chunk)
+        xs = jnp.take(x, token, axis=0)
+        gate = jax.lax.ragged_dot(xs, w_gate, sizes,
+                                  preferred_element_type=jnp.float32)
+        up = jax.lax.ragged_dot(xs, w_up, sizes,
+                                preferred_element_type=jnp.float32)
+        hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+        out = jax.lax.ragged_dot(hidden, w_down, sizes,
+                                 preferred_element_type=jnp.float32)
+        # rows past the last of ours belong to no group
+        out = jnp.where(ours[:, None],
+                        out * jnp.take(flat_w, rows)[:, None], 0.0)
+        return y.at[token].add(out)
+
+    y = jax.lax.fori_loop(0, -(-total // chunk), one_chunk,
+                          jnp.zeros((tokens, d), jnp.float32))
+    return y, counts

@@ -26,18 +26,26 @@ not per-request.  This package is that tier:
 - :class:`~.model.TinyGPT` -- a GPT-style decoder in pure-function
   form (prefill + paged decode step + full-forward oracle), the
   CI/bench workload.
+- :class:`~.latent_moe.LatentMoEDecoder` -- a DeepSeek-V3-style decoder
+  (latent attention over ONE cache row a token, rotary positions, RMS
+  norm, routed experts told which experts they hold) in the same form.
 
-The decode-step attention itself is a kernel-registry citizen
-(``kernels.paged_attention``): a Pallas online-softmax walk over the
-slot's block table on TPU (interpret mode on CPU under
-``MXNET_TPU_KERNELS=1``), an XLA gather+masked-softmax fallback
-everywhere else.  docs/serving.md covers tuning.
+A model declares what a token keeps in the cache (``cache_rows()``) and
+the engine builds the one ``PagedKVCache`` from that.  The decode-step
+attention itself is a kernel-registry citizen
+(``kernels.paged_attention`` for per-head K and V,
+``kernels.mla_paged_attention`` for latent rows): a Pallas
+online-softmax walk over the slot's block table on TPU (interpret mode
+on CPU under ``MXNET_TPU_KERNELS=1``), an XLA gather+masked-softmax
+fallback everywhere else.  docs/serving.md covers tuning.
 """
 from .engine import (DecodeEngine, GenerationStream, GenerativeServable,
                      GenerativeWatcher)
 from .kvcache import BlockTable, KVCacheExhausted, PagedKVCache
+from .latent_moe import LatentMoEDecoder
 from .model import TinyGPT, tiny_gpt
 
 __all__ = ["BlockTable", "DecodeEngine", "GenerationStream",
            "GenerativeServable", "GenerativeWatcher",
-           "KVCacheExhausted", "PagedKVCache", "TinyGPT", "tiny_gpt"]
+           "KVCacheExhausted", "LatentMoEDecoder", "PagedKVCache",
+           "TinyGPT", "tiny_gpt"]

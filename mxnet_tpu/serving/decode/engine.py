@@ -212,7 +212,13 @@ class _AotPrograms:
             return self._programs[key]
         jfn = jax.jit(fn, donate_argnums=donate_argnums)
         lowered = jfn.lower(*specs)
-        fp = stablehlo_fingerprint(lowered.as_text())
+        # the lowering drops an argument that the program does not read,
+        # the exported artifact's calling convention keeps it: a key of
+        # the text alone would hand a caller of six arguments the
+        # artifact of a caller of five
+        fp = stablehlo_fingerprint(
+            lowered.as_text() + "\n// called with %s"
+            % (jax.tree_util.tree_structure(specs),))
         # compiled here, never at the first request: warmup() promises
         # that no request pays a compile; with its scopes in the XLA
         # cache's key, so that a trace reads this version's names
@@ -288,9 +294,9 @@ class DecodeEngine:
                          else _env.get("MXNET_TPU_SERVING_KV_BLOCK"))
         num_blocks = int(num_blocks if num_blocks is not None
                          else _env.get("MXNET_TPU_SERVING_KV_BLOCKS"))
-        self.cache = PagedKVCache(model.num_layers, model.num_heads,
-                                  model.head_dim, block_size,
-                                  num_blocks, dtype=kv_dtype)
+        # the model declares what a token keeps in a layer
+        self.cache = PagedKVCache(model.num_layers, model.cache_rows(),
+                                  block_size, num_blocks, dtype=kv_dtype)
         # fixed compiled block-table width: enough for the longest
         # sequence the model can hold
         self.max_blocks_per_seq = self.cache.blocks_for(model.max_seq)
@@ -307,17 +313,17 @@ class DecodeEngine:
         self._thread = None
 
     # -- AOT build ------------------------------------------------------
-    # argument numbers of (params, kv_k, kv_v, ...) that every prefill
-    # and decode program donates: both slab tuples, whole.  Never
+    # argument number of (params, slabs, ...) that every prefill and
+    # decode program donates: the cache's whole pytree.  Never
     # ``params``, which every call and every engine of a swap share.
-    _DONATED = (1, 2)
+    _DONATED = (1,)
 
-    def _prefill_impl(self, params, kv_k, kv_v, tokens, table,
-                      true_len):
+    def _prefill_impl(self, params, slabs, tokens, table, true_len):
         import jax
         import jax.numpy as jnp
         bs = self.cache.block_size
-        logits, ks, vs = self.model.prefill_kv(params, tokens)
+        logits, rows, stats = self.model.prefill_kv(params, tokens,
+                                                    true_len - 1)
         lb = tokens.shape[1]
         with jax.named_scope("mx.kv_scatter"):
             pos = jnp.arange(lb, dtype=jnp.int32)
@@ -325,47 +331,47 @@ class DecodeEngine:
                             jnp.take(table, pos // bs), SCRATCH_BLOCK)
             off = pos % bs
             # each layer's prompt rows into that layer's own slab
-            kv_k = tuple(slab.at[blk, off].set(slab_rows(k, slab))
-                         for slab, k in zip(kv_k, ks))
-            kv_v = tuple(slab.at[blk, off].set(slab_rows(v, slab))
-                         for slab, v in zip(kv_v, vs))
+            slabs = {name: tuple(
+                slab.at[blk, off].set(slab_rows(r, slab))
+                for slab, r in zip(layers, rows[name]))
+                for name, layers in slabs.items()}
         with jax.named_scope("mx.lm_head"):
-            last = jnp.take(logits[0], true_len - 1, axis=0)
-            first_token = jnp.argmax(last).astype(jnp.int32)
-        return first_token, kv_k, kv_v
+            first_token = jnp.argmax(logits).astype(jnp.int32)
+        return (first_token, stats), slabs
 
-    def _decode_impl(self, params, kv_k, kv_v, tokens, positions,
-                     tables):
-        next_token, _logits, kv_k, kv_v = self.model.decode_logits(
-            params, kv_k, kv_v, tokens, positions, tables,
-            self.cache.block_size)
-        return next_token, kv_k, kv_v
+    def _decode_impl(self, params, slabs, tokens, positions, tables, live):
+        next_token, _logits, slabs, stats = self.model.decode_logits(
+            params, slabs, tokens, positions, tables,
+            self.cache.block_size, live)
+        return (next_token, stats), slabs
 
     def _specs(self):
         import jax
         i32 = np.int32
-        kv = tuple(jax.ShapeDtypeStruct(a.shape, a.dtype)
-                   for a in self.cache.keys)
+        kv = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+            self.cache.slabs)
         pspec = {n: jax.ShapeDtypeStruct(v.shape, v.dtype)
                  for n, v in self.params.items()}
         mb = self.max_blocks_per_seq
         prefill = {
-            b: (pspec, kv, kv,
+            b: (pspec, kv,
                 jax.ShapeDtypeStruct((1, b), i32),
                 jax.ShapeDtypeStruct((mb,), i32),
                 jax.ShapeDtypeStruct((), i32))
             for b in self.prefill_buckets}
         decode = {
-            s: (pspec, kv, kv,
+            s: (pspec, kv,
                 jax.ShapeDtypeStruct((s,), i32),
                 jax.ShapeDtypeStruct((s,), i32),
-                jax.ShapeDtypeStruct((s, mb), i32))
+                jax.ShapeDtypeStruct((s, mb), i32),
+                jax.ShapeDtypeStruct((s,), np.bool_))
             for s in self.decode_buckets}
         return prefill, decode
 
     def warmup(self):
         """Compile every prefill and decode bucket (compile-cache
-        checked first), each with the K/V slabs donated; returns total
+        checked first), each with the cache's slabs donated; returns total
         warm-up seconds.  After this no request can trigger a
         compile."""
         t0 = time.perf_counter()
@@ -517,12 +523,13 @@ class DecodeEngine:
     def _prefill(self, req):
         bucket = self._bucket(self.prefill_buckets, len(req.prompt),
                               "prefill")
-        with _obs.span("mx.decode.prefill", bucket=bucket,
-                       prompt=len(req.prompt),
-                       links=_request_links((req,))):
-            self._prefill_spanned(req, bucket)
+        span = _obs.span("mx.decode.prefill", bucket=bucket,
+                         prompt=len(req.prompt),
+                         links=_request_links((req,)))
+        with span:
+            self._prefill_spanned(req, bucket, span)
 
-    def _prefill_spanned(self, req, bucket):
+    def _prefill_spanned(self, req, bucket, span):
         import jax
         with _obs.span("mx.decode.prefill.build"):
             tokens = np.zeros((1, bucket), np.int32)
@@ -540,11 +547,13 @@ class DecodeEngine:
                 # the slabs that go in are donated: dead once the call
                 # returns, so the outputs are bound before anything
                 # else can read the cache
-                first, cache.keys, cache.values = call(
-                    self.params, cache.keys, cache.values,
-                    tokens, table, np.int32(len(req.prompt)))
+                out, cache.slabs = call(
+                    self.params, cache.slabs, tokens, table,
+                    np.int32(len(req.prompt)))
                 dispatched = True
-                first = int(jax.device_get(first))
+                # the token and the program's counts in one fetch
+                first, stats = jax.device_get(out)
+                first = int(first)
         except Exception as e:
             self._call_failed(e, [req], dispatched)
             return
@@ -555,6 +564,7 @@ class DecodeEngine:
                 _telemetry.hooks.decode_prefill(self._label, bucket,
                                                 len(req.prompt), now - t0)
                 _telemetry.hooks.decode_ttft(now - req.t_submit)
+            self._note_stats(span, stats)
             self._emit(req, first, now)
             if not self._maybe_finish(req):
                 self._active.append(req)
@@ -564,18 +574,22 @@ class DecodeEngine:
         ``mx.decode.step`` span."""
         n = len(self._active)
         bucket = self._bucket(self.decode_buckets, n, "decode")
-        with _obs.span("mx.decode.step", n=n, bucket=bucket,
-                       max_slots=self.max_slots,
-                       links=_request_links(self._active)):
-            self._step_spanned(n, bucket)
+        span = _obs.span("mx.decode.step", n=n, bucket=bucket,
+                         max_slots=self.max_slots,
+                         links=_request_links(self._active))
+        with span:
+            self._step_spanned(n, bucket, span)
 
-    def _step_spanned(self, n, bucket):
+    def _step_spanned(self, n, bucket, span):
         import jax
         with _obs.span("mx.decode.step.build"):
             tokens = np.zeros((bucket,), np.int32)
             positions = np.zeros((bucket,), np.int32)
             tables = np.full((bucket, self.max_blocks_per_seq),
                              SCRATCH_BLOCK, np.int32)
+            # which slots of the bucket hold a sequence: the rest is
+            # padding, and a model that counts its tokens leaves it out
+            live = np.arange(bucket) < n
             for i, req in enumerate(self._active):
                 tokens[i] = req.last_token
                 positions[i] = req.position
@@ -591,11 +605,11 @@ class DecodeEngine:
                                   model=self._label, occupancy=n,
                                   bucket=bucket)
                 # donated slabs in, the same memory out: rebind at once
-                out, cache.keys, cache.values = call(
-                    self.params, cache.keys, cache.values, tokens,
-                    positions, tables)
+                out, cache.slabs = call(
+                    self.params, cache.slabs, tokens, positions, tables,
+                    live)
                 dispatched = True
-                out = jax.device_get(out)
+                out, stats = jax.device_get(out)
         except Exception as e:
             self._call_failed(e, list(self._active), dispatched)
             return
@@ -604,6 +618,7 @@ class DecodeEngine:
             if _telemetry._ENABLED:
                 _telemetry.hooks.decode_step(self._label, n, bucket,
                                              now - t0)
+            self._note_stats(span, stats)
             finished = []
             for i, req in enumerate(self._active):
                 self._emit(req, int(out[i]), now)
@@ -617,6 +632,19 @@ class DecodeEngine:
                 # bucket
                 self._active = [r for r in self._active
                                 if r not in finished]
+
+    def _note_stats(self, span, stats):
+        """The counts a program returned beside its token (a model with
+        routed experts: ``moe_assignments``, ``moe_assignments_held``,
+        ``moe_expert_tokens_max``; empty for a dense one): onto the
+        step's or the prefill's span and the ``decode.moe.*``
+        counters."""
+        if not stats:
+            return
+        stats = {k: int(v) for k, v in stats.items()}
+        span.set(**stats)
+        if _telemetry._ENABLED:
+            _telemetry.hooks.decode_moe(self._label, stats)
 
     def _call_failed(self, error, served, dispatched):
         """A prefill or decode call raised.  Before the call took its

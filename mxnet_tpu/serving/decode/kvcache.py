@@ -2,31 +2,38 @@
 discipline).
 
 The generative engine never allocates per-request device memory: at
-construction it preallocates one slab pair PER LAYER -- ``keys[i]`` and
-``values[i]``, each ``(num_blocks, block_size, heads, lanes)`` with the
-first ``head_dim`` lanes in use -- carved into fixed-size blocks, and a
-request is admitted by handing it a **block table** (the ordered list
-of block ids its tokens map onto; one table names the same blocks in
-every layer's slab).  Token position ``p`` of a request lives at
+construction it preallocates, PER LAYER, one slab for each kind of row
+the MODEL declares a token to hold there (``rows``: name -> shape of
+one token's row).  A GPT-style decoder declares ``k`` and ``v`` of
+``(heads, head_dim)``, so ``slabs["k"][i]`` and ``slabs["v"][i]`` are
+``(num_blocks, block_size, heads, lanes)``; a latent-attention (MLA)
+decoder declares ONE row, ``latent`` of ``(kv_lora_rank +
+qk_rope_head_dim,)``, so ``slabs["latent"][i]`` is ``(num_blocks,
+block_size, lanes)``.  In both the first ``shape[-1]`` lanes are in
+use.  The slabs are carved into fixed-size blocks, and a request is
+admitted by handing it a **block table** (the ordered list of block
+ids its tokens map onto; one table names the same blocks in every
+layer's slabs).  Token position ``p`` of a request lives at
 ``(table[p // block_size], p % block_size)``; the decode-step attention
-kernel gathers K/V through the table, so sequences share the slabs
-without ever being contiguous.
+kernel gathers its rows through the table, so sequences share the
+slabs without ever being contiguous.  Allocation, the scratch block,
+donation and the lane padding are the same code whatever the rows are.
 
-The slabs are **donated**: ``keys`` / ``values`` are tuples the
-engine's compiled prefill and decode programs take as one pytree
-argument each and write in place (``DecodeEngine`` compiles them with
-``donate_argnums``; each layer's scatter and its attention kernel work
-on that layer's own array, so no program ever copies a slab).  After a
-call the arrays that went in are deleted and the engine rebinds
-``keys`` / ``values`` to the call's outputs.  So nobody but the
-engine's loop may hold on to a slab across a call: read
-``cache.keys[i]`` afresh, and copy (``np.asarray``) what must outlive
-the next step.
+The slabs are **donated**: ``slabs`` is one pytree (``{name: (layer 0's
+array, layer 1's, ...)}``) that the engine's compiled prefill and
+decode programs take as one argument and write in place
+(``DecodeEngine`` compiles them with ``donate_argnums``; each layer's
+scatter and its attention kernel work on that layer's own array, so no
+program ever copies a slab).  After a call the arrays that went in are
+deleted and the engine rebinds ``slabs`` to the call's outputs.  So
+nobody but the engine's loop may hold on to a slab across a call: read
+``cache.slabs[name][i]`` afresh, and copy (``np.asarray``) what must
+outlive the next step.
 
 In place also needs the slabs to LIE in memory the way the programs
-work on them, and that is why a slab's last dimension is ``lanes``,
-``head_dim`` rounded up to whole 128-lane tiles, with the lanes past
-``head_dim`` never read.  A TPU tiles the two minor dimensions of an
+work on them, and that is why a slab's last dimension is the row's own
+rounded up to whole 128-lane tiles (``lanes_for``), with the lanes past
+the row's width never read.  A TPU tiles the two minor dimensions of an
 array in (8, 128) tiles, so a 64-wide head occupies 128 lanes however
 it is declared; but for an array DECLARED 64 wide the device's default
 layout is a compact one with ``num_blocks`` minor, while the scatter
@@ -67,7 +74,7 @@ from ... import telemetry as _telemetry
 from ...base import MXNetError
 
 __all__ = ["PagedKVCache", "BlockTable", "KVCacheExhausted",
-           "SCRATCH_BLOCK", "slab_rows"]
+           "SCRATCH_BLOCK", "slab_rows", "lanes_for"]
 
 # a TPU's lane count: the minor dimension of its (8, 128) memory tiles
 LANE_TILE = 128
@@ -77,10 +84,16 @@ LANE_TILE = 128
 SCRATCH_BLOCK = 0
 
 
+def lanes_for(width):
+    """``width`` rounded up to whole 128-lane tiles: the last dimension
+    of a slab whose rows are ``width`` wide (module doc)."""
+    return -(-int(width) // LANE_TILE) * LANE_TILE
+
+
 def slab_rows(rows, slab):
-    """``rows`` (..., heads, head_dim) as whole rows of ``slab``
-    (num_blocks, block_size, heads, lanes >= head_dim): the slab's
-    dtype, zeros in the lanes past head_dim.  What a program scatters
+    """``rows`` (..., width) as whole rows of ``slab`` (num_blocks,
+    block_size, ..., lanes >= width): the slab's dtype, zeros in the
+    lanes past the rows' own width.  What a program scatters
     into a slab: a whole-row update is one scatter, a window of some
     lanes a loop of slot-sized updates on the TPU."""
     import jax.numpy as jnp
@@ -116,18 +129,21 @@ class BlockTable:
 
 
 class PagedKVCache:
-    """Fixed-size block allocator over preallocated per-layer K/V slabs.
+    """Fixed-size block allocator over preallocated per-layer slabs.
 
     Parameters
     ----------
-    layers, heads, head_dim : model geometry of the cached K/V
+    layers : how many layers keep rows
+    rows : ``{name: shape}``, what ONE token holds in ONE layer, as the
+        model declares it (``model.cache_rows()``): ``{"k": (heads,
+        head_dim), "v": (heads, head_dim)}`` or ``{"latent": (576,)}``
     block_size : tokens per block
-    num_blocks : total blocks in the slab (block 0 is scratch, so the
+    num_blocks : total blocks in a slab (block 0 is scratch, so the
         allocatable pool is ``num_blocks - 1``)
     dtype : cache dtype
     """
 
-    def __init__(self, layers, heads, head_dim, block_size, num_blocks,
+    def __init__(self, layers, rows, block_size, num_blocks,
                  dtype="float32"):
         import numpy as np
         if block_size < 1 or num_blocks < 2:
@@ -135,17 +151,23 @@ class PagedKVCache:
                 "PagedKVCache needs block_size >= 1 and num_blocks >= 2 "
                 "(block 0 is the reserved scratch block), got "
                 "block_size=%r num_blocks=%r" % (block_size, num_blocks))
+        if not rows or any(not shape or min(shape) < 1
+                           for shape in rows.values()):
+            raise MXNetError(
+                "PagedKVCache needs the rows a token holds, {name: "
+                "shape} with positive sizes, got %r" % (rows,))
         self.layers = int(layers)
-        self.heads = int(heads)
-        self.head_dim = int(head_dim)
+        self.rows = {str(name): tuple(int(n) for n in shape)
+                     for name, shape in rows.items()}
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
         self.dtype = np.dtype(dtype)
         # whole 128-lane tiles, so that the device's default layout is
         # the row-major one the programs work in (module doc)
-        self.lanes = -(-self.head_dim // LANE_TILE) * LANE_TILE
-        self.slab_shape = (self.num_blocks, self.block_size,
-                           self.heads, self.lanes)
+        self.slab_shapes = {
+            name: (self.num_blocks, self.block_size) + shape[:-1]
+            + (lanes_for(shape[-1]),)
+            for name, shape in self.rows.items()}
         self.reset_slabs()
         self._lock = _sync.Lock(name="serving.kvcache")
         self._free = list(range(1, self.num_blocks))  # 0 = scratch
@@ -153,30 +175,33 @@ class PagedKVCache:
 
     # -- the slabs ------------------------------------------------------
     def reset_slabs(self):
-        """Allocate fresh zeroed slabs: one ``keys[i]`` / ``values[i]``
-        per layer.  The engine's programs are compiled with both tuples
-        donated (``DecodeEngine.warmup``), so a call consumes the arrays
-        it is given and writes the new rows in place; the engine binds
-        the call's outputs here again.  Also the way back after a call
-        that failed with its arguments already consumed."""
+        """Allocate fresh zeroed slabs: ``slabs[name][i]`` for every
+        declared row and layer.  The engine's programs are compiled with
+        the whole pytree donated (``DecodeEngine.warmup``), so a call
+        consumes the arrays it is given and writes the new rows in
+        place; the engine binds the call's outputs here again.  Also the
+        way back after a call that failed with its arguments already
+        consumed."""
         import jax.numpy as jnp
         # let go of the old slabs first: two sets may not fit the device
-        self.keys = self.values = ()
-        self.keys = tuple(jnp.zeros(self.slab_shape, self.dtype)
-                          for _ in range(self.layers))
-        self.values = tuple(jnp.zeros(self.slab_shape, self.dtype)
-                            for _ in range(self.layers))
+        self.slabs = {}
+        self.slabs = {
+            name: tuple(jnp.zeros(shape, self.dtype)
+                        for _ in range(self.layers))
+            for name, shape in self.slab_shapes.items()}
+
+    def _arrays(self):
+        return [a for layers in self.slabs.values() for a in layers]
 
     def slab_bytes(self):
-        """Bytes the K and V slabs of all layers hold on their device
-        (unused lanes included)."""
-        return sum(a.on_device_size_in_bytes()
-                   for a in self.keys + self.values)
+        """Bytes the slabs of all layers hold on their device (unused
+        lanes included)."""
+        return sum(a.on_device_size_in_bytes() for a in self._arrays())
 
     def slabs_deleted(self):
         """Whether a call consumed the slabs without handing new ones
         back (a donating call that raised after it took them)."""
-        return any(a.is_deleted() for a in self.keys + self.values)
+        return any(a.is_deleted() for a in self._arrays())
 
     # -- sizing ---------------------------------------------------------
     def blocks_for(self, n_tokens):

@@ -18,6 +18,13 @@ model here is a plain params-dict + pure functions, the
   scatter the new K/V into the slot's block-table position of THAT
   LAYER's slab, attend over it through the ``paged_attention``
   kernel-registry entry.
+- :meth:`TinyGPT.cache_rows` -- what one token keeps in one layer of
+  the paged cache (``k`` and ``v``, per head): the engine builds its
+  ``PagedKVCache`` from this declaration and hands the slabs to the
+  two methods above as one pytree ``{name: (layer 0's, ...)}``.
+
+This is the duck type the engine serves; ``latent_moe.LatentMoEDecoder``
+(latent attention, routed experts) is the other spec that has it.
 
 Everything is fp32-accumulated and greedy-decodable: the engine's
 continuous-batching tests hold decode tokens bit-identical between a
@@ -158,31 +165,46 @@ class TinyGPT:
         (b, t, vocab)."""
         return self._forward(params, tokens, collect_kv=False)
 
-    def prefill_kv(self, params, tokens):
-        """tokens (1, t) -> (logits (1, t, vocab), keys, values) with
-        keys/values one array per layer: (t, heads, head_dim) each."""
+    def cache_rows(self):
+        """What one token keeps in one layer of the paged cache."""
+        return {"k": (self.num_heads, self.head_dim),
+                "v": (self.num_heads, self.head_dim)}
+
+    def prefill_kv(self, params, tokens, last):
+        """tokens (1, t), ``last`` the index of the prompt's last token
+        -> (its logits (vocab,), rows, stats): ``rows`` is ``{"k":
+        (layer 0's, ...), "v": ...}``, each array (t, heads, head_dim);
+        ``stats`` the counts a step fetches beside its token (none
+        here)."""
+        import jax
+        import jax.numpy as jnp
         logits, kvs = self._forward(params, tokens, collect_kv=True)
-        return (logits, tuple(k[0] for k, _v in kvs),
-                tuple(v[0] for _k, v in kvs))
+        with jax.named_scope("mx.lm_head"):
+            logits = jnp.take(logits[0], last, axis=0)
+        return (logits, {"k": tuple(k[0] for k, _v in kvs),
+                         "v": tuple(v[0] for _k, v in kvs)}, {})
 
     # -- decode step over the paged cache -------------------------------
-    def decode_logits(self, params, kv_keys, kv_values, token_ids,
-                      positions, block_tables, block_size):
+    def decode_logits(self, params, slabs, token_ids, positions,
+                      block_tables, block_size, live=None):
         """One decode step for a slot batch.
 
         token_ids (s,) int32; positions (s,) int32 (where each new
-        token is written, = its context length - 1); ``kv_keys`` /
-        ``kv_values`` one slab per layer, each (num_blocks, block_size,
-        heads, lanes >= head_dim) of which the first head_dim lanes are
-        used (``PagedKVCache`` pads them to whole tiles, a plain array
-        need not); block_tables (s, max_blocks) int32.  Returns
-        (next_token (s,) int32, logits (s, vocab), kv_keys',
-        kv_values'), the slabs as tuples in layer order.
+        token is written, = its context length - 1); ``slabs`` is
+        ``{"k": ..., "v": ...}`` with one slab per layer, each
+        (num_blocks, block_size, heads, lanes >= head_dim) of which the
+        first head_dim lanes are used (``PagedKVCache`` pads them to
+        whole tiles, a plain array need not); block_tables (s,
+        max_blocks) int32; ``live`` (s,) bool marks the slots that hold a
+        sequence, for a model that counts its tokens (this one does not
+        read it).  Returns (next_token (s,) int32, logits (s, vocab),
+        slabs', stats), the slabs as tuples in layer order and ``stats``
+        empty (``prefill_kv``).
 
-        Layer ``i`` writes its ``s`` new rows into ``kv_keys[i]`` and
+        Layer ``i`` writes its ``s`` new rows into ``slabs["k"][i]`` and
         hands that array's head_dim lanes to the attention kernel (a
         view of the same tiles on a TPU): no slab is cut out of another
-        or stacked, so a caller that donates both tuples (the
+        or stacked, so a caller that donates the pytree (the
         engine does) gets a step that updates the cache in place.  The
         padded slots of a bucket all write (scratch block, offset 0):
         the scatter's indices are not unique and nothing is promised
@@ -194,7 +216,7 @@ class TinyGPT:
         from .kvcache import slab_rows
         scope = jax.named_scope
         s, d = token_ids.shape[0], self.head_dim
-        kv_keys, kv_values = list(kv_keys), list(kv_values)
+        kv_keys, kv_values = list(slabs["k"]), list(slabs["v"])
         with scope("mx.embed"):
             blk = jnp.take_along_axis(
                 block_tables, (positions // block_size)[:, None],
@@ -237,7 +259,8 @@ class TinyGPT:
             x = self._ln(x, params["lnf_g"], params["lnf_b"])
             logits = jnp.dot(x, params["embed"].T)
             next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return next_token, logits, tuple(kv_keys), tuple(kv_values)
+        return (next_token, logits,
+                {"k": tuple(kv_keys), "v": tuple(kv_values)}, {})
 
     # -- single-shot oracle ---------------------------------------------
     def reference_decode(self, params, prompt, max_new_tokens,

@@ -45,7 +45,7 @@ __all__ = [
     "fleet_round", "fleet_alert", "fleet_alerts_firing",
     "decode_request", "decode_shed", "decode_prefill", "decode_step",
     "decode_ttft", "decode_inter_token", "decode_finish",
-    "decode_kv_aliased",
+    "decode_kv_aliased", "decode_moe",
     "kvcache_alloc", "kvcache_free", "kvcache_alloc_failure",
 ]
 
@@ -395,6 +395,21 @@ def decode_kv_aliased(model, aliased_bytes):
     """A decode engine compiled a program: the least bytes any of its
     prefill and decode programs writes into donated arguments."""
     _registry().gauge("decode.kv_aliased_bytes").set(aliased_bytes)
+
+
+def decode_moe(model, stats):
+    """The counts a prefill or decode program of a model with routed
+    experts returned beside its token: token-to-expert assignments of
+    the live tokens over all expert layers, those that fell on experts
+    this chip holds, and the largest count one held expert of one layer
+    was given."""
+    reg = _registry()
+    reg.counter("decode.moe.assignments").inc(
+        stats.get("moe_assignments", 0))
+    reg.counter("decode.moe.assignments_held").inc(
+        stats.get("moe_assignments_held", 0))
+    reg.counter("decode.moe.expert_tokens_max").inc(
+        stats.get("moe_expert_tokens_max", 0))
 
 
 def kvcache_alloc(in_use, fragmentation):
@@ -1012,6 +1027,18 @@ INSTRUMENTS = [
         "least bytes any compiled prefill/decode program of an engine "
         "writes into its donated K/V slabs (memory_analysis alias "
         "size); in place means it equals the bytes of both slabs"),
+    _ii("decode.moe.assignments", "counter", "serving", 28,
+        "token-to-expert assignments the router made for the live "
+        "tokens of prefill and decode programs, summed over the "
+        "expert layers (tokens x experts per token x layers)"),
+    _ii("decode.moe.assignments_held", "counter", "serving", 28,
+        "of those, the assignments that fell on experts this chip "
+        "holds (first_expert .. first_expert + n_held): what its "
+        "grouped matmul computed"),
+    _ii("decode.moe.expert_tokens_max", "counter", "serving", 28,
+        "sum over programs of the largest token count one held expert "
+        "of one layer was given in that program; over decode.steps it "
+        "is the mean worst expert load of a step"),
     _ii("kvcache.allocs", "counter", "serving", 18,
         "block-table allocations (one per admitted request)"),
     _ii("kvcache.frees", "counter", "serving", 18,

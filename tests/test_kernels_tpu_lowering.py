@@ -19,6 +19,8 @@ from mxnet_tpu.kernels.optimizer_update import (lamb_phase1_pallas,
 from mxnet_tpu.ops.pallas.flash_attention import (
     flash_attention_bwd_pallas, flash_attention_fwd_pallas)
 from mxnet_tpu.ops.pallas.layernorm import layernorm_fwd_pallas
+from mxnet_tpu.ops.pallas.mla_paged_attention import (
+    mla_paged_attention_pallas)
 from mxnet_tpu.ops.pallas.paged_attention import paged_attention_pallas
 
 S = jax.ShapeDtypeStruct
@@ -68,6 +70,17 @@ def test_paged_attention_every_decode_bucket(slots):
             q, k, v, bt, cl, scale=0.125),
         S((slots, 12, 64), F32), slab, slab,
         S((slots, 32), I32), S((slots, 1), I32))
+
+
+@pytest.mark.parametrize("slots", [16, 32])
+def test_mla_paged_attention_kimi_k2_decode_buckets(slots):
+    # Kimi-K2's latent row (512 + 64 values in 640 lanes, bf16), 64 heads,
+    # blocks of 64 tokens, tables wide enough for 16,384 tokens
+    _lowers_to_mosaic(
+        lambda q, c, bt, cl: mla_paged_attention_pallas(
+            q, c, bt, cl, v_width=512, scale=0.13),
+        S((slots, 64, 640), BF16), S((4289, 64, 640), BF16),
+        S((slots, 256), I32), S((slots, 1), I32))
 
 
 @pytest.mark.parametrize("channels", [64, 256])
@@ -166,7 +179,8 @@ def test_the_decode_programs_write_the_cache_in_place_on_the_chip(
     compiled = jax.jit(impl, donate_argnums=eng._DONATED).lower(
         *specs).compile()
     slab = 513 * 16 * 16 * 128 * 4
-    assert eng.cache.slab_shape == (513, 16, 16, 128)
+    assert eng.cache.slab_shapes == {"k": (513, 16, 16, 128),
+                                     "v": (513, 16, 16, 128)}
     stats = compiled.memory_analysis()
     assert stats.alias_size_in_bytes == 2 * model.num_layers * slab
     assert stats.temp_size_in_bytes < slab // 2
@@ -176,3 +190,50 @@ def test_the_decode_programs_write_the_cache_in_place_on_the_chip(
     moved = re.findall(r"= f32\[513,16,16,\d+\]\S* (?:copy|slice)\(", text)
     assert not moved, moved[:3]
     assert "remat_" not in text
+
+
+def test_the_latent_decode_step_writes_its_rows_in_place_on_the_chip(
+        one_chip, no_compile_cache, monkeypatch):
+    """Kimi-K2's widths, one dense and one expert layer holding two of
+    384 experts: the decode program aliases every byte of the latent
+    slabs, keeps no slab-sized temporary, runs the latent kernel once a
+    layer and the experts as a grouped matmul, and copies no slab."""
+    import re
+
+    from mxnet_tpu.kernels import registry
+    from mxnet_tpu.serving.decode import DecodeEngine, LatentMoEDecoder
+    monkeypatch.setattr(registry, "_backend", lambda: "tpu")
+    model = LatentMoEDecoder(
+        vocab_size=2048, hidden_size=7168, num_hidden_layers=2,
+        num_attention_heads=64, q_lora_rank=1536, kv_lora_rank=512,
+        qk_nope_head_dim=128, qk_rope_head_dim=64, v_head_dim=128,
+        intermediate_size=18432, moe_intermediate_size=2048,
+        n_routed_experts=384, num_experts_per_tok=8, n_shared_experts=1,
+        first_k_dense_replace=1, routed_scaling_factor=2.827,
+        rope_theta=50000, rope_scaling={
+            "beta_fast": 1, "beta_slow": 1, "factor": 32, "mscale": 1,
+            "mscale_all_dim": 1, "original_max_position_embeddings": 4096,
+            "type": "yarn"},
+        first_expert=0, n_held=2, max_seq=16384)
+    params = {name: S(shape, F32 if kind == "bias" else BF16)
+              for name, (shape, kind) in model.param_shapes().items()}
+    eng = DecodeEngine(model, params, prefill_buckets=(512,),
+                       decode_buckets=(16,), block_size=64, num_blocks=2049,
+                       kv_dtype="bfloat16")
+    assert eng.cache.slab_shapes == {"latent": (2049, 64, 640)}
+    assert eng.max_blocks_per_seq == 256
+    _prefill, decode = eng._specs()
+    specs = jax.tree.map(
+        lambda s: S(s.shape, s.dtype, sharding=one_chip), decode[16])
+    compiled = jax.jit(eng._decode_impl, donate_argnums=eng._DONATED).lower(
+        *specs).compile()
+    slab = 2049 * 64 * 640 * 2
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes == model.num_layers * slab
+    assert stats.temp_size_in_bytes < slab // 2
+    text = compiled.as_text()
+    assert len(re.findall(r"mla_paged_attention_pallas\S* = ", text)) \
+        == model.num_layers
+    assert "ragged" in text              # the grouped matmul, not a loop
+    moved = re.findall(r"= bf16\[2049,64,\d+\]\S* (?:copy|slice)\(", text)
+    assert not moved, moved[:3]

@@ -73,6 +73,16 @@ def counters():
     telemetry.disable()
 
 
+def _kv_rows(heads, head_dim):
+    """What a GPT-style decoder declares a token to keep in a layer."""
+    return {"k": (heads, head_dim), "v": (heads, head_dim)}
+
+
+def _slabs(cache):
+    """Every slab of every layer, as one tuple."""
+    return tuple(a for layers in cache.slabs.values() for a in layers)
+
+
 def _reference(params, prompt, max_new, eos_id=None):
     return MODEL.reference_decode(params, prompt, max_new, eos_id=eos_id)
 
@@ -82,7 +92,7 @@ def _reference(params, prompt, max_new, eos_id=None):
 # ---------------------------------------------------------------------
 
 def test_kvcache_alloc_free_cycle():
-    c = PagedKVCache(2, 2, 8, block_size=4, num_blocks=16)
+    c = PagedKVCache(2, _kv_rows(2, 8), block_size=4, num_blocks=16)
     assert c.total_blocks == 15          # block 0 reserved as scratch
     t = c.allocate(10)                   # ceil(10/4) = 3 blocks
     assert len(t.blocks) == 3
@@ -96,7 +106,8 @@ def test_kvcache_alloc_free_cycle():
 
 
 def test_kvcache_exhaustion_and_can_admit():
-    c = PagedKVCache(1, 1, 4, block_size=4, num_blocks=5)  # 4 usable
+    c = PagedKVCache(1, _kv_rows(1, 4), block_size=4,
+                     num_blocks=5)       # 4 usable
     t = c.allocate(12)                   # 3 of 4
     assert c.can_admit(4) and not c.can_admit(5)
     with pytest.raises(KVCacheExhausted):
@@ -107,7 +118,7 @@ def test_kvcache_exhaustion_and_can_admit():
 
 
 def test_kvcache_fragmentation_and_padded_table():
-    c = PagedKVCache(1, 1, 4, block_size=4, num_blocks=16)
+    c = PagedKVCache(1, _kv_rows(1, 4), block_size=4, num_blocks=16)
     t = c.allocate(6)                    # 2 blocks for 6 tokens
     c.note_tokens(t, 5)                  # 5 live of 8 allocated slots
     assert c.stats()["fragmentation"] == pytest.approx(3 / 8)
@@ -349,25 +360,28 @@ def test_close_without_drain_resolves_streams(make_engine):
 # ---------------------------------------------------------------------
 
 def test_kvcache_holds_one_slab_per_layer_and_resets_to_zeros():
-    c = PagedKVCache(3, 2, 8, block_size=4, num_blocks=16)
-    assert len(c.keys) == len(c.values) == 3
+    c = PagedKVCache(3, _kv_rows(2, 8), block_size=4, num_blocks=16)
+    assert sorted(c.slabs) == ["k", "v"]
+    assert len(c.slabs["k"]) == len(c.slabs["v"]) == 3
     # head_dim 8 in whole 128-lane tiles: the device's default layout
     # is then the row-major one the programs work in
-    assert c.lanes == 128 and c.head_dim == 8
-    for slab in c.keys + c.values:
+    assert c.rows["k"] == (2, 8) and c.slab_shapes["k"] == (16, 4, 2, 128)
+    for slab in _slabs(c):
         assert slab.shape == (16, 4, 2, 128) and slab.dtype == np.float32
     assert c.slab_bytes() == 2 * 3 * 16 * 4 * 2 * 128 * 4
-    assert PagedKVCache(1, 2, 256, block_size=4, num_blocks=4).lanes == 256
-    assert PagedKVCache(1, 2, 130, block_size=4, num_blocks=4).lanes == 256
-    old = c.keys
+    for width in (256, 130):
+        wide = PagedKVCache(1, _kv_rows(2, width), block_size=4,
+                            num_blocks=4)
+        assert wide.slab_shapes["v"][-1] == 256
+    old = c.slabs["k"]
     t = c.allocate(6)
     c.reset_slabs()                      # the allocator is untouched
     assert c.blocks_in_use() == len(t.blocks)
-    assert all(new is not o for new, o in zip(c.keys, old))
+    assert all(new is not o for new, o in zip(c.slabs["k"], old))
     assert not c.slabs_deleted()
-    assert all(not np.asarray(a).any() for a in c.keys + c.values)
+    assert all(not np.asarray(a).any() for a in _slabs(c))
     old[0].delete()
-    c.keys = old
+    c.slabs = dict(c.slabs, k=old)
     assert c.slabs_deleted()
 
 
@@ -417,6 +431,40 @@ def test_every_program_writes_both_slabs_in_place(params, ccache,
             + sig.count("jax.buffer_donor") == slabs
 
 
+def test_a_dense_models_decode_program_does_not_take_the_live_mask():
+    """The engine hands every model the bucket's live slots; ``TinyGPT``
+    does not read them, so its compiled step keeps the arguments it had
+    (params, slabs, tokens, positions, tables) and not one more."""
+    import jax
+    import re
+    eng = DecodeEngine(MODEL, MODEL.init_params(0), **ENGINE_KW)
+    _prefill, decode = eng._specs()
+    specs = decode[2]
+    assert specs[-1].shape == (2,) and specs[-1].dtype == np.bool_
+    text = jax.jit(eng._decode_impl, donate_argnums=eng._DONATED).lower(
+        *specs).compile().as_text()
+    entry = text.split("ENTRY", 1)[1]
+    taken = len(set(re.findall(r" parameter\((\d+)\)", entry)))
+    assert taken == len(jax.tree.leaves(specs)) - 1
+
+
+def test_an_unread_argument_is_part_of_a_cached_programs_key(ccache):
+    """A lowering drops an argument that the program does not read, the
+    exported artifact's calling convention keeps it: two programs that
+    lower to the same text and are called with two and with three
+    arguments are two entries of the cache."""
+    import jax
+    from mxnet_tpu.serving.decode.engine import _AotPrograms
+    spec = jax.ShapeDtypeStruct((2,), np.float32)
+    two = _AotPrograms(cache=ccache)
+    three = _AotPrograms(cache=ccache)
+    f2 = two.build(("p", 2), lambda a, b: a + 1, (spec, spec))
+    f3 = three.build(("p", 3), lambda a, b, c: a + 1, (spec, spec, spec))
+    assert two.fingerprints["p", 2] != three.fingerprints["p", 3]
+    x = np.ones(2, np.float32)
+    assert float(f2(x, x)[0]) == 2.0 and float(f3(x, x, x)[0]) == 2.0
+
+
 def test_compile_through_donates_only_when_told(ccache):
     """``BucketExecutorPool`` shares ``compile_through`` and keeps its
     behaviour: no donation unless the caller names the arguments, and
@@ -447,18 +495,18 @@ def test_compile_through_donates_only_when_told(ccache):
 
 def test_slabs_are_rebound_and_the_old_ones_deleted(make_engine, params):
     eng = make_engine()
-    before = eng.cache.keys + eng.cache.values
+    before = _slabs(eng.cache)
     # one token comes from the prefill alone: no decode step runs
     assert eng.submit([3, 7, 1], 1).tokens() \
         == _reference(params, [3, 7, 1], 1)
-    after_prefill = eng.cache.keys + eng.cache.values
+    after_prefill = _slabs(eng.cache)
     assert all(a.is_deleted() for a in before)
     assert not any(a.is_deleted() for a in after_prefill)
     seen = []
     with chaos.scenario(seed=0):
         # fires before each step's call: what the step is about to take
         chaos.on("serving.decode.step", action=lambda ctx: seen.append(
-            eng.cache.keys + eng.cache.values))
+            _slabs(eng.cache)))
         assert eng.submit([5, 5, 6], 3).tokens() \
             == _reference(params, [5, 5, 6], 3)
     assert len(seen) == 2                # two steps made tokens 2 and 3
@@ -466,7 +514,7 @@ def test_slabs_are_rebound_and_the_old_ones_deleted(make_engine, params):
         assert all(a.is_deleted() for a in taken)
     assert all(a.is_deleted() for a in after_prefill)
     assert not eng.cache.slabs_deleted()
-    assert len(eng.cache.keys) == MODEL.num_layers
+    assert len(eng.cache.slabs["k"]) == MODEL.num_layers
 
 
 class _ConsumesThenFails:
@@ -548,11 +596,11 @@ def test_a_step_fail_point_leaves_the_slabs_alone(make_engine, params):
         slabs = None
         with pytest.raises(chaos.ChaosInjected):
             next(stream)                 # the prefill's token
-            slabs = eng.cache.keys
+            slabs = eng.cache.slabs
             list(stream)
     assert stream.finish_reason == "error"
     assert eng.cache.blocks_in_use() == 0
-    assert slabs is not None and eng.cache.keys is slabs  # not reset
+    assert slabs is not None and eng.cache.slabs is slabs  # not reset
     assert eng.submit([3, 7, 1], 6).tokens() \
         == _reference(params, [3, 7, 1], 6)
 
@@ -575,9 +623,11 @@ def test_padded_slots_write_the_scratch_block_only(params, lanes):
     tables[0, :2] = [5, 3]
     tokens = np.array([7, 0, 0, 0], np.int32)
     positions = np.array([6, 0, 0, 0], np.int32)     # block 3, offset 2
-    _next, _logits, new_k, new_v = MODEL.decode_logits(
-        params, keys, values, jnp.asarray(tokens), jnp.asarray(positions),
-        jnp.asarray(tables), 4)
+    _next, _logits, new, stats = MODEL.decode_logits(
+        params, {"k": keys, "v": values}, jnp.asarray(tokens),
+        jnp.asarray(positions), jnp.asarray(tables), 4)
+    new_k, new_v = new["k"], new["v"]
+    assert stats == {}
     assert isinstance(new_k, tuple) and len(new_k) == MODEL.num_layers
     for old, new in list(zip(keys, new_k)) + list(zip(values, new_v)):
         old, new = np.asarray(old), np.asarray(new)
@@ -594,10 +644,10 @@ def test_the_padded_lanes_never_reach_the_tokens(params):
     eng = DecodeEngine(MODEL, params, **ENGINE_KW)
     eng.warmup()
     d = MODEL.head_dim
-    junk = np.zeros(eng.cache.slab_shape, np.float32)
+    junk = np.zeros(eng.cache.slab_shapes["k"], np.float32)
     junk[..., d:] = 1e9
-    eng.cache.keys = tuple(jnp.asarray(junk) for _ in eng.cache.keys)
-    eng.cache.values = tuple(jnp.asarray(junk) for _ in eng.cache.values)
+    eng.cache.slabs = {name: tuple(jnp.asarray(junk) for _ in layers)
+                       for name, layers in eng.cache.slabs.items()}
     eng.start()
     try:
         assert eng.submit([3, 7, 1, 9, 2], 8).tokens() \
