@@ -15,10 +15,40 @@ Layout: q ``(slots, heads, head_dim)``; per-layer cache slabs
 ``(num_blocks, block_size, heads, head_dim)``; ``block_tables``
 ``(slots, max_blocks)`` int32; ``context_lens`` ``(slots, 1)`` int32
 (tokens 0..ctx-1 are live).  fp32 accumulation regardless of cache
-dtype.  The block table and context lengths are scalar-prefetched into
-SMEM and the grid is ``(slots, max_blocks)``: each step's K/V block is
-the ONE cache block the table names, copied HBM->VMEM by the pipeline,
-so VMEM holds two blocks per operand whatever the cache size.
+dtype.
+
+The grid is the LIVE page groups of the call and nothing else: one
+step walks ``pages`` table blocks of one slot, ``pages`` the largest of
+8, 4, 2, 1 that divides the table, and a slot takes as many steps as
+its context has page groups (at least one), so the grid's size is a
+traced number: 16 slots of some 330 tokens with a table 64 wide are 49
+steps of 8 pages, where one 16-token block a step over the whole table
+was 1,024 of which two thirds were dead.  On a v5e a grid step costs
+about 76 ns for every operand it has, whatever it moves, so a dead step
+is not free and neither is a page: the steps, not the bytes, were the
+kernel's time.  Which slot and group a step is, and which cache block
+each of its pages names, is worked out beforehand in plain XLA
+(:func:`_live_steps`: the same for every layer of a decode step, so
+it is computed once a step) and scalar-prefetched into SMEM, so an
+index map is one load.  The K slab and the V slab are each passed
+``pages`` times with an index map of their own, each page the ONE
+cache block the table names, copied HBM->VMEM by the pipeline: VMEM
+holds two page groups per operand whatever the cache size.  A page
+past the slot's last live block names the block its operand already
+holds (the pipeline does not fetch an unchanged index again) and the
+position mask takes it out: a call fetches its live blocks and no
+other.
+
+A page is used in the cache's own order, ``(token, head)`` rows of
+``head_dim`` lanes, with no relayout: q against ALL of a page's rows is
+one matmul, ``(heads, d) x (block_size * heads, d)^T``, in which row
+``(t, h')`` is head ``h``'s key only where ``h' == h``.  The mask keeps
+that diagonal and the live positions, the step's pages are ONE run of
+columns for one running maximum / sum / accumulator update, and the
+masked probabilities (exactly 0 off the diagonal) times the page's rows
+are the step's values.  That spends ``heads`` times the MXU work the
+scores need, on a unit that is otherwise idle, to save putting heads
+first (a relayout of every block).
 """
 from __future__ import annotations
 
@@ -67,52 +97,94 @@ def paged_attention_reference(q, k_cache, v_cache, block_tables,
 
 
 # ----------------------------------------------------------------------
-# Pallas kernel: grid (slots, table blocks), online softmax carried in
-# VMEM scratch across a slot's blocks
+# Pallas kernel: one grid step a live page group, online softmax carried
+# in VMEM scratch across a slot's groups
 # ----------------------------------------------------------------------
 
-def _decode_kernel(bt_ref, ctx_ref, q_ref, k_ref, v_ref, o_ref,
-                   m_ref, l_ref, acc_ref, *, block_size, scale):
-    slot = pl.program_id(0)
-    j = pl.program_id(1)
-    ctx = ctx_ref[slot]
+# table blocks one grid step walks, the largest that divides the table
+PAGES = (8, 4, 2, 1)
 
-    @pl.when(j == 0)
+
+def _live_steps(block_tables, ctx, bs, pages):
+    """The call's grid: ``(steps, slot, group, blocks)``.  Step ``i`` is
+    page group ``group[i]`` of slot ``slot[i]``, the slots in order and
+    each with the groups its context reaches (one for an empty one, so
+    every slot's output is written); ``blocks[k, i]`` is the cache block
+    of its page ``k``.  A page past the slot's last live block names
+    what operand ``k`` held at the last step that reached it, whichever
+    slot that was.  Rows from ``steps`` on are never run."""
+    slots, mb = block_tables.shape
+    span = pages * bs
+    groups = jnp.maximum((ctx + span - 1) // span, 1)            # (slots,)
+    ends = jnp.cumsum(groups)
+    i = jnp.arange(slots * (mb // pages), dtype=jnp.int32)
+    slot = jnp.minimum(jnp.searchsorted(ends, i, side="right",
+                                        method="compare_all"),
+                       slots - 1).astype(jnp.int32)
+    group = i - (ends - groups)[slot]
+    at = group[:, None] * pages + jnp.arange(pages, dtype=jnp.int32)
+    live = at * bs < ctx[slot][:, None]                    # (steps, pages)
+    named = block_tables[slot[:, None], jnp.minimum(at, mb - 1)]
+    held = jax.lax.cummax(jnp.where(live, i[:, None], 0), axis=0)
+    blocks = jnp.take_along_axis(named, held, axis=0)
+    return ends[-1], slot, group, blocks.T
+
+
+def _decode_kernel(ctx_ref, slot_ref, group_ref, blocks_ref, q_ref, *refs,
+                   block_size, pages, scale):
+    k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
+    o_ref, m_ref, l_ref, acc_ref = refs[2 * pages:]
+    i = pl.program_id(0)
+    ctx = ctx_ref[slot_ref[i]]
+    start = group_ref[i] * pages * block_size
+
+    @pl.when(start == 0)
     def _():
         m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
         acc_ref[...] = jnp.zeros(acc_ref.shape, jnp.float32)
 
-    @pl.when(j * block_size < ctx)
+    @pl.when(start < ctx)
     def _():
-        q = q_ref[0].astype(jnp.float32)          # (heads, d)
-        heads = q.shape[0]
-        k = k_ref[0].astype(jnp.float32)          # (bs, heads, d)
-        v = v_ref[0].astype(jnp.float32)
-        # (heads, 1, d) x (heads, bs, d) -> (heads, 1, bs): one query
-        # row per head against the block's keys
-        s = jax.lax.dot_general(
-            q[:, None, :], k.transpose(1, 0, 2),
-            (((2,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)[:, 0, :] * scale
-        tpos = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, (heads, block_size), 1)
-        s = jnp.where(tpos < ctx, s, NEG_INF)     # (heads, bs)
+        q = q_ref[0].astype(jnp.float32)              # (heads, d)
+        heads, d = q.shape
+        rows = block_size * heads
+        # a page in the cache's own order, (token, head) rows of d lanes:
+        # no relayout.  q against ALL of a page's rows is one matmul,
+        # (heads, d) x (rows, d)^T; row (t, h') is head h's key where
+        # h' == h, and the mask keeps that diagonal and the live tokens
+        col = jax.lax.broadcasted_iota(jnp.int32, (heads, rows), 1)
+        head = jax.lax.broadcasted_iota(jnp.int32, (heads, rows), 0)
+        tok = col // heads
+        own = col - tok * heads == head
+        s = []
+        for k in range(pages):
+            sk = jax.lax.dot_general(
+                q, k_refs[k][0].astype(jnp.float32).reshape(rows, d),
+                (((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * scale
+            live = own & (start + k * block_size + tok < ctx)
+            s.append(jnp.where(live, sk, NEG_INF))
+        # the step's pages as ONE run of columns: one softmax update
+        s = s[0] if pages == 1 else jnp.concatenate(s, axis=1)
         m = m_ref[...]
         m_new = jnp.maximum(m, jnp.max(s, axis=-1, keepdims=True))
-        p = jnp.exp(s - m_new)
+        p = jnp.exp(s - m_new)                        # 0 off the diagonal
         alpha = jnp.exp(m - m_new)
         l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=-1,
                                                   keepdims=True)
-        # (heads, 1, bs) x (heads, bs, d) -> (heads, d)
-        pv = jax.lax.dot_general(
-            p[:, None, :], v.transpose(1, 0, 2),
-            (((2,), (1,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)[:, 0, :]
+        pv = jnp.zeros(acc_ref.shape, jnp.float32)
+        for k in range(pages):
+            pv += jax.lax.dot_general(
+                p[:, k * rows:(k + 1) * rows],
+                v_refs[k][0].astype(jnp.float32).reshape(rows, d),
+                (((1,), (0,)), ((), ())),
+                preferred_element_type=jnp.float32)   # (heads, d)
         acc_ref[...] = acc_ref[...] * alpha + pv
         m_ref[...] = m_new
 
-    @pl.when(j == pl.num_programs(1) - 1)
+    # the slot's last group: the next step is another slot's
+    @pl.when(start + pages * block_size >= ctx)
     def _():
         o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
                     ).astype(o_ref.dtype)
@@ -127,30 +199,32 @@ def paged_attention_pallas(q, k_cache, v_cache, block_tables,
     slots, heads, d = q.shape
     _nb, bs, _, _ = k_cache.shape
     mb = block_tables.shape[1]
+    pages = next(p for p in PAGES if mb % p == 0)
+    ctx = context_lens.reshape(slots)
+    steps, slot, group, blocks = _live_steps(block_tables, ctx, bs, pages)
 
-    def kv_block(s, j, bt, ctx):
-        # steps past the slot's last live block name that block again:
-        # an unchanged block index is not re-fetched, so dead steps
-        # cost no DMA (the body skips them)
-        last = jnp.maximum(ctx[s] - 1, 0) // bs
-        return (bt[s, jnp.minimum(j, last)], 0, 0, 0)
+    def kv_block(k):
+        return pl.BlockSpec(
+            (1, bs, heads, d),
+            lambda i, ctx, slot, group, blocks: (blocks[k, i], 0, 0, 0))
 
-    def q_block(s, j, bt, ctx):
-        return (s, 0, 0)
+    def q_block(i, ctx, slot, group, blocks):
+        return (slot[i], 0, 0)
 
+    kv_specs = [kv_block(k) for k in range(pages)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(slots, mb),
-        in_specs=[pl.BlockSpec((1, heads, d), q_block),
-                  pl.BlockSpec((1, bs, heads, d), kv_block),
-                  pl.BlockSpec((1, bs, heads, d), kv_block)],
+        num_scalar_prefetch=4,
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((1, heads, d), q_block)] + kv_specs * 2,
         out_specs=pl.BlockSpec((1, heads, d), q_block),
         scratch_shapes=[pltpu.VMEM((heads, 1), jnp.float32),
                         pltpu.VMEM((heads, 1), jnp.float32),
                         pltpu.VMEM((heads, d), jnp.float32)])
     return pl.pallas_call(
-        functools.partial(_decode_kernel, block_size=bs, scale=scale),
+        functools.partial(_decode_kernel, block_size=bs, pages=pages,
+                          scale=scale),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,
-    )(block_tables, context_lens.reshape(slots), q, k_cache, v_cache)
+    )(ctx, slot, group, blocks, q,
+      *([k_cache] * pages), *([v_cache] * pages))

@@ -72,6 +72,20 @@ def test_paged_attention_every_decode_bucket(slots):
         S((slots, 32), I32), S((slots, 1), I32))
 
 
+@pytest.mark.parametrize("slots", [8, 16])
+def test_paged_attention_gpt2_medium_decode_buckets(slots):
+    # the GPT-2 cells as ``decode_logits`` calls the kernel: 16 heads of
+    # 64 seen through the [..., :64] view of a 128-lane slab of 1,025
+    # blocks, a table 64 wide (8 pages a grid step).  Bucket 16 is
+    # gpt2m_serve_closed16's every step; gpt2m_serve_open_r80 runs 8 and 16
+    slab = S((1025, 16, 16, 128), F32)
+    _lowers_to_mosaic(
+        lambda q, k, v, bt, cl: paged_attention_pallas(
+            q, k[..., :64], v[..., :64], bt, cl, scale=0.125),
+        S((slots, 16, 64), F32), slab, slab,
+        S((slots, 64), I32), S((slots, 1), I32))
+
+
 @pytest.mark.parametrize("slots", [16, 32])
 def test_mla_paged_attention_kimi_k2_decode_buckets(slots):
     # Kimi-K2's latent row (512 + 64 values in 640 lanes, bf16), 64 heads,

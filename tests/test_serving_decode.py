@@ -152,6 +152,109 @@ def test_paged_attention_pallas_matches_reference():
                                atol=1e-5)
 
 
+def _decode_shaped(rng, mb, heads, d=64, bs=16):
+    """q, slabs and a table shaped as ``decode_logits`` hands them over:
+    128-lane slabs seen through their ``[..., :d]`` view, two slots whose
+    tables share no block, block 0 the scratch block."""
+    import jax.numpy as jnp
+    nb = 2 * mb + 1
+    k, v = (rng.normal(size=(nb, bs, heads, 128)).astype(np.float32)
+            for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(2, heads, d)).astype(np.float32))
+    bt = (1 + rng.permutation(nb - 1)).reshape(2, mb).astype(np.int32)
+    return q, k, v, bt
+
+
+# table width -> the pages a grid step walks (the largest of 8, 4, 2, 1
+# that divides it)
+_PAGES = {64: 8, 12: 4, 6: 2, 5: 1}
+
+
+@pytest.mark.parametrize("heads", [16, 12])
+@pytest.mark.parametrize("context", ["inside_a_group", "on_a_group_edge",
+                                     "one_token", "full_table"])
+@pytest.mark.parametrize("mb", sorted(_PAGES))
+def test_paged_attention_pallas_walks_pages_like_the_reference(
+        mb, context, heads):
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas.paged_attention import (
+        PAGES, paged_attention_pallas, paged_attention_reference)
+    d, bs = 64, 16
+    pages = next(p for p in PAGES if mb % p == 0)
+    assert pages == _PAGES[mb]
+    span = pages * bs
+    q, k, v, bt = _decode_shaped(np.random.default_rng(mb + heads), mb,
+                                 heads, d, bs)
+    ctx0 = {"inside_a_group": span + (bs if pages > 1 else 0) + bs // 2 + 1,
+            "on_a_group_edge": span, "one_token": 1,
+            "full_table": mb * bs}[context]
+    if context == "one_token":
+        bt[0] = 0                # a bucket's padded slot: all scratch
+    # the second slot ends mid-page in the table's last group, so the
+    # step from one slot to the next crosses live and dead pages
+    ctx = jnp.asarray(np.array([[ctx0], [mb * bs - bs // 2 - 1]], np.int32))
+    args = (q, jnp.asarray(k)[..., :d], jnp.asarray(v)[..., :d],
+            jnp.asarray(bt), ctx)
+    ref = paged_attention_reference(*args, scale=0.125)
+    pal = paged_attention_pallas(*args, scale=0.125, interpret=True)
+    np.testing.assert_allclose(np.asarray(pal), np.asarray(ref),
+                               atol=1e-5)
+
+
+def test_paged_attention_pallas_masks_dead_pages():
+    # the reference's poison test for the kernel: a table 8 wide is ONE
+    # page group, the context ends in its second page; the pages after
+    # it, the scratch block and the dead rows of the last live block
+    # must not contribute
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas.paged_attention import (
+        paged_attention_pallas)
+    rng = np.random.default_rng(2)
+    k = rng.normal(size=(10, 4, 2, 8)).astype(np.float32)
+    v = rng.normal(size=(10, 4, 2, 8)).astype(np.float32)
+    q = jnp.asarray(rng.normal(size=(2, 2, 8)).astype(np.float32))
+    bt = jnp.asarray(np.array([[1, 2, 3, 4, 5, 6, 7, 8],
+                               [9, 0, 0, 0, 0, 0, 0, 0]], np.int32))
+    ctx = jnp.asarray(np.array([[5], [3]], np.int32))
+
+    def run():
+        return np.asarray(paged_attention_pallas(
+            q, jnp.asarray(k), jnp.asarray(v), bt, ctx, scale=0.35,
+            interpret=True))
+    base = run()
+    for slab in (k, v):
+        slab[2, 1:] = 1e6        # positions 5..7 of the last live block
+        slab[3:9] = 1e6          # pages 2..7 of the live group
+        slab[9, 3:] = 1e6        # the second slot's dead row
+        slab[0] = 1e6            # the scratch block
+    np.testing.assert_allclose(run(), base, atol=1e-6)
+
+
+def test_paged_attention_grid_is_the_live_page_groups():
+    # one step a page group a context reaches, no other; every page of
+    # every step names a LIVE block, so nothing else is ever fetched
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas.paged_attention import _live_steps
+    bs, pages, mb = 4, 2, 8
+    bt = np.arange(1, 33, dtype=np.int32).reshape(4, mb)
+    bt[3] = 0                            # a padded slot: all scratch
+    ctx = np.array([17, 32, 3, 1], np.int32)
+    steps, slot, group, blocks = _live_steps(
+        jnp.asarray(bt), jnp.asarray(ctx), bs, pages)
+    steps = int(steps)
+    assert steps == 3 + 4 + 1 + 1
+    assert list(np.asarray(slot)[:steps]) == [0] * 3 + [1] * 4 + [2, 3]
+    assert list(np.asarray(group)[:steps]) == [0, 1, 2, 0, 1, 2, 3, 0, 0]
+    blocks = np.asarray(blocks)[:, :steps]
+    assert blocks.shape == (pages, steps)
+    live = {int(b) for s in range(4)
+            for b in bt[s, :-(-int(ctx[s]) // bs)]}
+    assert set(blocks.ravel().tolist()) == live
+    # slot 0 ends in the first page of its third group: the second
+    # page keeps block 4, what it held a step before
+    assert blocks[:, 2].tolist() == [5, 4]
+
+
 def test_paged_attention_reference_masks_dead_context():
     # tokens past context_lens must not contribute: poison them
     import jax.numpy as jnp
