@@ -174,123 +174,6 @@ def _goodput_extra(tag):
     return {"goodput": gp} if gp else {}
 
 
-# ----------------------------------------------------------------------
-# kernel-tier before/after HLO diff (ISSUE 11): the resnet50-scan and
-# BERT-flash lines carry per-category compiled-HLO byte deltas of the
-# SAME probe model built with the Pallas kernel tier off vs armed --
-# the `mxprof diff` of the kernel tier, riding the JSONL line itself.
-# ----------------------------------------------------------------------
-
-def _hlo_category_bytes(step):
-    """Category byte counters of a TrainStep's most recent compiled
-    program (analysis.perf.audit_hlo_text over the compiled HLO)."""
-    from mxnet_tpu.analysis.perf import audit_hlo_text
-    fn, arg_shapes = step._last_call
-    text = fn.lower(*arg_shapes).compile().as_text()
-    c = audit_hlo_text(text)
-    out = {k: int(v) for k, v in c["category_bytes"].items()}
-    out["unfused_elementwise"] = int(c["unfused_elementwise_bytes"])
-    out["bytes_total"] = int(c["bytes_total"])
-    return out
-
-
-def _kernels_probe_step(model):
-    """Compile one small fwd+bwd+update step of the probe model under
-    the CURRENT kernel-tier mode and return the TrainStep.  NHWC +
-    LARS for the resnet probe (the fused BN+ReLU sites and the
-    bucket-flattened optimizer both engage); a small flash BERT for
-    the attention probe."""
-    import mxnet_tpu as mx
-    from mxnet_tpu import gluon
-    from mxnet_tpu.parallel import TrainStep
-    ctx = _ctx()
-    rng = np.random.RandomState(0)
-    if model == "resnet":
-        from mxnet_tpu.gluon.model_zoo.vision import resnet18_v1
-        net = resnet18_v1(classes=10, thumbnail=True, layout="NHWC")
-        net.initialize(ctx=ctx)
-        net.hybridize()
-        trainer = gluon.Trainer(net.collect_params(), "lars",
-                                {"learning_rate": 0.1}, kvstore=None)
-        step = TrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(),
-                         trainer, mesh=None)
-        x = mx.nd.array(rng.rand(2, 32, 32, 3).astype(np.float32),
-                        ctx=ctx)
-        y = mx.nd.array(rng.randint(0, 10, (2,)).astype(np.float32),
-                        ctx=ctx)
-    else:                             # "bert": the flash-attention probe
-        vocab = 512
-        net = gluon.model_zoo.bert_small(vocab_size=vocab,
-                                         max_length=256, dropout=0.0)
-        net.initialize(ctx=ctx)
-        net.hybridize()
-        ce = gluon.loss.SoftmaxCrossEntropyLoss()
-
-        class _MLM(gluon.HybridBlock):
-            def hybrid_forward(self, F, outs, labels):
-                mlm, _nsp = outs
-                return ce(mlm.reshape((-1, vocab)),
-                          labels.reshape((-1,)))
-
-        trainer = gluon.Trainer(net.collect_params(), "adam",
-                                {"learning_rate": 1e-4}, kvstore=None)
-        step = TrainStep(net, _MLM(), trainer, mesh=None)
-        x = mx.nd.array(rng.randint(0, vocab, (1, 256))
-                        .astype(np.float32), ctx=ctx)
-        y = mx.nd.array(rng.randint(0, vocab, (1, 256))
-                        .astype(np.float32), ctx=ctx)
-    step(x, y)
-    return step
-
-
-def _kernels_diff(model):
-    """Before/after category bytes of the probe model's compiled step:
-    kernel tier off (MXNET_TPU_KERNELS=0) vs armed (=1).  Returns the
-    {probe, before, after, delta} dict the JSONL line carries, or None
-    when pallas is unavailable."""
-    from mxnet_tpu import kernels as _k
-    if not _k.available():
-        return None
-    saved = _os.environ.get("MXNET_TPU_KERNELS")
-    try:
-        _os.environ["MXNET_TPU_KERNELS"] = "0"
-        before = _hlo_category_bytes(_kernels_probe_step(model))
-        _os.environ["MXNET_TPU_KERNELS"] = "1"
-        after = _hlo_category_bytes(_kernels_probe_step(model))
-    finally:
-        if saved is None:
-            _os.environ.pop("MXNET_TPU_KERNELS", None)
-        else:
-            _os.environ["MXNET_TPU_KERNELS"] = saved
-    keys = sorted(set(before) | set(after))
-    import jax
-    interp = jax.default_backend() != "tpu"
-    return {
-        "probe": ("resnet18v1-nhwc-lars-b2-32x32" if model == "resnet"
-                  else "bert_small-flash-b1-seq256"),
-        # on a non-TPU backend the 'after' program is the INTERPRET-
-        # mode lowering of the kernels (correctness only -- its byte
-        # counts are not a perf statement); on TPU it is the real
-        # Mosaic program and the deltas are the kernel tier's win
-        "after_interpret": interp,
-        "before": before,
-        "after": after,
-        "delta": {k: after.get(k, 0) - before.get(k, 0) for k in keys},
-    }
-
-
-def _kernels_diff_extra(model, est_s=240):
-    """extra_fn fields: the kernel-tier HLO diff, budget-gated and
-    never fatal to the line that carries it."""
-    if _remaining() < est_s:
-        return {}
-    try:
-        diff = _kernels_diff(model)
-    except Exception as e:
-        return {"kernels_diff_error": str(e)[:120]}
-    return {"kernels_diff": diff} if diff else {}
-
-
 def _cost_extra(tag):
     """extra_fn fields for the emitted JSONL line: artifact path plus
     the top category + its roofline bound, so the line itself says
@@ -1457,12 +1340,11 @@ def main():
                           "max": max(rn_out.get("wins") or [0]),
                           "windows": rn_out.get("wins"),
                           **_cost_extra("resnet50_bf16"),
-                          **_goodput_extra("resnet50_bf16"),
-                          **_kernels_diff_extra("resnet")})
+                          **_goodput_extra("resnet50_bf16")})
 
     # -- 2: headline BERT (bs=256 is the single-chip knee, r4) --------
     def _emit_bert(metric, bs, seq, dt_name, iters, windows=1,
-                   attempts=2, kernels_probe=False):
+                   attempts=2):
         out = {}
 
         def run():
@@ -1480,8 +1362,6 @@ def main():
                 rec.update({"min": min(out["wins"]),
                             "max": max(out["wins"]),
                             "windows": out["wins"]})
-            if kernels_probe:
-                rec.update(_kernels_diff_extra("bert"))
             return rec
         return _emit_with_retry(metric, run, attempts=attempts,
                                 extra_fn=extra)
@@ -1684,11 +1564,9 @@ def main():
                        "bfloat16", 10, attempts=1)
         if _budget_ok("bert_base_pretrain_seq1024_bf16_flash", 600):
             # long-context config: seq 1024 is where the Pallas flash
-            # fwd+bwd kernels pull away from XLA (81k vs 60k tok/s, r3);
-            # the line carries the kernel-tier before/after HLO diff
+            # fwd+bwd kernels pull away from XLA (81k vs 60k tok/s, r3)
             _emit_bert("bert_base_pretrain_seq1024_bf16_flash", 16,
-                       1024, "bfloat16", 10, attempts=1,
-                       kernels_probe=True)
+                       1024, "bfloat16", 10, attempts=1)
 
     print(json.dumps({"metric": "bench_complete",
                       "elapsed_s": round(time.monotonic() - _T_START, 1),

@@ -3,9 +3,9 @@
 One process drives the normal entry points once, at the full width of
 the models the repo supports, and checks what comes out:
 
-- ``census``: every registered Pallas kernel and the layernorm kernel,
-  compiled (not interpreted) at one real shape and compared with its
-  XLA reference, beside the registry's decision for that shape;
+- ``census``: every registered Pallas kernel, compiled (not
+  interpreted) at one real shape and compared with its XLA reference,
+  beside the registry's decision for that shape;
 - ``train_bert``: BERT-base, ``gluon`` -> ``Trainer`` -> ``TrainStep``
   under bf16 AMP, host batches staged through ``DeviceFeed``;
 - ``train_resnet``: ResNet-50 v1, one ``TrainStep.run_steps`` dispatch;
@@ -47,11 +47,12 @@ FULL = {
             "max_seq": 512, "prompt_lens": (3, 16, 17, 40, 64, 100, 128, 5),
             "max_new": 32, "kv_blocks": None},
     "census": {"flash": (32 * 12, 512, 64),          # BERT-base seq 512
-               "bn": (128, 56, 56, 64),              # ResNet-50 stage 1
-               "flat": 25557032,                     # ResNet-50 params
-               "ln": (16384, 768),
                "paged": {"slots": 8, "heads": 12, "d": 64, "blocks": 512,
-                         "block": 16, "table": 32}},
+                         "block": 16, "table": 32},
+               # Kimi-K2's latent row: 512 + 64 values in 640 lanes
+               "latent": {"slots": 16, "heads": 64, "lanes": 640,
+                          "v_width": 512, "blocks": 1025, "block": 64,
+                          "table": 64}},
     "dp": {"chips": 4, "batch": 128, "steps": 3},
 }
 TINY = {
@@ -62,10 +63,12 @@ TINY = {
     "gpt": {"vocab": 128, "units": 32, "layers": 2, "heads": 2,
             "max_seq": 64, "prompt_lens": (3, 16, 17, 9), "max_new": 6,
             "kv_blocks": 64},
-    "census": {"flash": (4, 32, 16), "bn": (2, 4, 4, 8), "flat": 3000,
-               "ln": (32, 64),
+    "census": {"flash": (4, 32, 16),
                "paged": {"slots": 2, "heads": 2, "d": 16, "blocks": 12,
-                         "block": 4, "table": 5}},
+                         "block": 4, "table": 5},
+               "latent": {"slots": 2, "heads": 2, "lanes": 128,
+                          "v_width": 16, "blocks": 12, "block": 4,
+                          "table": 4}},
     "dp": {"chips": 4, "batch": 8, "steps": 3},
 }
 
@@ -189,29 +192,16 @@ def phase_census(smoke, cfg):
     """Compile and run each kernel once where the registry would, and
     hold it to its XLA reference.  A kernel the registry selects on
     this backend that does not compile raises out of here.  Off the
-    chip the registry picks XLA everywhere, so the rehearsal arms the
-    tier the way the repo's tests do (``MXNET_TPU_KERNELS=1``: the
-    kernel bodies run in interpret mode)."""
-    if smoke.tiny:
-        os.environ["MXNET_TPU_KERNELS"] = "1"
-    try:
-        return _census(smoke, cfg)
-    finally:
-        if smoke.tiny:
-            del os.environ["MXNET_TPU_KERNELS"]
-
-
-def _census(smoke, cfg):
+    chip the registry picks XLA everywhere, so the rehearsal asks for
+    the kernels the way the repo's tests do (``force=True`` /
+    ``use_pallas=True``: the kernel bodies run in interpret mode)."""
     from mxnet_tpu import kernels
-    from mxnet_tpu.kernels import fused_bn_relu as kbn
-    from mxnet_tpu.kernels import optimizer_update as kopt
+    from mxnet_tpu.kernels.mla_paged_attention import mla_paged_attention
     from mxnet_tpu.kernels.paged_attention import paged_attention
-    from mxnet_tpu.ops.nn import _ln_xla_lastaxis
     from mxnet_tpu.ops.pallas.flash_attention import (
         flash_attention_bwd_pallas, flash_attention_fwd_pallas)
-    from mxnet_tpu.ops.pallas.layernorm import layernorm_fwd_pallas
 
-    interpret = smoke.tiny
+    force = True if smoke.tiny else None
     rng = np.random.RandomState(0)
     rows = []
 
@@ -229,8 +219,8 @@ def _census(smoke, cfg):
     scale = 1.0 / d ** 0.5
     q, k, v = (jnp.asarray(rng.randn(bh, seq, d), jnp.bfloat16)
                for _ in range(3))
-    ch = kernels.choose("flash_attention", seq=seq, block_q=256,
-                        block_k=256)
+    ch = kernels.choose("flash_attention", force=force, seq=seq,
+                        block_q=256, block_k=256)
     if ch.use_pallas:
         ref_fn = kernels.get("flash_attention").xla_ref
 
@@ -262,74 +252,39 @@ def _census(smoke, cfg):
                                  (pg["slots"], pg["table"])), jnp.int32)
     cl = jnp.asarray(rng.randint(1, pg["table"] * pg["block"],
                                  (pg["slots"], 1)), jnp.int32)
-    ch = kernels.choose("paged_attention", heads=pg["heads"],
-                        head_dim=pg["d"], block_size=pg["block"])
+    ch = kernels.choose("paged_attention", force=force,
+                        heads=pg["heads"], head_dim=pg["d"],
+                        block_size=pg["block"])
     if ch.use_pallas:
-        out = paged_attention(qd, kc, vc, bt, cl, scale=0.125)
+        out = paged_attention(qd, kc, vc, bt, cl, scale=0.125,
+                              use_pallas=force)
         ref = kernels.get("paged_attention").xla_ref(
             qd, kc, vc, bt, cl, scale=0.125)
         record("paged_attention", ch, "decode", rel_err(out, ref))
     else:
         record("paged_attention", ch, None)
 
-    # fused BatchNorm+ReLU, NHWC training forward and backward
-    n, h, w, c = cfg["bn"]
-    x = jnp.asarray(rng.randn(n, h, w, c), jnp.bfloat16)
-    gamma = jnp.asarray(rng.rand(c) + 0.5, jnp.float32)
-    beta = jnp.asarray(rng.randn(c), jnp.float32)
-    mean0 = jnp.zeros((c,), jnp.float32)
-    var0 = jnp.ones((c,), jnp.float32)
-    ch = kernels.choose("fused_bn_relu", axis=3, ndim=4, rows=n * h * w)
+    # latent (MLA) paged attention: rows that are keys and values at once
+    lt = cfg["latent"]
+    ql = jnp.asarray(rng.randn(lt["slots"], lt["heads"], lt["lanes"]),
+                     jnp.bfloat16)
+    rows_l = jnp.asarray(rng.randn(lt["blocks"], lt["block"], lt["lanes"]),
+                         jnp.bfloat16)
+    bt = jnp.asarray(rng.randint(1, lt["blocks"],
+                                 (lt["slots"], lt["table"])), jnp.int32)
+    cl = jnp.asarray(rng.randint(1, lt["table"] * lt["block"],
+                                 (lt["slots"], 1)), jnp.int32)
+    ch = kernels.choose("mla_paged_attention", force=force,
+                        heads=lt["heads"], lanes=lt["lanes"],
+                        v_width=lt["v_width"], block_size=lt["block"])
     if ch.use_pallas:
-        def run_bn(fn):
-            def loss(x, gamma, beta):
-                out, nm, nv = fn(x, gamma, beta, mean0, var0,
-                                 fix_gamma=False, axis=3, training=True)
-                return jnp.sum(out.astype(jnp.float32)), (out, nm, nv)
-            (_, aux), grads = jax.value_and_grad(
-                loss, argnums=(0, 1, 2), has_aux=True)(x, gamma, beta)
-            return list(aux) + list(grads)
-        got = run_bn(kbn.fused_bn_relu)
-        want = run_bn(kernels.get("fused_bn_relu").xla_ref)
-        record("fused_bn_relu", ch, "fwd+bwd",
-               max(rel_err(g, r) for g, r in zip(got, want)))
+        out = mla_paged_attention(ql, rows_l, bt, cl, lt["v_width"],
+                                  scale=0.04, use_pallas=force)
+        ref = kernels.get("mla_paged_attention").xla_ref(
+            ql, rows_l, bt, cl, lt["v_width"], scale=0.04)
+        record("mla_paged_attention", ch, "decode", rel_err(out, ref))
     else:
-        record("fused_bn_relu", ch, None)
-
-    # bucket optimizer: off in auto mode (its decision is printed as
-    # such), compiled here all the same so a refusal is seen
-    size = cfg["flat"]
-    wf = jnp.asarray(rng.randn(size), jnp.float32)
-    gf = jnp.asarray(rng.randn(size) * 0.01, jnp.float32)
-    zeros = jnp.zeros((size,), jnp.float32)
-    lr = jnp.full((size,), 0.1, jnp.float32)
-    wd = jnp.full((size,), 1e-4, jnp.float32)
-    ones = jnp.ones((size,), jnp.float32)
-    one = jnp.float32(1.0)
-    got = kopt.lars_flat_pallas(wf, gf, zeros, lr, wd, ones, one,
-                                interpret=interpret)
-    want = kopt._lars_math(wf, gf, zeros, lr, wd, ones, one, 0.9, 0.0)
-    err = max(rel_err(g, r) for g, r in zip(got, want))
-    sc3 = jnp.asarray([1.0, 10.0, 1000.0], jnp.float32)
-    got = kopt.lamb_phase1_pallas(wf, gf, zeros, zeros, wd, sc3,
-                                  interpret=interpret)
-    want = kopt._lamb1_math(wf, gf, zeros, zeros, wd, sc3, 0.9, 0.999,
-                            1e-6, 0.0)
-    err = max([err] + [rel_err(g, r) for g, r in zip(got, want)])
-    record("bucket_optimizer", kernels.choose("bucket_optimizer"),
-           "lars+lamb (forced)", err)
-
-    # layernorm sits outside the registry (ops/nn.py use_pallas=True)
-    r, dim = cfg["ln"]
-    xl = jnp.asarray(rng.randn(r, dim), jnp.bfloat16)
-    gl = jnp.asarray(rng.rand(dim) + 0.5, jnp.float32)
-    bl = jnp.asarray(rng.randn(dim), jnp.float32)
-    out = layernorm_fwd_pallas(xl, gl, bl, interpret=interpret)
-    rows.append({"kernel": "layernorm (unregistered)", "ran": "fwd",
-                 "rel_err": round(rel_err(
-                     out, _ln_xla_lastaxis(xl, gl, bl, 1e-5)), 5)})
-    check(rows[-1]["rel_err"] <= KERNEL_TOL, "layernorm kernel differs")
-    jax.block_until_ready(out)
+        record("mla_paged_attention", ch, None)
     return {"kernels": rows}
 
 

@@ -96,16 +96,11 @@
 #              must never flag, chaos-pinned arrays must flag within
 #              3 windows naming the pinned shape bucket
 #   kernels -> Pallas kernel tier gates (docs/kernels.md): the
-#              interpret-mode kernel tests (registry policy, fused
-#              BN+ReLU numerics+vjp, flash op-level pallas path incl.
-#              the masked backward, bucket-flattened LARS/LAMB), an
-#              explicit fallback proof (Pallas monkeypatched away ->
-#              every choice lands on XLA, numerics intact), then a
-#              kernels-armed smoke train (NHWC BN+ReLU fusion sites +
-#              bucketed LARS through one compiled TrainStep, kernels
-#              in interpret mode on CPU) whose perf audit must show
-#              zero drift against the blessed train_step:KernelSmokeNet
-#              row of ci/perf_baseline.json (mxlint --perf-diff)
+#              interpret-mode kernel tests (the registry's choice
+#              table, flash op-level pallas path incl. the masked
+#              backward, paged and latent paged decode attention),
+#              then an explicit fallback proof (Pallas monkeypatched
+#              away -> every choice lands on XLA, numerics intact)
 #   obs -> observability ops plane (docs/observability.md): a traced
 #          smoke train+serve run whose request spans must reconcile
 #          with the serving.requests/batches counters and whose
@@ -1241,102 +1236,42 @@ EOF
 
 run_kernels() {
     log "kernels: interpret-mode kernel tests (registry + numerics + vjp + fallback)"
-    # tests arm MXNET_TPU_KERNELS themselves (fixtures) so the CPU
-    # backend runs the REAL Pallas kernel bodies in interpret mode
+    # the tests pass use_pallas=True / force=True themselves, so the
+    # CPU backend runs the REAL Pallas kernel bodies in interpret mode
     JAX_PLATFORMS=cpu python -m pytest tests/test_kernels.py \
         tests/test_flash_attention.py -q -m 'not slow'
     log "kernels: fallback proof (Pallas unavailable -> XLA, numerics intact)"
-    JAX_PLATFORMS=cpu MXNET_TPU_KERNELS=1 python - <<'EOF'
+    JAX_PLATFORMS=cpu python - <<'EOF'
 import numpy as np
 import jax.numpy as jnp
 from mxnet_tpu import kernels
-from mxnet_tpu.kernels import fused_bn_relu as fbr
 from mxnet_tpu.kernels import registry as kreg
+from mxnet_tpu.kernels.paged_attention import paged_attention
 
 # simulate a build without pallas: every choice must land on XLA
 kreg._has_pallas = lambda: False
 for name, kw in (("flash_attention",
                   dict(seq=512, block_q=256, block_k=256)),
-                 ("fused_bn_relu", dict(axis=3, ndim=4)),
-                 ("bucket_optimizer", {})):
+                 ("paged_attention",
+                  dict(heads=16, head_dim=64, block_size=16)),
+                 ("mla_paged_attention",
+                  dict(heads=64, lanes=640, v_width=512,
+                       block_size=64))):
     ch = kernels.choose(name, force=True, **kw)
     assert not ch.use_pallas, (name, ch)
     assert "unavailable" in ch.reason, ch.reason
 rng = np.random.RandomState(0)
-x = jnp.asarray(rng.randn(2, 4, 4, 8).astype(np.float32))
-g = jnp.asarray(rng.rand(8).astype(np.float32) + 0.5)
-b = jnp.asarray(rng.randn(8).astype(np.float32))
-mm, mv = jnp.zeros(8, jnp.float32), jnp.ones(8, jnp.float32)
-out, _, _ = fbr.fused_bn_relu(x, g, b, mm, mv, fix_gamma=False,
-                              axis=3, training=True)
-ro, _, _ = fbr.xla_reference(x, g, b, mm, mv, fix_gamma=False,
-                             axis=3, training=True)
-np.testing.assert_allclose(np.asarray(out), np.asarray(ro),
-                           rtol=1e-6, atol=1e-6)
-print("fallback proof ok: 3 kernels decline, fused op == XLA reference")
+q = jnp.asarray(rng.randn(2, 2, 16).astype(np.float32))
+kc = jnp.asarray(rng.randn(12, 4, 2, 16).astype(np.float32))
+vc = jnp.asarray(rng.randn(12, 4, 2, 16).astype(np.float32))
+bt = jnp.asarray(rng.randint(1, 12, (2, 5)), jnp.int32)
+cl = jnp.asarray([[7], [18]], jnp.int32)
+out = paged_attention(q, kc, vc, bt, cl, scale=0.25, use_pallas=True)
+ref = kernels.get("paged_attention").xla_ref(q, kc, vc, bt, cl,
+                                             scale=0.25)
+np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+print("fallback proof ok: 3 kernels decline, wrapper == XLA reference")
 EOF
-    log "kernels: zero-drift perf audit with the kernel tier armed"
-    kdir=$(mktemp -d /tmp/mxtpu_kernels_ci.XXXXXX)
-    JAX_PLATFORMS=cpu MXNET_TPU_KERNELS=1 MXNET_TPU_PROFILING=1 \
-        python - "$kdir" <<'EOF'
-import os, sys
-import numpy as np
-import mxnet_tpu as mx
-from mxnet_tpu import gluon, kernels, profiling
-from mxnet_tpu.analysis import perf
-from mxnet_tpu.parallel import TrainStep
-
-kdir = sys.argv[1]
-assert profiling.enabled(), "MXNET_TPU_PROFILING=1 did not arm capture"
-assert kernels.mode() == "on", "MXNET_TPU_KERNELS=1 did not arm the tier"
-assert mx.runtime.Features().is_enabled("KERNELS")
-
-
-class KernelSmokeNet(gluon.nn.HybridSequential):
-    """Named so the kernels-armed audit row is stable across CI runs."""
-
-
-net = KernelSmokeNet()
-net.add(gluon.nn.Conv2D(8, 3, padding=1, layout="NHWC"),
-        gluon.nn.BatchNorm(axis=3),
-        gluon.nn.Activation("relu"),
-        gluon.nn.Flatten(),
-        gluon.nn.Dense(32, activation="relu"),
-        gluon.nn.Dense(10))
-net.initialize(ctx=mx.cpu())
-net.hybridize()
-tr = gluon.Trainer(net.collect_params(), "lars", {"learning_rate": 0.1},
-                   kvstore=None)
-step = TrainStep(net, gluon.loss.SoftmaxCrossEntropyLoss(), tr,
-                 mesh=None)
-rng = np.random.RandomState(0)
-x = mx.nd.array(rng.rand(8, 12, 12, 1).astype(np.float32))
-y = mx.nd.array(rng.randint(0, 10, (8,)).astype(np.float32))
-for _ in range(2):                      # fused BN+ReLU + bucketed LARS
-    loss = step(x, y)
-loss.asnumpy()
-# the compiled step really selected the kernels (interpret on CPU)
-assert kernels.choose("fused_bn_relu", axis=3, ndim=4).use_pallas
-from mxnet_tpu.kernels import optimizer_update as kopt
-assert kopt.bucket_active(tr._optimizer)
-# audit scoped to the kernels-armed executable: the eager/hybrid op
-# labels belong to the perflint smoke's blessed rows
-audit = perf.perf_audit()
-label = "train_step:KernelSmokeNet"
-assert label in audit["executables"], audit["executables"].keys()
-audit["executables"] = {label: audit["executables"][label]}
-audit["advisories"] = [a for a in audit["advisories"]
-                       if a.get("executable") == label]
-perf.save_audit(os.path.join(kdir, "current.json"), audit)
-print("kernels smoke ok: %s audited (%d advisories)"
-      % (label, len(audit["advisories"])))
-EOF
-    # gate: the kernels-armed executable's efficiency metrics vs the
-    # blessed train_step:KernelSmokeNet row -- growth errors naming the
-    # executable + kind (with the remedy kernel), improvements pass
-    python -m mxnet_tpu.analysis --perf-diff \
-        ci/perf_baseline.json "$kdir/current.json" --json
-    rm -rf "$kdir"
 }
 
 run_obs() {

@@ -625,18 +625,6 @@ def _metrics_of(counters: Dict, xla_flops=0.0, xla_bytes=0.0) -> Dict:
     return metrics
 
 
-def _kernel_remedy(kind: str) -> Optional[str]:
-    """The registered Pallas kernel remedying an advisory kind
-    (docs/kernels.md), e.g. ``unfused-elementwise >= 15% -> candidate
-    kernel kernels.fused_bn_relu``.  None when no kernel covers it or
-    the kernel tier is unimportable."""
-    try:
-        from ..kernels import remedy_for
-        return remedy_for(kind)
-    except Exception:
-        return None
-
-
 def _advisories_for(label: str, metrics: Dict, counters: Dict,
                     ridge: Optional[float], thresholds: Dict) -> List[Dict]:
     adv = []
@@ -698,10 +686,6 @@ def _advisories_for(label: str, metrics: Dict, counters: Dict,
                        "activations)"
                        % (label, metrics["intensity"], factor, ridge),
         })
-    for a in adv:
-        remedy = _kernel_remedy(a["kind"])
-        if remedy:
-            a["remedy"] = remedy
     adv.sort(key=lambda a: -a["share"])
     return adv
 
@@ -834,16 +818,13 @@ def diff_audit(baseline: Dict, current: Dict,
         blessed_kinds = {a["kind"] for a in base.get("advisories", [])}
         for a in cur.get("advisories", []):
             if a["kind"] not in blessed_kinds:
-                remedy = a.get("remedy") or _kernel_remedy(a["kind"])
                 diags.append(Diagnostic(
                     "perf-drift",
                     "executable %r gained unblessed %r advisory "
-                    "(category %s, cost share %.1f%%%s): %s -- fix the "
+                    "(category %s, cost share %.1f%%): %s -- fix the "
                     "regression or re-bless via analysis.perf."
                     "save_audit" % (label, a["kind"], a["category"],
-                                    100 * a["share"],
-                                    ", remedy: %s" % remedy if remedy
-                                    else "", a["message"]),
+                                    100 * a["share"], a["message"]),
                     node=label))
         bm = base.get("metrics", {})
         cm = cur.get("metrics", {})

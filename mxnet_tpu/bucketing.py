@@ -1,17 +1,16 @@
 """Dtype-group flatten/concat bucketing.
 
-PR 9 taught the host collectives to coalesce a whole tensor list into
-ONE contiguous buffer per dtype (``distributed.host_allreduce_bucketed``)
-instead of one RPC per tensor.  The fused bucket-flattened optimizer
-update (``mxnet_tpu.kernels.optimizer_update``) needs the exact same
-grouping over *traced* jax arrays, so the machinery lives here once and
-both consumers share it: group by dtype preserving input order, flatten
-each group into one 1-D buffer, split results back to the original
-shapes.
+The host collectives coalesce a whole tensor list into ONE contiguous
+buffer per dtype (``distributed.host_allreduce_bucketed``) instead of
+one RPC per tensor, and the numerics sentinel's in-graph finite check
+(``analysis.numerics.finite_tree``) reduces over the same grouping of
+*traced* jax arrays, so the machinery lives here once: group by dtype
+preserving input order, flatten each group into one 1-D buffer, split
+results back to the original shapes.
 
 The helpers are array-module agnostic: pass ``xp=numpy`` for host
 buffers (collectives) or ``xp=jax.numpy`` for traced buffers (the
-compiled optimizer update).
+compiled train step).
 """
 from __future__ import annotations
 
@@ -24,8 +23,8 @@ def dtype_groups(arrays: Sequence[Any]) -> List[Tuple[Any, List[int]]]:
     """Group ``arrays`` by dtype, preserving first-seen order.
 
     Returns ``[(dtype, [index, ...]), ...]`` where indices point into the
-    input sequence in their original order -- the contract both the host
-    collectives and the fused optimizer rely on to reassemble results.
+    input sequence in their original order -- the contract the host
+    collectives rely on to reassemble results.
     """
     order: List[Any] = []
     groups: Dict[Any, List[int]] = {}

@@ -244,18 +244,6 @@ _VARS = [
            "least-recently-used program is dropped beyond it (counted "
            "in serving.compile_evictions).  Per-predictor override: "
            "Predictor(jit_cache_size=...)."),
-    EnvVar("MXNET_TPU_KERNELS", str, "",
-           "Pallas kernel tier selection (mx.kernels, docs/kernels.md). "
-           "Unset (auto): Pallas kernels only where measured profitable "
-           "and only on TPU (flash attention above the seq>=256 "
-           "crossover); the gluon BatchNorm+ReLU fusion sites and the "
-           "bucket-flattened LARS/LAMB optimizer update stay off.  "
-           "'1': the whole tier arms -- fusion sites rewrite, the "
-           "bucketed optimizer replaces the per-parameter update swarm "
-           "in compiled train steps, and on non-TPU backends kernels "
-           "run in interpret mode so tests exercise the real kernel "
-           "bodies.  '0': XLA fallback everywhere (kill switch).  Read "
-           "per trace: arm before building/compiling the net."),
     EnvVar("MXNET_TPU_PERF_AUDIT_TOL", float, 0.02,
            "Absolute growth tolerance for the perf auditor's share "
            "metrics (transpose share, unfused-elementwise share, MXU "

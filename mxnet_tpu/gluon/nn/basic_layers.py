@@ -7,7 +7,6 @@ from ... import autograd
 from ...base import MXNetError
 from ..block import Block, HybridBlock
 from ..parameter import shape_is_known
-from .activations import Activation as _Activation
 
 
 class Sequential(Block):
@@ -35,36 +34,6 @@ class Sequential(Block):
         super().hybridize(active, **kwargs)
 
 
-def _bn_relu_fusion_plan(children):
-    """Pair each ``BatchNorm`` directly followed by a relu
-    ``Activation`` for the fused kernel-tier op (docs/kernels.md).
-
-    Returns ``[(block, fused)]`` where ``fused=True`` marks a BatchNorm
-    whose trailing relu is folded into ``_forward_fused_relu`` (the
-    Activation block is consumed).  Active only when the Pallas tier is
-    armed (``MXNET_TPU_KERNELS=1``) -- the decision is read per forward
-    and baked into each trace like every other static op param, so arm
-    the tier before building/tracing the net."""
-    from ...kernels import mode as _kernels_mode
-    blocks = list(children)
-    if _kernels_mode() != "on":
-        return [(b, False) for b in blocks]
-    plan = []
-    i = 0
-    while i < len(blocks):
-        b = blocks[i]
-        nxt = blocks[i + 1] if i + 1 < len(blocks) else None
-        if type(b) in (BatchNorm, SyncBatchNorm) \
-                and type(nxt) is _Activation \
-                and getattr(nxt, "_act", None) == "relu":
-            plan.append((b, True))
-            i += 2
-            continue
-        plan.append((b, False))
-        i += 1
-    return plan
-
-
 class HybridSequential(HybridBlock):
     """Compilable stack (reference: ``HybridSequential``)."""
 
@@ -76,13 +45,13 @@ class HybridSequential(HybridBlock):
             self._children[str(len(self._children))] = b
 
     def _forward_impl(self, x):
-        for b, fused in _bn_relu_fusion_plan(self._children.values()):
-            x = b._forward_fused_relu(x) if fused else b(x)
+        for b in self._children.values():
+            x = b(x)
         return x
 
     def hybrid_forward(self, F, x):
-        for b, fused in _bn_relu_fusion_plan(self._children.values()):
-            x = b._forward_fused_relu(x) if fused else b(x)
+        for b in self._children.values():
+            x = b(x)
         return x
 
     def __getitem__(self, i):
@@ -187,42 +156,6 @@ class BatchNorm(HybridBlock):
     def hybrid_forward(self, F, x, gamma, beta, running_mean, running_var):
         out, new_mean, new_var = F.BatchNorm(
             x, gamma, beta, running_mean, running_var, eps=self._eps,
-            momentum=self._momentum, fix_gamma=not self._scale,
-            use_global_stats=self._use_global_stats, axis=self._axis)
-        if autograd.is_training() and not self._use_global_stats:
-            self.running_mean.set_data(new_mean)
-            self.running_var.set_data(new_var)
-        return out
-
-    def _forward_fused_relu(self, x):
-        """BN+ReLU through the kernel tier's fused op -- the
-        HybridSequential fusion-site entry (docs/kernels.md): a
-        BatchNorm directly followed by a relu Activation dispatches
-        here when MXNET_TPU_KERNELS=1, consuming the Activation.  Same
-        running-stat contract as ``hybrid_forward``; works eagerly and
-        under trace (``Parameter.data()`` yields the traced value
-        inside ``functionalize``)."""
-        from ...symbol.symbol import Symbol
-        if isinstance(x, Symbol):
-            from ... import symbol as F
-            params = {k: p.var() for k, p in self._reg_params.items()}
-            out, _nm, _nv = F.fused_batch_norm_relu(
-                x, params["gamma"], params["beta"],
-                params["running_mean"], params["running_var"],
-                eps=self._eps, momentum=self._momentum,
-                fix_gamma=not self._scale,
-                use_global_stats=self._use_global_stats, axis=self._axis)
-            return out
-        from ... import ndarray as F
-        from ..parameter import DeferredInitializationError
-        try:
-            params = {k: p.data() for k, p in self._reg_params.items()}
-        except DeferredInitializationError:
-            self._infer_and_finish(x)
-            params = {k: p.data() for k, p in self._reg_params.items()}
-        out, new_mean, new_var = F.fused_batch_norm_relu(
-            x, params["gamma"], params["beta"], params["running_mean"],
-            params["running_var"], eps=self._eps,
             momentum=self._momentum, fix_gamma=not self._scale,
             use_global_stats=self._use_global_stats, axis=self._axis)
         if autograd.is_training() and not self._use_global_stats:

@@ -1,33 +1,29 @@
 """``mxnet_tpu.kernels`` -- the Pallas custom-kernel tier.
 
-A registry of hand-written Pallas TPU kernels with automatic XLA
-fallback (docs/kernels.md).  Three kernels ship through it:
+A registry of hand-written Pallas TPU kernels, each with its XLA
+reference (docs/kernels.md).  Three kernels ship through it, the three
+the benchmark's device traces name:
 
-- ``fused_bn_relu``: NHWC-native fused BatchNorm+ReLU (training
-  forward AND backward; bf16 activations, fp32 batch statistics),
-  wired into the gluon ``HybridSequential`` BatchNorm+Activation
-  fusion sites behind ``MXNET_TPU_KERNELS=1``.
-- ``flash_attention``: the blockwise online-softmax attention kernels
-  (``ops/pallas/flash_attention.py``), promoted out of ad-hoc
-  ``use_pallas`` branches into ONE registry selection point.
-- ``bucket_optimizer``: LARS/LAMB trust-ratio + momentum update over
-  one concatenated per-dtype buffer (shared ``mxnet_tpu.bucketing``
-  grouping), replacing the per-parameter elementwise-kernel swarm in
-  the compiled train step.
+- ``flash_attention``: the blockwise online-softmax attention kernels,
+  forward and backward (``ops/pallas/flash_attention.py``), behind the
+  ``flash_attention`` / ``flash_attention_masked`` ops.
 - ``paged_attention``: decode-step attention over the generative
   serving tier's paged KV cache (``ops/pallas/paged_attention.py``):
   one query token per slot walks its block table with online softmax;
-  XLA fallback gathers the table's blocks and masks.
+  the XLA reference gathers the table's blocks and masks.
+- ``mla_paged_attention``: the same walk over a paged cache of latent
+  (MLA) rows, which are keys and values at once
+  (``ops/pallas/mla_paged_attention.py``).
 
-Selection policy (``registry.choose``): ``MXNET_TPU_KERNELS`` unset =
-auto (Pallas only where measured profitable, on TPU), ``1`` = forced
-(interpret mode on CPU so tier-1 exercises the real kernel bodies),
-``0`` = XLA everywhere.
+Selection (``registry.choose``) is a function of the backend, the
+call's shape and the caller's ``force`` / ``use_pallas`` argument:
+Pallas compiled on a TPU where the kernel supports the shape and
+measured profitable, the XLA reference elsewhere; ``use_pallas=True``
+off the chip runs the real kernel body in interpret mode (tier-1),
+``use_pallas=False`` is the XLA reference anywhere.
 """
-from .registry import (KernelChoice, KernelSpec, available, choose,
-                       describe, enabled, get, list_kernels, mode,
-                       register_kernel, remedy_for)
+from .registry import (KernelChoice, KernelSpec, available, choose, get,
+                       list_kernels, register_kernel)
 
-__all__ = ["KernelChoice", "KernelSpec", "available", "choose",
-           "describe", "enabled", "get", "list_kernels", "mode",
-           "register_kernel", "remedy_for"]
+__all__ = ["KernelChoice", "KernelSpec", "available", "choose", "get",
+           "list_kernels", "register_kernel"]

@@ -2,9 +2,8 @@
 
 The kernel bodies live in ``ops/pallas/flash_attention.py`` (blockwise
 online-softmax forward AND backward, O(seq*d) HBM); this module
-promotes them into the kernel tier: ONE ``registry.choose``
-selection point replaces the five scattered ``use_pallas`` branches
-that used to live in ``ops/transformer.py``, and the auto-mode
+promotes them into the kernel tier: ``registry.choose`` is the ONE
+selection point (``ops/transformer.py`` asks it), and the
 profitability gate carries the measured v5e crossover (seq >= 256,
 below which XLA's fused materialized-scores path wins -- see
 ``ops/transformer.py`` for the per-seq numbers).
@@ -41,10 +40,8 @@ register_kernel(KernelSpec(
     doc="Blockwise online-softmax attention, forward AND backward "
         "(ops/pallas/flash_attention.py): scores never leave VMEM, "
         "HBM traffic O(seq*d) instead of O(seq^2) both directions; "
-        "optional padding mask.  Auto mode applies the measured "
-        "seq>=256 crossover and selects Pallas on TPU only.",
-    categories=("elementwise_fusion", "conv_dot"),
-    remedies=("memory-bound",),
+        "optional padding mask.  A caller that leaves the choice open "
+        "gets the measured seq>=256 crossover, and Pallas on TPU only.",
     supports=_supports,
     auto_predicate=_auto,
     xla_ref=_xla_reference,

@@ -41,8 +41,6 @@ register_kernel(KernelSpec(
         "and the values are the same rows' first lanes, so a live "
         "token is read once a layer for all heads.  XLA fallback "
         "gathers the table's blocks and runs a masked softmax.",
-    categories=("gather", "conv_dot"),
-    remedies=(),
     supports=_supports,
     xla_ref=_xla_reference,
 ))

@@ -40,8 +40,6 @@ register_kernel(KernelSpec(
         "traffic is the slot's live context only -- no contiguous "
         "(or padded-to-max) K/V copy per step.  XLA fallback gathers "
         "the table's blocks and runs a masked softmax.",
-    categories=("gather", "conv_dot"),
-    remedies=(),
     supports=_supports,
     xla_ref=_xla_reference,
 ))

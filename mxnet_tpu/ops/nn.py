@@ -17,8 +17,6 @@ chains fuse into them.  Stateful-looking ops are functional here:
 """
 from __future__ import annotations
 
-import functools
-
 import numpy as np
 
 import jax
@@ -315,77 +313,13 @@ def _batch_norm(data, gamma, beta, moving_mean, moving_var, eps=1e-5,
             lax.stop_gradient(new_var))
 
 
-@register("fused_batch_norm_relu",
-          args=("data", "gamma", "beta", "moving_mean", "moving_var"),
-          num_diff_outputs=1)
-def _fused_batch_norm_relu(data, gamma, beta, moving_mean, moving_var,
-                           eps=1e-5, momentum=0.9, fix_gamma=True,
-                           use_global_stats=False, axis=1,
-                           training=False):
-    """Fused BatchNorm+ReLU (kernel tier, docs/kernels.md): same
-    functional contract as ``BatchNorm`` -- returns ``(out,
-    new_moving_mean, new_moving_var)`` -- with the relu epilogue fused
-    into the normalize pass.  Kernel-vs-XLA selection happens ONCE in
-    the registry (``kernels.choose('fused_bn_relu')``): the Pallas VMEM
-    kernel on TPU (channels-last inputs; interpret mode on CPU under
-    MXNET_TPU_KERNELS=1), ``relu(BatchNorm(...))`` otherwise.  The
-    gluon ``HybridSequential`` BatchNorm+Activation fusion sites
-    dispatch here when the tier is armed."""
-    from ..kernels.fused_bn_relu import fused_bn_relu as _fused
-    return _fused(data, gamma, beta, moving_mean, moving_var, eps=eps,
-                  momentum=momentum, fix_gamma=fix_gamma,
-                  use_global_stats=use_global_stats, axis=axis,
-                  training=training)
-
-
-def _ln_xla_lastaxis(data, gamma, beta, eps):
-    xf = data.astype(jnp.float32)
-    mean = jnp.mean(xf, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(xf - mean), axis=-1, keepdims=True)
-    out = (xf - mean) * lax.rsqrt(var + eps)
-    out = out * gamma.astype(jnp.float32) + beta.astype(jnp.float32)
-    return out.astype(data.dtype)
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _ln_pallas(data, gamma, beta, eps):
-    # forward = fused VMEM kernel; backward = XLA math (recompute), the
-    # same pattern as the flash-attention op -- pallas_call has no
-    # transpose rule, so the custom_vjp keeps the op differentiable
-    from .pallas.layernorm import layernorm_fwd_pallas
-    shape = data.shape
-    out2d = layernorm_fwd_pallas(data.reshape(-1, shape[-1]), gamma,
-                                 beta, eps=eps)
-    return out2d.reshape(shape)
-
-
-def _ln_pallas_fwd(data, gamma, beta, eps):
-    return _ln_pallas(data, gamma, beta, eps), (data, gamma, beta)
-
-
-def _ln_pallas_bwd(eps, res, g):
-    data, gamma, beta = res
-    _, vjp = jax.vjp(lambda d, ga, be: _ln_xla_lastaxis(d, ga, be, eps),
-                     data, gamma, beta)
-    return vjp(g)
-
-
-_ln_pallas.defvjp(_ln_pallas_fwd, _ln_pallas_bwd)
-
-
 @register("LayerNorm", args=("data", "gamma", "beta"))
-def _layer_norm(data, gamma, beta, axis=-1, eps=1e-5, use_pallas=False):
+def _layer_norm(data, gamma, beta, axis=-1, eps=1e-5):
     """Layer normalization (reference: ``src/operator/nn/layer_norm.cc``).
 
-    Default path is written so XLA fuses the whole thing into one
-    elementwise pass; ``use_pallas=True`` selects the explicit fused
-    VMEM kernel (``ops/pallas/layernorm.py``) for last-axis
-    normalization.  Stats accumulate in fp32 for bf16 activations.
+    Written so XLA fuses the whole thing into one elementwise pass.
+    Stats accumulate in fp32 for bf16 activations.
     """
-    if use_pallas and axis in (-1, data.ndim - 1):
-        # asked for by name: a backend or shape the kernel cannot take
-        # raises here instead of quietly running the XLA path
-        return _ln_pallas(data, gamma, beta, float(eps))
     xf = data.astype(jnp.float32)
     mean = jnp.mean(xf, axis=axis, keepdims=True)
     var = jnp.mean(jnp.square(xf - mean), axis=axis, keepdims=True)

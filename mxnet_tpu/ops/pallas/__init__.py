@@ -1,4 +1,5 @@
-"""Hand-written Pallas TPU kernel bodies (flash attention, layernorm).
+"""Hand-written Pallas TPU kernel bodies (flash attention, paged and
+latent paged decode attention).
 
 Selection/fallback policy lives in ``mxnet_tpu.kernels`` (the kernel
 registry, docs/kernels.md); these modules hold only the kernels.

@@ -382,36 +382,16 @@ class TrainStep:
             # through), or frozen params would be left deleted.
             new_w = dict(pvals)
             new_s = {}
-            from ..kernels import optimizer_update as _kopt
             with _scalar_feed(opt, t, lr_map, wd_map, rescale), \
                     jax.named_scope("mx.optimizer"):
-                if _kopt.bucket_active(opt):
-                    # kernel tier (MXNET_TPU_KERNELS=1): the LARS/LAMB
-                    # update runs over ONE concatenated per-dtype buffer
-                    # instead of a per-parameter elementwise-kernel
-                    # swarm (docs/kernels.md)
-                    upd_w, upd_s = _kopt.bucket_update(
-                        opt, [(i, pvals[name_by_idx[i]],
-                               grads[name_by_idx[i]], svals.get(i))
-                              for i in idxs])
-                    for i in idxs:
-                        nm = name_by_idx[i]
-                        new_w[nm] = jnp.where(all_finite, upd_w[i],
-                                              pvals[nm])
-                        new_s[i] = _select_state(
-                            all_finite, _wrap_state(upd_s[i]),
-                            svals.get(i))
-                else:
-                    for i in idxs:
-                        nm = name_by_idx[i]
-                        w = NDArray(pvals[nm])
-                        g = NDArray(grads[nm])
-                        s = _wrap_state(svals.get(i))
-                        opt.update_multi_precision(i, w, g, s)
-                        new_w[nm] = jnp.where(all_finite, w._data,
-                                              pvals[nm])
-                        new_s[i] = _select_state(all_finite, s,
-                                                 svals.get(i))
+                for i in idxs:
+                    nm = name_by_idx[i]
+                    w = NDArray(pvals[nm])
+                    g = NDArray(grads[nm])
+                    s = _wrap_state(svals.get(i))
+                    opt.update_multi_precision(i, w, g, s)
+                    new_w[nm] = jnp.where(all_finite, w._data, pvals[nm])
+                    new_s[i] = _select_state(all_finite, s, svals.get(i))
             return new_w, new_s, aux, mean_loss, all_finite
 
         @scope

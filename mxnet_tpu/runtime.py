@@ -54,11 +54,6 @@ def _detect():
         # whether MXNET_TPU_SHARD_CHECK armed collective-contract
         # capture for this run
         "SHARD_CHECK": _shard_check_enabled(),
-        # Pallas kernel tier (mx.kernels): whether MXNET_TPU_KERNELS=1
-        # armed the full tier for this run (fusion sites + bucketed
-        # optimizer + interpret-mode kernels off-TPU); auto mode still
-        # selects profitable kernels on TPU with this row False
-        "KERNELS": _kernels_armed(),
         # chaos fault injection (mx.chaos): LIVE arm state -- True only
         # inside a chaos.arm()/chaos.scenario() window, never in a
         # production process (no env var arms it)
@@ -113,11 +108,6 @@ def _tsan_enabled():
 def _profiling_enabled():
     from . import profiling
     return profiling.enabled()
-
-
-def _kernels_armed():
-    from . import kernels
-    return kernels.mode() == "on"
 
 
 def _chaos_armed():

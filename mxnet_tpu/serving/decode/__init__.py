@@ -36,8 +36,8 @@ attention itself is a kernel-registry citizen
 (``kernels.paged_attention`` for per-head K and V,
 ``kernels.mla_paged_attention`` for latent rows): a Pallas
 online-softmax walk over the slot's block table on TPU (interpret mode
-on CPU under ``MXNET_TPU_KERNELS=1``), an XLA gather+masked-softmax
-fallback everywhere else.  docs/serving.md covers tuning.
+on CPU where the caller passes ``use_pallas=True``), an XLA
+gather+masked-softmax fallback everywhere else.  docs/serving.md covers tuning.
 """
 from .engine import (DecodeEngine, GenerationStream, GenerativeServable,
                      GenerativeWatcher)

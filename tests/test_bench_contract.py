@@ -111,18 +111,6 @@ def bench_mod(monkeypatch):
             "verdict": "compute-bound: device busy 90% of wall",
             "bound": "compute", "reconciled": True,
             "env_degraded": False}})
-    # the kernel-tier HLO diff compiles two probe models; stub it with
-    # the contract shape (the REAL probe is covered by test_kernels.py)
-    monkeypatch.setattr(
-        bench, "_kernels_diff",
-        lambda model: {
-            "probe": model, "after_interpret": False,
-            "before": {"transpose_layout": 1000,
-                       "unfused_elementwise": 500, "bytes_total": 4000},
-            "after": {"transpose_layout": 400,
-                      "unfused_elementwise": 100, "bytes_total": 3000},
-            "delta": {"transpose_layout": -600,
-                      "unfused_elementwise": -400, "bytes_total": -1000}})
     # _emit_with_retry sleeps between real retries; stubs don't need it
     monkeypatch.setattr(bench.time, "sleep", lambda s: None)
     import mxnet_tpu as mx
@@ -186,20 +174,6 @@ def test_degraded_env_flips_on_slow_dispatch(bench_mod, capsys,
     assert by["env_health"]["degraded_env"] is True
     assert by["resnet50_imagenet_train_bf16_scan"]["degraded_env"] is True
     assert by["resnet50_imagenet_train"]["degraded_env"] is True
-
-
-def test_scan_line_carries_kernels_diff(bench_mod, capsys):
-    """ISSUE 11 acceptance: the resnet50-scan line carries the kernel
-    tier's before/after mxprof category deltas (transpose_layout /
-    unfused-elementwise bytes)."""
-    bench_mod.main()
-    _names, lines = _metrics(capsys)
-    by = {ln["metric"]: ln for ln in lines}
-    kd = by["resnet50_imagenet_train_bf16_scan"]["kernels_diff"]
-    for key in ("probe", "after_interpret", "before", "after", "delta"):
-        assert key in kd, key
-    assert kd["delta"]["transpose_layout"] < 0
-    assert kd["delta"]["unfused_elementwise"] < 0
 
 
 def test_budget_exhaustion_skips_garnish_only(bench_mod, capsys,

@@ -33,9 +33,10 @@ def test_tiny_rehearsal_ends_in_rehearsal():
     by = {r["phase"]: r for r in records}
     assert by["train_bert"]["compiles_after_step_1"] == 0
     assert by["serve"]["compiles_after_warmup"] == 0
-    assert {k["kernel"] for k in by["census"]["kernels"]} >= {
-        "flash_attention", "paged_attention", "fused_bn_relu",
-        "bucket_optimizer"}
+    census = by["census"]["kernels"]
+    assert [k["kernel"] for k in census] == [
+        "flash_attention", "paged_attention", "mla_paged_attention"]
+    assert all(k["use_pallas"] and k["interpret"] for k in census)
 
 
 def test_real_run_refuses_a_cpu_before_any_work():

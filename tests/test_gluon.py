@@ -118,6 +118,56 @@ def test_batchnorm_layer_stats():
     assert not np.allclose(rm, 0)  # updated toward batch mean
 
 
+@pytest.mark.parametrize("layout,axis", [("NCHW", 1), ("NHWC", 3)])
+@pytest.mark.parametrize("training", [True, False],
+                         ids=["training", "inference"])
+def test_hybrid_sequential_is_its_children_in_turn(layout, axis, training):
+    """A hybridized Conv-BatchNorm-relu stack gives the output and the
+    running statistics of the three blocks called one after another:
+    nothing between the children is paired, fused or skipped."""
+    def blocks():
+        return [nn.Conv2D(8, 3, padding=1, layout=layout),
+                nn.BatchNorm(axis=axis), nn.Activation("relu")]
+
+    def in_turn(chain, x):
+        for b in chain:
+            x = b(x)
+        return x
+
+    shape = (4, 3, 6, 6) if layout == "NCHW" else (4, 6, 6, 3)
+    xs = [mx.nd.array(np.random.RandomState(i).randn(*shape)
+                      .astype(np.float32) * 2 + 1) for i in range(2)]
+    net = nn.HybridSequential()
+    net.add(*blocks())
+    net.initialize()
+    net(xs[0])                          # inference: shapes, no stats
+    ref = blocks()
+    for b in ref:
+        b.initialize()
+    in_turn(ref, xs[0])
+    for got, want in zip(ref, net):
+        for q, p in zip(got.collect_params().values(),
+                        want.collect_params().values()):
+            q.set_data(p.data())
+    net.hybridize()
+    bn, ref_bn = net[1], ref[1]
+    for x in xs:                        # the second pass runs compiled
+        if training:
+            with autograd.record():
+                out, want = net(x), in_turn(ref, x)
+        else:
+            out, want = net(x), in_turn(ref, x)
+        assert_almost_equal(out.asnumpy(), want.asnumpy(), rtol=1e-5,
+                            atol=1e-5)
+        assert out.asnumpy().min() >= 0.0
+        for stat in ("running_mean", "running_var"):
+            assert_almost_equal(getattr(bn, stat).data().asnumpy(),
+                                getattr(ref_bn, stat).data().asnumpy(),
+                                rtol=1e-5, atol=1e-6)
+        moved = not np.allclose(bn.running_mean.data().asnumpy(), 0)
+        assert moved == training
+
+
 def test_trainer_step_decreases_loss():
     net = nn.HybridSequential()
     net.add(nn.Dense(32, activation="relu"), nn.Dense(2))

@@ -12,13 +12,8 @@ import jax.numpy as jnp
 import pytest
 from jax import export
 
-from mxnet_tpu.kernels.fused_bn_relu import (bn_relu_apply_pallas,
-                                             bn_relu_bwd_pallas)
-from mxnet_tpu.kernels.optimizer_update import (lamb_phase1_pallas,
-                                                lars_flat_pallas)
 from mxnet_tpu.ops.pallas.flash_attention import (
     flash_attention_bwd_pallas, flash_attention_fwd_pallas)
-from mxnet_tpu.ops.pallas.layernorm import layernorm_fwd_pallas
 from mxnet_tpu.ops.pallas.mla_paged_attention import (
     mla_paged_attention_pallas)
 from mxnet_tpu.ops.pallas.paged_attention import paged_attention_pallas
@@ -95,43 +90,6 @@ def test_mla_paged_attention_kimi_k2_decode_buckets(slots):
             q, c, bt, cl, v_width=512, scale=0.13),
         S((slots, 64, 640), BF16), S((4289, 64, 640), BF16),
         S((slots, 256), I32), S((slots, 1), I32))
-
-
-@pytest.mark.parametrize("channels", [64, 256])
-def test_fused_bn_relu_resnet50_stage1(channels):
-    x = S((128 * 56 * 56, channels), BF16)       # NHWC rows x C
-    vec = S((1, channels), F32)
-    _lowers_to_mosaic(bn_relu_apply_pallas, x, vec, vec)
-    _lowers_to_mosaic(bn_relu_bwd_pallas, x, x, x, vec, vec, vec, vec,
-                      vec)
-
-
-def test_bucket_optimizer_resnet50_flat_buffer():
-    flat = S((25557032,), F32)                   # 199,665 rows of 128
-    _lowers_to_mosaic(lars_flat_pallas, flat, flat, flat, flat, flat,
-                      flat, S((), F32))
-    _lowers_to_mosaic(lamb_phase1_pallas, flat, flat, flat, flat, flat,
-                      S((3,), F32))
-
-
-def test_layernorm_bert_base_rows():
-    vec = S((768,), F32)
-    _lowers_to_mosaic(layernorm_fwd_pallas, S((16384, 768), BF16), vec,
-                      vec)
-
-
-def test_row_blocks_are_a_multiple_of_8_or_the_whole_extent():
-    from mxnet_tpu import kernels
-    from mxnet_tpu.ops.pallas.tiling import row_block
-    assert row_block(128 * 56 * 56, 256) == 256
-    assert row_block(50, 256) == 50              # fits one block
-    assert row_block(1000, 256) == 200           # not 250: 250 % 8 != 0
-    assert row_block(199665, 64) is None         # odd: caller pads
-    # a shape with no such block is declined by name, not refused by
-    # the lowering later
-    ch = kernels.choose("fused_bn_relu", force=True, axis=3, ndim=4,
-                        rows=2 * 15 * 15)
-    assert not ch.use_pallas and "multiple of 8" in ch.reason
 
 
 # ----------------------------------------------------------------------

@@ -199,12 +199,11 @@ def test_numerics_rules_registered_and_fixed_tree_clean():
                 "half-optimizer-state", "implicit-downcast",
                 "nonfinite-guard-missing", "numerics-drift"):
         assert rid in an.RULES, rid
-    # the armed-rules acceptance: the nn/kernel code the BN-stats fix
+    # the armed-rules acceptance: the nn code the BN-stats fix
     # brought into shape lints clean WITHOUT suppressions (full --self
     # runs in CI)
     diags = an.lint_paths([
         os.path.join(REPO, "mxnet_tpu", "ops", "nn.py"),
-        os.path.join(REPO, "mxnet_tpu", "kernels", "fused_bn_relu.py"),
         os.path.join(REPO, "mxnet_tpu", "gluon", "model_zoo"),
     ])
     assert [d.format() for d in diags] == []
@@ -724,24 +723,3 @@ def test_batch_norm_bf16_eval_adds_eps_in_fp32():
     got = float(np.asarray(out).ravel()[0])
     assert abs(got - ref) < 1e-3
     assert abs(got - wrong) > 1e-3
-
-
-def test_fused_bn_relu_bf16_stats_blend_in_fp32():
-    from mxnet_tpu.kernels import fused_bn_relu as k
-    rng = np.random.RandomState(1)
-    x = jnp.asarray(rng.randn(4, 6, 3).astype(np.float32))
-    gamma = jnp.ones((3,), jnp.float32)
-    beta = jnp.zeros((3,), jnp.float32)
-    mm = jnp.zeros((3,), jnp.bfloat16)
-    mv = jnp.ones((3,), jnp.bfloat16)
-    out, new_mean, new_var = k.fused_bn_relu(
-        x, gamma, beta, mm, mv, training=True, momentum=0.9,
-        fix_gamma=False, axis=2)
-    assert new_mean.dtype == jnp.bfloat16
-    assert new_var.dtype == jnp.bfloat16
-    batch_mean = np.asarray(x, np.float32).mean(axis=(0, 1))
-    ref = (0.1 * batch_mean).astype(jnp.bfloat16.dtype)
-    np.testing.assert_allclose(np.asarray(new_mean, np.float32),
-                               ref.astype(np.float32), rtol=2 ** -7,
-                               atol=2 ** -10)
-    assert bool((np.asarray(out) >= 0).all())    # relu applied

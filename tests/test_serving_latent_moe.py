@@ -151,7 +151,7 @@ def test_mla_attention_never_reads_past_the_context():
                                    atol=1e-6)
 
 
-def test_mla_paged_attention_is_a_registry_entry(monkeypatch):
+def test_mla_paged_attention_is_a_registry_entry():
     from mxnet_tpu.kernels.mla_paged_attention import mla_paged_attention
     assert "mla_paged_attention" in kernels.list_kernels()
     shape = dict(heads=4, lanes=128, v_width=8, block_size=4)
@@ -165,8 +165,8 @@ def test_mla_paged_attention_is_a_registry_entry(monkeypatch):
     q, cache, bt, ctx, v = _kernel_case(rng, "float32", 2, [5, 17])
     xla = mla_paged_attention(q, cache, bt, ctx, v, scale=0.2,
                               use_pallas=False)
-    monkeypatch.setenv("MXNET_TPU_KERNELS", "1")        # the env's choice
-    pal = mla_paged_attention(q, cache, bt, ctx, v, scale=0.2)
+    pal = mla_paged_attention(q, cache, bt, ctx, v, scale=0.2,
+                              use_pallas=True)
     np.testing.assert_allclose(np.asarray(pal), np.asarray(xla), atol=1e-5)
 
 

@@ -214,19 +214,3 @@ def test_bert_trainstep_compiled():
     for _ in range(5):
         last = float(step(ids, labels).asscalar())
     assert last < first
-
-
-def test_layernorm_pallas_interpret_matches_xla():
-    """The fused Pallas LayerNorm kernel in interpreter mode against the
-    XLA path (the same kernel runs compiled on TPU)."""
-    import numpy as np
-    import mxnet_tpu as mx
-    from mxnet_tpu.ops.pallas.layernorm import layernorm_fwd_pallas
-    rng = np.random.RandomState(0)
-    x = rng.randn(64, 96).astype(np.float32)
-    g = (rng.rand(96) + 0.5).astype(np.float32)
-    b = rng.randn(96).astype(np.float32)
-    got = np.asarray(layernorm_fwd_pallas(x, g, b, interpret=True))
-    ref = mx.nd.LayerNorm(mx.nd.array(x), mx.nd.array(g),
-                          mx.nd.array(b)).asnumpy()
-    np.testing.assert_allclose(got, ref, rtol=1e-5, atol=1e-5)
