@@ -17,6 +17,20 @@ whose batch membership changes every step.  This module is the loop:
   step they finish, and the live slots pad up to the smallest decode
   bucket -- no bucket flush, no drain-the-batch barrier (Orca's
   iteration-level scheduling).
+- **one step in flight**: the loop dispatches step *n+1* before it
+  fetches step *n*'s tokens, so the device runs one step while the
+  host emits the last one and builds the next; a prefill is dispatched
+  behind the step in flight and, before its first token is waited for,
+  the running streams' next step behind IT.  A slot of step *n+1*
+  takes its token from step *n*'s output ON THE DEVICE (the decode
+  program is handed that output and, per slot, which of its entries
+  to take); position, block table and the length limit the host counts
+  ahead from the tokens it has DISPATCHED.  What the host cannot count
+  ahead -- EOS, ``cancel()`` -- it finds one step late: the token that
+  step *n+1* computed for the ended stream is discarded
+  (``decode.tokens_discarded``), never pushed.  An error that the
+  fetch of step *n* raises arrives with step *n+1* dispatched: every
+  live stream ends with it and the step in flight is dropped.
 - **admission backpressure**: the whole ``prompt + max_new`` KV budget
   allocates at submit; an exhausted
   :class:`~.kvcache.PagedKVCache` (or a full pending queue) sheds with
@@ -29,9 +43,9 @@ whose batch membership changes every step.  This module is the loop:
 - **spans**: the worker's time is named through ``obs.span`` --
   ``mx.decode.idle``, ``.admit`` (``.queue_wait``), ``.prefill`` and
   ``.step``, the last two split into ``.build`` / ``.call`` / ``.emit``.
-  ONE ``mx.decode.step`` a step (``n``, ``bucket``, ``max_slots``) whose
-  ring record links the ``serving.request`` root of every sequence it
-  served (docs/observability.md).
+  ONE ``mx.decode.step`` a step (``n``, ``bucket``, ``max_slots``,
+  ``overlapped``) whose ring record links the ``serving.request`` root
+  of every sequence it served (docs/observability.md).
 
 Hot swap (the PR-12 contract extended mid-decode): re-registering a
 :class:`GenerativeServable` installs the replacement for NEW requests
@@ -142,8 +156,8 @@ class GenerationStream:
 
 class _GenRequest:
     __slots__ = ("prompt", "max_new", "eos_id", "table", "stream",
-                 "deadline", "tctx", "generated", "last_token",
-                 "t_last_emit", "t_submit")
+                 "deadline", "tctx", "generated", "dispatched", "slot",
+                 "done", "last_token", "t_last_emit", "t_submit")
 
     def __init__(self, prompt, max_new, eos_id, table, stream, timeout):
         self.prompt = prompt
@@ -154,15 +168,36 @@ class _GenRequest:
         self.t_submit = stream.t_submit
         self.deadline = (self.t_submit + timeout) if timeout else None
         self.tctx = None
+        # tokens pushed to the stream, and tokens whose call has been
+        # dispatched: the second runs at most one step ahead
         self.generated = 0
+        self.dispatched = 0
+        self.slot = None            # its slot in the step last dispatched
+        self.done = False           # ended: a token still in flight is dropped
         self.last_token = None
         self.t_last_emit = None
 
     @property
     def position(self):
-        """Cache position the NEXT decode step writes (the last
-        generated token's index in the full sequence)."""
-        return len(self.prompt) + self.generated - 1
+        """Cache position the NEXT decode step dispatched writes (the
+        last dispatched token's index in the full sequence)."""
+        return len(self.prompt) + self.dispatched - 1
+
+
+class _Flight:
+    """One decode step dispatched and not yet fetched: the requests it
+    carries in slot order, its outputs on the device (the tokens at the
+    engine's fixed width, the program's counts), when it was dispatched
+    and whether the step before it was still unfetched then."""
+
+    __slots__ = ("batch", "bucket", "out", "t_dispatch", "overlapped")
+
+    def __init__(self, batch, bucket, out, t_dispatch, overlapped):
+        self.batch = batch
+        self.bucket = bucket
+        self.out = out
+        self.t_dispatch = t_dispatch
+        self.overlapped = overlapped
 
 
 def _request_links(reqs):
@@ -307,6 +342,14 @@ class DecodeEngine:
         self._cond = _sync.Condition(name="serving.decode")
         self._pending = collections.deque()
         self._active = []
+        # the decode step dispatched and not yet fetched, and when the
+        # last step's tokens reached the host
+        self._flight = None
+        self._t_fetched = 0.0
+        # what a step with no step before it is handed as that step's
+        # tokens: every slot of such a step holds a token of the host's
+        import jax.numpy as jnp
+        self._no_tokens = jnp.zeros((self.max_slots,), jnp.int32)
         self._closed = False
         self._drain = True
         self._drained_live = 0      # sequences in flight at close()
@@ -339,10 +382,24 @@ class DecodeEngine:
             first_token = jnp.argmax(logits).astype(jnp.int32)
         return (first_token, stats), slabs
 
-    def _decode_impl(self, params, slabs, tokens, positions, tables, live):
+    def _decode_impl(self, params, slabs, prev, tokens, positions, tables,
+                     live):
+        import jax
+        import jax.numpy as jnp
+        with jax.named_scope("mx.step_tokens"):
+            # a slot's token is an id the host holds (a prefill's first
+            # token), or, written ``-1 - j``, slot j of the step
+            # before, whose output ``prev`` never left the device
+            tokens = jnp.where(tokens < 0,
+                               jnp.take(prev, jnp.maximum(-1 - tokens, 0)),
+                               tokens)
         next_token, _logits, slabs, stats = self.model.decode_logits(
             params, slabs, tokens, positions, tables,
             self.cache.block_size, live)
+        # at ONE width whatever the bucket, so that every bucket's
+        # program can take any bucket's output
+        next_token = jnp.pad(next_token,
+                             (0, self.max_slots - next_token.shape[0]))
         return (next_token, stats), slabs
 
     def _specs(self):
@@ -362,6 +419,7 @@ class DecodeEngine:
             for b in self.prefill_buckets}
         decode = {
             s: (pspec, kv,
+                jax.ShapeDtypeStruct((self.max_slots,), i32),
                 jax.ShapeDtypeStruct((s,), i32),
                 jax.ShapeDtypeStruct((s,), i32),
                 jax.ShapeDtypeStruct((s, mb), i32),
@@ -464,43 +522,60 @@ class DecodeEngine:
     def _worker(self):
         while True:
             with self._cond:
-                if not self._pending and not self._active \
-                        and not self._closed:
+                if not self._busy() and not self._closed:
                     with _obs.span("mx.decode.idle"):
-                        while not self._pending and not self._active \
-                                and not self._closed:
+                        while not self._busy() and not self._closed:
                             self._cond.wait(_IDLE_WAIT_S)
                 if self._closed:
                     if not self._drain:
                         self._abort_locked()
                         return
-                    if not self._pending and not self._active:
+                    if not self._busy():
                         return
             if self._pending:
                 with _obs.span("mx.decode.admit"):
                     self._admit()
-            if self._active:
+            if self._turn_due():
                 self._step()
+
+    def _busy(self):
+        return bool(self._pending or self._active
+                    or self._flight is not None)
+
+    def _turn_due(self):
+        """Whether :meth:`_step` has work: a step to fetch or a stream
+        to step."""
+        return self._flight is not None or bool(self._live())
+
+    def _live(self):
+        """The streams with a token still to dispatch, in slot order.
+        (In ``_active`` and in no step: one whose first token is not
+        out yet, and one whose last token is in flight.)"""
+        return [r for r in self._active if 0 < r.dispatched < r.max_new]
 
     def _abort_locked(self):
         """close(drain=False): resolve everything as closed, free every
-        table -- still zero *lost* streams, they all end explicitly."""
+        table -- still zero *lost* streams, they all end explicitly.
+        The step in flight is dropped with them."""
         err = ServableClosed("generative servable %r closed without "
                              "drain" % self._label)
         for req in list(self._pending) + self._active:
             self.cache.free(req.table)
+            req.done = True
             req.stream._finish("closed", error=err)
         self._pending.clear()
         del self._active[:]
+        self._flight = None
 
     def _admit(self):
         """Step-boundary admission: pending requests take free slots in
-        the RUNNING batch (one prefill each).  Expired/cancelled
-        requests resolve here and never occupy a slot."""
+        the RUNNING batch (one prefill each, dispatched behind the step
+        in flight).  Expired/cancelled requests resolve here and never
+        occupy a slot."""
         while True:
             with self._cond:
                 if not self._pending \
-                        or len(self._active) >= self.max_slots:
+                        or len(self._live()) >= self.max_slots:
                     return
                 req = self._pending.popleft()
             with _obs.span("mx.decode.queue_wait", since=req.t_submit,
@@ -546,14 +621,32 @@ class DecodeEngine:
                                   model=self._label, bucket=bucket)
                 # the slabs that go in are donated: dead once the call
                 # returns, so the outputs are bound before anything
-                # else can read the cache
+                # else can read the cache.  The call runs behind the
+                # decode step in flight
                 out, cache.slabs = call(
                     self.params, cache.slabs, tokens, table,
                     np.int32(len(req.prompt)))
                 dispatched = True
-                # the token and the program's counts in one fetch
-                first, stats = jax.device_get(out)
-                first = int(first)
+                # it holds a cache from here on; it steps once its
+                # first token is out
+                self._active.append(req)
+                stepping = self._turn_due()
+                if not stepping:
+                    # the token and the program's counts in one fetch
+                    first, stats = jax.device_get(out)
+            if stepping:
+                # the running streams take their next turn BEHIND the
+                # prefill before its token is waited for: the device
+                # then has a step to run when the prefill ends, where
+                # the token's way to the host and a dispatch's way back
+                # would leave it idle.  This request joins the step
+                # after that one
+                self._step()
+                if req.done:            # that turn lost the cache
+                    return
+                with _obs.span("mx.decode.prefill.call"):
+                    first, stats = jax.device_get(out)
+            first = int(first)
         except Exception as e:
             self._call_failed(e, [req], dispatched)
             return
@@ -565,73 +658,119 @@ class DecodeEngine:
                                                 len(req.prompt), now - t0)
                 _telemetry.hooks.decode_ttft(now - req.t_submit)
             self._note_stats(span, stats)
+            req.dispatched = 1          # the prefill was its dispatch
             self._emit(req, first, now)
-            if not self._maybe_finish(req):
-                self._active.append(req)
+            if self._maybe_finish(req):
+                self._active.remove(req)
 
     def _step(self):
-        """ONE decode iteration for every live slot, under ONE
-        ``mx.decode.step`` span."""
-        n = len(self._active)
-        bucket = self._bucket(self.decode_buckets, n, "decode")
-        span = _obs.span("mx.decode.step", n=n, bucket=bucket,
+        """One turn of the loop under ONE ``mx.decode.step`` span: the
+        step in flight is fetched and emitted with its successor
+        already dispatched behind it.  Where nothing is in flight (the
+        first step after an idle moment) the span's step is dispatched
+        here first."""
+        flight, self._flight = self._flight, None
+        batch = flight.batch if flight is not None else self._live()
+        span = _obs.span("mx.decode.step", n=len(batch),
+                         bucket=self._bucket(self.decode_buckets,
+                                             len(batch), "decode"),
                          max_slots=self.max_slots,
-                         links=_request_links(self._active))
+                         overlapped=int(flight is not None
+                                        and flight.overlapped),
+                         links=_request_links(batch))
         with span:
-            self._step_spanned(n, bucket, span)
+            self._step_spanned(flight, batch, span)
 
-    def _step_spanned(self, n, bucket, span):
+    def _step_spanned(self, flight, batch, span):
         import jax
         with _obs.span("mx.decode.step.build"):
-            tokens = np.zeros((bucket,), np.int32)
-            positions = np.zeros((bucket,), np.int32)
-            tables = np.full((bucket, self.max_blocks_per_seq),
-                             SCRATCH_BLOCK, np.int32)
-            # which slots of the bucket hold a sequence: the rest is
-            # padding, and a model that counts its tokens leaves it out
-            live = np.arange(bucket) < n
-            for i, req in enumerate(self._active):
-                tokens[i] = req.last_token
-                positions[i] = req.position
-                tables[i] = self.cache.padded_table(
-                    req.table, self.max_blocks_per_seq)
-        t0 = time.perf_counter()
-        call = self._programs.get(("decode", bucket))
-        cache = self.cache
-        dispatched = False
-        try:
-            with _obs.span("mx.decode.step.call"):
-                _chaos.fail_point("serving.decode.step",
-                                  model=self._label, occupancy=n,
-                                  bucket=bucket)
-                # donated slabs in, the same memory out: rebind at once
-                out, cache.slabs = call(
-                    self.params, cache.slabs, tokens, positions, tables,
-                    live)
-                dispatched = True
-                out, stats = jax.device_get(out)
-        except Exception as e:
-            self._call_failed(e, list(self._active), dispatched)
-            return
+            if flight is None:
+                try:
+                    flight = self._dispatch(batch, self._build(batch))
+                except Exception as e:
+                    self._call_failed(e, batch, dispatched=False)
+                    return
+            # who steps on behind it: known without its tokens
+            after = self._live()
+            args = self._build(after) if after else None
+        with _obs.span("mx.decode.step.call"):
+            if after:
+                try:
+                    self._flight = self._dispatch(after, args, flight)
+                except Exception as e:
+                    if self._call_failed(e, after, dispatched=False):
+                        return          # the slabs went with it
+            try:
+                out, stats = jax.device_get(flight.out)
+            except Exception as e:
+                self._call_failed(e, flight.batch, dispatched=True)
+                return
         with _obs.span("mx.decode.step.emit"):
             now = time.perf_counter()
-            if _telemetry._ENABLED:
-                _telemetry.hooks.decode_step(self._label, n, bucket,
-                                             now - t0)
             self._note_stats(span, stats)
-            finished = []
-            for i, req in enumerate(self._active):
+            discarded = 0
+            for i, req in enumerate(flight.batch):
+                if req.done:
+                    # it ended (EOS, cancel, an error) with this step
+                    # already dispatched: the token is nobody's
+                    discarded += 1
+                    continue
                 self._emit(req, int(out[i]), now)
                 self.cache.note_tokens(req.table,
                                        len(req.prompt) + req.generated)
-                if self._maybe_finish(req):
-                    finished.append(req)
-            if finished:
-                # finished sequences vacate their slot IMMEDIATELY: the
-                # next iteration packs the survivors into a smaller
-                # bucket
-                self._active = [r for r in self._active
-                                if r not in finished]
+                self._maybe_finish(req)
+            # finished sequences vacate their slot IMMEDIATELY: the
+            # next step dispatched packs the survivors into a smaller
+            # bucket
+            self._active = [r for r in self._active if not r.done]
+            if _telemetry._ENABLED:
+                # the loop's period: from the tokens of the step before
+                # (or this step's dispatch, where none was in flight)
+                # to this step's tokens
+                _telemetry.hooks.decode_step(
+                    self._label, len(flight.batch), flight.bucket,
+                    now - max(self._t_fetched, flight.t_dispatch),
+                    flight.overlapped, discarded)
+            self._t_fetched = now
+
+    def _build(self, batch):
+        """The host's arguments of one decode step for ``batch``: each
+        slot's token (or where on the device it is), position and block
+        table, padded to the bucket."""
+        bucket = self._bucket(self.decode_buckets, len(batch), "decode")
+        tokens = np.zeros((bucket,), np.int32)
+        positions = np.zeros((bucket,), np.int32)
+        tables = np.full((bucket, self.max_blocks_per_seq),
+                         SCRATCH_BLOCK, np.int32)
+        # which slots of the bucket hold a sequence: the rest is
+        # padding, and a model that counts its tokens leaves it out
+        live = np.arange(bucket) < len(batch)
+        for i, req in enumerate(batch):
+            # a token still in flight is taken from that step's output
+            tokens[i] = req.last_token if req.dispatched == req.generated \
+                else -1 - req.slot
+            positions[i] = req.position
+            tables[i] = self.cache.padded_table(
+                req.table, self.max_blocks_per_seq)
+        return bucket, (tokens, positions, tables, live)
+
+    def _dispatch(self, batch, built, behind=None):
+        """Dispatch one decode step for ``batch`` behind the step in
+        flight (None: nothing is), whose tokens it takes on the device,
+        and return it in flight."""
+        bucket, args = built
+        prev = behind.out[0] if behind is not None else self._no_tokens
+        _chaos.fail_point("serving.decode.step", model=self._label,
+                          occupancy=len(batch), bucket=bucket)
+        call = self._programs.get(("decode", bucket))
+        cache = self.cache
+        t0 = time.perf_counter()
+        # donated slabs in, the same memory out: rebind at once
+        out, cache.slabs = call(self.params, cache.slabs, prev, *args)
+        for i, req in enumerate(batch):
+            req.slot = i
+            req.dispatched += 1
+        return _Flight(batch, bucket, out, t0, behind is not None)
 
     def _note_stats(self, span, stats):
         """The counts a program returned beside its token (a model with
@@ -650,22 +789,36 @@ class DecodeEngine:
         """A prefill or decode call raised.  Before the call took its
         arguments (the chaos fail points, a bad argument) the cache is
         whole and only the requests the call ``served`` end with the
-        error.  After it -- the slabs are deleted, or are the outputs
+        error; a step in flight that carries them discards their
+        tokens.  After it -- the slabs are deleted, or are the outputs
         of a program that failed -- no live stream has a cache left:
-        every one ends with the error, and fresh zeroed slabs serve the
-        next request."""
+        every one ends with the error, the step in flight is dropped,
+        and fresh zeroed slabs serve the next request.  Returns whether
+        the cache was lost."""
         if _telemetry._ENABLED:
             _telemetry.hooks.serving_error(self._label)
         lost = dispatched or self.cache.slabs_deleted()
         failed = list(served)
         if lost:
             failed += [r for r in self._active if r not in failed]
-        for req in failed:
-            self.cache.free(req.table)
-            req.stream._finish("error", error=error)
-        self._active = [r for r in self._active if r not in failed]
-        if lost:
+            flight, self._flight = self._flight, None
+            if flight is not None:
+                # it holds the old slabs until it ends, and two sets
+                # may not fit the device
+                import jax
+                try:
+                    jax.block_until_ready(flight.out)
+                except Exception:       # whatever failed the call
+                    pass
+            # before a stream hears of it: its client may ask again
             self.cache.reset_slabs()
+        for req in failed:
+            if not req.done:
+                req.done = True
+                self.cache.free(req.table)
+                req.stream._finish("error", error=error)
+        self._active = [r for r in self._active if not r.done]
+        return lost
 
     def _emit(self, req, token, now):
         req.generated += 1
@@ -688,6 +841,11 @@ class DecodeEngine:
         return False
 
     def _finish(self, req, reason):
+        # a step in flight may still write this request's next row: into
+        # a block of its own budget, and the device runs calls in the
+        # order they were dispatched, so a prefill that reuses the
+        # block runs after that write
+        req.done = True
         self.cache.free(req.table)
         now = time.perf_counter()
         if req.tctx is not None:        # tracing was armed at submit

@@ -360,12 +360,21 @@ def decode_prefill(model, bucket, prompt_len, seconds):
                                              prompt_len=prompt_len)
 
 
-def decode_step(model, occupancy, bucket, seconds):
+def decode_step(model, occupancy, bucket, seconds, overlapped=False,
+                discarded=0):
     """One continuous-batching decode iteration: ``occupancy`` live
-    sequences padded to the ``bucket`` slot count."""
+    sequences padded to the ``bucket`` slot count, ``seconds`` after
+    the tokens of the step before it (or after its own dispatch, where
+    none was in flight).  ``overlapped``: it was dispatched while that
+    step's tokens were still unfetched; ``discarded``: tokens it
+    computed for streams that had ended by the time they arrived."""
     reg = _registry()
     reg.counter("decode.steps").inc()
-    reg.counter("decode.tokens").inc(int(occupancy))
+    if overlapped:
+        reg.counter("decode.steps_overlapped").inc()
+    if discarded:
+        reg.counter("decode.tokens_discarded").inc(int(discarded))
+    reg.counter("decode.tokens").inc(int(occupancy) - int(discarded))
     reg.gauge("decode.occupancy").set(occupancy)
     reg.timer("decode.step_time").observe(seconds, model=model,
                                           bucket=bucket,
@@ -1009,11 +1018,22 @@ INSTRUMENTS = [
     _ii("decode.steps", "counter", "serving", 18,
         "continuous-batching decode iterations"),
     _ii("decode.tokens", "counter", "serving", 18,
-        "tokens decoded (occupancy summed over steps)"),
+        "tokens decoded and streamed (occupancy summed over steps, "
+        "less decode.tokens_discarded)"),
+    _ii("decode.steps_overlapped", "counter", "serving", 31,
+        "decode iterations dispatched while the step before them was "
+        "still unfetched (over decode.steps: how often the loop keeps "
+        "the device busy through its own fetch and emit)"),
+    _ii("decode.tokens_discarded", "counter", "serving", 31,
+        "tokens a step in flight computed for a stream that had ended "
+        "(EOS, cancel, an error) by the time they reached the host: "
+        "never streamed, one a stream at most"),
     _ii("decode.occupancy", "gauge", "serving", 18,
         "live sequences in the running decode batch"),
     _ii("decode.step_time", "timer", "serving", 18,
-        "decode iteration wall time, tagged bucket + occupancy"),
+        "the decode loop's period: from the tokens of the step before "
+        "(or this step's dispatch, where none was in flight) to this "
+        "step's tokens on the host; tagged bucket + occupancy"),
     _ii("decode.ttft", "timer", "serving", 18,
         "submit -> first streamed token (product-layer TTFT)"),
     _ii("decode.inter_token", "timer", "serving", 18,

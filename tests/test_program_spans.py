@@ -8,6 +8,7 @@ import collections
 import glob
 import itertools
 import os
+import time
 
 import numpy as np
 import pytest
@@ -166,6 +167,95 @@ def test_a_profiler_trace_of_a_decode_engine_holds_one_span_a_step(tmp_path):
             "mx.decode.step.emit"]
     assert {s.line for s in spans} == {admit.line}      # the engine's thread
     assert not [s for s in spans if "decode_step" in s.name]
+    # the first step after the engine ran empty had nothing before it;
+    # each of the others was dispatched behind its unfetched predecessor
+    assert [s.attrs["overlapped"] for s in sorted(
+        steps, key=lambda s: s.start)] == [0, 1, 1, 1]
+
+
+def test_a_prefill_beside_running_streams_holds_their_next_step(tmp_path):
+    """A request admitted while another decodes: its prefill is
+    dispatched, the running stream's next step behind it, and only then
+    is the first token waited for -- two ``.call`` spans with the step
+    between them, neither holding the other (a reader that adds up the
+    ``.call`` spans counts no moment twice)."""
+    from mxnet_tpu import chaos
+    eng = _engine()
+    try:
+        eng.submit([3, 7, 1], 2).tokens()   # every shape has run once
+
+        def work():
+            with chaos.scenario(seed=0):
+                chaos.on("serving.decode.step",
+                         action=lambda ctx: time.sleep(0.005))
+                first = eng.submit([3, 7, 1, 4], 12)
+                next(first)
+                next(first)     # a step's token: its successor is in flight
+                assert len(eng.submit([5, 5, 6], 3).tokens()) == 3
+                first.tokens()
+
+        spans = _traced(tmp_path, work)
+    finally:
+        eng.close(drain=False)
+    lone, beside = sorted(_named(spans, "mx.decode.prefill"),
+                          key=lambda s: s.start)
+    assert [c.name for c in _children(spans, lone)] == [
+        "mx.decode.prefill.build", "mx.decode.prefill.call",
+        "mx.decode.prefill.emit"]
+    kids = _children(spans, beside)
+    assert [c.name for c in kids] == [
+        "mx.decode.prefill.build", "mx.decode.prefill.call",
+        "mx.decode.step", "mx.decode.prefill.call",
+        "mx.decode.prefill.emit"]
+    step = kids[2]
+    assert (step.attrs["n"], step.attrs["overlapped"]) == (1, 1)
+    assert [c.name for c in _children(spans, step)] == [
+        "mx.decode.step.build", "mx.decode.step.call",
+        "mx.decode.step.emit"]
+    # the joiner steps from the turn after that one
+    later = [s for s in _named(spans, "mx.decode.step")
+             if s.start > beside.end]
+    assert later[0].attrs["n"] == 1 and later[1].attrs["n"] == 2
+
+
+def test_the_step_spans_and_counters_say_how_often_the_loop_overlaps():
+    """``overlapped`` on every ``mx.decode.step`` span and the counters
+    ``decode.steps_overlapped`` / ``decode.tokens_discarded`` beside
+    ``decode.steps``: two streams, one of which ends by EOS with the next
+    step dispatched."""
+    from mxnet_tpu import telemetry
+    params = MODEL.init_params(0)
+    ref = MODEL.reference_decode(params, [1, 2, 3, 4], 8)
+    eos = ref[1]                            # [4, 6, 6, ...]: ends at two
+    assert eos != ref[0]
+    telemetry.enable()
+    telemetry.reset("decode.")
+    obs.enable_tracing()
+    eng = _engine()
+    try:
+        long = eng.submit([3, 7, 1], 6)
+        short = eng.submit([1, 2, 3, 4], 8, eos_id=eos)
+        assert short.tokens() == ref[:2] and len(long.tokens()) == 6
+        eng.close(drain=True)
+        steps = sorted((s for s in obs.spans()
+                        if s["name"] == "mx.decode.step"),
+                       key=lambda s: s["t0"])
+        reg = telemetry.registry()
+        assert reg.counter("decode.steps").value == len(steps)
+        flags = [s["attrs"]["overlapped"] for s in steps]
+        assert set(flags) <= {0, 1} and flags[0] == 0
+        assert reg.counter("decode.steps_overlapped").value == sum(flags)
+        assert sum(flags) >= len(steps) - 2
+        # the step dispatched before the EOS reached the host carried the
+        # ended stream: one token nobody gets
+        assert reg.counter("decode.tokens_discarded").value == 1
+        assert reg.counter("decode.tokens").value \
+            == sum(s["attrs"]["n"] for s in steps) - 1 == (6 - 1) + (2 - 1)
+    finally:
+        eng.close(drain=False)
+        obs.disable_tracing()
+        telemetry.reset("decode.")
+        telemetry.disable()
 
 
 def test_with_no_session_and_tracing_off_a_site_makes_no_ring_call(

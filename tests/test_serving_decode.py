@@ -41,9 +41,10 @@ def ccache(tmp_path_factory):
 def make_engine(params, ccache):
     engines = []
 
-    def _make(**overrides):
+    def _make(weights=None, **overrides):
         kw = dict(ENGINE_KW, cache=ccache, **overrides)
-        eng = DecodeEngine(MODEL, params, **kw)
+        eng = DecodeEngine(MODEL, params if weights is None else weights,
+                           **kw)
         eng.warmup()
         eng.start()
         engines.append(eng)
@@ -621,17 +622,22 @@ def test_slabs_are_rebound_and_the_old_ones_deleted(make_engine, params):
 
 
 class _ConsumesThenFails:
-    """A compiled program that takes its donated slabs and then fails:
-    what a device error after dispatch looks like to the engine."""
+    """A compiled program that, from its ``from_call``-th call on, takes
+    its donated slabs and then fails: what a device error after dispatch
+    looks like to the engine (from the second call on: with the step
+    before it in flight)."""
 
-    def __init__(self, real):
+    def __init__(self, real, from_call=1):
         self.real = real
+        self.from_call = from_call
         self.calls = 0
 
     def __call__(self, *args):
         self.calls += 1
-        self.real(*args)
-        raise RuntimeError("device lost after the slabs were taken")
+        out = self.real(*args)
+        if self.calls >= self.from_call:
+            raise RuntimeError("device lost after the slabs were taken")
+        return out
 
 
 @pytest.mark.parametrize("kind", ["decode", "prefill"])
@@ -640,19 +646,23 @@ def test_a_call_that_fails_with_the_slabs_taken_ends_every_stream(
     eng = make_engine()
     programs = eng._programs._programs
     real = dict(programs)
+    # worked out before the stream starts: it must still be decoding
+    # when the programs are swapped
+    want_first = _reference(params, [3, 7, 1, 9, 2], 1)[0]
     with chaos.scenario(seed=0):
         chaos.on("serving.decode.step",
                  action=lambda ctx: time.sleep(0.02))
         running = eng.submit([3, 7, 1, 9, 2], 20)
         first = next(running)            # decoding from here on
-        assert first == _reference(params, [3, 7, 1, 9, 2], 1)[0]
+        assert first == want_first
         for key in real:
             if key[0] == kind:
                 programs[key] = _ConsumesThenFails(real[key])
         joiner = eng.submit([5, 5, 6], 10)
         for stream in (running, joiner):
-            if stream is joiner and kind == "decode":
-                next(stream)             # its prefill still worked
+            # (the joiner's prefill still works where the decode
+            # programs fail, but the step that runs behind it takes the
+            # cache before its first token is out)
             with pytest.raises(RuntimeError, match="device lost"):
                 list(stream)
             assert stream.finish_reason == "error"
@@ -779,6 +789,280 @@ def test_the_aliased_bytes_gauge_is_catalogued_and_set(params, counters):
     eng.warmup()
     assert counters.gauge("decode.kv_aliased_bytes").value \
         == eng.cache.slab_bytes()
+
+
+# ---------------------------------------------------------------------
+# one decode step in flight (ISSUE 31)
+# ---------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def lively():
+    """Weights under which a stream's tokens change from step to step
+    and differ between streams (``init_params(0)`` repeats one id), so
+    that a token taken from the wrong slot or the wrong step shows."""
+    return {name: a * 10 if name == "pos_embed"
+            else a * 6 if a.ndim == 2 and name != "embed" else a
+            for name, a in MODEL.init_params(2).items()}
+
+
+def _greedy(params, prompt, max_new, eos_id=None):
+    """The reference loop: ONE request, one full forward a token, no
+    cache, no batch, nothing in flight."""
+    import jax.numpy as jnp
+    seq, out = list(prompt), []
+    while len(out) < max_new:
+        logits = MODEL.full_logits(params, jnp.asarray([seq], jnp.int32))
+        out.append(int(jnp.argmax(logits[0, -1])))
+        seq.append(out[-1])
+        if out[-1] == eos_id:
+            break
+    return out
+
+
+def _serve_on_schedule(eng, first, plan):
+    """Submit ``first`` now and ``plan[k]`` (a list of (prompt, max_new,
+    eos_id)) from the engine's own thread at the fail point of its k-th
+    decode dispatch -- with the step before that one in flight, so the
+    admission arrives while a step runs.  Returns the streams in the
+    order submitted and the occupancy of every step dispatched."""
+    streams, occupancy = [], []
+
+    def at_dispatch(ctx):
+        occupancy.append(ctx["occupancy"])
+        for prompt, max_new, eos_id in plan.get(len(occupancy), ()):
+            streams.append(eng.submit(prompt, max_new, eos_id=eos_id))
+
+    with chaos.scenario(seed=0):
+        chaos.on("serving.decode.step", action=at_dispatch)
+        streams.append(eng.submit(*first[:2], eos_id=first[2]))
+        got = []
+        i = 0
+        while i < len(streams):          # the list grows as the plan runs
+            got.append(streams[i].tokens())
+            i += 1
+    return streams, got, occupancy
+
+
+_A, _B, _C, _D = [3, 7, 1, 9, 2], [5, 5, 6], [1, 2, 3, 4], [9, 8, 7]
+_SCHEDULES = {
+    # B joins while A's first step is in flight and ends after three
+    # tokens (2 -> 1); C and D join later (1 -> 2 -> 3)
+    "shrinks_to_one_and_grows_again": (
+        (_A, 14, None), {1: [(_B, 3, None)], 6: [(_C, 6, None)],
+                         7: [(_D, 5, None)]}),
+    # two arrive at one boundary, one of them for a single token (it
+    # never takes a slot of a step)
+    "two_at_one_boundary": (
+        (_B, 9, None), {2: [(_A, 1, None), (_C, 7, None)]}),
+    # the last request arrives at the running streams' last step: the
+    # batch runs empty and the loop starts again from nothing in flight
+    "runs_empty_and_starts_again": (
+        (_C, 8, None), {1: [(_D, 5, None)], 7: [(_A, 6, None)]}),
+    # more streams than the largest bucket: the fifth waits for a slot
+    "a_full_house": (
+        (_A, 10, None), {1: [(_B, 4, None), (_C, 8, None), (_D, 12, None),
+                             ([2, 4, 6, 8], 5, None)]}),
+}
+
+
+@pytest.mark.parametrize("schedule", sorted(_SCHEDULES))
+def test_tokens_are_the_reference_loops_under_any_schedule(
+        make_engine, lively, counters, schedule):
+    params = lively
+    first, plan = _SCHEDULES[schedule]
+    eng = make_engine(weights=params)
+    streams, got, occupancy = _serve_on_schedule(eng, first, plan)
+    asked = [first] + [r for k in sorted(plan) for r in plan[k]]
+    assert len(streams) == len(asked)
+    for (prompt, max_new, eos_id), tokens in zip(asked, got):
+        assert tokens == _greedy(params, prompt, max_new, eos_id)
+        assert len(set(tokens)) > 1 or max_new == 1      # lively
+    assert {s.finish_reason for s in streams} == {"length"}
+    assert 1 < max(occupancy) <= eng.max_slots
+    if schedule == "shrinks_to_one_and_grows_again":
+        assert [n for i, n in enumerate(occupancy)
+                if i == 0 or n != occupancy[i - 1]][:5] == [1, 2, 1, 2, 3]
+    # every step but the first after an empty moment was dispatched
+    # behind its predecessor, and greedy traffic wastes no slot-step
+    steps = counters.counter("decode.steps").value
+    assert steps == len(occupancy)
+    assert counters.counter("decode.steps_overlapped").value \
+        >= steps - 3
+    assert counters.counter("decode.tokens_discarded").value == 0
+    assert counters.counter("decode.tokens").value == sum(occupancy)
+    assert eng.cache.blocks_in_use() == 0
+
+
+@pytest.mark.parametrize("how", ["eos", "cancel"])
+def test_a_stream_that_ends_early_drops_the_token_in_flight(
+        make_engine, lively, counters, how):
+    """EOS and cancel are found one step late, with the next step
+    already dispatched: that one token is discarded, the stream holds
+    exactly the tokens up to its end, the others never notice."""
+    params = lively
+    eng = make_engine(weights=params)
+    want = _greedy(params, _A, 12)
+    ref_b = _greedy(params, _B, 10)
+    # B ends in the middle: at the first id it has not emitted before
+    k = next(i for i in range(2, 8) if ref_b[i] not in ref_b[:i])
+    eos = ref_b[k] if how == "eos" else None
+    seen = []
+    with chaos.scenario(seed=0):
+        chaos.on("serving.decode.step",
+                 action=lambda ctx: time.sleep(0.01))
+        a = eng.submit(_A, 12)
+        b = eng.submit(_B, 10, eos_id=eos)
+        for tok in b:
+            seen.append(tok)
+            if how == "cancel" and len(seen) == k + 1:
+                b.cancel()
+        rest = a.tokens()
+    assert rest == want
+    assert b.finish_reason == how
+    if how == "eos":
+        assert seen == ref_b[:k + 1] and seen[-1] == eos
+    else:
+        # the cancel lands at the next emit: a token or two more, never
+        # one that is not the reference's
+        assert k + 1 <= len(seen) <= k + 3 and seen == ref_b[:len(seen)]
+    assert counters.counter("decode.tokens_discarded").value == 1
+    assert counters.counter("decode.tokens").value \
+        == len(want) - 1 + len(seen) - 1
+    assert eng.cache.blocks_in_use() == 0
+
+
+def test_a_freed_table_is_reused_under_the_step_in_flight(make_engine,
+                                                          lively):
+    """A stream that ends by EOS frees its blocks while the step in
+    flight still writes its next row into one of them; the next
+    admission takes those very blocks (the pool has no others) and
+    reads none of that row."""
+    # 8 usable blocks of 4: A budgets 5 + 12 -> 5 blocks, B 3 + 9 -> 3
+    params = lively
+    eng = make_engine(weights=params, num_blocks=9)
+    ref_b = _greedy(params, _B, 9)
+    k = next(i for i in range(2, 8) if ref_b[i] not in ref_b[:i])
+    eos = ref_b[k]
+    taken = []
+    real_allocate = eng.cache.allocate
+
+    def allocate(n_tokens):
+        taken.append(real_allocate(n_tokens))
+        return taken[-1]
+
+    eng.cache.allocate = allocate
+    with chaos.scenario(seed=0):
+        chaos.on("serving.decode.step",
+                 action=lambda ctx: time.sleep(0.01))
+        a = eng.submit(_A, 12)
+        b = eng.submit(_B, 9, eos_id=eos)
+        assert b.tokens() == ref_b[:k + 1]
+        c = eng.submit(_D, 9)            # fits only where B was
+        assert c.tokens() == _greedy(params, _D, 9)
+        assert a.tokens() == _greedy(params, _A, 12)
+    assert set(taken[2].blocks) == set(taken[1].blocks)
+    assert eng.cache.blocks_in_use() == 0
+
+
+@pytest.mark.parametrize("drain", [True, False])
+def test_close_with_a_step_in_flight(make_engine, lively, drain):
+    params = lively
+    eng = make_engine(weights=params)
+    with chaos.scenario(seed=0):
+        chaos.on("serving.decode.step",
+                 action=lambda ctx: time.sleep(0.01))
+        streams = [eng.submit(_A, 12), eng.submit(_B, 7)]
+        firsts = [next(s) for s in streams]
+        assert eng.close(drain=drain) == 2
+        if drain:
+            # every token of every step, the one in flight included
+            assert [[f] + list(s) for f, s in zip(firsts, streams)] \
+                == [_greedy(params, _A, 12), _greedy(params, _B, 7)]
+            assert {s.finish_reason for s in streams} == {"length"}
+        else:
+            for s in streams:
+                with pytest.raises(ServableClosed):
+                    list(s)
+                assert s.finish_reason == "closed"
+    assert eng.cache.blocks_in_use() == 0
+    assert eng._flight is None
+
+
+@pytest.mark.parametrize("where", ["second_call", "fetch", "fail_point"])
+def test_a_failure_with_a_step_in_flight_loses_nothing_silently(
+        make_engine, lively, counters, monkeypatch, where):
+    """The error of step n arrives when step n+1 is dispatched.  With
+    the slabs taken (a call that raises, a fetch that raises) every live
+    stream ends with it, the step in flight is dropped and fresh slabs
+    serve the next request; a fail point fires before the call takes
+    anything, so the cache stays and only that step's streams end."""
+    import jax
+    params = lively
+    eng = make_engine(weights=params)
+    programs = eng._programs._programs
+    real = dict(programs)
+    resets = []
+    real_reset = eng.cache.reset_slabs
+    eng.cache.reset_slabs = lambda: (resets.append(1), real_reset())
+    with chaos.scenario(seed=0):
+        streams = [eng.submit(_A, 12), eng.submit(_B, 12)]
+        if where == "second_call":
+            for key in real:
+                if key[0] == "decode":
+                    programs[key] = _ConsumesThenFails(real[key],
+                                                       from_call=2)
+            error = RuntimeError
+        elif where == "fetch":
+            real_get, gets = jax.device_get, []
+
+            def device_get(x):
+                # prefills fetch a scalar token, steps a vector
+                if np.ndim(x[0]) == 1:
+                    gets.append(x)
+                    if len(gets) == 2:
+                        raise RuntimeError("device lost at the fetch")
+                return real_get(x)
+
+            monkeypatch.setattr(jax, "device_get", device_get)
+            error = RuntimeError
+        else:
+            # the third dispatch: its predecessor is in flight
+            chaos.on("serving.decode.step", action=chaos.RAISE, nth=3)
+            error = chaos.ChaosInjected
+        for s in streams:
+            with pytest.raises(error):
+                list(s)
+            assert s.finish_reason == "error"
+    monkeypatch.undo()
+    programs.update(real)
+    assert eng.cache.blocks_in_use() == 0
+    assert eng.active_sequences() == 0 and eng._flight is None
+    assert not eng.cache.slabs_deleted()
+    assert counters.counter("serving.errors").value >= 1
+    for prompt in (_A, [1]):
+        assert eng.submit(prompt, 8).tokens() == _greedy(params, prompt, 8)
+    # a fail point leaves the cache it found: the next request ran on it
+    assert len(resets) == (0 if where == "fail_point" else 1)
+    assert eng.cache.blocks_in_use() == 0
+
+
+def test_the_engine_compiles_one_program_a_bucket(lively):
+    params = lively
+    eng = DecodeEngine(MODEL, params, **ENGINE_KW)
+    eng.warmup()
+    built = set(eng._programs._programs)
+    assert built == {("prefill", b) for b in eng.prefill_buckets} \
+        | {("decode", b) for b in eng.decode_buckets}
+    eng.start()
+    try:
+        streams = [eng.submit(p, 6) for p in (_A, _B, _C)]
+        assert [s.tokens() for s in streams] \
+            == [_greedy(params, p, 6) for p in (_A, _B, _C)]
+    finally:
+        eng.close(drain=False)
+    # every bucket's step takes any bucket's tokens: nothing was built
+    # for a pair of buckets
+    assert set(eng._programs._programs) == built
 
 
 # ---------------------------------------------------------------------

@@ -420,6 +420,44 @@ def test_the_engine_counts_expert_assignments(engine, params):
         telemetry.disable()
 
 
+def test_an_eos_in_the_middle_of_a_batch_ends_that_stream_alone(
+        engine, params):
+    """The second model through the loop that keeps a step in flight:
+    the stream that hits its EOS holds exactly the tokens up to it, the
+    token the next step computed for it is discarded, and each step's
+    expert counts are on ONE span and in the counters once."""
+    prompts = [[3, 14, 15, 92, 65, 35], [27, 18, 28]]
+    want = [_greedy(MODEL, params, p, 9) for p in prompts]
+    k = next(i for i in range(1, 8) if want[1][i] not in want[1][:i])
+    telemetry.enable()
+    telemetry.reset("decode.")
+    obs.trace.clear()
+    obs.enable_tracing()
+    try:
+        first = engine.submit(prompts[0], 9)
+        other = engine.submit(prompts[1], 9, eos_id=want[1][k])
+        assert other.tokens() == want[1][:k + 1]
+        assert other.finish_reason == "eos"
+        assert first.tokens() == want[0]
+        reg = telemetry.registry()
+        assert reg.counter("decode.tokens_discarded").value == 1
+        steps = [s for s in obs.spans() if s["name"] == "mx.decode.step"]
+        assert reg.counter("decode.steps").value == len(steps)
+        # two experts a token, two expert layers: a step's count is its
+        # live slots' alone (the discarded token's slot was live in it)
+        for s in steps:
+            assert s["attrs"]["moe_assignments"] == s["attrs"]["n"] * 2 * 2
+        prefills = [s for s in obs.spans()
+                    if s["name"] == "mx.decode.prefill"]
+        assert reg.counter("decode.moe.assignments").value == sum(
+            s["attrs"]["moe_assignments"] for s in steps + prefills)
+    finally:
+        obs.disable_tracing()
+        telemetry.reset("decode.")
+        telemetry.disable()
+    assert engine.cache.blocks_in_use() == 0
+
+
 def test_a_dense_model_returns_no_counts(params):
     import jax.numpy as jnp
     gpt = TinyGPT(vocab_size=64, units=32, num_layers=1, num_heads=2,
