@@ -52,7 +52,10 @@ FULL = {
                # Kimi-K2's latent row: 512 + 64 values in 640 lanes
                "latent": {"slots": 16, "heads": 64, "lanes": 640,
                           "v_width": 512, "blocks": 1025, "block": 64,
-                          "table": 64}},
+                          "table": 64},
+               # Mellum2's expert layer: a chunk of a prefill's sorted rows
+               "grouped": {"rows": 2048, "groups": 64, "k": 2304,
+                           "n": 896}},
     "dp": {"chips": 4, "batch": 128, "steps": 3},
 }
 TINY = {
@@ -68,7 +71,8 @@ TINY = {
                          "block": 4, "table": 5},
                "latent": {"slots": 2, "heads": 2, "lanes": 128,
                           "v_width": 16, "blocks": 12, "block": 4,
-                          "table": 4}},
+                          "table": 4},
+               "grouped": {"rows": 40, "groups": 64, "k": 32, "n": 16}},
     "dp": {"chips": 4, "batch": 8, "steps": 3},
 }
 
@@ -196,6 +200,7 @@ def phase_census(smoke, cfg):
     the kernels the way the repo's tests do (``force=True`` /
     ``use_pallas=True``: the kernel bodies run in interpret mode)."""
     from mxnet_tpu import kernels
+    from mxnet_tpu.kernels.grouped_matmul import grouped_matmul
     from mxnet_tpu.kernels.mla_paged_attention import mla_paged_attention
     from mxnet_tpu.kernels.paged_attention import paged_attention
     from mxnet_tpu.ops.pallas.flash_attention import (
@@ -285,6 +290,23 @@ def phase_census(smoke, cfg):
         record("mla_paged_attention", ch, "decode", rel_err(out, ref))
     else:
         record("mla_paged_attention", ch, None)
+
+    # grouped matmul: sorted rows, some groups empty, rows past the last
+    gr = cfg["grouped"]
+    lhs = jnp.asarray(rng.randn(gr["rows"], gr["k"]), jnp.bfloat16)
+    rhs = jnp.asarray(rng.randn(gr["groups"], gr["k"], gr["n"]),
+                      jnp.bfloat16)
+    sizes = rng.multinomial(gr["rows"] * 7 // 8,
+                            rng.dirichlet(np.ones(gr["groups"]) * 0.5))
+    sizes = jnp.asarray(sizes, jnp.int32)
+    ch = kernels.choose("grouped_matmul", force=force,
+                        groups=gr["groups"], k=gr["k"], n=gr["n"])
+    if ch.use_pallas:
+        out = grouped_matmul(lhs, rhs, sizes, use_pallas=force)
+        ref = kernels.get("grouped_matmul").xla_ref(lhs, rhs, sizes)
+        record("grouped_matmul", ch, "prefill chunk", rel_err(out, ref))
+    else:
+        record("grouped_matmul", ch, None)
     return {"kernels": rows}
 
 

@@ -1256,7 +1256,8 @@ for name, kw in (("flash_attention",
                   dict(heads=16, head_dim=64, block_size=16)),
                  ("mla_paged_attention",
                   dict(heads=64, lanes=640, v_width=512,
-                       block_size=64))):
+                       block_size=64)),
+                 ("grouped_matmul", dict(groups=64, k=2304, n=896))):
     ch = kernels.choose(name, force=True, **kw)
     assert not ch.use_pallas, (name, ch)
     assert "unavailable" in ch.reason, ch.reason
@@ -1270,7 +1271,7 @@ out = paged_attention(q, kc, vc, bt, cl, scale=0.25, use_pallas=True)
 ref = kernels.get("paged_attention").xla_ref(q, kc, vc, bt, cl,
                                              scale=0.25)
 np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
-print("fallback proof ok: 3 kernels decline, wrapper == XLA reference")
+print("fallback proof ok: 4 kernels decline, wrapper == XLA reference")
 EOF
 }
 

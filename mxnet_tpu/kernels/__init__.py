@@ -1,7 +1,7 @@
 """``mxnet_tpu.kernels`` -- the Pallas custom-kernel tier.
 
 A registry of hand-written Pallas TPU kernels, each with its XLA
-reference (docs/kernels.md).  Three kernels ship through it, the three
+reference (docs/kernels.md).  Four kernels ship through it, the four
 the benchmark's device traces name:
 
 - ``flash_attention``: the blockwise online-softmax attention kernels,
@@ -14,6 +14,10 @@ the benchmark's device traces name:
 - ``mla_paged_attention``: the same walk over a paged cache of latent
   (MLA) rows, which are keys and values at once
   (``ops/pallas/mla_paged_attention.py``).
+- ``grouped_matmul``: rows sorted by group times each group's own
+  matrix, the expert matmul of a routed layer's prefill
+  (``ops/pallas/grouped_matmul.py``, JAX's ``megablox`` kernel);
+  the XLA reference is ``jax.lax.ragged_dot``.
 
 Selection (``registry.choose``) is a function of the backend, the
 call's shape and the caller's ``force`` / ``use_pallas`` argument:

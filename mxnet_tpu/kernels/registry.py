@@ -88,8 +88,8 @@ def register_kernel(spec: KernelSpec) -> KernelSpec:
 def _ensure_registered():
     # importing the kernel modules registers their specs; lazy so that
     # `import mxnet_tpu` does not pull pallas machinery upfront
-    from . import (flash_attention, mla_paged_attention,  # noqa: F401
-                   paged_attention)
+    from . import (flash_attention, grouped_matmul,  # noqa: F401
+                   mla_paged_attention, paged_attention)
 
 
 def get(name: str) -> KernelSpec:

@@ -1,5 +1,5 @@
-"""Hand-written Pallas TPU kernel bodies (flash attention, paged and
-latent paged decode attention).
+"""Pallas TPU kernel bodies (flash attention, paged and latent paged
+decode attention, the grouped matmul of a routed expert layer).
 
 Selection/fallback policy lives in ``mxnet_tpu.kernels`` (the kernel
 registry, docs/kernels.md); these modules hold only the kernels.

@@ -118,31 +118,50 @@ def moe_load_balancing_loss(x, gate_w):
 # routed experts of an expert-parallel deployment (DeepSeek-V3 style)
 # ----------------------------------------------------------------------
 
-def route_top_k(x, gate_w, selection_bias, top_k, scale=1.0):
-    """The router of a DeepSeek-V3-style expert layer over ALL experts.
+def route_top_k(x, gate_w, selection_bias, top_k, scale=1.0,
+                scoring="sigmoid", normalize=True):
+    """The router of an expert layer over ALL experts.
 
-    ``x`` (tokens, d); ``gate_w`` (d, experts); ``selection_bias``
-    (experts,), added to the scores for the choice only
+    ``x`` (tokens, d); ``gate_w`` (d, experts); ``scoring`` how a logit
+    becomes a score: ``"sigmoid"`` (DeepSeek-V3) or ``"softmax"`` over
+    all the experts (Mixtral and its descendants); ``selection_bias``
+    (experts,) or None, added to the scores for the choice only
     (``e_score_correction_bias``).  Returns ``(chosen (tokens, top_k)
-    int32, weights (tokens, top_k) float32)``: ``scores = sigmoid(x
+    int32, weights (tokens, top_k) float32)``: ``scores = scoring(x
     gate_w)``, ``chosen = top_k(scores + bias)``, ``weights =
-    scores[chosen] / (sum + 1e-20) * scale``.  float32 throughout and at
-    matmul precision ``highest``: the choice is discontinuous, so a
-    score is not to differ from another implementation's by more than
-    float32 rounding.
+    scores[chosen] * scale``, divided first by their sum (+ 1e-20) where
+    ``normalize``.  float32 throughout and at matmul precision
+    ``highest``: the choice is discontinuous, so a score is not to
+    differ from another implementation's by more than float32 rounding.
     """
-    scores = jax.nn.sigmoid(jnp.dot(
-        x.astype(jnp.float32), gate_w.astype(jnp.float32),
-        precision=jax.lax.Precision.HIGHEST))
-    _, chosen = jax.lax.top_k(scores + selection_bias.astype(jnp.float32),
-                              int(top_k))
-    picked = jnp.take_along_axis(scores, chosen, axis=-1)
-    weights = picked / (jnp.sum(picked, axis=-1, keepdims=True) + 1e-20)
+    if scoring not in ("sigmoid", "softmax"):
+        raise MXNetError("route_top_k: scoring is 'sigmoid' or 'softmax', "
+                         "got %r" % (scoring,))
+    logits = jnp.dot(x.astype(jnp.float32), gate_w.astype(jnp.float32),
+                     precision=jax.lax.Precision.HIGHEST)
+    scores = jax.nn.sigmoid(logits) if scoring == "sigmoid" \
+        else jax.nn.softmax(logits, axis=-1)
+    biased = scores if selection_bias is None \
+        else scores + selection_bias.astype(jnp.float32)
+    _, chosen = jax.lax.top_k(biased, int(top_k))
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    if normalize:
+        weights = weights / (jnp.sum(weights, axis=-1, keepdims=True)
+                             + 1e-20)
     return chosen.astype(jnp.int32), weights * scale
 
 
+# a decode step of at most this many tokens may take every held expert
+# over every token (``routed_experts``, ``num_experts=``).  The chip has
+# run that route at 16 and at 32 tokens, the decode buckets of the one
+# deployment that takes it (PERF.md section 6, PR 32), and at no more:
+# the bound is what was measured, not where the roofline would put it
+DENSE_TOKENS = 32
+
+
 def routed_experts(x, chosen, weights, w_gate, w_up, w_down, first_expert,
-                   live=None, chunk_rows=2048):
+                   live=None, chunk_rows=2048, num_experts=None,
+                   use_pallas=None):
     """The part of an expert layer's output that THIS chip's experts
     give, for an expert layer that is told which experts it holds.
 
@@ -160,11 +179,32 @@ def routed_experts(x, chosen, weights, w_gate, w_up, w_down, first_expert,
 
     No capacity and no dropped token: the assignments that fall on held
     experts are sorted by expert and go through a grouped matmul
-    (``jax.lax.ragged_dot``) ``chunk_rows`` sorted rows at a time, in a
-    loop that runs as many chunks as there ARE such assignments -- one
-    for a decode step, ``tokens * top_k / chunk_rows`` if every token
-    chose only experts held here.
+    (``kernels.grouped_matmul``: ``jax.lax.ragged_dot``, or on a TPU and
+    for a layer of many experts the Pallas kernel; ``use_pallas`` is the
+    caller's tri-state as everywhere in the kernel tier) ``chunk_rows``
+    sorted rows at a time, in a loop that runs as many chunks as there
+    ARE such assignments -- one for a decode step, ``tokens * top_k /
+    chunk_rows`` if every token chose only experts held here.
+
+    **A decode step that keeps every expert busy** may take another
+    route to the same sum.  A caller whose tokens are one decode step's,
+    a slot each, says so by giving ``num_experts``, the width of the
+    router (all experts, held or not); a call without it, and every
+    prefill, is the grouped matmul above.  Where such a step has at most
+    ``DENSE_TOKENS`` tokens and the router sends an expert a token or
+    more on average (``tokens * top_k >= num_experts``), every held
+    expert runs over every token as one batched matmul and a token's row
+    is weighed in float32 by its router weight, zero where it did not
+    choose the expert.  A token chooses an expert at most once, so that
+    is exact with nothing dropped, it reads each expert's weights once
+    as the grouped matmul must, and it has no sort, gather or group
+    loop: 64 whole experts at 4 tokens each took the grouped matmul
+    1.55 ms a matmul where their 264 MB are 0.32 ms of the chip's
+    bandwidth (my chip runs, PR 32).  A decode step of a deployment that
+    holds a few of many experts (12 of 384 at 0.7 tokens each) stays on
+    the grouped matmul, which reads the experts that were chosen alone.
     """
+    from ..kernels.grouped_matmul import grouped_matmul
     n_held, d, _f = w_gate.shape
     tokens, top_k = chosen.shape
     n_assign = tokens * top_k
@@ -172,6 +212,10 @@ def routed_experts(x, chosen, weights, w_gate, w_up, w_down, first_expert,
     held = (local >= 0) & (local < n_held)
     if live is not None:
         held = held & live[:, None]
+    if num_experts is not None and tokens <= DENSE_TOKENS \
+            and n_assign >= num_experts:
+        return _every_expert_over_every_token(
+            x, jnp.where(held, local, n_held), weights, w_gate, w_up, w_down)
     # an assignment's expert here, n_held for one that is not ours
     expert = jnp.where(held, local, n_held).reshape(n_assign)
     # a compare-and-sum, not a scatter-add: every update of a scatter
@@ -196,13 +240,10 @@ def routed_experts(x, chosen, weights, w_gate, w_up, w_down, first_expert,
         sizes = jnp.clip(ends - at, 0, chunk) - jnp.clip(starts - at, 0,
                                                           chunk)
         xs = jnp.take(x, token, axis=0)
-        gate = jax.lax.ragged_dot(xs, w_gate, sizes,
-                                  preferred_element_type=jnp.float32)
-        up = jax.lax.ragged_dot(xs, w_up, sizes,
-                                preferred_element_type=jnp.float32)
+        gate = grouped_matmul(xs, w_gate, sizes, use_pallas)
+        up = grouped_matmul(xs, w_up, sizes, use_pallas)
         hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
-        out = jax.lax.ragged_dot(hidden, w_down, sizes,
-                                 preferred_element_type=jnp.float32)
+        out = grouped_matmul(hidden, w_down, sizes, use_pallas)
         # rows past the last of ours belong to no group
         out = jnp.where(ours[:, None],
                         out * jnp.take(flat_w, rows)[:, None], 0.0)
@@ -211,3 +252,24 @@ def routed_experts(x, chosen, weights, w_gate, w_up, w_down, first_expert,
     y = jax.lax.fori_loop(0, -(-total // chunk), one_chunk,
                           jnp.zeros((tokens, d), jnp.float32))
     return y, counts
+
+
+def _every_expert_over_every_token(x, expert, weights, w_gate, w_up, w_down):
+    """``routed_experts`` for a decode step that keeps every expert busy
+    (its doc): ``expert`` (tokens, top_k) is each assignment's expert
+    here, ``n_held`` for one that is not ours."""
+    n_held = w_gate.shape[0]
+    ours = expert[:, :, None] == jnp.arange(n_held, dtype=jnp.int32)
+    # a token's weight for each held expert, zero where it did not choose it
+    weight = jnp.sum(jnp.where(ours, weights[:, :, None], 0.0), axis=1)
+    gate = jnp.einsum("td,edf->etf", x, w_gate,
+                      preferred_element_type=jnp.float32)
+    up = jnp.einsum("td,edf->etf", x, w_up,
+                    preferred_element_type=jnp.float32)
+    hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+    out = jnp.einsum("etf,efd->etd", hidden, w_down,
+                     preferred_element_type=jnp.float32)
+    # float32 times float32, as the grouped matmul weighs its rows: a
+    # matmul at the default precision may round both to bfloat16 first
+    y = jnp.sum(out * weight.T[:, :, None], axis=0)
+    return y, jnp.sum(ours, axis=(0, 1), dtype=jnp.int32)

@@ -29,6 +29,13 @@ not per-request.  This package is that tier:
 - :class:`~.latent_moe.LatentMoEDecoder` -- a DeepSeek-V3-style decoder
   (latent attention over ONE cache row a token, rotary positions, RMS
   norm, routed experts told which experts they hold) in the same form.
+- :class:`~.window_moe.WindowMoEDecoder` -- grouped-query attention in
+  sliding-window and full layers (a bounded ring of cache blocks a
+  sequence in the window layers, a whole table in the full ones, side by
+  side in the one cache), two rotary tables by layer type, a softmax
+  top-k router over experts that are all held here.  What the two
+  share (RMS norm, rotary, SwiGLU, blocked prefill attention, the routed
+  FFN with its counts) is :mod:`~.blocks`.
 
 A model declares what a token keeps in the cache (``cache_rows()``) and
 the engine builds the one ``PagedKVCache`` from that.  The decode-step
@@ -44,8 +51,9 @@ from .engine import (DecodeEngine, GenerationStream, GenerativeServable,
 from .kvcache import BlockTable, KVCacheExhausted, PagedKVCache
 from .latent_moe import LatentMoEDecoder
 from .model import TinyGPT, tiny_gpt
+from .window_moe import WindowMoEDecoder
 
 __all__ = ["BlockTable", "DecodeEngine", "GenerationStream",
            "GenerativeServable", "GenerativeWatcher",
            "KVCacheExhausted", "LatentMoEDecoder", "PagedKVCache",
-           "TinyGPT", "tiny_gpt"]
+           "TinyGPT", "WindowMoEDecoder", "tiny_gpt"]

@@ -70,8 +70,8 @@ from ...base import MXNetError, scopes_in_cache_key
 from ..batcher import RequestTimeout, ServableClosed, ServingQueueFull
 from ..cache import compile_through, stablehlo_fingerprint
 from ..loop import RegistryWatcher as _RegistryWatcher
-from .kvcache import (SCRATCH_BLOCK, KVCacheExhausted, PagedKVCache,
-                      slab_rows)
+from .kvcache import (FULL, SCRATCH_BLOCK, WINDOW, KVCacheExhausted,
+                      PagedKVCache, write_prompt)
 
 __all__ = ["DecodeEngine", "GenerationStream", "GenerativeServable",
            "GenerativeWatcher"]
@@ -289,6 +289,8 @@ class DecodeEngine:
     decode_buckets : slot-count buckets (each compiles one decode-step
         executable); the largest is the concurrent-sequence bound
     block_size / num_blocks : :class:`~.kvcache.PagedKVCache` geometry
+    window_blocks : blocks in a window layer's slab, for a model that
+        declares such layers (``cache_layers()``, ``sliding_window``)
     max_queue : pending-request bound past which submits shed
     cache : :class:`~mxnet_tpu.serving.cache.CompileCache` or None
     """
@@ -296,7 +298,7 @@ class DecodeEngine:
     def __init__(self, model, params, prefill_buckets=None,
                  decode_buckets=None, block_size=None, num_blocks=None,
                  max_queue=None, cache=None, label="generative",
-                 kv_dtype="float32"):
+                 kv_dtype="float32", window_blocks=None):
         from ... import env as _env
         self.model = model
         self.params = params
@@ -329,12 +331,21 @@ class DecodeEngine:
                          else _env.get("MXNET_TPU_SERVING_KV_BLOCK"))
         num_blocks = int(num_blocks if num_blocks is not None
                          else _env.get("MXNET_TPU_SERVING_KV_BLOCKS"))
-        # the model declares what a token keeps in a layer
-        self.cache = PagedKVCache(model.num_layers, model.cache_rows(),
-                                  block_size, num_blocks, dtype=kv_dtype)
-        # fixed compiled block-table width: enough for the longest
-        # sequence the model can hold
-        self.max_blocks_per_seq = self.cache.blocks_for(model.max_seq)
+        # the model declares what a token keeps in a layer, and (a
+        # model with window layers) which layer is of which kind
+        kinds = model.cache_layers() \
+            if hasattr(model, "cache_layers") else None
+        self.cache = PagedKVCache(
+            model.num_layers, model.cache_rows(), block_size, num_blocks,
+            dtype=kv_dtype, kinds=kinds,
+            window=getattr(model, "sliding_window", None),
+            window_blocks=window_blocks,
+            fold_heads=getattr(model, "cache_fold_heads", False))
+        # fixed compiled block-table widths: a full layer's enough for
+        # the longest sequence the model can hold, a window layer's the
+        # ring
+        self._table_widths = self.cache.blocks_needed(model.max_seq)
+        self.max_blocks_per_seq = self._table_widths[FULL]
         self.max_queue = int(max_queue if max_queue is not None
                              else _env.get("MXNET_TPU_SERVING_QUEUE"))
         self.max_slots = self.decode_buckets[-1]
@@ -367,16 +378,16 @@ class DecodeEngine:
         bs = self.cache.block_size
         logits, rows, stats = self.model.prefill_kv(params, tokens,
                                                     true_len - 1)
-        lb = tokens.shape[1]
         with jax.named_scope("mx.kv_scatter"):
-            pos = jnp.arange(lb, dtype=jnp.int32)
-            blk = jnp.where(pos < true_len,
-                            jnp.take(table, pos // bs), SCRATCH_BLOCK)
-            off = pos % bs
-            # each layer's prompt rows into that layer's own slab
+            # each layer's prompt rows into that layer's own slab,
+            # through the table of the layer's kind; a window layer
+            # keeps what its ring holds at the prompt's end
+            tables = table if isinstance(table, dict) else {FULL: table}
             slabs = {name: tuple(
-                slab.at[blk, off].set(slab_rows(r, slab))
-                for slab, r in zip(layers, rows[name]))
+                write_prompt(slab, r, tables[kind], true_len, bs,
+                             ring=kind == WINDOW)
+                for slab, r, kind in zip(layers, rows[name],
+                                         self.cache.kinds))
                 for name, layers in slabs.items()}
         with jax.named_scope("mx.lm_head"):
             first_token = jnp.argmax(logits).astype(jnp.int32)
@@ -410,11 +421,16 @@ class DecodeEngine:
             self.cache.slabs)
         pspec = {n: jax.ShapeDtypeStruct(v.shape, v.dtype)
                  for n, v in self.params.items()}
-        mb = self.max_blocks_per_seq
+
+        def tables(rows=None):
+            return jax.tree_util.tree_map(
+                lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype),
+                self._tables((), rows))
+
         prefill = {
             b: (pspec, kv,
                 jax.ShapeDtypeStruct((1, b), i32),
-                jax.ShapeDtypeStruct((mb,), i32),
+                tables(),
                 jax.ShapeDtypeStruct((), i32))
             for b in self.prefill_buckets}
         decode = {
@@ -422,10 +438,25 @@ class DecodeEngine:
                 jax.ShapeDtypeStruct((self.max_slots,), i32),
                 jax.ShapeDtypeStruct((s,), i32),
                 jax.ShapeDtypeStruct((s,), i32),
-                jax.ShapeDtypeStruct((s, mb), i32),
+                tables(s),
                 jax.ShapeDtypeStruct((s,), np.bool_))
             for s in self.decode_buckets}
         return prefill, decode
+
+    def _tables(self, reqs, rows=None):
+        """The block tables of ``reqs`` at the compiled widths, padded
+        with the scratch block to ``rows`` rows (None: ONE request's
+        table, without the slot axis): an int32 array, or for a model
+        with window layers one a kind of layer, ``{"full": ...,
+        "window": ...}`` (the ring)."""
+        out = {}
+        for kind, width in self._table_widths.items():
+            arr = np.full((1 if rows is None else rows, width),
+                          SCRATCH_BLOCK, np.int32)
+            for i, req in enumerate(reqs):
+                arr[i] = self.cache.padded_table(req.table, width, kind)
+            out[kind] = arr[0] if rows is None else arr
+        return out if len(out) > 1 else out[FULL]
 
     def warmup(self):
         """Compile every prefill and decode bucket (compile-cache
@@ -609,8 +640,7 @@ class DecodeEngine:
         with _obs.span("mx.decode.prefill.build"):
             tokens = np.zeros((1, bucket), np.int32)
             tokens[0, :len(req.prompt)] = req.prompt
-            table = self.cache.padded_table(req.table,
-                                            self.max_blocks_per_seq)
+            table = self._tables((req,))
         t0 = time.perf_counter()
         call = self._programs.get(("prefill", bucket))
         cache = self.cache
@@ -740,8 +770,7 @@ class DecodeEngine:
         bucket = self._bucket(self.decode_buckets, len(batch), "decode")
         tokens = np.zeros((bucket,), np.int32)
         positions = np.zeros((bucket,), np.int32)
-        tables = np.full((bucket, self.max_blocks_per_seq),
-                         SCRATCH_BLOCK, np.int32)
+        tables = self._tables(batch, bucket)
         # which slots of the bucket hold a sequence: the rest is
         # padding, and a model that counts its tokens leaves it out
         live = np.arange(bucket) < len(batch)
@@ -750,8 +779,6 @@ class DecodeEngine:
             tokens[i] = req.last_token if req.dispatched == req.generated \
                 else -1 - req.slot
             positions[i] = req.position
-            tables[i] = self.cache.padded_table(
-                req.table, self.max_blocks_per_seq)
         return bucket, (tokens, positions, tables, live)
 
     def _dispatch(self, batch, built, behind=None):
@@ -775,15 +802,19 @@ class DecodeEngine:
     def _note_stats(self, span, stats):
         """The counts a program returned beside its token (a model with
         routed experts: ``moe_assignments``, ``moe_assignments_held``,
-        ``moe_expert_tokens_max``; empty for a dense one): onto the
-        step's or the prefill's span and the ``decode.moe.*``
-        counters."""
+        ``moe_expert_tokens_max``; one with window layers: ``kv_rows_full``,
+        ``kv_rows_window``; empty for a dense one of full layers): onto
+        the step's or the prefill's span and the ``decode.moe.*`` /
+        ``decode.kv.*`` counters."""
         if not stats:
             return
         stats = {k: int(v) for k, v in stats.items()}
         span.set(**stats)
         if _telemetry._ENABLED:
-            _telemetry.hooks.decode_moe(self._label, stats)
+            if "moe_assignments" in stats:
+                _telemetry.hooks.decode_moe(self._label, stats)
+            if "kv_rows_full" in stats:
+                _telemetry.hooks.decode_kv_rows(self._label, stats)
 
     def _call_failed(self, error, served, dispatched):
         """A prefill or decode call raised.  Before the call took its

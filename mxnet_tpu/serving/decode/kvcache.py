@@ -48,6 +48,34 @@ from JAX's persistent compilation cache hands its outputs back tagged
 with the default layout and the next call refuses them; my chip runs,
 PR 26.)  On a backend without tiles the padding is real memory.
 
+**Two kinds of layer** may live side by side in ONE manager.  The model
+says which layer is of which kind (``cache_layers()``: ``"full"`` or
+``"window"`` a layer; a model without the method has full layers only).
+A full layer's table covers the request's whole budget, as above.  A
+WINDOW layer attends over its last ``window`` positions only, so a
+sequence keeps a **bounded ring** there: ``ring = ceil(window /
+block_size) + 1`` blocks whatever its length (fewer for a request
+shorter than that), position ``p`` living in ring entry ``(p //
+block_size) % ring`` -- the entry that held position ``p - ring *
+block_size``, which no query can see any more.  The ring's blocks come
+from a pool of their own (``window_blocks`` blocks in every window
+layer's slabs, block 0 the scratch block there too): one
+:class:`BlockTable` holds both lists, and :meth:`allocate` takes from
+both pools or from neither.  A reader of a window layer's slab may
+assume nothing of a ring entry but this: after the write of position
+``p`` the entry ``(p // block_size) % ring`` holds positions ``(p //
+block_size) * block_size .. p`` at their offsets, and the ``ring - 1``
+entries before it (cyclically) the ``ring - 1`` blocks before that one;
+rows past ``p`` in its entry are stale.
+
+**Folded heads**: a ``(heads, width)`` row lies ``(num_blocks,
+block_size, heads, lanes)`` by default.  A bfloat16 array's tiles are
+(16, 128), so 4 K/V heads in the second-minor dimension would be padded
+to 16 on the chip; with ``fold_heads`` the slab is declared
+``(num_blocks, block_size * heads, lanes)`` -- the same memory order,
+(token, head) rows, and whole tiles.  :func:`write_tokens` and
+:func:`write_prompt` write either layout.
+
 Admission-time sizing is the backpressure contract: a request's whole
 budget -- ``prompt_len + max_new_tokens`` -- is allocated **at
 admission** and the allocator raises :class:`KVCacheExhausted` when the
@@ -63,8 +91,9 @@ padded prefill positions route their writes there (a compiled program
 always writes *somewhere*), so it is never handed to a request and its
 contents are garbage by design.
 
-Telemetry: ``kvcache.blocks_in_use`` / ``kvcache.fragmentation``
-gauges, ``kvcache.allocs`` / ``kvcache.frees`` /
+Telemetry: ``kvcache.blocks_in_use`` (all pools; with two kinds of
+layer also ``kvcache.blocks_in_use.full`` / ``.window``) /
+``kvcache.fragmentation`` gauges, ``kvcache.allocs`` / ``kvcache.frees`` /
 ``kvcache.alloc_failures`` counters.
 """
 from __future__ import annotations
@@ -74,7 +103,11 @@ from ... import telemetry as _telemetry
 from ...base import MXNetError
 
 __all__ = ["PagedKVCache", "BlockTable", "KVCacheExhausted",
-           "SCRATCH_BLOCK", "slab_rows", "lanes_for"]
+           "SCRATCH_BLOCK", "FULL", "WINDOW", "slab_rows", "lanes_for",
+           "write_tokens", "write_prompt"]
+
+# the kinds of layer a model may declare (``cache_layers()``)
+FULL, WINDOW = "full", "window"
 
 # a TPU's lane count: the minor dimension of its (8, 128) memory tiles
 LANE_TILE = 128
@@ -104,6 +137,71 @@ def slab_rows(rows, slab):
     return rows
 
 
+def _entries(table, blocks):
+    """The table's entry for each logical block: a table wide enough
+    for every block is a ring that never wraps."""
+    import jax.numpy as jnp
+    return jnp.take(table, blocks % table.shape[-1], axis=-1)
+
+
+def write_tokens(slab, rows, table, positions, block_size):
+    """One new row a slot into ``slab``: ``rows`` (slots, ..., width) at
+    ``positions`` (slots,) through ``table`` (slots, width of the
+    table), read as a ring (module doc).  ``slab`` (num_blocks,
+    block_size, ..., lanes) or, heads folded, (num_blocks, block_size *
+    heads, lanes).  The padded slots of a bucket all write (scratch
+    block, offset 0): the scatter's indices are not unique and nothing
+    is promised to XLA about them."""
+    import jax.numpy as jnp
+    blk = jnp.take_along_axis(
+        table, ((positions // block_size) % table.shape[1])[:, None],
+        axis=1)[:, 0]
+    off = positions % block_size
+    rows = slab_rows(rows, slab)
+    if slab.ndim == rows.ndim + 1:
+        return slab.at[blk, off].set(rows)
+    heads = rows.shape[1]                           # folded
+    return slab.at[blk[:, None], off[:, None] * heads
+                   + jnp.arange(heads, dtype=off.dtype)].set(rows)
+
+
+def write_prompt(slab, rows, table, true_len, block_size, ring=False):
+    """A prompt's rows into ``slab``: ``rows`` (t, ..., width), of which
+    the first ``true_len`` are the prompt's, through ``table`` (width of
+    the table,).  ``ring``: only the rows the ring still holds at the
+    prompt's end are written (the last ``table width`` blocks; a window
+    layer's).  Padding goes to the scratch block."""
+    import jax
+    import jax.numpy as jnp
+    t = rows.shape[0]
+    rows = slab_rows(rows, slab)
+    if slab.ndim == rows.ndim + 1:
+        pos = jnp.arange(t, dtype=jnp.int32)
+        kept = pos < true_len
+        if ring:
+            kept &= pos // block_size \
+                > (true_len - 1) // block_size - table.shape[0]
+        blocks = pos // block_size
+        blk = jnp.where(kept, _entries(table, blocks) if ring
+                        else jnp.take(table, blocks), SCRATCH_BLOCK)
+        return slab.at[blk, pos % block_size].set(rows)
+    # folded heads: whole blocks of (token, head) rows, one update each
+    n_blocks = -(-t // block_size)
+    rows = jnp.pad(rows, [(0, n_blocks * block_size - t)]
+                   + [(0, 0)] * (rows.ndim - 1))
+    rows = rows.reshape((n_blocks, block_size * rows.shape[1])
+                        + rows.shape[2:])
+    last = (true_len - 1) // block_size
+    n = min(n_blocks, table.shape[0]) if ring else n_blocks
+    # the n blocks that end with the prompt's last (all of them: n_blocks)
+    first = jnp.clip(last + 1 - n, 0, n_blocks - n)
+    rows = jax.lax.dynamic_slice_in_dim(rows, first, n, axis=0)
+    blocks = first + jnp.arange(n, dtype=jnp.int32)
+    blk = jnp.where((blocks <= last) & (blocks > last - table.shape[0]),
+                    _entries(table, blocks), SCRATCH_BLOCK)
+    return slab.at[blk].set(rows)
+
+
 class KVCacheExhausted(MXNetError):
     """Admission-time allocation failed: the free list cannot cover the
     request's ``prompt + max_new`` block budget.  The engine sheds the
@@ -111,21 +209,45 @@ class KVCacheExhausted(MXNetError):
 
 
 class BlockTable:
-    """One request's ordered block ids plus its token-capacity bound."""
+    """One request's ordered block ids plus its token-capacity bound:
+    ``blocks`` in the full layers' slabs and, where the cache has window
+    layers, ``ring`` in theirs."""
 
-    __slots__ = ("blocks", "capacity", "freed")
+    __slots__ = ("blocks", "ring", "capacity", "freed")
 
-    def __init__(self, blocks, capacity):
+    def __init__(self, blocks, capacity, ring=()):
         self.blocks = list(blocks)
+        self.ring = list(ring)
         self.capacity = int(capacity)   # tokens the table can hold
         self.freed = False
+
+    def of(self, kind):
+        return self.ring if kind == WINDOW else self.blocks
 
     def __len__(self):
         return len(self.blocks)
 
     def __repr__(self):
-        return "BlockTable(blocks=%r, capacity=%d%s)" % (
-            self.blocks, self.capacity, ", freed" if self.freed else "")
+        return "BlockTable(blocks=%r%s, capacity=%d%s)" % (
+            self.blocks, ", ring=%r" % (self.ring,) if self.ring else "",
+            self.capacity, ", freed" if self.freed else "")
+
+
+class _Pool:
+    """The blocks of one kind of layer: ``num_blocks`` in each of its
+    slabs, block 0 the scratch block, the rest a free list."""
+
+    def __init__(self, num_blocks):
+        self.num_blocks = int(num_blocks)
+        self.free = list(range(1, self.num_blocks))     # 0 = scratch
+
+    @property
+    def total(self):
+        return self.num_blocks - 1
+
+    @property
+    def in_use(self):
+        return self.total - len(self.free)
 
 
 class PagedKVCache:
@@ -138,13 +260,20 @@ class PagedKVCache:
         model declares it (``model.cache_rows()``): ``{"k": (heads,
         head_dim), "v": (heads, head_dim)}`` or ``{"latent": (576,)}``
     block_size : tokens per block
-    num_blocks : total blocks in a slab (block 0 is scratch, so the
-        allocatable pool is ``num_blocks - 1``)
+    num_blocks : total blocks in a full layer's slab (block 0 is
+        scratch, so the allocatable pool is ``num_blocks - 1``)
     dtype : cache dtype
+    kinds : the kind of each layer, ``"full"`` or ``"window"``
+        (``model.cache_layers()``); None: every layer full
+    window : positions a window layer attends over; sizes the ring
+    window_blocks : total blocks in a window layer's slab
+    fold_heads : lay a ``(heads, width)`` row's heads into the block's
+        rows (module doc)
     """
 
     def __init__(self, layers, rows, block_size, num_blocks,
-                 dtype="float32"):
+                 dtype="float32", kinds=None, window=None,
+                 window_blocks=None, fold_heads=False):
         import numpy as np
         if block_size < 1 or num_blocks < 2:
             raise MXNetError(
@@ -157,26 +286,53 @@ class PagedKVCache:
                 "PagedKVCache needs the rows a token holds, {name: "
                 "shape} with positive sizes, got %r" % (rows,))
         self.layers = int(layers)
+        self.kinds = tuple(kinds) if kinds is not None \
+            else (FULL,) * self.layers
+        if len(self.kinds) != self.layers \
+                or set(self.kinds) - {FULL, WINDOW}:
+            raise MXNetError(
+                "PagedKVCache needs one kind a layer, %r or %r: %d "
+                "layers, kinds %r" % (FULL, WINDOW, self.layers, kinds))
         self.rows = {str(name): tuple(int(n) for n in shape)
                      for name, shape in rows.items()}
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
         self.dtype = np.dtype(dtype)
+        self.fold_heads = bool(fold_heads)
+        self.window = self.ring = None
+        self._pools = {FULL: _Pool(num_blocks)}
+        if WINDOW in self.kinds:
+            if not window or window < 1 or not window_blocks \
+                    or window_blocks < 2:
+                raise MXNetError(
+                    "a cache with window layers needs window >= 1 and "
+                    "window_blocks >= 2, got window=%r window_blocks=%r"
+                    % (window, window_blocks))
+            self.window = int(window)
+            # the blocks that hold a window's positions, wherever in a
+            # block it starts
+            self.ring = -(-self.window // self.block_size) + 1
+            self._pools[WINDOW] = _Pool(window_blocks)
         # whole 128-lane tiles, so that the device's default layout is
         # the row-major one the programs work in (module doc)
-        self.slab_shapes = {
-            name: (self.num_blocks, self.block_size) + shape[:-1]
-            + (lanes_for(shape[-1]),)
-            for name, shape in self.rows.items()}
+        self.slab_shapes = {name: self._slab_shape(name, FULL)
+                            for name in self.rows}
         self.reset_slabs()
         self._lock = _sync.Lock(name="serving.kvcache")
-        self._free = list(range(1, self.num_blocks))  # 0 = scratch
         self._used_tokens = {}          # id(table) -> tokens written
+
+    def _slab_shape(self, name, kind):
+        shape = self.rows[name]
+        head = (self._pools[kind].num_blocks, self.block_size)
+        if self.fold_heads and len(shape) == 2:
+            head, shape = (head[0], head[1] * shape[0]), shape[1:]
+        return head + shape[:-1] + (lanes_for(shape[-1]),)
 
     # -- the slabs ------------------------------------------------------
     def reset_slabs(self):
         """Allocate fresh zeroed slabs: ``slabs[name][i]`` for every
-        declared row and layer.  The engine's programs are compiled with
+        declared row and layer, a window layer's with the window pool's
+        blocks.  The engine's programs are compiled with
         the whole pytree donated (``DecodeEngine.warmup``), so a call
         consumes the arrays it is given and writes the new rows in
         place; the engine binds the call's outputs here again.  Also the
@@ -186,9 +342,9 @@ class PagedKVCache:
         # let go of the old slabs first: two sets may not fit the device
         self.slabs = {}
         self.slabs = {
-            name: tuple(jnp.zeros(shape, self.dtype)
-                        for _ in range(self.layers))
-            for name, shape in self.slab_shapes.items()}
+            name: tuple(jnp.zeros(self._slab_shape(name, kind), self.dtype)
+                        for kind in self.kinds)
+            for name in self.rows}
 
     def _arrays(self):
         return [a for layers in self.slabs.values() for a in layers]
@@ -205,79 +361,108 @@ class PagedKVCache:
 
     # -- sizing ---------------------------------------------------------
     def blocks_for(self, n_tokens):
-        """Blocks needed to hold ``n_tokens`` (ceil)."""
+        """Blocks a full layer needs to hold ``n_tokens`` (ceil)."""
         return max(1, -(-int(n_tokens) // self.block_size))
+
+    def blocks_needed(self, n_tokens):
+        """{kind: blocks} a sequence of ``n_tokens`` holds in a layer of
+        each kind the cache has: its whole length in a full layer, the
+        ring at the most in a window one.  What :meth:`allocate` takes
+        for a request, and (of the longest sequence) how wide the
+        fixed-width tables of a compiled program are."""
+        need = self.blocks_for(n_tokens)
+        return {kind: min(need, self.ring) if kind == WINDOW else need
+                for kind in self._pools}
 
     @property
     def total_blocks(self):
-        """Allocatable pool size (scratch excluded)."""
+        """Allocatable pool size of the full layers (scratch excluded)."""
         return self.num_blocks - 1
 
-    def free_blocks(self):
+    def free_blocks(self, kind=FULL):
         with self._lock:
-            return len(self._free)
+            return len(self._pools[kind].free)
 
-    def blocks_in_use(self):
+    def blocks_in_use(self, kind=None):
+        """Blocks requests hold: of one kind's pool, or (None) of all."""
         with self._lock:
-            return self.total_blocks - len(self._free)
+            return self._in_use_locked(kind)
+
+    def _in_use_locked(self, kind=None):
+        if kind is not None:
+            return self._pools[kind].in_use
+        return sum(pool.in_use for pool in self._pools.values())
 
     def can_admit(self, n_tokens):
         """Whether :meth:`allocate` for ``n_tokens`` would succeed now
         (admission pre-check; racing admitters still handle the
         exception path)."""
         with self._lock:
-            return self.blocks_for(n_tokens) <= len(self._free)
+            return all(n <= len(self._pools[kind].free)
+                       for kind, n in self.blocks_needed(n_tokens).items())
 
     # -- allocate / free ------------------------------------------------
     def allocate(self, n_tokens):
         """Carve a :class:`BlockTable` holding ``n_tokens`` from the
-        free list, or raise :class:`KVCacheExhausted` (counted as
+        free lists -- every pool's share or none of any -- or raise
+        :class:`KVCacheExhausted` (counted as
         ``kvcache.alloc_failures``) without partial allocation."""
-        need = self.blocks_for(n_tokens)
+        need = self.blocks_needed(n_tokens)
         with self._lock:
-            if need > len(self._free):
-                shortfall = (need, len(self._free))
-            else:
-                blocks = [self._free.pop() for _ in range(need)]
-                table = BlockTable(blocks,
-                                   capacity=need * self.block_size)
+            short = [(kind, n, len(self._pools[kind].free))
+                     for kind, n in need.items()
+                     if n > len(self._pools[kind].free)]
+            if not short:
+                got = {kind: [self._pools[kind].free.pop()
+                              for _ in range(n)]
+                       for kind, n in need.items()}
+                table = BlockTable(
+                    got[FULL], capacity=need[FULL] * self.block_size,
+                    ring=got.get(WINDOW, ()))
                 self._used_tokens[id(table)] = int(n_tokens)
-                in_use = self.total_blocks - len(self._free)
-                frag = self._fragmentation_locked()
-                shortfall = None
-        if shortfall is not None:
+                gauges = self._gauges_locked()
+        if short:
             if _telemetry._ENABLED:
                 _telemetry.hooks.kvcache_alloc_failure()
+            kind, n, free = short[0]
             raise KVCacheExhausted(
-                "kv cache exhausted: need %d blocks for %d tokens, "
-                "%d free (of %d)" % (shortfall[0], n_tokens,
-                                     shortfall[1], self.total_blocks))
+                "kv cache exhausted: need %d %s blocks for %d tokens, "
+                "%d free (of %d)" % (n, kind, n_tokens, free,
+                                     self._pools[kind].total))
         if _telemetry._ENABLED:
-            _telemetry.hooks.kvcache_alloc(in_use, frag)
+            _telemetry.hooks.kvcache_alloc(*gauges)
         return table
 
     def free(self, table):
-        """Return a table's blocks to the free list.  Idempotent -- the
+        """Return a table's blocks to the free lists.  Idempotent -- the
         EOS/timeout/cancel paths may race a drain, and double-freeing a
         block would corrupt a live sequence."""
         with self._lock:
             if table.freed:
                 return
             table.freed = True
-            self._free.extend(table.blocks)
+            for kind, pool in self._pools.items():
+                pool.free.extend(table.of(kind))
             self._used_tokens.pop(id(table), None)
-            in_use = self.total_blocks - len(self._free)
-            frag = self._fragmentation_locked()
+            gauges = self._gauges_locked()
         if _telemetry._ENABLED:
-            _telemetry.hooks.kvcache_free(in_use, frag)
+            _telemetry.hooks.kvcache_free(*gauges)
+
+    def _gauges_locked(self):
+        """(blocks in use over all pools, fragmentation, {kind: blocks
+        in use} where there is more than one kind)."""
+        by_kind = {kind: pool.in_use for kind, pool in self._pools.items()} \
+            if len(self._pools) > 1 else None
+        return (self._in_use_locked(), self._fragmentation_locked(),
+                by_kind)
 
     # -- introspection --------------------------------------------------
     def _fragmentation_locked(self):
-        """Internal fragmentation: share of allocated token slots not
-        (yet) holding a token -- admission-time whole-budget allocation
-        makes this the honest cost of the shed-never-mid-generation
-        contract."""
-        in_use = self.total_blocks - len(self._free)
+        """Internal fragmentation of the full layers' pool: share of
+        allocated token slots not (yet) holding a token --
+        admission-time whole-budget allocation makes this the honest
+        cost of the shed-never-mid-generation contract."""
+        in_use = self._pools[FULL].in_use
         if in_use == 0:
             return 0.0
         used = sum(self._used_tokens.values())
@@ -292,26 +477,34 @@ class PagedKVCache:
 
     def stats(self):
         with self._lock:
-            in_use = self.total_blocks - len(self._free)
-            return {
+            full = self._pools[FULL]
+            out = {
                 "block_size": self.block_size,
-                "total_blocks": self.total_blocks,
-                "blocks_in_use": in_use,
-                "free_blocks": len(self._free),
+                "total_blocks": full.total,
+                "blocks_in_use": full.in_use,
+                "free_blocks": len(full.free),
                 "fragmentation": round(self._fragmentation_locked(), 4),
             }
+            if WINDOW in self._pools:
+                ring = self._pools[WINDOW]
+                out.update(window=self.window, ring=self.ring,
+                           window_total_blocks=ring.total,
+                           window_blocks_in_use=ring.in_use,
+                           window_free_blocks=len(ring.free))
+            return out
 
-    def padded_table(self, table, width):
+    def padded_table(self, table, width, kind=FULL):
         """The table as a fixed-width int32 row for a compiled program:
         real ids first, scratch-block padding after (padded positions
         write into scratch, reads are masked by context length)."""
         import numpy as np
-        if len(table.blocks) > width:
+        blocks = table.of(kind)
+        if len(blocks) > width:
             raise MXNetError(
                 "block table %d wider than compiled width %d"
-                % (len(table.blocks), width))
+                % (len(blocks), width))
         row = np.full((width,), SCRATCH_BLOCK, np.int32)
-        row[:len(table.blocks)] = table.blocks
+        row[:len(blocks)] = blocks
         return row
 
     def __repr__(self):

@@ -63,39 +63,10 @@ import math
 import numpy as np
 
 from ...base import MXNetError
+from . import blocks
+from .blocks import yarn_inv_freq
 
 __all__ = ["LatentMoEDecoder", "yarn_inv_freq", "attention_scale"]
-
-# a prefill's attention scores are computed _Q_BLOCK query rows by
-# _K_BLOCK keys at a time
-_Q_BLOCK = 256
-_K_BLOCK = 1024
-
-
-def yarn_inv_freq(dim, theta, scaling=None):
-    """Inverse frequencies of the ``dim // 2`` rotary pairs, float64.
-
-    Plain rotary: ``theta^(-2j/dim)``.  With YaRN ``scaling`` (``factor``,
-    ``original_max_position_embeddings``, ``beta_fast``, ``beta_slow``):
-    pairs that turn fewer than ``beta_slow`` times over the original
-    context are interpolated (divided by ``factor``), pairs that turn
-    more than ``beta_fast`` times are kept, with a linear ramp between
-    (``modeling_deepseek.DeepseekV3YarnRotaryEmbedding``)."""
-    j = np.arange(dim // 2, dtype=np.float64)
-    freq = float(theta) ** (-2.0 * j / dim)
-    if not scaling:
-        return freq
-    factor = float(scaling["factor"])
-    original = float(scaling["original_max_position_embeddings"])
-
-    def correction(turns):
-        return dim * math.log(original / (turns * 2 * math.pi)) \
-            / (2 * math.log(float(theta)))
-    low = max(math.floor(correction(scaling["beta_fast"])), 0)
-    high = min(math.ceil(correction(scaling["beta_slow"])), dim - 1)
-    ramp = np.clip((j - low) / max(high - low, 0.001), 0.0, 1.0)
-    # ramp 0: the pair turns often, kept; ramp 1: interpolated
-    return freq / factor * ramp + freq * (1.0 - ramp)
 
 
 def _yarn_mscale(factor, mscale):
@@ -219,66 +190,25 @@ class LatentMoEDecoder:
         return out
 
     def init_params(self, seed=0):
-        """Flat name->array dict drawn from ``seed``: matmul weights
-        normal with standard deviation ``fan_in^-0.5``, norm weights 1 +
-        0.1 normal, the selection bias 0.1 normal (it is trained in a
-        published model; here it only has to move some choices).  One
-        jitted draw per tensor, so that the float32 draw of the largest
-        tensor is the most the initialiser adds to the weights."""
-        import functools
-        import jax
-        import jax.numpy as jnp
-
-        @functools.partial(jax.jit, static_argnums=(1, 2))
-        def draw(key, shape, kind):
-            z = jax.random.normal(key, shape, jnp.float32)
-            if kind == "norm":
-                return (1.0 + 0.1 * z).astype(self.dtype)
-            if kind == "bias":
-                return 0.1 * z
-            return (z * float(kind) ** -0.5).astype(self.dtype)
-
-        key = jax.random.PRNGKey(jnp.uint32(int(seed) % (2 ** 32)))
-        return {name: draw(jax.random.fold_in(key, n), shape, kind)
-                for n, (name, (shape, kind))
-                in enumerate(sorted(self.param_shapes().items()))}
+        """Flat name->array dict drawn from ``seed``
+        (:func:`~.blocks.draw_params`): matmul weights normal with
+        standard deviation ``fan_in^-0.5``, norm weights 1 + 0.1 normal,
+        the selection bias 0.1 normal (it is trained in a published
+        model; here it only has to move some choices)."""
+        return blocks.draw_params(self.param_shapes(), seed, self.dtype)
 
     # -- shared pieces --------------------------------------------------
     def _rms(self, x, w):
-        import jax
-        import jax.numpy as jnp
-        xf = x.astype(jnp.float32)
-        var = jnp.mean(jnp.square(xf), axis=-1, keepdims=True)
-        return (xf * jax.lax.rsqrt(var + self.eps)
-                * w.astype(jnp.float32)).astype(x.dtype)
+        return blocks.rms_norm(x, w, self.eps)
 
-    @staticmethod
-    def _dot(a, w):
-        """a (..., k) x w (k, n), float32 accumulation and result."""
-        import jax.numpy as jnp
-        return jnp.dot(a, w, preferred_element_type=jnp.float32)
+    _dot = staticmethod(blocks.dot)
+    _swiglu = staticmethod(blocks.swiglu)
 
     def _rotate(self, x, positions):
         """Rotary embedding of ``x`` (..., t, [heads,] rope) at
         ``positions`` (..., t): adjacent pairs (2j, 2j+1) turn by
         ``position * inv_freq[j]``.  float32 in, float32 out."""
-        import jax.numpy as jnp
-        angle = positions.astype(jnp.float32)[..., None] \
-            * jnp.asarray(self.inv_freq)
-        if x.ndim == angle.ndim + 1:        # a heads axis before rope
-            angle = angle[..., None, :]
-        cos, sin = jnp.cos(angle), jnp.sin(angle)
-        pairs = x.astype(jnp.float32).reshape(x.shape[:-1]
-                                              + (self.rope // 2, 2))
-        even, odd = pairs[..., 0], pairs[..., 1]
-        return jnp.stack([even * cos - odd * sin, odd * cos + even * sin],
-                         axis=-1).reshape(x.shape)
-
-    def _swiglu(self, x, w_gate, w_up, w_down):
-        import jax
-        hidden = (jax.nn.silu(self._dot(x, w_gate))
-                  * self._dot(x, w_up)).astype(x.dtype)
-        return self._dot(hidden, w_down)
+        return blocks.rotate(x, positions, self.inv_freq)
 
     def _q_latent(self, p, pre, x, positions):
         """x (..., t, d) -> q_nope (..., t, H, nope), q_rope (..., t, H,
@@ -316,8 +246,6 @@ class LatentMoEDecoder:
         ``chosen`` (tokens, top_k) the experts the router chose, None
         for a dense layer."""
         import jax
-        import jax.numpy as jnp
-        from ...parallel.moe import route_top_k, routed_experts
         pre, layer = "h%d_" % i, "h%d/" % i
         if not self.is_expert_layer(i):
             with jax.named_scope(layer + "mlp"):
@@ -325,92 +253,25 @@ class LatentMoEDecoder:
                 return x + self._swiglu(
                     h, p[pre + "w_gate"], p[pre + "w_up"],
                     p[pre + "w_down"]).astype(x.dtype), stats, None
-        with jax.named_scope(layer + "router"):
-            h = self._rms(x, p[pre + "ffn_norm"])
-            chosen, weights = route_top_k(
-                h, p[pre + "router"], p[pre + "router_bias"], self.top_k,
-                self.routed_scale)
-        with jax.named_scope(layer + "experts"):
-            routed, counts = routed_experts(
-                h, chosen, weights, p[pre + "experts_gate"],
-                p[pre + "experts_up"], p[pre + "experts_down"],
-                self.first_expert, live=live)
+        h, routed, stats, chosen = blocks.routed_ffn(
+            layer, x, p[pre + "ffn_norm"], self.eps, p[pre + "router"],
+            p[pre + "router_bias"],
+            (p[pre + "experts_gate"], p[pre + "experts_up"],
+             p[pre + "experts_down"]), self.top_k, live, stats,
+            first_expert=self.first_expert, scale=self.routed_scale)
         with jax.named_scope(layer + "shared_expert"):
             shared = self._swiglu(h, p[pre + "shared_gate"],
                                   p[pre + "shared_up"],
                                   p[pre + "shared_down"])
             x = x + (routed + shared).astype(x.dtype)
-        n_live = jnp.sum(live.astype(jnp.int32))
-        stats = {
-            "moe_assignments": stats["moe_assignments"]
-            + n_live * self.top_k,
-            "moe_assignments_held": stats["moe_assignments_held"]
-            + jnp.sum(counts),
-            "moe_expert_tokens_max": jnp.maximum(
-                stats["moe_expert_tokens_max"], jnp.max(counts))}
         return x, stats, chosen
 
     def _new_stats(self):
-        import jax.numpy as jnp
         if self.num_layers <= self.dense_layers:
             return {}
-        zero = jnp.zeros((), jnp.int32)
-        return {"moe_assignments": zero, "moe_assignments_held": zero,
-                "moe_expert_tokens_max": zero}
+        return blocks.new_moe_stats()
 
     # -- full causal forward (reference + prefill) ----------------------
-    def _causal_attention(self, q, k, v):
-        """q, k (b, t, H, nope + rope), v (b, t, H, v_dim) -> (b, t, H *
-        v_dim), causal.  ``_Q_BLOCK`` query rows at a time against the
-        keys ``_K_BLOCK`` at a time, with a running maximum and sum
-        (the flash recurrence at a coarse grain, in plain XLA): a query
-        block visits only the key blocks at or before it, and no
-        reduction runs over more than ``_K_BLOCK`` keys (over 8,192 in
-        one the TPU compiler's softmax fusion took 47 ms a block where
-        4,096 took 1.2; my chip runs, PR 28)."""
-        import jax
-        import jax.numpy as jnp
-        b, t, heads, _ = q.shape
-        powers = (1024, 512, 256, 128, 64, 32, 16, 8, 4, 2, 1)
-        qb = next(n for n in powers if n <= _Q_BLOCK and t % n == 0)
-        kb = next(n for n in powers if n <= _K_BLOCK and t % n == 0)
-        ks = k.reshape(b, t // kb, kb, heads, -1)
-        vs = v.reshape(b, t // kb, kb, heads, -1)
-
-        def rows(args):
-            qblk, start = args                      # (b, qb, H, .)
-            qpos = start + jnp.arange(qb, dtype=jnp.int32)
-
-            def keys(j, carry):
-                m, l, acc = carry
-                kj = jax.lax.dynamic_index_in_dim(ks, j, 1, keepdims=False)
-                vj = jax.lax.dynamic_index_in_dim(vs, j, 1, keepdims=False)
-                s = jnp.einsum("bqhd,bkhd->bhqk", qblk, kj,
-                               preferred_element_type=jnp.float32) \
-                    * self.scale
-                kpos = j * kb + jnp.arange(kb, dtype=jnp.int32)
-                s = jnp.where(kpos[None, :] <= qpos[:, None], s, -1e30)
-                m_new = jnp.maximum(m, jnp.max(s, axis=-1))
-                p = jnp.exp(s - m_new[..., None])
-                alpha = jnp.exp(m - m_new)
-                l = alpha * l + jnp.sum(p, axis=-1)
-                acc = acc * alpha[..., None] + jnp.einsum(
-                    "bhqk,bkhd->bhqd", p.astype(v.dtype), vj,
-                    preferred_element_type=jnp.float32)
-                return m_new, l, acc
-
-            m, l, acc = jax.lax.fori_loop(
-                0, (start + qb - 1) // kb + 1, keys,
-                (jnp.full((b, heads, qb), -1e30, jnp.float32),
-                 jnp.zeros((b, heads, qb), jnp.float32),
-                 jnp.zeros((b, heads, qb, self.v_dim), jnp.float32)))
-            return (acc / l[..., None]).astype(v.dtype).swapaxes(1, 2)
-
-        qs = q.reshape(b, t // qb, qb, heads, -1).swapaxes(0, 1)
-        starts = jnp.arange(0, t, qb, dtype=jnp.int32)
-        out = jax.lax.map(rows, (qs, starts))       # (n, b, qb, H, v)
-        return out.swapaxes(0, 1).reshape(b, t, heads * self.v_dim)
-
     def _forward(self, params, tokens, live):
         """tokens (b, t) -> (hidden (b, t, d) before the final norm, the
         latent rows of every layer (b, t, kv_rank + rope), stats, the
@@ -442,8 +303,9 @@ class LatentMoEDecoder:
                         k_rope[:, :, None, :],
                         (b, t, self.num_heads, self.rope))], axis=-1)
             with scope(layer + "attention"):
-                att = self._causal_attention(
-                    jnp.concatenate([q_nope, q_rope], axis=-1), k, v)
+                att = blocks.causal_attention(
+                    jnp.concatenate([q_nope, q_rope], axis=-1), k, v,
+                    self.scale)
             with scope(layer + "proj"):
                 x = x + self._dot(att, params[pre + "wo"]).astype(x.dtype)
             flat, stats, chosen = self._ffn(

@@ -249,7 +249,7 @@ class ModelRegistry:
                             prefill_buckets=None, decode_buckets=None,
                             block_size=None, num_blocks=None,
                             max_queue=None, warmup=True,
-                            kv_dtype="float32"):
+                            kv_dtype="float32", window_blocks=None):
         """Deploy an autoregressive decoder as a generative servable.
 
         ``model`` is the pure-function spec
@@ -276,7 +276,8 @@ CheckpointManager` root whose step carries a ``params`` item).
                               block_size=block_size,
                               num_blocks=num_blocks,
                               max_queue=max_queue, cache=self._cache,
-                              label=name, kv_dtype=kv_dtype)
+                              label=name, kv_dtype=kv_dtype,
+                              window_blocks=window_blocks)
         if warmup:
             _w = _obs.begin_span("serving.register.warm", model=name) \
                 if _obs._TRACE_ENABLED else None

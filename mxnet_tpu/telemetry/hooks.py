@@ -421,22 +421,40 @@ def decode_moe(model, stats):
         stats.get("moe_expert_tokens_max", 0))
 
 
-def kvcache_alloc(in_use, fragmentation):
-    """A block-table allocation succeeded; gauges carry the cache's
-    post-alloc occupancy and internal fragmentation (unused fraction
-    of allocated blocks)."""
-    reg = _registry()
-    reg.counter("kvcache.allocs").inc()
+def _kvcache_gauges(reg, in_use, fragmentation, by_kind):
     reg.gauge("kvcache.blocks_in_use").set(in_use)
     reg.gauge("kvcache.fragmentation").set(fragmentation)
+    for kind, n in (by_kind or {}).items():
+        reg.gauge("kvcache.blocks_in_use." + kind).set(n)
 
 
-def kvcache_free(in_use, fragmentation):
+def decode_kv_rows(model, stats):
+    """The cache rows ONE layer of each kind had to read in a prefill or
+    decode call of a model with window layers: the live slots' context
+    lengths summed (``kv_rows_full``) and their windows' share of them
+    (``kv_rows_window``), as the program returned them beside its
+    token."""
+    reg = _registry()
+    reg.counter("decode.kv.rows_full").inc(stats.get("kv_rows_full", 0))
+    reg.counter("decode.kv.rows_window").inc(
+        stats.get("kv_rows_window", 0))
+
+
+def kvcache_alloc(in_use, fragmentation, by_kind=None):
+    """A block-table allocation succeeded; gauges carry the cache's
+    post-alloc occupancy (all pools; ``by_kind`` each pool's where the
+    cache has window layers beside full ones) and internal
+    fragmentation (unused fraction of allocated blocks)."""
+    reg = _registry()
+    reg.counter("kvcache.allocs").inc()
+    _kvcache_gauges(reg, in_use, fragmentation, by_kind)
+
+
+def kvcache_free(in_use, fragmentation, by_kind=None):
     """A finished/cancelled sequence returned its blocks."""
     reg = _registry()
     reg.counter("kvcache.frees").inc()
-    reg.gauge("kvcache.blocks_in_use").set(in_use)
-    reg.gauge("kvcache.fragmentation").set(fragmentation)
+    _kvcache_gauges(reg, in_use, fragmentation, by_kind)
 
 
 def kvcache_alloc_failure():
@@ -1059,6 +1077,14 @@ INSTRUMENTS = [
         "sum over programs of the largest token count one held expert "
         "of one layer was given in that program; over decode.steps it "
         "is the mean worst expert load of a step"),
+    _ii("decode.kv.rows_full", "counter", "serving", 32,
+        "cache rows ONE full-attention layer had to read in the prefill "
+        "and decode programs of a model with window layers: the live "
+        "slots' context lengths, summed (kv_rows_full, which the program "
+        "returns beside its token)"),
+    _ii("decode.kv.rows_window", "counter", "serving", 32,
+        "likewise for ONE window layer: the sum of min(context, "
+        "sliding_window) (kv_rows_window)"),
     _ii("kvcache.allocs", "counter", "serving", 18,
         "block-table allocations (one per admitted request)"),
     _ii("kvcache.frees", "counter", "serving", 18,
@@ -1067,7 +1093,12 @@ INSTRUMENTS = [
         "allocations refused for too few free blocks (admission-shed "
         "trigger)"),
     _ii("kvcache.blocks_in_use", "gauge", "serving", 18,
-        "KV cache blocks currently allocated across live sequences"),
+        "KV cache blocks currently allocated across live sequences, "
+        "over every pool of the cache"),
+    _ii("kvcache.blocks_in_use.<kind>", "gauge", "serving", 32,
+        "the same a pool (full, window), where the cache has window "
+        "layers beside full ones: a full layer's tables and the window "
+        "layers' rings"),
     _ii("kvcache.fragmentation", "gauge", "serving", 18,
         "unused fraction of allocated KV blocks (internal "
         "fragmentation; at worst one partial block per sequence)"),

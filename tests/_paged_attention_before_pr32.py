@@ -1,3 +1,8 @@
+# A verbatim copy of mxnet_tpu/ops/pallas/paged_attention.py as it stood
+# before PR 32 gave the kernel grouped-query heads and a window (commit
+# 68e136b): tests/test_serving_window_moe.py holds the ungrouped,
+# unwindowed call of today's kernel to this one bit for bit.  Not a test
+# module, and nothing in the program imports it.
 """Decode-step paged attention (Pallas/TPU): one query token per
 sequence attends over block-gathered K/V from the serving tier's
 :class:`~mxnet_tpu.serving.decode.kvcache.PagedKVCache`.
@@ -12,21 +17,10 @@ CPU fallback and the numerics oracle the registry's interpret-mode
 contract is tested against.
 
 Layout: q ``(slots, heads, head_dim)``; per-layer cache slabs
-``(num_blocks, block_size, kv_heads, head_dim)``, or with the heads
-folded into a block's rows ``(num_blocks, block_size * kv_heads,
-head_dim)`` (the same memory order; ``block_size`` then says where a
-token ends); ``block_tables`` ``(slots, max_blocks)`` int32;
-``context_lens`` ``(slots, 1)`` int32 (tokens 0..ctx-1 are live).  fp32
-accumulation regardless of cache dtype.
-
-**Grouped-query heads**: ``heads = group * kv_heads``; query head ``h``
-reads K/V head ``h // group``.  **A window**: with ``window`` a slot's
-live positions are ``[max(0, ctx - window), ctx)``, and the table is a
-RING: position ``p`` lives in ``table[(p // block_size) % width]``, so
-a table of ``ceil(window / block_size) + 1`` blocks holds every live
-position whatever ``ctx`` is (a table as wide as the context is a ring
-that never wraps).  ``group 1, window None`` is the call this kernel
-had before either: the same grid, copies and arithmetic.
+``(num_blocks, block_size, heads, head_dim)``; ``block_tables``
+``(slots, max_blocks)`` int32; ``context_lens`` ``(slots, 1)`` int32
+(tokens 0..ctx-1 are live).  fp32 accumulation regardless of cache
+dtype.
 
 The grid is the LIVE page groups of the call and nothing else: one
 step walks ``pages`` table blocks of one slot, ``pages`` the largest of
@@ -39,11 +33,9 @@ about 76 ns for every operand it has, whatever it moves, so a dead step
 is not free and neither is a page: the steps, not the bytes, were the
 kernel's time.  Which slot and group a step is, and which cache block
 each of its pages names, is worked out beforehand in plain XLA
-(:func:`_live_steps`: the same for every layer of a decode step that
-shares a table, so it is computed once a step and kind of layer; with
-a window the groups start at the one that holds the window's first
-position and there are ``ceil(window / span) + 1`` of them at most)
-and scalar-prefetched into SMEM, so an index map is one load.  The K slab and the V slab are each passed
+(:func:`_live_steps`: the same for every layer of a decode step, so
+it is computed once a step) and scalar-prefetched into SMEM, so an
+index map is one load.  The K slab and the V slab are each passed
 ``pages`` times with an index map of their own, each page the ONE
 cache block the table names, copied HBM->VMEM by the pipeline: VMEM
 holds two page groups per operand whatever the cache size.  A page
@@ -54,9 +46,9 @@ other.
 
 A page is used in the cache's own order, ``(token, head)`` rows of
 ``head_dim`` lanes, with no relayout: q against ALL of a page's rows is
-one matmul, ``(heads, d) x (block_size * kv_heads, d)^T``, in which row
-``(t, h')`` is head ``h``'s key only where ``h' == h // group``.  The
-mask keeps that diagonal and the live positions, the step's pages are ONE run of
+one matmul, ``(heads, d) x (block_size * heads, d)^T``, in which row
+``(t, h')`` is head ``h``'s key only where ``h' == h``.  The mask keeps
+that diagonal and the live positions, the step's pages are ONE run of
 columns for one running maximum / sum / accumulator update, and the
 masked probabilities (exactly 0 off the diagonal) times the page's rows
 are the step's values.  That spends ``heads`` times the MXU work the
@@ -84,50 +76,23 @@ except Exception:  # pragma: no cover
 # XLA reference / fallback
 # ----------------------------------------------------------------------
 
-def _token_rows(cache, block_size):
-    """A slab as ``(num_blocks, block_size, kv_heads, d)`` whether its
-    heads are folded into the block's rows or not."""
-    if cache.ndim == 4:
-        return cache
-    nb, rows, d = cache.shape
-    return cache.reshape(nb, block_size, rows // block_size, d)
-
-
-@functools.partial(jax.jit,
-                   static_argnames=("scale", "window", "block_size"))
+@functools.partial(jax.jit, static_argnames=("scale",))
 def paged_attention_reference(q, k_cache, v_cache, block_tables,
-                              context_lens, scale=1.0, window=None,
-                              block_size=None):
+                              context_lens, scale=1.0):
     """Gather-then-softmax reference: ``take`` the table's blocks into
-    a contiguous ``(slots, max_blocks*block_size, kv_heads, d)`` view
-    and mask positions past each slot's context length (and, with
-    ``window``, before its window: the table is then read as a ring,
-    module doc).  K and V are repeated ``group`` times for the query
-    heads that share them."""
+    a contiguous ``(slots, max_blocks*block_size, heads, d)`` view and
+    mask positions past each slot's context length."""
     s_, h, d = q.shape
-    k_cache = _token_rows(k_cache, block_size)
-    v_cache = _token_rows(v_cache, block_size)
-    nb, bs, kvh, _ = k_cache.shape
+    nb, bs, _, _ = k_cache.shape
     mb = block_tables.shape[1]
-    k = jnp.take(k_cache, block_tables, axis=0)        # (s, mb, bs, kvh, d)
+    k = jnp.take(k_cache, block_tables, axis=0)        # (s, mb, bs, h, d)
     v = jnp.take(v_cache, block_tables, axis=0)
-    k = k.reshape(s_, mb * bs, kvh, d).astype(jnp.float32)
-    v = v.reshape(s_, mb * bs, kvh, d).astype(jnp.float32)
-    if h != kvh:
-        k = jnp.repeat(k, h // kvh, axis=2)
-        v = jnp.repeat(v, h // kvh, axis=2)
+    k = k.reshape(s_, mb * bs, h, d).astype(jnp.float32)
+    v = v.reshape(s_, mb * bs, h, d).astype(jnp.float32)
     qf = q.astype(jnp.float32)
     scores = jnp.einsum("shd,sthd->sht", qf, k) * scale
-    ctx = context_lens.reshape(s_, 1, 1)
-    pos = jnp.arange(mb * bs, dtype=jnp.int32)[None, None, :]
-    if window is not None:
-        # ring entry r holds the one block b = r (mod mb) of the last mb
-        last = (ctx - 1) // bs
-        entry = pos // bs
-        pos = (last - (last - entry) % mb) * bs + pos % bs
-        live = (pos >= jnp.maximum(ctx - window, 0)) & (pos < ctx)
-    else:
-        live = pos < ctx
+    pos = jnp.arange(mb * bs, dtype=jnp.int32)
+    live = pos[None, None, :] < context_lens.reshape(s_, 1, 1)
     scores = jnp.where(live, scores, NEG_INF)
     m = jnp.max(scores, axis=-1, keepdims=True)
     p = jnp.exp(scores - m)
@@ -145,62 +110,40 @@ def paged_attention_reference(q, k_cache, v_cache, block_tables,
 PAGES = (8, 4, 2, 1)
 
 
-def _live_steps(block_tables, ctx, bs, pages, window=None):
+def _live_steps(block_tables, ctx, bs, pages):
     """The call's grid: ``(steps, slot, group, blocks)``.  Step ``i`` is
     page group ``group[i]`` of slot ``slot[i]``, the slots in order and
-    each with the groups its live positions reach (one for an empty
-    one, so every slot's output is written): from group 0, or with
-    ``window`` from the group that holds position ``ctx - window``.
-    ``blocks[k, i]`` is the cache block of its page ``k``, read from
-    the table as a ring with ``window``.  A page with no live position
-    names what operand ``k`` held at the last step that reached it,
-    whichever slot that was.  Rows from ``steps`` on are never run."""
+    each with the groups its context reaches (one for an empty one, so
+    every slot's output is written); ``blocks[k, i]`` is the cache block
+    of its page ``k``.  A page past the slot's last live block names
+    what operand ``k`` held at the last step that reached it, whichever
+    slot that was.  Rows from ``steps`` on are never run."""
     slots, mb = block_tables.shape
     span = pages * bs
-    groups = (ctx + span - 1) // span                            # (slots,)
-    if window is None:
-        most, first = mb // pages, None
-    else:
-        most = -(-(window // bs + 1) // pages) + 1
-        first = jnp.maximum(ctx - window, 0) // span
-        groups = groups - first
-    groups = jnp.maximum(groups, 1)
+    groups = jnp.maximum((ctx + span - 1) // span, 1)            # (slots,)
     ends = jnp.cumsum(groups)
-    i = jnp.arange(slots * most, dtype=jnp.int32)
+    i = jnp.arange(slots * (mb // pages), dtype=jnp.int32)
     slot = jnp.minimum(jnp.searchsorted(ends, i, side="right",
                                         method="compare_all"),
                        slots - 1).astype(jnp.int32)
     group = i - (ends - groups)[slot]
-    if first is not None:
-        group = group + first[slot]
     at = group[:, None] * pages + jnp.arange(pages, dtype=jnp.int32)
     live = at * bs < ctx[slot][:, None]                    # (steps, pages)
-    if window is None:
-        named = block_tables[slot[:, None], jnp.minimum(at, mb - 1)]
-    else:
-        live &= (at + 1) * bs > (ctx[slot] - window)[:, None]
-        named = block_tables[slot[:, None], at % mb]
+    named = block_tables[slot[:, None], jnp.minimum(at, mb - 1)]
     held = jax.lax.cummax(jnp.where(live, i[:, None], 0), axis=0)
     blocks = jnp.take_along_axis(named, held, axis=0)
     return ends[-1], slot, group, blocks.T
 
 
 def _decode_kernel(ctx_ref, slot_ref, group_ref, blocks_ref, q_ref, *refs,
-                   block_size, pages, scale, group, window):
+                   block_size, pages, scale):
     k_refs, v_refs = refs[:pages], refs[pages:2 * pages]
     o_ref, m_ref, l_ref, acc_ref = refs[2 * pages:]
     i = pl.program_id(0)
     ctx = ctx_ref[slot_ref[i]]
-    span = pages * block_size
-    start = group_ref[i] * span
-    # the slot's first live position, and whether this is its first group
-    if window is None:
-        opens = start == 0
-    else:
-        low = jnp.maximum(ctx - window, 0)
-        opens = group_ref[i] == low // span
+    start = group_ref[i] * pages * block_size
 
-    @pl.when(opens)
+    @pl.when(start == 0)
     def _():
         m_ref[...] = jnp.full(m_ref.shape, NEG_INF, jnp.float32)
         l_ref[...] = jnp.zeros(l_ref.shape, jnp.float32)
@@ -210,28 +153,22 @@ def _decode_kernel(ctx_ref, slot_ref, group_ref, blocks_ref, q_ref, *refs,
     def _():
         q = q_ref[0].astype(jnp.float32)              # (heads, d)
         heads, d = q.shape
-        kv_heads = heads // group
-        rows = block_size * kv_heads
-        # a page in the cache's own order, (token, kv head) rows of d
-        # lanes: no relayout.  q against ALL of a page's rows is one
-        # matmul, (heads, d) x (rows, d)^T; row (t, h') is head h's key
-        # where h' == h // group, and the mask keeps those and the live
-        # tokens
+        rows = block_size * heads
+        # a page in the cache's own order, (token, head) rows of d lanes:
+        # no relayout.  q against ALL of a page's rows is one matmul,
+        # (heads, d) x (rows, d)^T; row (t, h') is head h's key where
+        # h' == h, and the mask keeps that diagonal and the live tokens
         col = jax.lax.broadcasted_iota(jnp.int32, (heads, rows), 1)
         head = jax.lax.broadcasted_iota(jnp.int32, (heads, rows), 0)
-        tok = col // kv_heads
-        own = col - tok * kv_heads == (head if group == 1
-                                       else head // group)
+        tok = col // heads
+        own = col - tok * heads == head
         s = []
         for k in range(pages):
             sk = jax.lax.dot_general(
                 q, k_refs[k][0].astype(jnp.float32).reshape(rows, d),
                 (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * scale
-            pos = start + k * block_size + tok
-            live = own & (pos < ctx)
-            if window is not None:
-                live &= pos >= low
+            live = own & (start + k * block_size + tok < ctx)
             s.append(jnp.where(live, sk, NEG_INF))
         # the step's pages as ONE run of columns: one softmax update
         s = s[0] if pages == 1 else jnp.concatenate(s, axis=1)
@@ -252,38 +189,29 @@ def _decode_kernel(ctx_ref, slot_ref, group_ref, blocks_ref, q_ref, *refs,
         m_ref[...] = m_new
 
     # the slot's last group: the next step is another slot's
-    @pl.when(start + span >= ctx)
+    @pl.when(start + pages * block_size >= ctx)
     def _():
         o_ref[0] = (acc_ref[...] / jnp.maximum(l_ref[...], 1e-30)
                     ).astype(o_ref.dtype)
 
 
-@functools.partial(jax.jit, static_argnames=("scale", "interpret", "window",
-                                             "block_size"))
+@functools.partial(jax.jit, static_argnames=("scale", "interpret"))
 def paged_attention_pallas(q, k_cache, v_cache, block_tables,
-                           context_lens, scale=1.0, interpret=False,
-                           window=None, block_size=None):
-    """q (slots, heads, d); caches (nb, bs, kv_heads, d), or (nb, bs *
-    kv_heads, d) with ``block_size`` = bs; block_tables (slots, mb)
-    int32, a ring with ``window``; context_lens (slots, 1) int32 ->
-    (slots, heads, d)."""
+                           context_lens, scale=1.0, interpret=False):
+    """q (slots, heads, d); caches (nb, bs, heads, d); block_tables
+    (slots, mb) int32; context_lens (slots, 1) int32 -> (slots, heads,
+    d)."""
     slots, heads, d = q.shape
-    page = tuple(k_cache.shape[1:])         # one block of the slab
-    bs = page[0] if len(page) == 3 else int(block_size)
-    kv_heads = page[1] if len(page) == 3 else page[0] // bs
+    _nb, bs, _, _ = k_cache.shape
     mb = block_tables.shape[1]
-    # a ring is read modulo its width, so the page group need not divide it
-    pages = next(p for p in PAGES if mb % p == 0) if window is None \
-        else PAGES[0]
+    pages = next(p for p in PAGES if mb % p == 0)
     ctx = context_lens.reshape(slots)
-    steps, slot, group, blocks = _live_steps(block_tables, ctx, bs, pages,
-                                             window)
+    steps, slot, group, blocks = _live_steps(block_tables, ctx, bs, pages)
 
     def kv_block(k):
         return pl.BlockSpec(
-            (1,) + page,
-            lambda i, ctx, slot, group, blocks:
-            (blocks[k, i],) + (0,) * len(page))
+            (1, bs, heads, d),
+            lambda i, ctx, slot, group, blocks: (blocks[k, i], 0, 0, 0))
 
     def q_block(i, ctx, slot, group, blocks):
         return (slot[i], 0, 0)
@@ -299,8 +227,7 @@ def paged_attention_pallas(q, k_cache, v_cache, block_tables,
                         pltpu.VMEM((heads, d), jnp.float32)])
     return pl.pallas_call(
         functools.partial(_decode_kernel, block_size=bs, pages=pages,
-                          scale=scale, group=heads // kv_heads,
-                          window=window),
+                          scale=scale),
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         grid_spec=grid_spec,
         interpret=interpret,

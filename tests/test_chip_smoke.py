@@ -35,7 +35,8 @@ def test_tiny_rehearsal_ends_in_rehearsal():
     assert by["serve"]["compiles_after_warmup"] == 0
     census = by["census"]["kernels"]
     assert [k["kernel"] for k in census] == [
-        "flash_attention", "paged_attention", "mla_paged_attention"]
+        "flash_attention", "paged_attention", "mla_paged_attention",
+        "grouped_matmul"]
     assert all(k["use_pallas"] and k["interpret"] for k in census)
 
 

@@ -51,6 +51,10 @@ CELL_SHAPES = {
         dict(heads=64, lanes=640, v_width=512, block_size=64),
         dict(heads=64, lanes=512, v_width=576, block_size=64),
         "v_width"),
+    # mellum2_serve_closed32: a prefill's sorted rows over 64 whole
+    # experts of 2,304 x 896
+    "grouped_matmul": (dict(groups=64, k=2304, n=896),
+                       dict(groups=0, k=2304, n=896), "positive"),
 }
 KERNEL_NAMES = sorted(CELL_SHAPES)
 
@@ -73,7 +77,7 @@ def _situate(monkeypatch, backend, has_pallas=True):
     monkeypatch.setattr(kreg, "_has_pallas", lambda: has_pallas)
 
 
-def test_registry_lists_the_three_kernels():
+def test_registry_lists_the_four_kernels():
     assert kernels.list_kernels() == KERNEL_NAMES
 
 
@@ -99,6 +103,18 @@ def test_flash_below_its_crossover_is_the_callers_to_force(
     _situate(monkeypatch, "tpu")
     ch = kernels.choose("flash_attention", force=force, seq=128,
                         block_q=256, block_k=256)
+    assert (ch.use_pallas, ch.interpret) == (use_pallas, False), ch
+    assert ("tpu" if use_pallas else "auto policy") in ch.reason
+
+
+@pytest.mark.parametrize("force,use_pallas", [(None, False), (True, True)])
+def test_a_layer_of_few_held_experts_keeps_ragged_dot_unless_asked(
+        monkeypatch, force, use_pallas):
+    """kimi_k2_serve_closed32's 12 groups of 7,168 x 2,048 on a TPU: the
+    open choice is ``ragged_dot``, which its cell was measured with."""
+    _situate(monkeypatch, "tpu")
+    ch = kernels.choose("grouped_matmul", force=force, groups=12, k=7168,
+                        n=2048)
     assert (ch.use_pallas, ch.interpret) == (use_pallas, False), ch
     assert ("tpu" if use_pallas else "auto policy") in ch.reason
 
@@ -154,9 +170,18 @@ def _latent_case(rng):
             args, dict(scale=0.1))
 
 
+def _grouped_case(rng):
+    from mxnet_tpu.kernels.grouped_matmul import grouped_matmul
+    lhs = jnp.asarray(rng.randn(40, 32), jnp.float32)
+    rhs = jnp.asarray(rng.randn(5, 32, 16), jnp.float32)
+    args = (lhs, rhs, jnp.asarray([7, 0, 20, 1, 9], jnp.int32))
+    return (lambda: grouped_matmul(*args, use_pallas=True), args, {})
+
+
 @pytest.mark.parametrize("name,case", [
     ("flash_attention", _flash_case), ("paged_attention", _paged_case),
-    ("mla_paged_attention", _latent_case)])
+    ("mla_paged_attention", _latent_case),
+    ("grouped_matmul", _grouped_case)])
 def test_without_pallas_every_wrapper_is_its_xla_reference(
         monkeypatch, name, case):
     """The fallback proof: with Pallas monkeypatched away a wrapper that

@@ -14,6 +14,7 @@ from jax import export
 
 from mxnet_tpu.ops.pallas.flash_attention import (
     flash_attention_bwd_pallas, flash_attention_fwd_pallas)
+from mxnet_tpu.ops.pallas.grouped_matmul import grouped_matmul_pallas
 from mxnet_tpu.ops.pallas.mla_paged_attention import (
     mla_paged_attention_pallas)
 from mxnet_tpu.ops.pallas.paged_attention import paged_attention_pallas
@@ -90,6 +91,37 @@ def test_mla_paged_attention_kimi_k2_decode_buckets(slots):
             q, c, bt, cl, v_width=512, scale=0.13),
         S((slots, 64, 640), BF16), S((4289, 64, 640), BF16),
         S((slots, 256), I32), S((slots, 1), I32))
+
+
+@pytest.mark.parametrize("window,blocks,table", [(None, 8385, 262),
+                                                 (1024, 545, 17)],
+                         ids=["full", "window"])
+@pytest.mark.parametrize("slots", [16, 32])
+def test_paged_attention_mellum2_decode_buckets(slots, window, blocks,
+                                                table):
+    # mellum2_serve_closed32: 32 query heads over 4 K/V heads of 128, the
+    # heads folded into a block's rows (64 tokens x 4 heads), bf16; a full
+    # layer's table covers 16,768 tokens (2 pages a step: 262 = 2 x 131),
+    # a window layer's is a ring of 17 read 8 pages a step
+    slab = S((blocks, 64 * 4, 128), BF16)
+    _lowers_to_mosaic(
+        lambda q, k, v, bt, cl: paged_attention_pallas(
+            q, k, v, bt, cl, scale=128 ** -0.5, window=window,
+            block_size=64),
+        S((slots, 32, 128), BF16), slab, slab,
+        S((slots, table), I32), S((slots, 1), I32))
+
+
+_GROUPED = {"gate_up": (2304, 896), "down": (896, 2304)}
+
+
+@pytest.mark.parametrize("which", sorted(_GROUPED))
+def test_grouped_matmul_mellum2_prefill_chunk(which):
+    # mellum2_serve_closed32's prefill: 2,048 sorted rows a chunk over 64
+    # whole experts of 2,304 x 896 (gate, up) and 896 x 2,304 (down), bf16
+    k, n = _GROUPED[which]
+    _lowers_to_mosaic(grouped_matmul_pallas, S((2048, k), BF16),
+                      S((64, k, n), BF16), S((64,), I32))
 
 
 # ----------------------------------------------------------------------
@@ -209,3 +241,85 @@ def test_the_latent_decode_step_writes_its_rows_in_place_on_the_chip(
     assert "ragged" in text              # the grouped matmul, not a loop
     moved = re.findall(r"= bf16\[2049,64,\d+\]\S* (?:copy|slice)\(", text)
     assert not moved, moved[:3]
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_window_and_full_layers_write_both_pools_in_place_on_the_chip(
+        one_chip, no_compile_cache, monkeypatch, kind):
+    """Mellum2's widths, a window layer and a full one with all 64
+    experts: the programs alias every byte of both pools' slabs (declared
+    in whole tiles: their bytes are the arithmetic), keep no slab-sized
+    temporary, run the kernel once a layer in decode and copy no slab."""
+    import re
+
+    from mxnet_tpu.kernels import registry
+    from mxnet_tpu.serving.decode import DecodeEngine, WindowMoEDecoder
+    monkeypatch.setattr(registry, "_backend", lambda: "tpu")
+    model = WindowMoEDecoder(
+        vocab_size=2048, hidden_size=2304, num_attention_heads=32,
+        num_key_value_heads=4, head_dim=128,
+        layer_types=["sliding_attention", "full_attention"],
+        sliding_window=1024, rope_parameters={
+            "full_attention": {
+                "rope_type": "yarn", "rope_theta": 500000, "factor": 16,
+                "original_max_position_embeddings": 8192, "beta_fast": 32,
+                "beta_slow": 1, "attention_factor": 1.2772588722239782},
+            "sliding_attention": {"rope_type": "default",
+                                  "rope_theta": 500000}},
+        moe_intermediate_size=896, num_experts=64, num_experts_per_tok=8,
+        max_seq=16768)
+    params = {name: S(shape, BF16)
+              for name, (shape, _kind) in model.param_shapes().items()}
+    eng = DecodeEngine(model, params, prefill_buckets=(2048,),
+                       decode_buckets=(32,), block_size=64, num_blocks=2097,
+                       window_blocks=137, kv_dtype="bfloat16")
+    assert [a.shape for a in eng.cache.slabs["k"]] \
+        == [(137, 256, 128), (2097, 256, 128)]
+    # one block is 64 tokens x 2,048 B, K and V, no padding
+    slabs = (137 + 2097) * 64 * 2048
+    assert eng.cache.slab_bytes() == slabs
+    assert eng._table_widths == {"full": 262, "window": 17}
+    prefill, decode = eng._specs()
+    fn, specs = (eng._decode_impl, decode[32]) if kind == "decode" \
+        else (eng._prefill_impl, prefill[2048])
+    specs = jax.tree.map(
+        lambda s: S(s.shape, s.dtype, sharding=one_chip), specs)
+    compiled = jax.jit(fn, donate_argnums=eng._DONATED).lower(
+        *specs).compile()
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes == slabs
+    # a step keeps less than half a slab; a prefill what one chunk of
+    # 8,192 sorted rows takes through an expert layer (the gathered
+    # inputs, gate and up in float32, their product, the output)
+    assert stats.temp_size_in_bytes < (
+        2097 * 64 * 1024 // 2 if kind == "decode"
+        else 8192 * (2304 * 2 + 2 * 896 * 4 + 896 * 2 + 2304 * 4))
+    text = compiled.as_text()
+    assert len(re.findall(r"paged_attention_pallas\S* = ", text)) \
+        == (model.num_layers if kind == "decode" else 0)
+    # a prompt's tokens go through the grouped-matmul kernel, gate, up
+    # and down a layer, where XLA's ragged-dot stood; a step's 32, four
+    # to an expert, run every expert over every token (parallel/moe.py).
+    # The instructions' names: the text also lists the names of the
+    # functions that were on the stack, this process's tests among them
+    assert len(re.findall(r"gmm\S* = \S+ custom-call\(", text)) \
+        == (3 * model.num_layers if kind == "prefill" else 0)
+    assert "ragged-dot" not in text
+    moved = re.findall(r"= bf16\[(?:137|2097),256,128\]\S* (?:copy|slice)\(",
+                       text)
+    assert not moved, moved[:3]
+
+
+@pytest.mark.parametrize("which", sorted(_GROUPED))
+def test_the_grouped_matmuls_tiles_fit_the_chips_fast_memory(
+        one_chip, no_compile_cache, which):
+    """The tiles ``ops/pallas/grouped_matmul.py`` chooses at Mellum2's
+    widths compile for a v5e: two buffers of each operand and the
+    accumulator stay inside what a kernel may use."""
+    k, n = _GROUPED[which]
+
+    def on_chip(*shape_dtype):
+        return S(*shape_dtype, sharding=one_chip)
+    jax.jit(grouped_matmul_pallas).lower(
+        on_chip((2048, k), BF16), on_chip((64, k, n), BF16),
+        on_chip((64,), I32)).compile()
