@@ -13,7 +13,11 @@ from __future__ import annotations
 from .registry import KernelSpec, register_kernel
 
 # measured v5e crossover (BERT-base bf16 train, r3): seq 128 pallas 93k
-# vs xla 117k tok/s; seq 256 111k vs 107k; seq 1024 81k vs 60k
+# vs xla 117k tok/s; seq 256 111k vs 107k; seq 1024 81k vs 60k.  These
+# readings are OLDER than the kernels as they stand (PR 33: whole-row
+# tiles, one backward kernel; seq 512 about twice as fast a layer), so
+# the crossover may lie lower now; it stays until a seq-128 cell
+# (ROADMAP W4) can say otherwise
 AUTO_MIN_SEQ = 256
 
 

@@ -13,7 +13,8 @@ import jax.numpy as jnp
 
 import mxnet_tpu as mx
 from mxnet_tpu.ops.pallas import flash_attention as fa
-from mxnet_tpu.ops.transformer import _attention_reference
+from mxnet_tpu.ops.transformer import (_attention_reference,
+                                       _attention_reference_masked)
 
 pytestmark = pytest.mark.skipif(not fa._HAS_PALLAS,
                                 reason="no pallas on this backend")
@@ -183,3 +184,150 @@ def test_mha_masked_uses_flash_path():
         p2.set_data(p1.data())
     out2 = att_drop(x, mask).asnumpy()
     np.testing.assert_allclose(out1, out2, rtol=2e-4, atol=2e-5)
+
+
+# ----------------------------------------------------------------------
+# bf16 operands: what the BERT cells give the kernels under AMP
+# ----------------------------------------------------------------------
+
+def _rel(got, want):
+    got, want = (np.asarray(t, np.float32) for t in (got, want))
+    return float(np.abs(got - want).max() / np.abs(want).max())
+
+
+# largest error over the largest reference value.  Each limit is set from
+# what the XLA path (``_attention_reference`` and its autodiff on the
+# SAME bf16 operands) reads against the float32 reference of the
+# bf16-rounded values, the reading beside it; none is looser than twice
+# its reading, which the test holds too
+_BF16_LIMITS = {
+    #                  limit     XLA path reads
+    ("plain", "out"): 0.0070,   # 0.00359
+    ("plain", "dq"): 0.0058,    # 0.00291
+    ("plain", "dk"): 0.0051,    # 0.00260
+    ("plain", "dv"): 0.0068,    # 0.00345
+    ("causal", "out"): 0.0036,  # 0.00183
+    ("causal", "dq"): 0.0052,   # 0.00261
+    ("causal", "dk"): 0.0043,   # 0.00216
+    ("causal", "dv"): 0.0050,   # 0.00253
+    ("masked", "out"): 0.0072,  # 0.00364
+    ("masked", "dq"): 0.0069,   # 0.00349
+    ("masked", "dk"): 0.0065,   # 0.00326
+    ("masked", "dv"): 0.0074,   # 0.00373
+}
+
+
+@pytest.fixture
+def blockwise(monkeypatch):
+    """No score budget: a non-causal call walks ``block``-sized tiles
+    with the online softmax, as a long sequence does.  The budget is
+    read while a wrapper is traced and jit keeps its traces, so the
+    wrappers are handed out undecorated."""
+    monkeypatch.setattr(fa, "WHOLE_ROW_BYTES", 0)
+    return (fa.flash_attention_fwd_pallas.__wrapped__,
+            fa.flash_attention_bwd_pallas.__wrapped__)
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("kind", ["plain", "causal", "masked"])
+def test_bf16_operands_match_float32_reference(kind, direction):
+    """bf16 q, k, v (and dout) reach the kernels as bf16; the result is
+    held to the float32 reference of the same bf16-rounded values as
+    closely as the XLA path that rounds ``p`` the same way.  At this
+    size a non-causal call is one whole tile a head."""
+    _check_bf16(kind, direction)
+
+
+@pytest.mark.parametrize("direction", ["forward", "backward"])
+@pytest.mark.parametrize("kind", ["plain", "masked"])
+def test_bf16_operands_blockwise(kind, direction, blockwise):
+    """The same operands and limits through the blockwise walk."""
+    fwd, _bwd = blockwise
+    q = jax.ShapeDtypeStruct((BH, SEQ, D), jnp.bfloat16)
+    walk = jax.make_jaxpr(lambda q: fwd(q, q, q, block_q=32, block_k=32))(q)
+    assert "scan" in {e.primitive.name for e in _eqns(walk.jaxpr)}
+    _check_bf16(kind, direction, *blockwise)
+
+
+def _check_bf16(kind, direction, fwd=fa.flash_attention_fwd_pallas,
+                bwd=fa.flash_attention_bwd_pallas):
+    rng = np.random.RandomState(7)
+    q, k, v, dout = (jnp.asarray(rng.randn(BH, SEQ, D) * 0.5, jnp.bfloat16)
+                     for _ in range(4))
+    scale = 1.0 / np.sqrt(D)
+    causal = kind == "causal"
+    mask = _mask(7) if kind == "masked" else None
+
+    def ref(q, k, v):
+        if mask is None:
+            return _attention_reference(q, k, v, causal, scale)
+        return _attention_reference_masked(
+            q, k, v, jnp.repeat(mask, HEADS, axis=0), scale)
+
+    f32 = [t.astype(jnp.float32) for t in (q, k, v, dout)]
+    want, vjp = jax.vjp(ref, *f32[:3])
+    xla, vjp_xla = jax.vjp(ref, q, k, v)
+    out, lse = fwd(q, k, v, mask, causal=causal, scale=scale, block_q=32,
+                   block_k=32, heads=HEADS, interpret=True)
+    assert out.dtype == jnp.bfloat16 and lse.dtype == jnp.float32
+    if direction == "forward":
+        names, got, wants, xlas = ["out"], [out], [want], [xla]
+    else:
+        delta = jnp.sum(f32[3] * out.astype(jnp.float32), axis=-1)
+        got = bwd(q, k, v, lse, dout, delta, mask, causal=causal,
+                  scale=scale, block_q=32, block_k=32, heads=HEADS,
+                  interpret=True)
+        assert all(g.dtype == jnp.bfloat16 for g in got)
+        names, wants, xlas = ["dq", "dk", "dv"], vjp(f32[3]), vjp_xla(dout)
+    for name, g, w, x in zip(names, got, wants, xlas):
+        limit = _BF16_LIMITS[kind, name]
+        assert limit <= 2 * _rel(x, w), (name, limit, _rel(x, w))
+        assert _rel(g, w) <= limit, (name, _rel(g, w), limit)
+
+
+# ----------------------------------------------------------------------
+# what the MXU is fed, read from the traced kernels (no chip needed)
+# ----------------------------------------------------------------------
+
+def _eqns(jaxpr):
+    """Every equation of a jaxpr and of the jaxprs inside it (the
+    ``pallas_call``'s kernel, loop and ``pl.when`` bodies)."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for val in eqn.params.values():
+            for sub in (val if isinstance(val, (list, tuple)) else [val]):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _eqns(sub)
+
+
+def _kernel_dots(fn, *args):
+    dots = [e for e in _eqns(jax.make_jaxpr(fn)(*args).jaxpr)
+            if e.primitive.name == "dot_general"]
+    return [tuple(v.aval.dtype for v in e.invars) + (e.outvars[0].aval.dtype,)
+            for e in dots]
+
+
+@pytest.mark.parametrize("kind", ["plain", "causal", "masked"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32])
+def test_matmuls_take_the_operands_dtype(dtype, kind):
+    """The counter of the mechanism: every matmul inside the kernels
+    takes q, k, v and dout in the dtype they arrive in (bf16 under AMP,
+    so one MXU pass) and accumulates in float32; a forward has two, a
+    backward the published five."""
+    seq, d = 512, 64
+    qkv = jax.ShapeDtypeStruct((2 * HEADS, seq, d), dtype)
+    vec = jax.ShapeDtypeStruct((2 * HEADS, seq), jnp.float32)
+    mask = ([jax.ShapeDtypeStruct((2, seq, seq), jnp.float32)]
+            if kind == "masked" else [])
+    kw = dict(causal=kind == "causal", scale=0.125, heads=HEADS)
+    fwd = _kernel_dots(
+        lambda q, k, v, *m: fa.flash_attention_fwd_pallas(q, k, v, *m, **kw),
+        qkv, qkv, qkv, *mask)
+    bwd = _kernel_dots(
+        lambda q, k, v, lse, do, delta, *m: fa.flash_attention_bwd_pallas(
+            q, k, v, lse, do, delta, *m, **kw),
+        qkv, qkv, qkv, vec, qkv, vec, *mask)
+    assert len(fwd) == 2 and len(bwd) == 5
+    want = (jnp.dtype(dtype), jnp.dtype(dtype), jnp.dtype(jnp.float32))
+    assert set(fwd + bwd) == {want}
