@@ -36,9 +36,21 @@ not per-request.  This package is that tier:
   top-k router over experts that are all held here.  What the two
   share (RMS norm, rotary, SwiGLU, blocked prefill attention, the routed
   FFN with its counts) is :mod:`~.blocks`.
+- :class:`~.looped.LoopedDecoder` -- a dense decoder (four RMS norms a
+  layer, rotary attention, SwiGLU, from :mod:`~.blocks` too) whose stack
+  of layers runs ``total_ut_steps`` times a token over ONE set of
+  weights, the passes a loop of the compiled program, with a learned
+  exit gate that picks which pass's hidden state the head reads.  Each
+  pass of each layer keeps its own K and V: the cache has
+  ``cache_passes x num_layers`` layers over ``num_layers`` layers of
+  weights.
 
-A model declares what a token keeps in the cache (``cache_rows()``) and
-the engine builds the one ``PagedKVCache`` from that.  The decode-step
+A model declares what a token keeps in the cache (``cache_rows()``),
+where it has them which layers are window layers (``cache_layers()``)
+and how many cache layers a layer of weights keeps (``cache_passes``, 1
+where it is not declared), and the engine builds the one
+``PagedKVCache`` from that: the cache's layers are the model's
+declaration, not its ``num_layers``.  The decode-step
 attention itself is a kernel-registry citizen
 (``kernels.paged_attention`` for per-head K and V,
 ``kernels.mla_paged_attention`` for latent rows): a Pallas
@@ -50,10 +62,12 @@ from .engine import (DecodeEngine, GenerationStream, GenerativeServable,
                      GenerativeWatcher)
 from .kvcache import BlockTable, KVCacheExhausted, PagedKVCache
 from .latent_moe import LatentMoEDecoder
+from .looped import LoopedDecoder
 from .model import TinyGPT, tiny_gpt
 from .window_moe import WindowMoEDecoder
 
 __all__ = ["BlockTable", "DecodeEngine", "GenerationStream",
            "GenerativeServable", "GenerativeWatcher",
-           "KVCacheExhausted", "LatentMoEDecoder", "PagedKVCache",
+           "KVCacheExhausted", "LatentMoEDecoder", "LoopedDecoder",
+           "PagedKVCache",
            "TinyGPT", "WindowMoEDecoder", "tiny_gpt"]

@@ -228,11 +228,12 @@ def routed_ffn(layer, x, norm_w, eps, router, router_bias, experts, top_k,
 
 def draw_params(shapes, seed, dtype):
     """Flat name->array dict drawn from ``seed`` for ``shapes`` =
-    ``{name: (shape, kind)}``; kind "norm" (1 + 0.1 normal), "bias"
-    (0.1 normal, float32) or the fan-in of a matmul weight (normal with
-    standard deviation ``fan_in^-0.5``).  One jitted draw per tensor, so
-    that the float32 draw of the largest tensor is the most the
-    initialiser adds to the weights."""
+    ``{name: (shape, kind)}``; kind "norm" (1 + 0.1 normal), ``("norm",
+    gain)`` (a norm whose weights lie about ``gain``: ``gain * (1 + 0.1
+    normal)``), "bias" (0.1 normal, float32) or the fan-in of a matmul
+    weight (normal with standard deviation ``fan_in^-0.5``).  One jitted
+    draw per tensor, so that the float32 draw of the largest tensor is
+    the most the initialiser adds to the weights."""
     import functools
     import jax
     import jax.numpy as jnp
@@ -242,6 +243,8 @@ def draw_params(shapes, seed, dtype):
         z = jax.random.normal(key, shape, jnp.float32)
         if kind == "norm":
             return (1.0 + 0.1 * z).astype(dtype)
+        if isinstance(kind, tuple):
+            return (kind[1] * (1.0 + 0.1 * z)).astype(dtype)
         if kind == "bias":
             return 0.1 * z
         return (z * float(kind) ** -0.5).astype(dtype)

@@ -281,8 +281,8 @@ class DecodeEngine:
 
     Parameters
     ----------
-    model : :class:`~.model.TinyGPT`-shaped spec (``prefill_kv`` /
-        ``decode_logits`` / geometry attributes)
+    model : :class:`~.model.TinyGPT`-shaped spec (``prefill_kv`` or
+        ``prefill_cache`` / ``decode_logits`` / geometry attributes)
     params : flat name -> device-array dict
     prefill_buckets : prompt-length buckets (each compiles one prefill
         executable at batch 1)
@@ -331,8 +331,10 @@ class DecodeEngine:
                          else _env.get("MXNET_TPU_SERVING_KV_BLOCK"))
         num_blocks = int(num_blocks if num_blocks is not None
                          else _env.get("MXNET_TPU_SERVING_KV_BLOCKS"))
-        # the model declares what a token keeps in a layer, and (a
-        # model with window layers) which layer is of which kind
+        # the model declares what a token keeps in a layer, (a model
+        # with window layers) which layer is of which kind, and (a model
+        # that runs its layers several times) how many cache layers a
+        # layer of weights keeps: the cache's layers are not the model's
         kinds = model.cache_layers() \
             if hasattr(model, "cache_layers") else None
         self.cache = PagedKVCache(
@@ -340,7 +342,8 @@ class DecodeEngine:
             dtype=kv_dtype, kinds=kinds,
             window=getattr(model, "sliding_window", None),
             window_blocks=window_blocks,
-            fold_heads=getattr(model, "cache_fold_heads", False))
+            fold_heads=getattr(model, "cache_fold_heads", False),
+            passes=getattr(model, "cache_passes", 1))
         # fixed compiled block-table widths: a full layer's enough for
         # the longest sequence the model can hold, a window layer's the
         # ring
@@ -376,19 +379,26 @@ class DecodeEngine:
         import jax
         import jax.numpy as jnp
         bs = self.cache.block_size
-        logits, rows, stats = self.model.prefill_kv(params, tokens,
-                                                    true_len - 1)
-        with jax.named_scope("mx.kv_scatter"):
-            # each layer's prompt rows into that layer's own slab,
-            # through the table of the layer's kind; a window layer
-            # keeps what its ring holds at the prompt's end
-            tables = table if isinstance(table, dict) else {FULL: table}
-            slabs = {name: tuple(
-                write_prompt(slab, r, tables[kind], true_len, bs,
-                             ring=kind == WINDOW)
-                for slab, r, kind in zip(layers, rows[name],
-                                         self.cache.kinds))
-                for name, layers in slabs.items()}
+        if hasattr(self.model, "prefill_cache"):
+            # a model that runs its layers several times writes each
+            # pass's rows itself, inside its loop over the passes
+            logits, slabs, stats = self.model.prefill_cache(
+                params, slabs, tokens, true_len - 1, table, bs)
+        else:
+            logits, rows, stats = self.model.prefill_kv(params, tokens,
+                                                        true_len - 1)
+            with jax.named_scope("mx.kv_scatter"):
+                # each layer's prompt rows into that layer's own slab,
+                # through the table of the layer's kind; a window layer
+                # keeps what its ring holds at the prompt's end
+                tables = table if isinstance(table, dict) \
+                    else {FULL: table}
+                slabs = {name: tuple(
+                    write_prompt(slab, r, tables[kind], true_len, bs,
+                                 ring=kind == WINDOW)
+                    for slab, r, kind in zip(layers, rows[name],
+                                             self.cache.kinds))
+                    for name, layers in slabs.items()}
         with jax.named_scope("mx.lm_head"):
             first_token = jnp.argmax(logits).astype(jnp.int32)
         return (first_token, stats), slabs
@@ -803,9 +813,11 @@ class DecodeEngine:
         """The counts a program returned beside its token (a model with
         routed experts: ``moe_assignments``, ``moe_assignments_held``,
         ``moe_expert_tokens_max``; one with window layers: ``kv_rows_full``,
-        ``kv_rows_window``; empty for a dense one of full layers): onto
-        the step's or the prefill's span and the ``decode.moe.*`` /
-        ``decode.kv.*`` counters."""
+        ``kv_rows_window``; one that runs its layers several times:
+        ``ut_passes``, ``exit_step_sum``, ``exit_early``, ``kv_rows``;
+        empty for a dense one of full layers run once): onto the step's
+        or the prefill's span and the ``decode.moe.*`` / ``decode.kv.*``
+        / ``decode.ut.*`` counters."""
         if not stats:
             return
         stats = {k: int(v) for k, v in stats.items()}
@@ -815,6 +827,8 @@ class DecodeEngine:
                 _telemetry.hooks.decode_moe(self._label, stats)
             if "kv_rows_full" in stats:
                 _telemetry.hooks.decode_kv_rows(self._label, stats)
+            if "ut_passes" in stats:
+                _telemetry.hooks.decode_ut_passes(self._label, stats)
 
     def _call_failed(self, error, served, dispatched):
         """A prefill or decode call raised.  Before the call took its

@@ -68,6 +68,21 @@ block_size) * block_size .. p`` at their offsets, and the ``ring - 1``
 entries before it (cyclically) the ``ring - 1`` blocks before that one;
 rows past ``p`` in its entry are stale.
 
+**Passes**: a model that runs its stack of layers several times a
+token over ONE set of weights keeps a token's rows once a pass a layer
+(``model.cache_passes``): a **cache layer** is then one (pass, layer)
+pair, and there are ``cache_layers = passes * layers`` of them over
+``layers`` layers of weights.  A layer of weights still owns ONE array a
+row, which holds the blocks of all its passes: ``passes * num_blocks``
+blocks, pass ``t``'s copy of block ``b`` at ``t * num_blocks + b``.  The
+allocator, the block tables and the gauges know nothing of passes -- a
+sequence holds the same block ids in every pass -- and a program reaches
+pass ``t`` by adding ``t * num_blocks`` to the table it hands
+:func:`write_tokens`, :func:`write_prompt` and the attention kernel,
+whose block index is data: no slab is ever sliced by pass.  Block ``t *
+num_blocks`` is the scratch block of pass ``t``.  :meth:`slab_bytes` and
+``stats()["kv_bytes_per_token"]`` count every cache layer.
+
 **Folded heads**: a ``(heads, width)`` row lies ``(num_blocks,
 block_size, heads, lanes)`` by default.  A bfloat16 array's tiles are
 (16, 128), so 4 K/V heads in the second-minor dimension would be padded
@@ -255,7 +270,7 @@ class PagedKVCache:
 
     Parameters
     ----------
-    layers : how many layers keep rows
+    layers : how many layers own slabs (the model's layers of weights)
     rows : ``{name: shape}``, what ONE token holds in ONE layer, as the
         model declares it (``model.cache_rows()``): ``{"k": (heads,
         head_dim), "v": (heads, head_dim)}`` or ``{"latent": (576,)}``
@@ -269,11 +284,13 @@ class PagedKVCache:
     window_blocks : total blocks in a window layer's slab
     fold_heads : lay a ``(heads, width)`` row's heads into the block's
         rows (module doc)
+    passes : cache layers a layer of weights keeps
+        (``model.cache_passes``; module doc, "Passes")
     """
 
     def __init__(self, layers, rows, block_size, num_blocks,
                  dtype="float32", kinds=None, window=None,
-                 window_blocks=None, fold_heads=False):
+                 window_blocks=None, fold_heads=False, passes=1):
         import numpy as np
         if block_size < 1 or num_blocks < 2:
             raise MXNetError(
@@ -285,7 +302,12 @@ class PagedKVCache:
             raise MXNetError(
                 "PagedKVCache needs the rows a token holds, {name: "
                 "shape} with positive sizes, got %r" % (rows,))
+        if passes < 1:
+            raise MXNetError("PagedKVCache needs passes >= 1, got %r"
+                             % (passes,))
         self.layers = int(layers)
+        self.passes = int(passes)
+        self.cache_layers = self.layers * self.passes
         self.kinds = tuple(kinds) if kinds is not None \
             else (FULL,) * self.layers
         if len(self.kinds) != self.layers \
@@ -323,7 +345,8 @@ class PagedKVCache:
 
     def _slab_shape(self, name, kind):
         shape = self.rows[name]
-        head = (self._pools[kind].num_blocks, self.block_size)
+        head = (self.passes * self._pools[kind].num_blocks,
+                self.block_size)
         if self.fold_heads and len(shape) == 2:
             head, shape = (head[0], head[1] * shape[0]), shape[1:]
         return head + shape[:-1] + (lanes_for(shape[-1]),)
@@ -358,6 +381,13 @@ class PagedKVCache:
         """Whether a call consumed the slabs without handing new ones
         back (a donating call that raised after it took them)."""
         return any(a.is_deleted() for a in self._arrays())
+
+    def kv_bytes_per_token(self):
+        """Bytes the rows of ONE token take over all cache layers, at
+        the rows' own width (lanes a slab pads them to not counted)."""
+        import math
+        return self.cache_layers * self.dtype.itemsize * sum(
+            math.prod(shape) for shape in self.rows.values())
 
     # -- sizing ---------------------------------------------------------
     def blocks_for(self, n_tokens):
@@ -484,6 +514,8 @@ class PagedKVCache:
                 "blocks_in_use": full.in_use,
                 "free_blocks": len(full.free),
                 "fragmentation": round(self._fragmentation_locked(), 4),
+                "cache_layers": self.cache_layers,
+                "kv_bytes_per_token": self.kv_bytes_per_token(),
             }
             if WINDOW in self._pools:
                 ring = self._pools[WINDOW]

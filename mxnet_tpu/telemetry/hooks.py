@@ -45,7 +45,7 @@ __all__ = [
     "fleet_round", "fleet_alert", "fleet_alerts_firing",
     "decode_request", "decode_shed", "decode_prefill", "decode_step",
     "decode_ttft", "decode_inter_token", "decode_finish",
-    "decode_kv_aliased", "decode_moe",
+    "decode_kv_aliased", "decode_moe", "decode_ut_passes",
     "kvcache_alloc", "kvcache_free", "kvcache_alloc_failure",
 ]
 
@@ -438,6 +438,20 @@ def decode_kv_rows(model, stats):
     reg.counter("decode.kv.rows_full").inc(stats.get("kv_rows_full", 0))
     reg.counter("decode.kv.rows_window").inc(
         stats.get("kv_rows_window", 0))
+
+
+def decode_ut_passes(model, stats):
+    """The counts a prefill or decode program of a model that runs its
+    layers several times returned beside its token: passes run for the
+    call's live slots, the sum of the passes they exit at (``tau``), how
+    many exit before the last pass, and the live context rows ONE cache
+    layer had to read."""
+    reg = _registry()
+    reg.counter("decode.ut.passes").inc(stats.get("ut_passes", 0))
+    reg.counter("decode.ut.exit_step_sum").inc(
+        stats.get("exit_step_sum", 0))
+    reg.counter("decode.ut.exit_early").inc(stats.get("exit_early", 0))
+    reg.counter("decode.kv.rows").inc(stats.get("kv_rows", 0))
 
 
 def kvcache_alloc(in_use, fragmentation, by_kind=None):
@@ -1085,6 +1099,22 @@ INSTRUMENTS = [
     _ii("decode.kv.rows_window", "counter", "serving", 32,
         "likewise for ONE window layer: the sum of min(context, "
         "sliding_window) (kv_rows_window)"),
+    _ii("decode.ut.passes", "counter", "serving", 34,
+        "passes through the stack of layers that the prefill and decode "
+        "programs of a model that runs its layers several times ran for "
+        "their live slots (total_ut_steps a slot a call: ut_passes, which "
+        "the program returns beside its token); over the tokens emitted "
+        "it is the passes a token costs"),
+    _ii("decode.ut.exit_step_sum", "counter", "serving", 34,
+        "sum over those slots of the pass whose hidden state the exit "
+        "gate handed to the head (tau, from 1)"),
+    _ii("decode.ut.exit_early", "counter", "serving", 34,
+        "slots of those calls whose gate chose a pass before the last "
+        "(tau < total_ut_steps); every pass is run all the same"),
+    _ii("decode.kv.rows", "counter", "serving", 34,
+        "cache rows ONE cache layer (one pass of one layer) had to read "
+        "in those programs: the live slots' context lengths, summed "
+        "(kv_rows)"),
     _ii("kvcache.allocs", "counter", "serving", 18,
         "block-table allocations (one per admitted request)"),
     _ii("kvcache.frees", "counter", "serving", 18,

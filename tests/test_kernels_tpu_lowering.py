@@ -112,6 +112,21 @@ def test_paged_attention_mellum2_decode_buckets(slots, window, blocks,
         S((slots, table), I32), S((slots, 1), I32))
 
 
+@pytest.mark.parametrize("slots", [8, 16])
+def test_paged_attention_ouro_decode_buckets(slots):
+    # ouro_serve_closed16 as ``LoopedDecoder.decode_logits`` calls the
+    # kernel: 16 heads of 128 in bfloat16 (a (16, 128) tile a token, so
+    # the heads stay a dimension of their own), a layer's slab of 4
+    # passes x 81 blocks of 64 tokens, a table 8 wide: one page group a
+    # slot, 8 pages a grid step, the pass chosen by the table's entries
+    slab = S((324, 64, 16, 128), BF16)
+    _lowers_to_mosaic(
+        lambda q, k, v, bt, cl: paged_attention_pallas(
+            q, k, v, bt + 3 * 81, cl, scale=128 ** -0.5),
+        S((slots, 16, 128), BF16), slab, slab,
+        S((slots, 8), I32), S((slots, 1), I32))
+
+
 _GROUPED = {"gate_up": (2304, 896), "down": (896, 2304)}
 
 
@@ -307,6 +322,57 @@ def test_window_and_full_layers_write_both_pools_in_place_on_the_chip(
     assert "ragged-dot" not in text
     moved = re.findall(r"= bf16\[(?:137|2097),256,128\]\S* (?:copy|slice)\(",
                        text)
+    assert not moved, moved[:3]
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_a_looped_decoders_passes_write_one_cache_in_place_on_the_chip(
+        one_chip, no_compile_cache, monkeypatch, kind):
+    """Ouro-2.6B's widths, two layers run four times: the programs alias
+    every byte of the slabs (each holds the blocks of all four passes),
+    keep no slab-sized temporary, copy and slice no slab, and hold ONE
+    kernel call a layer -- the passes are a loop, not four copies."""
+    import re
+
+    from mxnet_tpu.kernels import registry
+    from mxnet_tpu.serving.decode import DecodeEngine, LoopedDecoder
+    monkeypatch.setattr(registry, "_backend", lambda: "tpu")
+    model = LoopedDecoder(
+        vocab_size=2048, hidden_size=2048, num_attention_heads=16,
+        num_key_value_heads=16, head_dim=128, intermediate_size=5632,
+        num_hidden_layers=2, total_ut_steps=4, early_exit_threshold=1,
+        rope_theta=1000000, max_seq=512)
+    params = {name: S(shape, F32 if kind_ == "bias" else BF16)
+              for name, (shape, kind_) in model.param_shapes().items()}
+    eng = DecodeEngine(model, params, prefill_buckets=(128,),
+                       decode_buckets=(16,), block_size=64, num_blocks=81,
+                       kv_dtype="bfloat16")
+    assert [a.shape for a in eng.cache.slabs["k"]] \
+        == [(4 * 81, 64, 16, 128)] * 2
+    # 192 x 2 x 16 x 128 x 2 B a token at the whole depth, no padding
+    slab = 4 * 81 * 64 * 16 * 128 * 2
+    assert eng.cache.slab_bytes() == 2 * 2 * slab
+    assert eng.cache.kv_bytes_per_token() * 48 // 2 == 1572864
+    assert eng._table_widths == {"full": 8}
+    prefill, decode = eng._specs()
+    fn, specs = (eng._decode_impl, decode[16]) if kind == "decode" \
+        else (eng._prefill_impl, prefill[128])
+    specs = jax.tree.map(
+        lambda s: S(s.shape, s.dtype, sharding=one_chip), specs)
+    compiled = jax.jit(fn, donate_argnums=eng._DONATED).lower(
+        *specs).compile()
+    stats = compiled.memory_analysis()
+    assert stats.alias_size_in_bytes == eng.cache.slab_bytes()
+    # a step keeps next to nothing; a prefill the compiler's re-laid
+    # copies of two 2,048 x 2,048 weights a layer, hoisted out of the loop
+    assert stats.temp_size_in_bytes < (slab // 2 if kind == "decode"
+                                       else slab)
+    text = compiled.as_text()
+    assert len(re.findall(r"paged_attention_pallas\S* = ", text)) \
+        == (model.num_layers if kind == "decode" else 0)
+    assert len(re.findall(r" while\(", text)) >= 1
+    moved = re.findall(
+        r"= bf16\[324,64,16,128\]\S* (?:copy|slice|dynamic-slice)\(", text)
     assert not moved, moved[:3]
 
 
