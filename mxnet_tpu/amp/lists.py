@@ -30,7 +30,13 @@ TARGET_DTYPE_OPS = [
 # FP32_FUNCS core; softmax/losses).  BatchNorm/LayerNorm are NOT here:
 # their kernels accumulate stats in fp32 internally while activations
 # stay in the compute dtype (ops/nn.py), which saves two full-tensor
-# casts per normalization.
+# casts per normalization.  sparse_softmax_ce (the op under
+# gluon.loss.SoftmaxCrossEntropyLoss for integer labels over the last
+# axis) is NOT here for the same reason: it takes the logits in the
+# compute dtype, keeps the maximum, the exponent, the sum and the loss in
+# fp32 inside, and its own backward writes the gradient once in the
+# logits' dtype -- listed here, the cast's transpose would write that
+# gradient in fp32 over the whole vocabulary and copy it back.
 FP32_OPS = [
     "L2Normalization",
     "softmax",

@@ -1,6 +1,7 @@
 """Loss blocks (reference: ``python/mxnet/gluon/loss.py``)."""
 from __future__ import annotations
 
+from .. import telemetry as _telemetry
 from ..base import MXNetError
 from .block import HybridBlock
 
@@ -48,7 +49,16 @@ class L1Loss(Loss):
 
 class SoftmaxCrossEntropyLoss(Loss):
     """Reference: ``SoftmaxCrossEntropyLoss`` -- the canonical classifier
-    loss (BASELINE configs 1-2)."""
+    loss (BASELINE configs 1-2).
+
+    Integer labels over logits with the classes on the last axis
+    (``sparse_label=True``, ``from_logits=False``, ``axis`` the last one)
+    go through ONE op, ``sparse_softmax_ce``: it takes ``pred`` in the
+    dtype it arrives in (bf16/fp16 under AMP), computes in float32 inside
+    and writes the gradient once, in ``pred``'s dtype.  Dense labels,
+    ``from_logits=True`` and any other axis keep ``log_softmax`` +
+    ``pick`` / ``sum``.  Which path a call took is counted
+    (``loss.softmax_ce_fused`` / ``loss.softmax_ce_fallback``)."""
 
     def __init__(self, axis=-1, sparse_label=True, from_logits=False,
                  weight=1.0, batch_axis=0, **kwargs):
@@ -58,13 +68,22 @@ class SoftmaxCrossEntropyLoss(Loss):
         self._from_logits = from_logits
 
     def hybrid_forward(self, F, pred, label, sample_weight=None):
-        if not self._from_logits:
-            pred = F.log_softmax(pred, axis=self._axis)
-        if self._sparse_label:
-            loss = -F.pick(pred, label, axis=self._axis, keepdims=True)
+        # a Symbol has no rank to ask: there only -1 says "the last axis"
+        last = getattr(pred, "ndim", 0) - 1
+        fused = self._sparse_label and not self._from_logits \
+            and self._axis in (-1, last)
+        if _telemetry._ENABLED:
+            _telemetry.hooks.loss_softmax_ce(fused)
+        if fused:
+            loss = F.sparse_softmax_ce(pred, label, keepdims=True)
         else:
-            label = _reshape_like(F, pred, label)
-            loss = -F.sum(pred * label, axis=self._axis, keepdims=True)
+            if not self._from_logits:
+                pred = F.log_softmax(pred, axis=self._axis)
+            if self._sparse_label:
+                loss = -F.pick(pred, label, axis=self._axis, keepdims=True)
+            else:
+                label = _reshape_like(F, pred, label)
+                loss = -F.sum(pred * label, axis=self._axis, keepdims=True)
         loss = _apply_weighting(F, loss, self._weight, sample_weight)
         return F.mean(loss, axis=self._batch_axis, exclude=True)
 

@@ -506,6 +506,75 @@ def _softmax_cross_entropy(data, label):
     return -jnp.sum(picked)
 
 
+def _ce_label_hit(data, label):
+    """Where each row's class is its label's: labels cast to int32 and
+    clipped into the class range (the reference's pick mode="clip")."""
+    nclass = data.shape[-1]
+    idx = jnp.clip(label.astype(jnp.int32), 0, nclass - 1)
+    classes = lax.broadcasted_iota(jnp.int32, data.shape, data.ndim - 1)
+    return classes == idx[..., None]
+
+
+def _sparse_softmax_ce_rows(data, label):
+    """Per-row loss and log-sum-exp, both float32 whatever ``data`` is."""
+    with jax.named_scope("softmax_ce"):
+        x = data.astype(jnp.float32)
+        m = jnp.max(x, axis=-1, keepdims=True)
+        lse = m[..., 0] + jnp.log(jnp.sum(jnp.exp(x - m), axis=-1))
+        # the label's logit as a masked sum, a reduction like the one
+        # beside it: a gather makes XLA lay the logits out anew for it,
+        # in float32
+        hit = _ce_label_hit(data, label)
+        return lse - jnp.sum(jnp.where(hit, x, 0.0), axis=-1), lse
+
+
+@jax.custom_vjp
+def _sparse_softmax_ce_core(data, label):
+    return _sparse_softmax_ce_rows(data, label)[0]
+
+
+def _sparse_softmax_ce_core_fwd(data, label):
+    loss, lse = _sparse_softmax_ce_rows(data, label)
+    # the residuals: the logits as they arrived, the labels and one
+    # float32 a row -- never a float32 array over the classes
+    return loss, (data, label, lse)
+
+
+def _sparse_softmax_ce_core_bwd(res, g):
+    data, label, lse = res
+    # the transposed ops of a custom_vjp are traced here, outside the
+    # forward's scope: name them, so a trace still files them under it
+    with jax.named_scope("softmax_ce"):
+        p = jnp.exp(data.astype(jnp.float32) - lse[..., None])
+        grad = jnp.where(_ce_label_hit(data, label), p - 1.0, p)
+        # ONE pass: float32 in registers, written once in data's dtype
+        return (grad * g[..., None]).astype(data.dtype), None
+
+
+_sparse_softmax_ce_core.defvjp(_sparse_softmax_ce_core_fwd,
+                               _sparse_softmax_ce_core_bwd)
+
+
+@register("sparse_softmax_ce", args=("data", "label"))
+def _sparse_softmax_ce(data, label, keepdims=False):
+    """Softmax cross-entropy of integer class labels over the LAST axis,
+    one float32 loss a row: ``logsumexp(data) - data[label]``.
+
+    ``data`` is taken in the dtype it arrives in (bf16/fp16 under AMP:
+    the op is NOT in ``amp.lists.FP32_OPS``); the maximum, the exponent,
+    the sum and the loss are float32 inside, as the norms keep their
+    statistics.  Its own backward (``jax.custom_vjp``) keeps ``data``,
+    ``label`` and the float32 log-sum-exp of each row, and writes
+    ``(softmax(data) - onehot(label)) * g`` once, in ``data``'s dtype:
+    no float32 array over the classes is stored or handed on, where
+    ``log_softmax`` + ``pick`` under AMP write the gradient in float32
+    and copy it to the compute dtype.  Labels are cast to int32 and
+    clipped into the class range; ``label.shape == data.shape[:-1]``.
+    """
+    loss = _sparse_softmax_ce_core(data, label)
+    return loss[..., None] if keepdims else loss
+
+
 @register("smooth_l1", args=("data",))
 def _smooth_l1(data, scalar=1.0):
     s2 = scalar * scalar

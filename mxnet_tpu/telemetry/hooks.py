@@ -47,6 +47,7 @@ __all__ = [
     "decode_ttft", "decode_inter_token", "decode_finish",
     "decode_kv_aliased", "decode_moe", "decode_ut_passes",
     "kvcache_alloc", "kvcache_free", "kvcache_alloc_failure",
+    "loss_softmax_ce",
 ]
 
 
@@ -159,6 +160,14 @@ def amp_overflow(scale_before, scale_after):
     reg.gauge("amp.loss_scale").set(scale_after)
     reg.event("amp.overflow").emit(scale_before=scale_before,
                                    scale_after=scale_after)
+
+
+def loss_softmax_ce(fused):
+    """``SoftmaxCrossEntropyLoss`` took the one fused op
+    (``sparse_softmax_ce``) or its ``log_softmax`` + ``pick`` / ``sum``
+    lines: counted a call, which inside a compiled step is a trace."""
+    _registry().counter("loss.softmax_ce_fused" if fused
+                        else "loss.softmax_ce_fallback").inc()
 
 
 def amp_rescale(scale_before, scale_after):
@@ -788,6 +797,13 @@ INSTRUMENTS = [
     _ii("amp.rescale", "event", "amp", 2,
         "loss-scale growth after a clean window"),
     _ii("amp.loss_scale", "gauge", "amp", 2, "current loss scale"),
+    _ii("loss.softmax_ce_fused", "counter", "gluon", 35,
+        "SoftmaxCrossEntropyLoss calls (traces, inside a compiled "
+        "step) that took the fused op sparse_softmax_ce: integer "
+        "labels over logits, classes on the last axis"),
+    _ii("loss.softmax_ce_fallback", "counter", "gluon", 35,
+        "the same that kept log_softmax + pick / sum: dense labels, "
+        "from_logits, another axis"),
     _ii("numerics.checks", "counter", "numerics", 16,
         "non-finite sentinel checks run (MXNET_TPU_NUMERICS_CHECK=1)"),
     _ii("numerics.check_time", "timer", "numerics", 16,

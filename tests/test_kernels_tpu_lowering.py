@@ -389,3 +389,62 @@ def test_the_grouped_matmuls_tiles_fit_the_chips_fast_memory(
     jax.jit(grouped_matmul_pallas).lower(
         on_chip((2048, k), BF16), on_chip((64, k, n), BF16),
         on_chip((64,), I32)).compile()
+
+
+# ----------------------------------------------------------------------
+# compiled for a described chip: the MLM head's loss writes no float32
+# array over the vocabulary
+# ----------------------------------------------------------------------
+
+@pytest.mark.parametrize("loss,writes_float32", [("sparse_softmax_ce", False),
+                                                 ("log_softmax_pick", True)])
+def test_the_loss_over_the_vocabulary_keeps_only_the_bf16_logits(
+        one_chip, no_compile_cache, loss, writes_float32):
+    """BERT-base's MLM decoder (768 -> 30,522, every position of 4 x 512)
+    and the loss under bf16 AMP, forward and backward, as ``MLMLoss``
+    reshapes them: with the one op the program's temporaries are the bf16
+    logits and no instruction of the entry computation writes a float32
+    array of rows x vocabulary; ``log_softmax`` + ``pick`` spelled out is
+    the control that does (the AMP cast's copy and the softmax's
+    backward: 10.7 ms of a 132 ms step on the chip, PERF.md, PR 35)."""
+    import re
+
+    import mxnet_tpu as mx
+    from mxnet_tpu import amp, gluon
+    from mxnet_tpu.ndarray import NDArray
+    batch, seq, units, vocab = 4, 512, 768, 30522
+    ce = gluon.loss.SoftmaxCrossEntropyLoss()
+
+    def spelled_out(pred, label):
+        return -mx.nd.pick(mx.nd.log_softmax(pred), label, axis=-1,
+                           keepdims=True)
+
+    loss_fn = ce if loss == "sparse_softmax_ce" else spelled_out
+
+    def head(hidden, weight, bias, labels):
+        def summed(hidden, weight, bias):
+            with amp.scope("bfloat16"):
+                logits = mx.nd.FullyConnected(
+                    NDArray(hidden), NDArray(weight), NDArray(bias),
+                    num_hidden=vocab, flatten=False)
+                rows = loss_fn(logits.reshape((-1, vocab)),
+                               NDArray(labels).reshape((-1,)))
+            return jnp.sum(rows._data)
+        return jax.value_and_grad(summed, argnums=(0, 1, 2))(
+            hidden, weight, bias)
+
+    def on_chip(*shape):
+        return S(shape, F32, sharding=one_chip)
+    compiled = jax.jit(head).lower(
+        on_chip(batch, seq, units), on_chip(vocab, units), on_chip(vocab),
+        on_chip(batch, seq)).compile()
+    logits_bytes = batch * seq * vocab * 2
+    entry = compiled.as_text().split("\nENTRY ", 1)[1]
+    wide = re.findall(r"= f32\[(?:%d,%d|%d),%d\]\S* (\S+?)\("
+                      % (batch, seq, batch * seq, vocab), entry)
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    if writes_float32:
+        assert wide and temp > 3 * logits_bytes, (wide, temp)
+    else:
+        assert not wide, wide
+        assert temp < 1.25 * logits_bytes, temp
