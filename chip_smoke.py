@@ -87,10 +87,10 @@ LOSS_TOL = 5e-2
 
 
 # The programs a run exists to compile: the train step, the K-step
-# scan, and the serving executables (the export wrapper ``jit_call`` of
-# each prefill/decode bucket).  A second run in the same call must find
+# scan, and the serving executables (``jit_mx_<kind>_b<bucket>``, one a
+# prefill and a decode bucket).  A second run in the same call must find
 # every one of them in the persistent cache.
-MAIN_PROGRAMS = ("jit_step_fn", "jit_scan_fn", "jit_call")
+MAIN_PROGRAMS = ("jit_step_fn", "jit_scan_fn", "jit_mx_")
 
 
 class CacheLog(logging.Filter):
@@ -123,7 +123,7 @@ class CacheLog(logging.Filter):
         seen = self.lookups - since if since else self.lookups
         main = {}
         for (name, kind), n in seen.items():
-            if name in MAIN_PROGRAMS:
+            if name.startswith(MAIN_PROGRAMS):
                 main.setdefault(name, {"hit": 0, "miss": 0})[kind] = n
         return {"cache_hits": sum(n for (_p, k), n in seen.items()
                                   if k == "hit"),

@@ -218,17 +218,21 @@ def routed_experts(x, chosen, weights, w_gate, w_up, w_down, first_expert,
             x, jnp.where(held, local, n_held), weights, w_gate, w_up, w_down)
     # an assignment's expert here, n_held for one that is not ours
     expert = jnp.where(held, local, n_held).reshape(n_assign)
-    # a compare-and-sum, not a scatter-add: every update of a scatter
-    # into n_held bins collides, and a TPU runs those one by one
-    counts = jnp.sum(expert[:, None] == jnp.arange(n_held, dtype=jnp.int32),
-                     axis=0, dtype=jnp.int32)
-    ends = jnp.cumsum(counts)
-    starts, total = ends - counts, ends[-1]
     chunk = min(int(chunk_rows), n_assign)
     n_chunks = -(-n_assign // chunk)
-    # ours first and grouped by expert; padded to whole chunks
-    order = jnp.argsort(expert, stable=True).astype(jnp.int32)
-    order = jnp.pad(order, (0, n_chunks * chunk - n_assign))
+    # the four parts bear a scope each under the caller's (a device
+    # trace reads ``h3/experts/gather``): sort, gather, matmul, combine
+    with jax.named_scope("sort"):
+        # a compare-and-sum, not a scatter-add: every update of a scatter
+        # into n_held bins collides, and a TPU runs those one by one
+        counts = jnp.sum(
+            expert[:, None] == jnp.arange(n_held, dtype=jnp.int32),
+            axis=0, dtype=jnp.int32)
+        ends = jnp.cumsum(counts)
+        starts, total = ends - counts, ends[-1]
+        # ours first and grouped by expert; padded to whole chunks
+        order = jnp.argsort(expert, stable=True).astype(jnp.int32)
+        order = jnp.pad(order, (0, n_chunks * chunk - n_assign))
     flat_w = weights.reshape(n_assign)
 
     def one_chunk(c, y):
@@ -239,15 +243,18 @@ def routed_experts(x, chosen, weights, w_gate, w_up, w_down, first_expert,
         # this chunk's share of every expert's group
         sizes = jnp.clip(ends - at, 0, chunk) - jnp.clip(starts - at, 0,
                                                           chunk)
-        xs = jnp.take(x, token, axis=0)
-        gate = grouped_matmul(xs, w_gate, sizes, use_pallas)
-        up = grouped_matmul(xs, w_up, sizes, use_pallas)
-        hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
-        out = grouped_matmul(hidden, w_down, sizes, use_pallas)
-        # rows past the last of ours belong to no group
-        out = jnp.where(ours[:, None],
-                        out * jnp.take(flat_w, rows)[:, None], 0.0)
-        return y.at[token].add(out)
+        with jax.named_scope("gather"):
+            xs = jnp.take(x, token, axis=0)
+        with jax.named_scope("matmul"):
+            gate = grouped_matmul(xs, w_gate, sizes, use_pallas)
+            up = grouped_matmul(xs, w_up, sizes, use_pallas)
+            hidden = (jax.nn.silu(gate) * up).astype(x.dtype)
+            out = grouped_matmul(hidden, w_down, sizes, use_pallas)
+        with jax.named_scope("combine"):
+            # rows past the last of ours belong to no group
+            out = jnp.where(ours[:, None],
+                            out * jnp.take(flat_w, rows)[:, None], 0.0)
+            return y.at[token].add(out)
 
     y = jax.lax.fori_loop(0, -(-total // chunk), one_chunk,
                           jnp.zeros((tokens, d), jnp.float32))

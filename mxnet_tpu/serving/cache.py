@@ -22,7 +22,8 @@ import re
 
 from .. import telemetry as _telemetry
 
-__all__ = ["CompileCache", "compile_through", "stablehlo_fingerprint"]
+__all__ = ["CompileCache", "compile_through", "named",
+           "stablehlo_fingerprint"]
 
 # StableHLO normalization: jax stamps every op line with a loc(#locN)
 # reference and appends a #locN = loc("file":line:col) table; the module
@@ -57,7 +58,18 @@ def default_cache_dir():
         or os.path.join(CACHE_ROOT, "serving")
 
 
-def compile_through(cache, key, jfn, lowered, specs, donate_argnums=()):
+def named(fn, name):
+    """``fn`` behind a function called ``name``: ``jax.jit`` calls the
+    module it compiles ``jit_<name>``, and a device trace calls the
+    module's executions that (``XLA Modules``)."""
+    def call(*args):
+        return fn(*args)
+    call.__name__ = call.__qualname__ = name
+    return call
+
+
+def compile_through(cache, key, jfn, lowered, specs, donate_argnums=(),
+                    name=None):
     """AOT-compile one servable program whose lowering is ``lowered``
     (``jfn.lower(*specs)``, fingerprint ``key``).  With a cache, the
     program compiled is the ``jax.export`` wrapper of the artifact --
@@ -65,6 +77,8 @@ def compile_through(cache, key, jfn, lowered, specs, donate_argnums=()):
     so the process that reads it back finds this compile in the
     persistent XLA cache; it is compiled here, not at the first
     request.  A program that cannot be exported is compiled as is.
+    The wrapper's module is ``jit_<name>``, which a caller that named
+    ``jfn``'s function so gives again (None: ``jit_call``).
 
     ``donate_argnums`` are the arguments ``jfn`` donates: the wrapper
     is a jit of its own, so it has to be told too, or the program the
@@ -80,7 +94,8 @@ def compile_through(cache, key, jfn, lowered, specs, donate_argnums=()):
             except Exception:
                 exported = None
         if exported is not None:
-            return jax.jit(exported.call, donate_argnums=donate_argnums
+            call = named(exported.call, name) if name else exported.call
+            return jax.jit(call, donate_argnums=donate_argnums
                            ).lower(*specs).compile()
     return lowered.compile()
 

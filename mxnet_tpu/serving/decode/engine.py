@@ -68,7 +68,7 @@ from ... import sync as _sync
 from ... import telemetry as _telemetry
 from ...base import MXNetError, scopes_in_cache_key
 from ..batcher import RequestTimeout, ServableClosed, ServingQueueFull
-from ..cache import compile_through, stablehlo_fingerprint
+from ..cache import compile_through, named, stablehlo_fingerprint
 from ..loop import RegistryWatcher as _RegistryWatcher
 from .kvcache import (FULL, SCRATCH_BLOCK, WINDOW, KVCacheExhausted,
                       PagedKVCache, write_prompt)
@@ -187,10 +187,12 @@ class _GenRequest:
 class _Flight:
     """One decode step dispatched and not yet fetched: the requests it
     carries in slot order, its outputs on the device (the tokens at the
-    engine's fixed width, the program's counts), when it was dispatched
-    and whether the step before it was still unfetched then."""
+    engine's fixed width, the program's counts), when it was dispatched,
+    whether the step before it was still unfetched then, and whether it
+    runs behind a prefill: its tokens then arrive a prefill late."""
 
-    __slots__ = ("batch", "bucket", "out", "t_dispatch", "overlapped")
+    __slots__ = ("batch", "bucket", "out", "t_dispatch", "overlapped",
+                 "after_prefill")
 
     def __init__(self, batch, bucket, out, t_dispatch, overlapped):
         self.batch = batch
@@ -198,6 +200,7 @@ class _Flight:
         self.out = out
         self.t_dispatch = t_dispatch
         self.overlapped = overlapped
+        self.after_prefill = False
 
 
 def _request_links(reqs):
@@ -245,21 +248,27 @@ class _AotPrograms:
         import jax
         if key in self._programs:
             return self._programs[key]
-        jfn = jax.jit(fn, donate_argnums=donate_argnums)
+        # its kind and bucket are the compiled module's name on both
+        # routes of ``compile_through``: a device trace reads an
+        # execution as ``jit_mx_prefill_b2048(<id>)``
+        name = "mx_%s_b%s" % tuple(key)
+        jfn = jax.jit(named(fn, name), donate_argnums=donate_argnums)
         lowered = jfn.lower(*specs)
         # the lowering drops an argument that the program does not read,
         # the exported artifact's calling convention keeps it: a key of
         # the text alone would hand a caller of six arguments the
-        # artifact of a caller of five
+        # artifact of a caller of five (and, the text being keyed without
+        # its names, this name an artifact made under another)
         fp = stablehlo_fingerprint(
-            lowered.as_text() + "\n// called with %s"
-            % (jax.tree_util.tree_structure(specs),))
+            lowered.as_text() + "\n// %s called with %s"
+            % (name, jax.tree_util.tree_structure(specs)))
         # compiled here, never at the first request: warmup() promises
         # that no request pays a compile; with its scopes in the XLA
         # cache's key, so that a trace reads this version's names
         with scopes_in_cache_key():
             call = compile_through(self._cache, fp, jfn, lowered, specs,
-                                   donate_argnums=donate_argnums)
+                                   donate_argnums=donate_argnums,
+                                   name=name)
         self._programs[key] = call
         self.fingerprints[key] = fp
         self.memory[key] = _memory_of(call)
@@ -639,8 +648,11 @@ class DecodeEngine:
     def _prefill(self, req):
         bucket = self._bucket(self.prefill_buckets, len(req.prompt),
                               "prefill")
+        # whom it holds up: the streams with a token still to dispatch,
+        # and whether it queues behind a decode step in flight
         span = _obs.span("mx.decode.prefill", bucket=bucket,
-                         prompt=len(req.prompt),
+                         prompt=len(req.prompt), live=len(self._live()),
+                         behind=int(self._flight is not None),
                          links=_request_links((req,)))
         with span:
             self._prefill_spanned(req, bucket, span)
@@ -681,7 +693,7 @@ class DecodeEngine:
                 # the token's way to the host and a dispatch's way back
                 # would leave it idle.  This request joins the step
                 # after that one
-                self._step()
+                self._step(after_prefill=True)
                 if req.done:            # that turn lost the cache
                     return
                 with _obs.span("mx.decode.prefill.call"):
@@ -703,12 +715,14 @@ class DecodeEngine:
             if self._maybe_finish(req):
                 self._active.remove(req)
 
-    def _step(self):
+    def _step(self, after_prefill=False):
         """One turn of the loop under ONE ``mx.decode.step`` span: the
         step in flight is fetched and emitted with its successor
         already dispatched behind it.  Where nothing is in flight (the
         first step after an idle moment) the span's step is dispatched
-        here first."""
+        here first.  ``after_prefill``: the turn is a prefill's own, so
+        the first step it dispatches runs behind that prefill, and the
+        span that delivers that step's tokens says so."""
         flight, self._flight = self._flight, None
         batch = flight.batch if flight is not None else self._live()
         span = _obs.span("mx.decode.step", n=len(batch),
@@ -717,16 +731,20 @@ class DecodeEngine:
                          max_slots=self.max_slots,
                          overlapped=int(flight is not None
                                         and flight.overlapped),
+                         after_prefill=int(
+                             flight.after_prefill if flight is not None
+                             else after_prefill),
                          links=_request_links(batch))
         with span:
-            self._step_spanned(flight, batch, span)
+            self._step_spanned(flight, batch, span, after_prefill)
 
-    def _step_spanned(self, flight, batch, span):
+    def _step_spanned(self, flight, batch, span, after_prefill):
         import jax
         with _obs.span("mx.decode.step.build"):
             if flight is None:
                 try:
                     flight = self._dispatch(batch, self._build(batch))
+                    flight.after_prefill, after_prefill = after_prefill, False
                 except Exception as e:
                     self._call_failed(e, batch, dispatched=False)
                     return
@@ -737,6 +755,7 @@ class DecodeEngine:
             if after:
                 try:
                     self._flight = self._dispatch(after, args, flight)
+                    self._flight.after_prefill = after_prefill
                 except Exception as e:
                     if self._call_failed(e, after, dispatched=False):
                         return          # the slabs went with it

@@ -361,9 +361,13 @@ def decode_shed(model, reason):
 
 def decode_prefill(model, bucket, prompt_len, seconds):
     """One prompt prefilled into cache blocks (the first token's
-    compiled call, bucketed by padded prompt length)."""
+    compiled call, bucketed by padded prompt length: the device computes
+    ``bucket`` tokens, of which ``bucket - prompt_len`` are padding)."""
     reg = _registry()
     reg.counter("decode.prefills").inc()
+    reg.counter("decode.prefill.tokens").inc(int(prompt_len))
+    reg.counter("decode.prefill.padded_tokens").inc(
+        int(bucket) - int(prompt_len))
     reg.timer("decode.prefill_time").observe(seconds, model=model,
                                              bucket=bucket,
                                              prompt_len=prompt_len)
@@ -1063,6 +1067,11 @@ INSTRUMENTS = [
         "prompt prefill calls (one per admitted request)"),
     _ii("decode.prefill_time", "timer", "serving", 18,
         "prefill call wall time, tagged bucket + prompt_len"),
+    _ii("decode.prefill.tokens", "counter", "serving", 36,
+        "prompt tokens prefilled (the useful part of the buckets)"),
+    _ii("decode.prefill.padded_tokens", "counter", "serving", 36,
+        "padding the prefills computed beside them (bucket - prompt): "
+        "over the sum of the two, the bucketed prefill's wasted share"),
     _ii("decode.steps", "counter", "serving", 18,
         "continuous-batching decode iterations"),
     _ii("decode.tokens", "counter", "serving", 18,

@@ -173,12 +173,9 @@ def test_a_profiler_trace_of_a_decode_engine_holds_one_span_a_step(tmp_path):
         steps, key=lambda s: s.start)] == [0, 1, 1, 1]
 
 
-def test_a_prefill_beside_running_streams_holds_their_next_step(tmp_path):
-    """A request admitted while another decodes: its prefill is
-    dispatched, the running stream's next step behind it, and only then
-    is the first token waited for -- two ``.call`` spans with the step
-    between them, neither holding the other (a reader that adds up the
-    ``.call`` spans counts no moment twice)."""
+def _a_prefill_beside_a_running_stream(tmp_path):
+    """The ``mx.`` spans of a trace in which one request is admitted
+    while another decodes."""
     from mxnet_tpu import chaos
     eng = _engine()
     try:
@@ -194,9 +191,18 @@ def test_a_prefill_beside_running_streams_holds_their_next_step(tmp_path):
                 assert len(eng.submit([5, 5, 6], 3).tokens()) == 3
                 first.tokens()
 
-        spans = _traced(tmp_path, work)
+        return _traced(tmp_path, work)
     finally:
         eng.close(drain=False)
+
+
+def test_a_prefill_beside_running_streams_holds_their_next_step(tmp_path):
+    """A request admitted while another decodes: its prefill is
+    dispatched, the running stream's next step behind it, and only then
+    is the first token waited for -- two ``.call`` spans with the step
+    between them, neither holding the other (a reader that adds up the
+    ``.call`` spans counts no moment twice)."""
+    spans = _a_prefill_beside_a_running_stream(tmp_path)
     lone, beside = sorted(_named(spans, "mx.decode.prefill"),
                           key=lambda s: s.start)
     assert [c.name for c in _children(spans, lone)] == [
@@ -216,6 +222,138 @@ def test_a_prefill_beside_running_streams_holds_their_next_step(tmp_path):
     later = [s for s in _named(spans, "mx.decode.step")
              if s.start > beside.end]
     assert later[0].attrs["n"] == 1 and later[1].attrs["n"] == 2
+
+
+def test_a_prefill_says_whom_it_held_and_the_step_behind_it_says_so(
+        tmp_path):
+    """``live`` and ``behind`` on ``mx.decode.prefill``: the streams with
+    a token still to dispatch and whether a decode step was in flight at
+    its dispatch.  ``after_prefill`` on ``mx.decode.step``: 1 on the span
+    of the step that ran BEHIND the prefill (the one whose tokens arrive a
+    prefill late), not on the turn that dispatched it."""
+    spans = _a_prefill_beside_a_running_stream(tmp_path)
+    lone, beside = sorted(_named(spans, "mx.decode.prefill"),
+                          key=lambda s: s.start)
+    assert (lone.attrs["live"], lone.attrs["behind"]) == (0, 0)
+    assert (beside.attrs["live"], beside.attrs["behind"]) == (1, 1)
+    assert (beside.attrs["bucket"], beside.attrs["prompt"]) == (8, 3)
+    steps = sorted(_named(spans, "mx.decode.step"), key=lambda s: s.start)
+    nested, = [s for s in steps
+               if beside.start <= s.start and s.end <= beside.end]
+    # the nested turn delivers the step that was in flight BEFORE the
+    # prefill; the step it dispatched is delivered by the next span
+    assert nested.attrs["after_prefill"] == 0
+    held = steps[steps.index(nested) + 1]
+    assert held.start > beside.end and held.attrs["after_prefill"] == 1
+    assert [s.attrs["after_prefill"] for s in steps
+            if s is not held] == [0] * (len(steps) - 1)
+
+
+def test_a_step_dispatched_by_a_prefills_own_turn_runs_behind_it(tmp_path):
+    """Nothing in flight and a stream to step (the first request's prefill
+    has just ended, the second is admitted in the same pass): the second
+    prefill's turn dispatches the first stream's step behind the prefill,
+    and that step's own span carries ``after_prefill``; its successor,
+    dispatched in the same turn, does not."""
+    eng = DecodeEngine(MODEL, MODEL.init_params(0), **ENGINE_KW)
+    eng.warmup()
+    try:
+        first = eng.submit([3, 7, 1], 4)
+        second = eng.submit([5, 5, 6], 2)   # both pending before the loop
+
+        def work():
+            eng.start()
+            assert len(first.tokens()) == 4 and len(second.tokens()) == 2
+
+        spans = _traced(tmp_path, work)
+    finally:
+        eng.close(drain=False)
+    lone, beside = sorted(_named(spans, "mx.decode.prefill"),
+                          key=lambda s: s.start)
+    assert (lone.attrs["live"], lone.attrs["behind"]) == (0, 0)
+    assert (beside.attrs["live"], beside.attrs["behind"]) == (1, 0)
+    steps = sorted(_named(spans, "mx.decode.step"), key=lambda s: s.start)
+    assert beside.start <= steps[0].start and steps[0].end <= beside.end
+    assert [s.attrs["after_prefill"] for s in steps] \
+        == [1] + [0] * (len(steps) - 1)
+    assert steps[0].attrs["overlapped"] == 0
+
+
+@pytest.mark.parametrize("route", ["plain", "exported"])
+def test_a_compiled_serving_program_bears_its_kind_and_bucket(route,
+                                                              tmp_path):
+    """Both routes of ``compile_through`` compile a module called
+    ``jit_mx_<kind>_b<bucket>``: what a device trace calls the program's
+    executions, and what ``obs.program_scopes()`` says of each label."""
+    from mxnet_tpu.serving.cache import CompileCache
+    cache = CompileCache(str(tmp_path)) if route == "exported" else None
+    label = "named_" + route
+    eng = DecodeEngine(MODEL, MODEL.init_params(0), label=label, cache=cache,
+                       **ENGINE_KW)
+    eng.warmup()
+    noted = obs.program_scopes()
+    assert {k: v["module"] for k, v in noted.items()
+            if k.startswith(label + ":")} == {
+        label + ":prefill:8": "jit_mx_prefill_b8",
+        label + ":decode:1": "jit_mx_decode_b1",
+        label + ":decode:2": "jit_mx_decode_b2"}
+    for kind, bucket in (("prefill", 8), ("decode", 1), ("decode", 2)):
+        head = eng._programs.get((kind, bucket)).as_text().split("\n")[0]
+        assert head.startswith("HloModule jit_mx_%s_b%d," % (kind, bucket))
+    exported = any("call_exported" in op for op in
+                   noted[label + ":decode:2"]["scopes"].values())
+    assert exported == (route == "exported")
+    # the names are the wrapper's alone: the scopes inside are as they were
+    assert any("/h0/kv_write/" in op for op in
+               noted[label + ":decode:2"]["scopes"].values())
+    # a second engine reads the artifacts back under the same names
+    if cache is not None:
+        again = DecodeEngine(MODEL, MODEL.init_params(0), label=label + "2",
+                             cache=cache, **ENGINE_KW)
+        again.warmup()
+        assert again._programs.fingerprints == eng._programs.fingerprints
+        assert obs.program_scopes()[label + "2:prefill:8"]["module"] \
+            == "jit_mx_prefill_b8"
+
+
+def test_compile_throughs_other_caller_keeps_its_module_name(tmp_path):
+    """``name`` is optional: ``BucketExecutorPool`` leaves it out and its
+    export wrapper is the module it was."""
+    import jax.numpy as jnp
+    from mxnet_tpu.profiling.hlo import module_name
+    from mxnet_tpu.serving.cache import (CompileCache, compile_through,
+                                         named, stablehlo_fingerprint)
+    specs = (jax.ShapeDtypeStruct((4,), jnp.float32),)
+    cache = CompileCache(str(tmp_path))
+    for name, want in ((None, "jit_call"), ("mx_x_b4", "jit_mx_x_b4")):
+        jfn = jax.jit(named(lambda x: x * 2, name) if name
+                      else (lambda x: x * 2))
+        lowered = jfn.lower(*specs)
+        call = compile_through(cache, stablehlo_fingerprint(
+            lowered.as_text()), jfn, lowered, specs, name=name)
+        assert module_name(call.as_text()) == want
+        assert float(call(jnp.ones((4,), jnp.float32))[0]) == 2.0
+
+
+def test_the_prefill_counters_add_up_to_the_buckets():
+    """``decode.prefill.tokens`` + ``decode.prefill.padded_tokens`` is the
+    tokens the device computed: a bucket a prefill."""
+    from mxnet_tpu import telemetry
+    telemetry.enable()
+    telemetry.reset("decode.")
+    eng = _engine()
+    try:
+        for prompt in ([3, 7, 1], [1, 2, 3, 4, 5, 6, 7, 8], [9]):
+            eng.submit(prompt, 2).tokens()
+        reg = telemetry.registry()
+        assert reg.counter("decode.prefills").value == 3
+        assert reg.counter("decode.prefill.tokens").value == 3 + 8 + 1
+        assert reg.counter("decode.prefill.padded_tokens").value \
+            == 3 * 8 - (3 + 8 + 1)
+    finally:
+        eng.close(drain=False)
+        telemetry.reset("decode.")
+        telemetry.disable()
 
 
 def test_the_step_spans_and_counters_say_how_often_the_loop_overlaps():

@@ -714,3 +714,60 @@ def test_the_scopes_name_each_layers_kind(params):
     text = jax.jit(eng._prefill_impl).lower(
         *eng._specs()[0][8]).as_text(debug_info=True)
     assert "h1/attention_window" in text and "mx.kv_scatter" in text
+
+
+def test_a_prefills_routed_experts_bear_a_scope_for_each_part(params):
+    """The COMPILED prefill program (what a device trace's instruction
+    names are looked up in) has instructions under ``h<i>/experts/sort``
+    and, inside the chunk loop, ``gather``, ``matmul`` and ``combine``;
+    and ``causal_attention``'s loops lie under the layer's own attention
+    scope, which is why it has none of its own."""
+    eng = DecodeEngine(MODEL, params, label="parts_test", **ENGINE_KW)
+    eng.warmup()
+    ops = obs.program_scopes()["parts_test:prefill:32"]["scopes"].values()
+    for part in ("/h3/experts/sort/", "/h3/experts/while/body/gather/",
+                 "/h3/experts/while/body/matmul/",
+                 "/h3/experts/while/body/combine/"):
+        assert any(part in op for op in ops), part
+    # the attention's block loops: every instruction inside one bears
+    # the scope of its layer's kind
+    looped = [op for op in ops if "/while/body/" in op
+              and "/experts/" not in op]
+    assert looped and all("/attention_full/" in op
+                          or "/attention_window/" in op for op in looped)
+    # a decode step of few tokens takes the grouped matmul too (one chunk)
+    ops = obs.program_scopes()["parts_test:decode:2"]["scopes"].values()
+    assert any("/h3/experts/while/body/matmul/" in op for op in ops)
+
+
+@pytest.mark.parametrize("chunk_rows", [16, 2048])
+@pytest.mark.parametrize("dtype", ["bfloat16", "float32"])
+def test_the_scopes_inside_routed_experts_change_no_bit(dtype, chunk_rows,
+                                                        monkeypatch):
+    """A ``jax.named_scope`` is metadata: the same call with every scope
+    taken out (the program as it was before the parts had names) gives
+    the same bits."""
+    import contextlib
+    import jax
+    import jax.numpy as jnp
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.normal(0, 1, (40, 16)), dtype)
+    gate, up = (jnp.asarray(rng.normal(0, 0.3, (8, 16, 24)), dtype)
+                for _ in range(2))
+    down = jnp.asarray(rng.normal(0, 0.3, (8, 24, 16)), dtype)
+    chosen, weights = route_top_k(
+        x, jnp.asarray(rng.normal(0, 1, (16, 8)), jnp.float32), None, 2,
+        scoring="softmax")
+
+    def run():
+        return jax.jit(lambda x: routed_experts(
+            x, chosen, weights, gate, up, down, 0,
+            chunk_rows=chunk_rows))(x)
+
+    y, counts = run()
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare, bare_counts = run()
+    assert np.array_equal(np.asarray(y), np.asarray(bare))
+    assert np.array_equal(np.asarray(counts), np.asarray(bare_counts))
+    assert np.asarray(counts).sum() == 80
