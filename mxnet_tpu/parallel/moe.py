@@ -14,6 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
+from .. import telemetry as _telemetry
 from ..base import MXNetError
 from ..gluon.block import HybridBlock
 
@@ -152,16 +153,22 @@ def route_top_k(x, gate_w, selection_bias, top_k, scale=1.0,
 
 
 # a decode step of at most this many tokens may take every held expert
-# over every token (``routed_experts``, ``num_experts=``).  The chip has
+# over every token (``routed_experts``, ``decode_step=``).  The chip has
 # run that route at 16 and at 32 tokens, the decode buckets of the one
 # deployment that takes it (PERF.md section 6, PR 32), and at no more:
 # the bound is what was measured, not where the roofline would put it
 DENSE_TOKENS = 32
 
+# the most tokens one pass of the gather route sorts and combines: its
+# buffer of weighed rows is top_k x BLOCK_TOKENS x d float32, 302 MB at
+# Mellum2's widths beside a 16,384-token prefill's 0.80 GB of
+# temporaries on a chip that is 96% full (PERF.md section 4)
+BLOCK_TOKENS = 4096
+
 
 def routed_experts(x, chosen, weights, w_gate, w_up, w_down, first_expert,
                    live=None, chunk_rows=2048, num_experts=None,
-                   use_pallas=None):
+                   decode_step=False, use_pallas=None):
     """The part of an expert layer's output that THIS chip's experts
     give, for an expert layer that is told which experts it holds.
 
@@ -170,12 +177,13 @@ def routed_experts(x, chosen, weights, w_gate, w_up, w_down, first_expert,
     (n_held, d, f) and ``w_down`` (n_held, f, d) the stacked weights of
     experts ``first_expert .. first_expert + n_held - 1``, each a gated
     SiLU MLP ``w_down(silu(x w_gate) * (x w_up))``; ``live`` (tokens,)
-    bool masks padding tokens out.  Returns ``(y (tokens, d) float32,
-    counts (n_held,) int32)``: ``y[t] = sum over the chosen experts held
-    here of weights * Expert(x[t])`` and ``counts`` the tokens each held
-    expert was given.  What the experts held elsewhere would add is
-    left out; on one chip there is no exchange and nothing stands in
-    for one.
+    bool masks padding tokens out; ``num_experts`` the width of the
+    router (all experts, held or not), where the caller knows it.
+    Returns ``(y (tokens, d) float32, counts (n_held,) int32)``: ``y[t]
+    = sum over the chosen experts held here of weights * Expert(x[t])``
+    and ``counts`` the tokens each held expert was given.  What the
+    experts held elsewhere would add is left out; on one chip there is
+    no exchange and nothing stands in for one.
 
     No capacity and no dropped token: the assignments that fall on held
     experts are sorted by expert and go through a grouped matmul
@@ -184,13 +192,34 @@ def routed_experts(x, chosen, weights, w_gate, w_up, w_down, first_expert,
     caller's tri-state as everywhere in the kernel tier) ``chunk_rows``
     sorted rows at a time, in a loop that runs as many chunks as there
     ARE such assignments -- one for a decode step, ``tokens * top_k /
-    chunk_rows`` if every token chose only experts held here.
+    chunk_rows`` if every token chose only experts held here.  Each
+    chunk's rows are weighed in float32 by their router weights, and
+    then combined into their tokens' rows by one of two routes, chosen
+    from the call's shapes:
 
-    **A decode step that keeps every expert busy** may take another
+    - **a gather**, where this layer holds every expert the router
+      scores (``first_expert`` 0, ``n_held >= num_experts``): every
+      assignment of a live token is then ours.  The chunks write their
+      rows in sorted order into a float32 buffer, contiguous, and after
+      the loop each token sums its ``top_k`` rows, found through the
+      inverse of the sort, in float32.  The buffer is a row an
+      assignment, so sort, matmul and combine run over blocks of at
+      most ``BLOCK_TOKENS`` tokens, each sorted on its own.
+    - **a scatter-add** into ``y`` inside the loop, for a layer that
+      holds a few of many experts (12 of 384: a buffer of every
+      assignment would be 32 times the rows computed) or a caller that
+      does not give ``num_experts``.  A token's assignments collide
+      there, and a TPU runs colliding updates one by one: 1.2 us a
+      token a layer in Mellum2's prefill (PERF.md section 5, PR 36).
+
+    Both give the same sums to float32 rounding (the order of a token's
+    terms differs); the counters ``moe.combine_gather`` /
+    ``moe.combine_scatter`` count the layers traced through each.
+
+    **A decode step that keeps every expert busy** may take a third
     route to the same sum.  A caller whose tokens are one decode step's,
-    a slot each, says so by giving ``num_experts``, the width of the
-    router (all experts, held or not); a call without it, and every
-    prefill, is the grouped matmul above.  Where such a step has at most
+    a slot each, says so by ``decode_step``; every prefill is the
+    grouped matmul above.  Where such a step has at most
     ``DENSE_TOKENS`` tokens and the router sends an expert a token or
     more on average (``tokens * top_k >= num_experts``), every held
     expert runs over every token as one batched matmul and a token's row
@@ -204,20 +233,62 @@ def routed_experts(x, chosen, weights, w_gate, w_up, w_down, first_expert,
     holds a few of many experts (12 of 384 at 0.7 tokens each) stays on
     the grouped matmul, which reads the experts that were chosen alone.
     """
-    from ..kernels.grouped_matmul import grouped_matmul
-    n_held, d, _f = w_gate.shape
+    n_held = w_gate.shape[0]
     tokens, top_k = chosen.shape
-    n_assign = tokens * top_k
     local = chosen - first_expert
     held = (local >= 0) & (local < n_held)
     if live is not None:
         held = held & live[:, None]
-    if num_experts is not None and tokens <= DENSE_TOKENS \
-            and n_assign >= num_experts:
-        return _every_expert_over_every_token(
-            x, jnp.where(held, local, n_held), weights, w_gate, w_up, w_down)
     # an assignment's expert here, n_held for one that is not ours
-    expert = jnp.where(held, local, n_held).reshape(n_assign)
+    expert = jnp.where(held, local, n_held)
+    if decode_step and num_experts is not None and tokens <= DENSE_TOKENS \
+            and tokens * top_k >= num_experts:
+        return _every_expert_over_every_token(
+            x, expert, weights, w_gate, w_up, w_down)
+    gather = num_experts is not None and first_expert == 0 \
+        and n_held >= num_experts
+    if _telemetry._ENABLED:
+        _telemetry.hooks.moe_combine(gather)
+    experts = (w_gate, w_up, w_down)
+    if not gather:
+        return _sorted_chunks(x, expert, weights, experts, chunk_rows,
+                              use_pallas, gather=False)
+    block = BLOCK_TOKENS
+    if tokens <= block:
+        return _sorted_chunks(x, expert, weights, experts, chunk_rows,
+                              use_pallas, gather=True)
+    # whole blocks, the last padded with assignments of no one
+    n_blocks = -(-tokens // block)
+    pad = n_blocks * block - tokens
+
+    def blocked(a, fill):
+        a = jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1),
+                    constant_values=fill)
+        return a.reshape((n_blocks, block) + a.shape[1:])
+
+    def one_block(_, part):
+        return None, _sorted_chunks(*part, experts, chunk_rows, use_pallas,
+                                    gather=True)
+
+    _, (y, counts) = jax.lax.scan(
+        one_block, None, (blocked(x, 0), blocked(expert, n_held),
+                          blocked(weights, 0)))
+    return y.reshape(n_blocks * block, -1)[:tokens], \
+        jnp.sum(counts, axis=0)
+
+
+def _sorted_chunks(x, expert, weights, experts, chunk_rows, use_pallas,
+                   gather):
+    """``routed_experts``' grouped matmul over the assignments of
+    ``expert`` (tokens, top_k), ``n_held`` for one that is not ours, and
+    its combine: by a gather through the inverse of the sort, or by a
+    scatter-add into ``y`` (the routes of its doc)."""
+    from ..kernels.grouped_matmul import grouped_matmul
+    w_gate, w_up, w_down = experts
+    n_held, d, _f = w_gate.shape
+    tokens, top_k = expert.shape
+    n_assign = tokens * top_k
+    expert = expert.reshape(n_assign)
     chunk = min(int(chunk_rows), n_assign)
     n_chunks = -(-n_assign // chunk)
     # the four parts bear a scope each under the caller's (a device
@@ -232,12 +303,12 @@ def routed_experts(x, chosen, weights, w_gate, w_up, w_down, first_expert,
         starts, total = ends - counts, ends[-1]
         # ours first and grouped by expert; padded to whole chunks
         order = jnp.argsort(expert, stable=True).astype(jnp.int32)
-        order = jnp.pad(order, (0, n_chunks * chunk - n_assign))
+        padded = jnp.pad(order, (0, n_chunks * chunk - n_assign))
     flat_w = weights.reshape(n_assign)
 
-    def one_chunk(c, y):
+    def one_chunk(c, acc):
         at = c * chunk
-        rows = jax.lax.dynamic_slice(order, (at,), (chunk,))
+        rows = jax.lax.dynamic_slice(padded, (at,), (chunk,))
         ours = at + jnp.arange(chunk, dtype=jnp.int32) < total
         token = rows // top_k
         # this chunk's share of every expert's group
@@ -254,11 +325,57 @@ def routed_experts(x, chosen, weights, w_gate, w_up, w_down, first_expert,
             # rows past the last of ours belong to no group
             out = jnp.where(ours[:, None],
                             out * jnp.take(flat_w, rows)[:, None], 0.0)
-            return y.at[token].add(out)
+            if gather:
+                # in sorted order at the chunk's own rows: no collision
+                return jax.lax.dynamic_update_slice(acc, out, (at, 0))
+            return acc.at[token].add(out)
 
-    y = jax.lax.fori_loop(0, -(-total // chunk), one_chunk,
-                          jnp.zeros((tokens, d), jnp.float32))
+    acc = jax.lax.fori_loop(
+        0, -(-total // chunk), one_chunk,
+        jnp.zeros((n_chunks * chunk if gather else tokens, d), jnp.float32))
+    if not gather:
+        return acc, counts
+    with jax.named_scope("combine"):
+        # where each assignment's row lies in the buffer; rows past the
+        # last of ours belong to no one, so a row is chosen by where
+        inv = jnp.argsort(order).astype(jnp.int32).reshape(tokens, top_k)
+        ours = (expert < n_held).reshape(tokens, top_k)
+        y = _gather_rows(acc, inv, ours)
     return y, counts
+
+
+# sorted rows one step of the combine gathers: 4,096 float32 rows of
+# 2,304 (37.7 MB), which the compiler keeps in the core's fast memory
+# for the sum that reads them.  Gathered all at once, a block's rows
+# (302 MB at Mellum2's widths) went to HBM and back, 907 MB of
+# temporaries for one layer (compiled for a described v5e, PR 37)
+COMBINE_ROWS = 4096
+
+
+def _gather_rows(buf, inv, ours):
+    """``y[t] = sum over k of where(ours[t, k], buf[inv[t, k]], 0)`` in
+    float32 (``buf`` (rows, d) float32; ``inv`` / ``ours`` (tokens,
+    top_k)), ``COMBINE_ROWS`` gathered rows a step."""
+    tokens, top_k = inv.shape
+    tile = min(tokens, max(1, COMBINE_ROWS // top_k))
+    n_tiles = -(-tokens // tile)
+    pad = ((0, n_tiles * tile - tokens), (0, 0))
+    inv, ours = jnp.pad(inv, pad), jnp.pad(ours, pad)
+
+    def one_tile(i, y):
+        at = i * tile
+        rows = jnp.take(buf, jax.lax.dynamic_slice(
+            inv, (at, 0), (tile, top_k)).reshape(tile * top_k), axis=0)
+        ok = jax.lax.dynamic_slice(ours, (at, 0), (tile, top_k))
+        part = jnp.sum(jnp.where(ok[:, :, None],
+                                 rows.reshape(tile, top_k, -1), 0.0),
+                       axis=1)
+        return jax.lax.dynamic_update_slice(y, part, (at, 0))
+
+    y = jax.lax.fori_loop(
+        0, n_tiles, one_tile,
+        jnp.zeros((n_tiles * tile, buf.shape[1]), jnp.float32))
+    return y[:tokens]
 
 
 def _every_expert_over_every_token(x, expert, weights, w_gate, w_up, w_down):

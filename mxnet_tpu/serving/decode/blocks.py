@@ -194,10 +194,11 @@ def routed_ffn(layer, x, norm_w, eps, router, router_bias, experts, top_k,
     ``normalize``, ``scale``); ``experts`` = (gate, up, down), the
     stacked weights of the experts held here from ``first_expert`` on
     (``parallel.moe.routed_experts``: sorted, grouped matmul
-    ``chunk_rows`` sorted rows at a time, nothing dropped;
-    ``decode_step``: the tokens are one decode step's, a slot
-    each, which tells ``routed_experts`` the router's width and so
-    allows it the route of a step that keeps every expert busy).
+    ``chunk_rows`` sorted rows at a time, nothing dropped; it is told
+    the router's width, from which it sees whether this layer holds
+    every expert; ``decode_step``: the tokens are one decode step's, a
+    slot each, which allows it the route of a step that keeps every
+    expert busy).
     ``live`` (tokens,) bool keeps padding out of every count; ``stats``
     the running counts, returned updated; ``chosen`` (tokens, top_k) the
     experts the router chose.  ``layer`` prefixes the scopes:
@@ -213,8 +214,8 @@ def routed_ffn(layer, x, norm_w, eps, router, router_bias, experts, top_k,
         routed, counts = routed_experts(h, chosen, weights, *experts,
                                         first_expert, live=live,
                                         chunk_rows=chunk_rows,
-                                        num_experts=router.shape[1]
-                                        if decode_step else None)
+                                        num_experts=router.shape[1],
+                                        decode_step=decode_step)
     n_live = jnp.sum(live.astype(jnp.int32))
     stats = dict(
         stats,

@@ -47,7 +47,7 @@ __all__ = [
     "decode_ttft", "decode_inter_token", "decode_finish",
     "decode_kv_aliased", "decode_moe", "decode_ut_passes",
     "kvcache_alloc", "kvcache_free", "kvcache_alloc_failure",
-    "loss_softmax_ce",
+    "loss_softmax_ce", "moe_combine",
 ]
 
 
@@ -168,6 +168,14 @@ def loss_softmax_ce(fused):
     lines: counted a call, which inside a compiled step is a trace."""
     _registry().counter("loss.softmax_ce_fused" if fused
                         else "loss.softmax_ce_fallback").inc()
+
+
+def moe_combine(gather):
+    """``parallel.moe.routed_experts`` combined an expert layer's rows by
+    a gather through the inverse of its sort, or by a scatter-add:
+    counted a layer traced, as ``loss_softmax_ce`` counts a call."""
+    _registry().counter("moe.combine_gather" if gather
+                        else "moe.combine_scatter").inc()
 
 
 def amp_rescale(scale_before, scale_after):
@@ -808,6 +816,14 @@ INSTRUMENTS = [
     _ii("loss.softmax_ce_fallback", "counter", "gluon", 35,
         "the same that kept log_softmax + pick / sum: dense labels, "
         "from_logits, another axis"),
+    _ii("moe.combine_gather", "counter", "parallel", 37,
+        "expert layers traced (a layer a compiled program) whose routed "
+        "experts combine their rows by a gather through the inverse of "
+        "the sort: the layer holds every expert the router scores"),
+    _ii("moe.combine_scatter", "counter", "parallel", 37,
+        "the same that keep the scatter-add into y: a layer holding a "
+        "share of the router's experts, or a caller that does not give "
+        "the router's width"),
     _ii("numerics.checks", "counter", "numerics", 16,
         "non-finite sentinel checks run (MXNET_TPU_NUMERICS_CHECK=1)"),
     _ii("numerics.check_time", "timer", "numerics", 16,

@@ -326,7 +326,7 @@ def test_a_decode_step_over_busy_experts_runs_every_expert_over_every_token(
     def route(**kw):
         return lambda x: routed_experts(
             x, chosen, weights, gate, up, down, first, live=live, **kw)
-    step = route(num_experts=experts)
+    step = route(num_experts=experts, decode_step=True)
     assert ("ragged_dot" not in str(jax.make_jaxpr(step)(x))) == dense
     y, counts = step(x)
     # 24 sorted rows a chunk: every case has several chunks to loop over
@@ -406,6 +406,119 @@ def test_routed_experts_through_the_kernel_equal_those_through_ragged_dot(
     (want, want_counts), (got, counts) = route(False), route(True)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-5)
     assert counts.tolist() == want_counts.tolist()
+
+
+@pytest.fixture()
+def combine_counts():
+    """The counters of ``routed_experts``' two combines, read as
+    (gather, scatter) from zero."""
+    was = telemetry.enabled()
+    telemetry.enable()
+    telemetry.reset("moe.")
+
+    def read():
+        return (telemetry.counter("moe.combine_gather").value,
+                telemetry.counter("moe.combine_scatter").value)
+    yield read
+    telemetry.reset("moe.")
+    if not was:
+        telemetry.disable()
+
+
+def _every_chosen_expert(x, chosen, weights, gate, up, down, live):
+    """The routed sum in float64, token by token: the reference neither
+    route shares any code with."""
+    x, gate, up, down = (np.asarray(a, np.float64)
+                         for a in (x, gate, up, down))
+    y = np.zeros(x.shape)
+    for t in np.flatnonzero(np.asarray(live)):
+        for e, w in zip(np.asarray(chosen)[t], np.asarray(weights)[t]):
+            h = x[t] @ gate[e]
+            y[t] += w * ((h / (1 + np.exp(-h)) * (x[t] @ up[e])) @ down[e])
+    return y
+
+
+@pytest.mark.parametrize("tokens,experts,top_k,padding,chunk_rows,block", [
+    (50, 8, 2, 0, 24, 4096),        # 100 rows: not a multiple of the chunk
+    (40, 8, 2, 7, 16, 4096),        # padding masked by live
+    (50, 8, 2, 5, 24, 16),          # four token blocks, the last padded
+    (40, 64, 8, 3, 64, 16)],        # top-8 of 64 experts, tiny widths
+    ids=["ragged_chunk", "padding", "token_blocks", "top8_of_64"])
+def test_a_layer_holding_every_expert_combines_by_a_gather(
+        tokens, experts, top_k, padding, chunk_rows, block, combine_counts,
+        monkeypatch):
+    """A layer that holds every expert the router scores (``num_experts``
+    its width, ``first_expert`` 0) takes the gather through the inverse
+    of the sort; a call that does not give the width keeps the
+    scatter-add.  Both equal the token-by-token sum to float32 rounding
+    and count the same tokens."""
+    import jax.numpy as jnp
+    from mxnet_tpu.parallel import moe
+    monkeypatch.setattr(moe, "BLOCK_TOKENS", block)
+    rng = np.random.default_rng(tokens + experts + block)
+    x = jnp.asarray(rng.normal(size=(tokens, 16)).astype(np.float32))
+    gate, up = (jnp.asarray(rng.normal(0, 0.3, (experts, 16, 12))
+                            .astype(np.float32)) for _ in range(2))
+    down = jnp.asarray(rng.normal(0, 0.3, (experts, 12, 16))
+                       .astype(np.float32))
+    router = jnp.asarray(rng.normal(size=(16, experts)).astype(np.float32))
+    chosen, weights = route_top_k(x, router, None, top_k, scoring="softmax")
+    live = jnp.arange(tokens) < tokens - padding
+
+    def route(**kw):
+        return routed_experts(x, chosen, weights, gate, up, down, 0,
+                              live=live, chunk_rows=chunk_rows, **kw)
+    y, counts = route(num_experts=experts)
+    assert combine_counts() == (1, 0)
+    want, want_counts = route()
+    assert combine_counts() == (1, 1)
+    np.testing.assert_allclose(np.asarray(y), np.asarray(want), atol=1e-5)
+    np.testing.assert_allclose(
+        np.asarray(y), _every_chosen_expert(x, chosen, weights, gate, up,
+                                            down, live), atol=1e-4)
+    assert counts.tolist() == want_counts.tolist()
+    assert int(counts.sum()) == (tokens - padding) * top_k
+    assert not np.asarray(y)[tokens - padding:].any()
+
+
+def test_a_layer_holding_a_share_of_the_experts_keeps_the_scatter(
+        combine_counts):
+    """Kimi-K2's case, 4 of 16 experts held from ``first_expert`` 4: told
+    the router's width or not, the call is the scatter-add it was, bit
+    for bit, and counts as ``moe.combine_scatter``."""
+    import jax
+    import jax.numpy as jnp
+    rng = np.random.default_rng(11)
+    x = jnp.asarray(rng.normal(size=(50, 16)), jnp.bfloat16)
+    gate, up = (jnp.asarray(rng.normal(0, 0.3, (4, 16, 12)), jnp.bfloat16)
+                for _ in range(2))
+    down = jnp.asarray(rng.normal(0, 0.3, (4, 12, 16)), jnp.bfloat16)
+    router = jnp.asarray(rng.normal(size=(16, 16)).astype(np.float32))
+    chosen, weights = route_top_k(x, router, None, 8, scoring="softmax")
+    live = jnp.arange(50) < 45
+
+    def route(**kw):
+        return jax.jit(lambda x: routed_experts(
+            x, chosen, weights, gate, up, down, 4, live=live,
+            chunk_rows=24, **kw))(x)
+    (y, counts), (was, was_counts) = route(num_experts=16), route()
+    assert combine_counts() == (0, 2)
+    assert np.array_equal(np.asarray(y), np.asarray(was))
+    assert np.array_equal(np.asarray(counts), np.asarray(was_counts))
+    assert 0 < int(counts.sum()) < 45 * 8
+
+
+def test_a_prefill_holding_every_expert_is_counted_a_gather_a_layer(
+        params, combine_counts):
+    """The engine's programs through ``blocks.routed_ffn``: every prefill
+    program traces its four expert layers through the gather, and a
+    decode step of 2 slots (grouped: too few assignments to keep 8
+    experts busy) too; a step of 4 slots takes every expert over every
+    token and no combine is counted."""
+    eng = DecodeEngine(MODEL, params, **ENGINE_KW)
+    eng.warmup()
+    layers = MODEL.num_layers
+    assert combine_counts() == (layers * (len(eng.prefill_buckets) + 1), 0)
 
 
 # ---------------------------------------------------------------------
