@@ -89,7 +89,7 @@ def _ensure_registered():
     # importing the kernel modules registers their specs; lazy so that
     # `import mxnet_tpu` does not pull pallas machinery upfront
     from . import (flash_attention, grouped_matmul,  # noqa: F401
-                   mla_paged_attention, paged_attention)
+                   kda_decode, mla_paged_attention, paged_attention)
 
 
 def get(name: str) -> KernelSpec:

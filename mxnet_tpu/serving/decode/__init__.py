@@ -44,11 +44,19 @@ not per-request.  This package is that tier:
   pass of each layer keeps its own K and V: the cache has
   ``cache_passes x num_layers`` layers over ``num_layers`` layers of
   weights.
+- :class:`~.linear_moe.LinearLatentMoEDecoder` -- Kimi Linear's block:
+  KDA linear-attention layers (a gated delta rule over a float32 state
+  a sequence, a short convolution; a chunked scan in prefill, one step
+  of the ``kda_decode`` kernel in decode) beside latent-attention layers
+  without positions, over the routed experts of ``LatentMoEDecoder``,
+  whose latent attention, FFN, embedding and head it reuses.  Its KDA
+  layers keep a STATE a sequence, not rows a token.
 
 A model declares what a token keeps in the cache (``cache_rows()``),
-where it has them which layers are window layers (``cache_layers()``)
-and how many cache layers a layer of weights keeps (``cache_passes``, 1
-where it is not declared), and the engine builds the one
+where it has them which layers are window or state layers
+(``cache_layers()``), what a sequence keeps in a state layer
+(``cache_states()``) and how many cache layers a layer of weights keeps
+(``cache_passes``, 1 where it is not declared), and the engine builds the one
 ``PagedKVCache`` from that: the cache's layers are the model's
 declaration, not its ``num_layers``.  The decode-step
 attention itself is a kernel-registry citizen
@@ -62,12 +70,14 @@ from .engine import (DecodeEngine, GenerationStream, GenerativeServable,
                      GenerativeWatcher)
 from .kvcache import BlockTable, KVCacheExhausted, PagedKVCache
 from .latent_moe import LatentMoEDecoder
+from .linear_moe import LinearLatentMoEDecoder
 from .looped import LoopedDecoder
 from .model import TinyGPT, tiny_gpt
 from .window_moe import WindowMoEDecoder
 
 __all__ = ["BlockTable", "DecodeEngine", "GenerationStream",
            "GenerativeServable", "GenerativeWatcher",
-           "KVCacheExhausted", "LatentMoEDecoder", "LoopedDecoder",
+           "KVCacheExhausted", "LatentMoEDecoder",
+           "LinearLatentMoEDecoder", "LoopedDecoder",
            "PagedKVCache",
            "TinyGPT", "WindowMoEDecoder", "tiny_gpt"]

@@ -231,16 +231,27 @@ def draw_params(shapes, seed, dtype):
     """Flat name->array dict drawn from ``seed`` for ``shapes`` =
     ``{name: (shape, kind)}``; kind "norm" (1 + 0.1 normal), ``("norm",
     gain)`` (a norm whose weights lie about ``gain``: ``gain * (1 + 0.1
-    normal)``), "bias" (0.1 normal, float32) or the fan-in of a matmul
-    weight (normal with standard deviation ``fan_in^-0.5``).  One jitted
-    draw per tensor, so that the float32 draw of the largest tensor is
-    the most the initialiser adds to the weights."""
+    normal)``), "bias" (0.1 normal, float32), ``("log_uniform", lo,
+    hi)`` (``log(a)``, ``a`` uniform on ``[lo, hi)``, float32),
+    ``("softplus_inv", lo, hi)`` (``softplus^-1(a)``, ``log(a)`` uniform
+    on ``[log lo, log hi)``, float32) or the fan-in of a matmul weight
+    (normal with standard deviation ``fan_in^-0.5``).  One jitted draw
+    per tensor, so that the float32 draw of the largest tensor is the
+    most the initialiser adds to the weights."""
     import functools
     import jax
     import jax.numpy as jnp
 
     @functools.partial(jax.jit, static_argnums=(1, 2))
     def draw(key, shape, kind):
+        if isinstance(kind, tuple) and kind[0] == "log_uniform":
+            return jnp.log(jax.random.uniform(key, shape, jnp.float32,
+                                              kind[1], kind[2]))
+        if isinstance(kind, tuple) and kind[0] == "softplus_inv":
+            a = jnp.exp(jax.random.uniform(key, shape, jnp.float32,
+                                           math.log(kind[1]),
+                                           math.log(kind[2])))
+            return a + jnp.log(-jnp.expm1(-a))
         z = jax.random.normal(key, shape, jnp.float32)
         if kind == "norm":
             return (1.0 + 0.1 * z).astype(dtype)

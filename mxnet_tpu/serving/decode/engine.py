@@ -340,27 +340,33 @@ class DecodeEngine:
                          else _env.get("MXNET_TPU_SERVING_KV_BLOCK"))
         num_blocks = int(num_blocks if num_blocks is not None
                          else _env.get("MXNET_TPU_SERVING_KV_BLOCKS"))
+        self.max_slots = self.decode_buckets[-1]
         # the model declares what a token keeps in a layer, (a model
-        # with window layers) which layer is of which kind, and (a model
-        # that runs its layers several times) how many cache layers a
-        # layer of weights keeps: the cache's layers are not the model's
+        # with window or state layers) which layer is of which kind and
+        # what a sequence keeps in a state layer, and (a model that runs
+        # its layers several times) how many cache layers a layer of
+        # weights keeps: the cache's layers are not the model's
         kinds = model.cache_layers() \
             if hasattr(model, "cache_layers") else None
+        states = model.cache_states() \
+            if hasattr(model, "cache_states") else None
         self.cache = PagedKVCache(
             model.num_layers, model.cache_rows(), block_size, num_blocks,
             dtype=kv_dtype, kinds=kinds,
             window=getattr(model, "sliding_window", None),
             window_blocks=window_blocks,
             fold_heads=getattr(model, "cache_fold_heads", False),
-            passes=getattr(model, "cache_passes", 1))
+            passes=getattr(model, "cache_passes", 1), states=states,
+            # a state row a slot (a sequence holds one from admission to
+            # its end) and row 0 the scratch row of padded slots
+            state_rows=self.max_slots + 1 if states else None)
         # fixed compiled block-table widths: a full layer's enough for
         # the longest sequence the model can hold, a window layer's the
-        # ring
+        # ring, a state layer's the one row
         self._table_widths = self.cache.blocks_needed(model.max_seq)
         self.max_blocks_per_seq = self._table_widths[FULL]
         self.max_queue = int(max_queue if max_queue is not None
                              else _env.get("MXNET_TPU_SERVING_QUEUE"))
-        self.max_slots = self.decode_buckets[-1]
         self._programs = _AotPrograms(cache=cache, label=label)
         self._cond = _sync.Condition(name="serving.decode")
         self._pending = collections.deque()
@@ -390,7 +396,8 @@ class DecodeEngine:
         bs = self.cache.block_size
         if hasattr(self.model, "prefill_cache"):
             # a model that runs its layers several times writes each
-            # pass's rows itself, inside its loop over the passes
+            # pass's rows itself, inside its loop over the passes; one
+            # with state layers its rows and its sequence's states
             logits, slabs, stats = self.model.prefill_cache(
                 params, slabs, tokens, true_len - 1, table, bs)
         else:
@@ -406,7 +413,7 @@ class DecodeEngine:
                     write_prompt(slab, r, tables[kind], true_len, bs,
                                  ring=kind == WINDOW)
                     for slab, r, kind in zip(layers, rows[name],
-                                             self.cache.kinds))
+                                             self.cache.table_kinds))
                     for name, layers in slabs.items()}
         with jax.named_scope("mx.lm_head"):
             first_token = jnp.argmax(logits).astype(jnp.int32)
@@ -466,8 +473,10 @@ class DecodeEngine:
         """The block tables of ``reqs`` at the compiled widths, padded
         with the scratch block to ``rows`` rows (None: ONE request's
         table, without the slot axis): an int32 array, or for a model
-        with window layers one a kind of layer, ``{"full": ...,
-        "window": ...}`` (the ring)."""
+        with window or state layers one a kind of layer, ``{"full": ...,
+        "window": ...}`` (the ring) or ``{"full": ..., "state": ...}``
+        (the sequence's state row, a table one entry wide; a padded
+        slot's is the scratch row)."""
         out = {}
         for kind, width in self._table_widths.items():
             arr = np.full((1 if rows is None else rows, width),
@@ -834,9 +843,11 @@ class DecodeEngine:
         ``moe_expert_tokens_max``; one with window layers: ``kv_rows_full``,
         ``kv_rows_window``; one that runs its layers several times:
         ``ut_passes``, ``exit_step_sum``, ``exit_early``, ``kv_rows``;
-        empty for a dense one of full layers run once): onto the step's
-        or the prefill's span and the ``decode.moe.*`` / ``decode.kv.*``
-        / ``decode.ut.*`` counters."""
+        one with state layers: ``state_rows`` (a decode step) or
+        ``scan_tokens`` (a prefill); empty for a dense one of full layers
+        run once): onto the step's or the prefill's span and the
+        ``decode.moe.*`` / ``decode.kv.*`` / ``decode.ut.*`` /
+        ``decode.linear.*`` counters."""
         if not stats:
             return
         stats = {k: int(v) for k, v in stats.items()}
@@ -848,6 +859,8 @@ class DecodeEngine:
                 _telemetry.hooks.decode_kv_rows(self._label, stats)
             if "ut_passes" in stats:
                 _telemetry.hooks.decode_ut_passes(self._label, stats)
+            if "state_rows" in stats or "scan_tokens" in stats:
+                _telemetry.hooks.decode_linear(self._label, stats)
 
     def _call_failed(self, error, served, dispatched):
         """A prefill or decode call raised.  Before the call took its

@@ -83,6 +83,26 @@ whose block index is data: no slab is ever sliced by pass.  Block ``t *
 num_blocks`` is the scratch block of pass ``t``.  :meth:`slab_bytes` and
 ``stats()["kv_bytes_per_token"]`` count every cache layer.
 
+**State layers**: a layer whose kind is ``"state"`` (a linear-attention
+layer: a recurrent state of fixed size a SEQUENCE, not a row a token)
+owns no slab of rows and reads no block table.  The model declares what
+ONE sequence keeps in ONE such layer (``cache_states()``: name ->
+``(shape, dtype)``, e.g. a float32 ``(heads, key, value)`` state and the
+short convolution's last inputs), and the cache keeps, a state layer,
+one array a state name, ``(state_rows, *shape)``: ``slabs[name][j]`` is
+the ``j``-th state layer's, beside ``slabs[row][i]`` for the ``i``-th
+TABLE layer (full or window).  A sequence is handed ONE row of them,
+the same in every state layer, from a pool of ``state_rows - 1`` (row 0
+the scratch row, as block 0 is the scratch block): :meth:`allocate`
+takes it with the sequence's blocks, all or nothing, the table holds it
+as ``table.of("state") == [row]``, :meth:`free` returns it, and
+:meth:`blocks_needed` counts it as a table one entry wide, so a program
+is handed each slot's state row as it is handed its block table.  A
+prefill writes the sequence's whole state (nothing of an earlier
+sequence in that row survives it); padded slots read and write the
+scratch row.  The state rows are donated with the slabs and counted in
+:meth:`slab_bytes`; ``stats()["state_bytes"]`` is their share.
+
 **Folded heads**: a ``(heads, width)`` row lies ``(num_blocks,
 block_size, heads, lanes)`` by default.  A bfloat16 array's tiles are
 (16, 128), so 4 K/V heads in the second-minor dimension would be padded
@@ -106,8 +126,9 @@ padded prefill positions route their writes there (a compiled program
 always writes *somewhere*), so it is never handed to a request and its
 contents are garbage by design.
 
-Telemetry: ``kvcache.blocks_in_use`` (all pools; with two kinds of
-layer also ``kvcache.blocks_in_use.full`` / ``.window``) /
+Telemetry: ``kvcache.blocks_in_use`` (all pools of blocks; with two
+kinds of table layer also ``kvcache.blocks_in_use.full`` / ``.window``;
+with state layers ``kvcache.state_rows_in_use``) /
 ``kvcache.fragmentation`` gauges, ``kvcache.allocs`` / ``kvcache.frees`` /
 ``kvcache.alloc_failures`` counters.
 """
@@ -118,11 +139,13 @@ from ... import telemetry as _telemetry
 from ...base import MXNetError
 
 __all__ = ["PagedKVCache", "BlockTable", "KVCacheExhausted",
-           "SCRATCH_BLOCK", "FULL", "WINDOW", "slab_rows", "lanes_for",
+           "SCRATCH_BLOCK", "FULL", "WINDOW", "STATE", "slab_rows",
+           "lanes_for",
            "write_tokens", "write_prompt"]
 
-# the kinds of layer a model may declare (``cache_layers()``)
-FULL, WINDOW = "full", "window"
+# the kinds of layer a model may declare (``cache_layers()``): two read a
+# block table of rows, one keeps a state a sequence
+FULL, WINDOW, STATE = "full", "window", "state"
 
 # a TPU's lane count: the minor dimension of its (8, 128) memory tiles
 LANE_TILE = 128
@@ -226,25 +249,29 @@ class KVCacheExhausted(MXNetError):
 class BlockTable:
     """One request's ordered block ids plus its token-capacity bound:
     ``blocks`` in the full layers' slabs and, where the cache has window
-    layers, ``ring`` in theirs."""
+    layers, ``ring`` in theirs; where it has state layers, ``state``
+    the one row the sequence keeps in them."""
 
-    __slots__ = ("blocks", "ring", "capacity", "freed")
+    __slots__ = ("blocks", "ring", "state", "capacity", "freed")
 
-    def __init__(self, blocks, capacity, ring=()):
+    def __init__(self, blocks, capacity, ring=(), state=()):
         self.blocks = list(blocks)
         self.ring = list(ring)
+        self.state = list(state)
         self.capacity = int(capacity)   # tokens the table can hold
         self.freed = False
 
     def of(self, kind):
-        return self.ring if kind == WINDOW else self.blocks
+        return self.ring if kind == WINDOW else \
+            self.state if kind == STATE else self.blocks
 
     def __len__(self):
         return len(self.blocks)
 
     def __repr__(self):
-        return "BlockTable(blocks=%r%s, capacity=%d%s)" % (
+        return "BlockTable(blocks=%r%s%s, capacity=%d%s)" % (
             self.blocks, ", ring=%r" % (self.ring,) if self.ring else "",
+            ", state=%r" % (self.state,) if self.state else "",
             self.capacity, ", freed" if self.freed else "")
 
 
@@ -278,19 +305,25 @@ class PagedKVCache:
     num_blocks : total blocks in a full layer's slab (block 0 is
         scratch, so the allocatable pool is ``num_blocks - 1``)
     dtype : cache dtype
-    kinds : the kind of each layer, ``"full"`` or ``"window"``
-        (``model.cache_layers()``); None: every layer full
+    kinds : the kind of each layer, ``"full"``, ``"window"`` or
+        ``"state"`` (``model.cache_layers()``); None: every layer full
     window : positions a window layer attends over; sizes the ring
     window_blocks : total blocks in a window layer's slab
     fold_heads : lay a ``(heads, width)`` row's heads into the block's
         rows (module doc)
     passes : cache layers a layer of weights keeps
         (``model.cache_passes``; module doc, "Passes")
+    states : ``{name: (shape, dtype)}``, what ONE sequence keeps in ONE
+        state layer (``model.cache_states()``; module doc, "State
+        layers")
+    state_rows : rows of each state array (row 0 is scratch, so
+        ``state_rows - 1`` sequences can hold one at a time)
     """
 
     def __init__(self, layers, rows, block_size, num_blocks,
                  dtype="float32", kinds=None, window=None,
-                 window_blocks=None, fold_heads=False, passes=1):
+                 window_blocks=None, fold_heads=False, passes=1,
+                 states=None, state_rows=None):
         import numpy as np
         if block_size < 1 or num_blocks < 2:
             raise MXNetError(
@@ -307,14 +340,31 @@ class PagedKVCache:
                              % (passes,))
         self.layers = int(layers)
         self.passes = int(passes)
-        self.cache_layers = self.layers * self.passes
         self.kinds = tuple(kinds) if kinds is not None \
             else (FULL,) * self.layers
         if len(self.kinds) != self.layers \
-                or set(self.kinds) - {FULL, WINDOW}:
+                or set(self.kinds) - {FULL, WINDOW, STATE}:
             raise MXNetError(
-                "PagedKVCache needs one kind a layer, %r or %r: %d "
-                "layers, kinds %r" % (FULL, WINDOW, self.layers, kinds))
+                "PagedKVCache needs one kind a layer, %r, %r or %r: %d "
+                "layers, kinds %r" % (FULL, WINDOW, STATE, self.layers,
+                                      kinds))
+        # the layers that keep rows through a block table, and those
+        # that keep a state a sequence: each list owns its own arrays
+        self.table_kinds = tuple(k for k in self.kinds if k != STATE)
+        self.state_layers = self.layers - len(self.table_kinds)
+        self.cache_layers = len(self.table_kinds) * self.passes
+        self.states = {str(name): (tuple(int(n) for n in shape),
+                                   np.dtype(dt))
+                       for name, (shape, dt) in (states or {}).items()}
+        if bool(self.state_layers) != bool(self.states) \
+                or (self.states and (self.passes != 1 or not state_rows
+                                     or state_rows < 2)):
+            raise MXNetError(
+                "a cache with state layers needs the states a sequence "
+                "keeps, state_rows >= 2 and one pass, and a cache without "
+                "them no states: %d state layers, states %r, state_rows "
+                "%r, passes %d" % (self.state_layers, states, state_rows,
+                                   self.passes))
         self.rows = {str(name): tuple(int(n) for n in shape)
                      for name, shape in rows.items()}
         self.block_size = int(block_size)
@@ -335,6 +385,11 @@ class PagedKVCache:
             # block it starts
             self.ring = -(-self.window // self.block_size) + 1
             self._pools[WINDOW] = _Pool(window_blocks)
+        self.state_rows = None
+        if self.states:
+            # a state row is a "block" of its own pool: one a sequence
+            self.state_rows = int(state_rows)
+            self._pools[STATE] = _Pool(self.state_rows)
         # whole 128-lane tiles, so that the device's default layout is
         # the row-major one the programs work in (module doc)
         self.slab_shapes = {name: self._slab_shape(name, FULL)
@@ -366,16 +421,25 @@ class PagedKVCache:
         self.slabs = {}
         self.slabs = {
             name: tuple(jnp.zeros(self._slab_shape(name, kind), self.dtype)
-                        for kind in self.kinds)
+                        for kind in self.table_kinds)
             for name in self.rows}
+        for name, (shape, dt) in self.states.items():
+            self.slabs[name] = tuple(
+                jnp.zeros((self.state_rows,) + shape, dt)
+                for _ in range(self.state_layers))
 
     def _arrays(self):
         return [a for layers in self.slabs.values() for a in layers]
 
     def slab_bytes(self):
         """Bytes the slabs of all layers hold on their device (unused
-        lanes included)."""
+        lanes included), state arrays with them."""
         return sum(a.on_device_size_in_bytes() for a in self._arrays())
+
+    def state_bytes(self):
+        """Bytes the state layers' arrays hold on their device."""
+        return sum(a.on_device_size_in_bytes() for name in self.states
+                   for a in self.slabs[name])
 
     def slabs_deleted(self):
         """Whether a call consumed the slabs without handing new ones
@@ -397,11 +461,13 @@ class PagedKVCache:
     def blocks_needed(self, n_tokens):
         """{kind: blocks} a sequence of ``n_tokens`` holds in a layer of
         each kind the cache has: its whole length in a full layer, the
-        ring at the most in a window one.  What :meth:`allocate` takes
-        for a request, and (of the longest sequence) how wide the
-        fixed-width tables of a compiled program are."""
+        ring at the most in a window one, one row in a state one.  What
+        :meth:`allocate` takes for a request, and (of the longest
+        sequence) how wide the fixed-width tables of a compiled program
+        are."""
         need = self.blocks_for(n_tokens)
-        return {kind: min(need, self.ring) if kind == WINDOW else need
+        return {kind: min(need, self.ring) if kind == WINDOW
+                else 1 if kind == STATE else need
                 for kind in self._pools}
 
     @property
@@ -414,14 +480,16 @@ class PagedKVCache:
             return len(self._pools[kind].free)
 
     def blocks_in_use(self, kind=None):
-        """Blocks requests hold: of one kind's pool, or (None) of all."""
+        """Blocks requests hold: of one kind's pool, or (None) of all
+        the pools of blocks (state rows are not blocks)."""
         with self._lock:
             return self._in_use_locked(kind)
 
     def _in_use_locked(self, kind=None):
         if kind is not None:
             return self._pools[kind].in_use
-        return sum(pool.in_use for pool in self._pools.values())
+        return sum(pool.in_use for kind, pool in self._pools.items()
+                   if kind != STATE)
 
     def can_admit(self, n_tokens):
         """Whether :meth:`allocate` for ``n_tokens`` would succeed now
@@ -448,7 +516,7 @@ class PagedKVCache:
                        for kind, n in need.items()}
                 table = BlockTable(
                     got[FULL], capacity=need[FULL] * self.block_size,
-                    ring=got.get(WINDOW, ()))
+                    ring=got.get(WINDOW, ()), state=got.get(STATE, ()))
                 self._used_tokens[id(table)] = int(n_tokens)
                 gauges = self._gauges_locked()
         if short:
@@ -479,12 +547,16 @@ class PagedKVCache:
             _telemetry.hooks.kvcache_free(*gauges)
 
     def _gauges_locked(self):
-        """(blocks in use over all pools, fragmentation, {kind: blocks
-        in use} where there is more than one kind)."""
-        by_kind = {kind: pool.in_use for kind, pool in self._pools.items()} \
-            if len(self._pools) > 1 else None
-        return (self._in_use_locked(), self._fragmentation_locked(),
-                by_kind)
+        """(blocks in use over all pools of blocks, fragmentation,
+        {kind: blocks in use} where there is more than one kind of
+        table, state rows in use where there are state layers)."""
+        tables = {kind: pool.in_use for kind, pool in self._pools.items()
+                  if kind != STATE}
+        gauges = (self._in_use_locked(), self._fragmentation_locked(),
+                  tables if len(tables) > 1 else None)
+        if STATE in self._pools:
+            gauges += (self._pools[STATE].in_use,)
+        return gauges
 
     # -- introspection --------------------------------------------------
     def _fragmentation_locked(self):
@@ -523,6 +595,12 @@ class PagedKVCache:
                            window_total_blocks=ring.total,
                            window_blocks_in_use=ring.in_use,
                            window_free_blocks=len(ring.free))
+            if STATE in self._pools:
+                rows = self._pools[STATE]
+                out.update(state_layers=self.state_layers,
+                           state_rows_total=rows.total,
+                           state_rows_in_use=rows.in_use,
+                           state_bytes=self.state_bytes())
             return out
 
     def padded_table(self, table, width, kind=FULL):

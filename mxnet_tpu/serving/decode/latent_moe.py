@@ -32,7 +32,10 @@ differs is the block:
 - **Rotary positions** on the ``qk_rope_head_dim`` slice only, adjacent
   pairs ``(2j, 2j+1)`` rotated, YaRN-scaled frequencies
   (:func:`yarn_inv_freq`); the softmax scale carries YaRN's ``mscale``
-  squared (:func:`attention_scale`).
+  squared (:func:`attention_scale`).  With ``mla_use_nope`` the slice
+  is not rotated at all (Kimi Linear's latent attention: no positional
+  encoding anywhere), and with ``q_lora_rank`` None the query is ONE
+  projection ``wq`` of the normed input, without the low-rank pair.
 - **Feed-forward**: the first ``first_k_dense_replace`` layers a dense
   gated-SiLU MLP; every later one an **expert layer**: a router over ALL
   ``n_routed_experts`` (sigmoid scores, a selection bias, top-k,
@@ -104,12 +107,12 @@ class LatentMoEDecoder:
                  first_k_dense_replace, routed_scaling_factor,
                  rope_theta, rope_scaling=None, rms_norm_eps=1e-6,
                  first_expert=0, n_held=None, max_seq=4096,
-                 dtype="bfloat16"):
+                 dtype="bfloat16", mla_use_nope=False):
         self.vocab_size = int(vocab_size)
         self.units = int(hidden_size)
         self.num_layers = int(num_hidden_layers)
         self.num_heads = int(num_attention_heads)
-        self.q_rank = int(q_lora_rank)
+        self.q_rank = None if q_lora_rank is None else int(q_lora_rank)
         self.kv_rank = int(kv_lora_rank)
         self.nope = int(qk_nope_head_dim)
         self.rope = int(qk_rope_head_dim)
@@ -136,8 +139,9 @@ class LatentMoEDecoder:
                 % (self.first_expert, self.first_expert + self.n_held,
                    self.num_experts, self.top_k))
         self.scale = attention_scale(self.nope + self.rope, rope_scaling)
-        self.inv_freq = yarn_inv_freq(self.rope, rope_theta,
-                                      rope_scaling).astype(np.float32)
+        # None: the shared key slice and its query slice are not rotated
+        self.inv_freq = None if mla_use_nope else yarn_inv_freq(
+            self.rope, rope_theta, rope_scaling).astype(np.float32)
 
     def cache_rows(self):
         """What one token keeps in one layer of the paged cache: the
@@ -158,12 +162,14 @@ class LatentMoEDecoder:
                "head": ((d, self.vocab_size), d)}
         for i in range(self.num_layers):
             pre = "h%d_" % i
-            out.update({
-                pre + "attn_norm": ((d,), "norm"),
+            q_width = h * (self.nope + self.rope)
+            out.update({pre + "wq": ((d, q_width), d)}
+                       if self.q_rank is None else {
                 pre + "wqa": ((d, self.q_rank), d),
                 pre + "q_norm": ((self.q_rank,), "norm"),
-                pre + "wqb": ((self.q_rank, h * (self.nope + self.rope)),
-                              self.q_rank),
+                pre + "wqb": ((self.q_rank, q_width), self.q_rank)})
+            out.update({
+                pre + "attn_norm": ((d,), "norm"),
                 pre + "wkva": ((d, self.kv_rank + self.rope), d),
                 pre + "kv_norm": ((self.kv_rank,), "norm"),
                 pre + "wkvb": ((self.kv_rank,
@@ -207,7 +213,10 @@ class LatentMoEDecoder:
     def _rotate(self, x, positions):
         """Rotary embedding of ``x`` (..., t, [heads,] rope) at
         ``positions`` (..., t): adjacent pairs (2j, 2j+1) turn by
-        ``position * inv_freq[j]``.  float32 in, float32 out."""
+        ``position * inv_freq[j]``.  float32 in, float32 out; ``x``
+        itself where the model has no positional encoding."""
+        if self.inv_freq is None:
+            return x
         return blocks.rotate(x, positions, self.inv_freq)
 
     def _q_latent(self, p, pre, x, positions):
@@ -216,9 +225,13 @@ class LatentMoEDecoder:
         normed input that the K/V side shares."""
         import jax.numpy as jnp
         h = self._rms(x, p[pre + "attn_norm"])
-        c_q = self._rms(self._dot(h, p[pre + "wqa"]).astype(x.dtype),
-                        p[pre + "q_norm"])
-        q = self._dot(c_q, p[pre + "wqb"]).reshape(
+        if self.q_rank is None:
+            q = self._dot(h, p[pre + "wq"])
+        else:
+            c_q = self._rms(self._dot(h, p[pre + "wqa"]).astype(x.dtype),
+                            p[pre + "q_norm"])
+            q = self._dot(c_q, p[pre + "wqb"])
+        q = q.reshape(
             x.shape[:-1] + (self.num_heads, self.nope + self.rope))
         q_nope, q_rope = jnp.split(q, [self.nope], axis=-1)
         return (q_nope.astype(x.dtype),
@@ -272,42 +285,58 @@ class LatentMoEDecoder:
         return blocks.new_moe_stats()
 
     # -- full causal forward (reference + prefill) ----------------------
+    def _mla_prefill(self, params, i, x, positions):
+        """Layer ``i``'s latent attention over a whole prompt, the
+        expanded form: x (b, t, d) -> (x + Attn(norm(x)), the layer's
+        latent rows (b, t, kv_rank + rope))."""
+        import jax
+        import jax.numpy as jnp
+        scope = jax.named_scope
+        b, t = x.shape[:2]
+        pre, layer = "h%d_" % i, "h%d/" % i
+        with scope(layer + "q_latent"):
+            q_nope, q_rope, h = self._q_latent(params, pre, x, positions)
+        with scope(layer + "kv_latent"):
+            row = self._kv_latent(params, pre, h, positions)
+            c_kv, k_rope = jnp.split(row, [self.kv_rank], axis=-1)
+            kv = self._dot(c_kv, params[pre + "wkvb"]).astype(
+                x.dtype).reshape(b, t, self.num_heads,
+                                 self.nope + self.v_dim)
+            k_nope, v = jnp.split(kv, [self.nope], axis=-1)
+            k = jnp.concatenate(
+                [k_nope, jnp.broadcast_to(
+                    k_rope[:, :, None, :],
+                    (b, t, self.num_heads, self.rope))], axis=-1)
+        with scope(layer + "attention"):
+            att = blocks.causal_attention(
+                jnp.concatenate([q_nope, q_rope], axis=-1), k, v,
+                self.scale)
+        with scope(layer + "proj"):
+            x = x + self._dot(att, params[pre + "wo"]).astype(x.dtype)
+        return x, row
+
+    def _positions(self, tokens):
+        import jax.numpy as jnp
+        b, t = tokens.shape
+        return jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32), (b, t))
+
+    def _embed(self, params, tokens):
+        import jax
+        import jax.numpy as jnp
+        with jax.named_scope("mx.embed"):
+            return jnp.take(params["embed"], tokens, axis=0)
+
     def _forward(self, params, tokens, live):
         """tokens (b, t) -> (hidden (b, t, d) before the final norm, the
         latent rows of every layer (b, t, kv_rank + rope), stats, the
         experts every expert layer's router chose (b, t, top_k))."""
-        import jax
-        import jax.numpy as jnp
-        scope = jax.named_scope
         b, t = tokens.shape
-        positions = jnp.broadcast_to(jnp.arange(t, dtype=jnp.int32),
-                                     (b, t))
-        with scope("mx.embed"):
-            x = jnp.take(params["embed"], tokens, axis=0)
+        positions = self._positions(tokens)
+        x = self._embed(params, tokens)
         stats, latents, routing = self._new_stats(), [], []
         for i in range(self.num_layers):
-            pre, layer = "h%d_" % i, "h%d/" % i
-            with scope(layer + "q_latent"):
-                q_nope, q_rope, h = self._q_latent(params, pre, x,
-                                                   positions)
-            with scope(layer + "kv_latent"):
-                row = self._kv_latent(params, pre, h, positions)
-                latents.append(row)
-                c_kv, k_rope = jnp.split(row, [self.kv_rank], axis=-1)
-                kv = self._dot(c_kv, params[pre + "wkvb"]).astype(
-                    x.dtype).reshape(b, t, self.num_heads,
-                                     self.nope + self.v_dim)
-                k_nope, v = jnp.split(kv, [self.nope], axis=-1)
-                k = jnp.concatenate(
-                    [k_nope, jnp.broadcast_to(
-                        k_rope[:, :, None, :],
-                        (b, t, self.num_heads, self.rope))], axis=-1)
-            with scope(layer + "attention"):
-                att = blocks.causal_attention(
-                    jnp.concatenate([q_nope, q_rope], axis=-1), k, v,
-                    self.scale)
-            with scope(layer + "proj"):
-                x = x + self._dot(att, params[pre + "wo"]).astype(x.dtype)
+            x, row = self._mla_prefill(params, i, x, positions)
+            latents.append(row)
             flat, stats, chosen = self._ffn(
                 params, i, x.reshape(b * t, -1), live.reshape(b * t), stats)
             x = flat.reshape(b, t, -1)
@@ -354,6 +383,63 @@ class LatentMoEDecoder:
         return logits, {"latent": tuple(r[0] for r in latents)}, stats
 
     # -- decode step over the paged cache -------------------------------
+    def _decode_prologue(self, params, token_ids, positions, block_tables,
+                         block_size, live):
+        """``(x, blk, off, ctx, live)`` of a decode step: the tokens'
+        embeddings, the block and offset each slot's new row goes to,
+        the context lengths (s, 1) and the live slots."""
+        import jax
+        import jax.numpy as jnp
+        s = token_ids.shape[0]
+        with jax.named_scope("mx.embed"):
+            blk = jnp.take_along_axis(
+                block_tables, (positions // block_size)[:, None],
+                axis=1)[:, 0]
+            off = positions % block_size
+            ctx = (positions + 1).astype(jnp.int32).reshape(s, 1)
+            if live is None:
+                live = jnp.ones((s,), bool)
+            x = jnp.take(params["embed"], token_ids, axis=0)
+        return x, blk, off, ctx, live
+
+    def _mla_decode(self, params, i, x, positions, slab, blk, off,
+                    block_tables, ctx):
+        """Layer ``i``'s latent attention in a decode step, the absorbed
+        form: writes the slots' new rows into ``slab`` (the layer's
+        latent slab) and hands it whole to the kernel; returns (x +
+        Attn(norm(x)), slab')."""
+        import jax
+        import jax.numpy as jnp
+        from ...kernels.mla_paged_attention import mla_paged_attention
+        from .kvcache import slab_rows
+        scope = jax.named_scope
+        s = x.shape[0]
+        pre, layer = "h%d_" % i, "h%d/" % i
+        with scope(layer + "q_latent"):
+            q_nope, q_rope, h = self._q_latent(params, pre, x, positions)
+        with scope(layer + "kv_latent"):
+            row = self._kv_latent(params, pre, h, positions)
+        with scope(layer + "kv_write"):
+            slab = slab.at[blk, off].set(slab_rows(row, slab))
+        with scope(layer + "absorb"):
+            w_uk, w_uv = self._up_projections(params, pre)
+            q_abs = jnp.einsum("shn,chn->shc", q_nope, w_uk,
+                               preferred_element_type=jnp.float32)
+            q = slab_rows(jnp.concatenate(
+                [q_abs.astype(x.dtype), q_rope], axis=-1), slab)
+        with scope(layer + "attention"):
+            att = mla_paged_attention(q, slab, block_tables, ctx,
+                                      v_width=self.kv_rank,
+                                      scale=self.scale)
+        with scope(layer + "absorb"):
+            att = jnp.einsum("shc,chv->shv", att.astype(x.dtype), w_uv,
+                             preferred_element_type=jnp.float32)
+            att = att.astype(x.dtype).reshape(
+                s, self.num_heads * self.v_dim)
+        with scope(layer + "proj"):
+            x = x + self._dot(att, params[pre + "wo"]).astype(x.dtype)
+        return x, slab
+
     def decode_logits(self, params, slabs, token_ids, positions,
                       block_tables, block_size, live=None):
         """One decode step for a slot batch, the absorbed form.
@@ -371,51 +457,17 @@ class LatentMoEDecoder:
         nothing is cut out of a slab."""
         import jax
         import jax.numpy as jnp
-        from ...kernels.mla_paged_attention import mla_paged_attention
-        from .kvcache import slab_rows
-        scope = jax.named_scope
-        s = token_ids.shape[0]
         latent = list(slabs["latent"])
-        with scope("mx.embed"):
-            blk = jnp.take_along_axis(
-                block_tables, (positions // block_size)[:, None],
-                axis=1)[:, 0]
-            off = positions % block_size
-            ctx = (positions + 1).astype(jnp.int32).reshape(s, 1)
-            if live is None:
-                live = jnp.ones((s,), bool)
-            x = jnp.take(params["embed"], token_ids, axis=0)
+        x, blk, off, ctx, live = self._decode_prologue(
+            params, token_ids, positions, block_tables, block_size, live)
         stats = self._new_stats()
         for i in range(self.num_layers):
-            pre, layer = "h%d_" % i, "h%d/" % i
-            with scope(layer + "q_latent"):
-                q_nope, q_rope, h = self._q_latent(params, pre, x,
-                                                   positions)
-            with scope(layer + "kv_latent"):
-                row = self._kv_latent(params, pre, h, positions)
-            with scope(layer + "kv_write"):
-                latent[i] = latent[i].at[blk, off].set(
-                    slab_rows(row, latent[i]))
-            with scope(layer + "absorb"):
-                w_uk, w_uv = self._up_projections(params, pre)
-                q_abs = jnp.einsum("shn,chn->shc", q_nope, w_uk,
-                                   preferred_element_type=jnp.float32)
-                q = slab_rows(jnp.concatenate(
-                    [q_abs.astype(x.dtype), q_rope], axis=-1), latent[i])
-            with scope(layer + "attention"):
-                att = mla_paged_attention(q, latent[i], block_tables, ctx,
-                                          v_width=self.kv_rank,
-                                          scale=self.scale)
-            with scope(layer + "absorb"):
-                att = jnp.einsum("shc,chv->shv", att.astype(x.dtype), w_uv,
-                                 preferred_element_type=jnp.float32)
-                att = att.astype(x.dtype).reshape(
-                    s, self.num_heads * self.v_dim)
-            with scope(layer + "proj"):
-                x = x + self._dot(att, params[pre + "wo"]).astype(x.dtype)
+            x, latent[i] = self._mla_decode(params, i, x, positions,
+                                            latent[i], blk, off,
+                                            block_tables, ctx)
             x, stats, _chosen = self._ffn(params, i, x, live, stats)
         logits = self._head(params, x)
-        with scope("mx.lm_head"):
+        with jax.named_scope("mx.lm_head"):
             next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return next_token, logits, {"latent": tuple(latent)}, stats
 

@@ -45,7 +45,7 @@ __all__ = [
     "fleet_round", "fleet_alert", "fleet_alerts_firing",
     "decode_request", "decode_shed", "decode_prefill", "decode_step",
     "decode_ttft", "decode_inter_token", "decode_finish",
-    "decode_kv_aliased", "decode_moe", "decode_ut_passes",
+    "decode_kv_aliased", "decode_moe", "decode_ut_passes", "decode_linear",
     "kvcache_alloc", "kvcache_free", "kvcache_alloc_failure",
     "loss_softmax_ce", "moe_combine",
 ]
@@ -442,11 +442,13 @@ def decode_moe(model, stats):
         stats.get("moe_expert_tokens_max", 0))
 
 
-def _kvcache_gauges(reg, in_use, fragmentation, by_kind):
+def _kvcache_gauges(reg, in_use, fragmentation, by_kind, state_rows):
     reg.gauge("kvcache.blocks_in_use").set(in_use)
     reg.gauge("kvcache.fragmentation").set(fragmentation)
     for kind, n in (by_kind or {}).items():
         reg.gauge("kvcache.blocks_in_use." + kind).set(n)
+    if state_rows is not None:
+        reg.gauge("kvcache.state_rows_in_use").set(state_rows)
 
 
 def decode_kv_rows(model, stats):
@@ -475,21 +477,35 @@ def decode_ut_passes(model, stats):
     reg.counter("decode.kv.rows").inc(stats.get("kv_rows", 0))
 
 
-def kvcache_alloc(in_use, fragmentation, by_kind=None):
+def kvcache_alloc(in_use, fragmentation, by_kind=None, state_rows=None):
     """A block-table allocation succeeded; gauges carry the cache's
-    post-alloc occupancy (all pools; ``by_kind`` each pool's where the
-    cache has window layers beside full ones) and internal
+    post-alloc occupancy (all pools of blocks; ``by_kind`` each pool's
+    where the cache has window layers beside full ones; ``state_rows``
+    the state rows held where it has state layers) and internal
     fragmentation (unused fraction of allocated blocks)."""
     reg = _registry()
     reg.counter("kvcache.allocs").inc()
-    _kvcache_gauges(reg, in_use, fragmentation, by_kind)
+    _kvcache_gauges(reg, in_use, fragmentation, by_kind, state_rows)
 
 
-def kvcache_free(in_use, fragmentation, by_kind=None):
-    """A finished/cancelled sequence returned its blocks."""
+def kvcache_free(in_use, fragmentation, by_kind=None, state_rows=None):
+    """A finished/cancelled sequence returned its blocks (and its state
+    row)."""
     reg = _registry()
     reg.counter("kvcache.frees").inc()
-    _kvcache_gauges(reg, in_use, fragmentation, by_kind)
+    _kvcache_gauges(reg, in_use, fragmentation, by_kind, state_rows)
+
+
+def decode_linear(model, stats):
+    """The counts a prefill or decode program of a model with
+    linear-attention layers returned beside its token: state rows a
+    decode step read and wrote (live slots x state layers) and prompt
+    tokens a prefill's chunked scan took in (true tokens x state
+    layers)."""
+    reg = _registry()
+    reg.counter("decode.linear.state_rows").inc(stats.get("state_rows", 0))
+    reg.counter("decode.linear.scan_tokens").inc(
+        stats.get("scan_tokens", 0))
 
 
 def kvcache_alloc_failure():
@@ -1156,6 +1172,13 @@ INSTRUMENTS = [
         "cache rows ONE cache layer (one pass of one layer) had to read "
         "in those programs: the live slots' context lengths, summed "
         "(kv_rows)"),
+    _ii("decode.linear.state_rows", "counter", "serving", 38,
+        "state rows the decode programs of a model with linear-attention "
+        "layers read and wrote: live slots x KDA layers a step "
+        "(state_rows, which the program returns beside its token)"),
+    _ii("decode.linear.scan_tokens", "counter", "serving", 38,
+        "prompt tokens the prefill programs' chunked scans took in: true "
+        "prompt tokens x KDA layers (scan_tokens)"),
     _ii("kvcache.allocs", "counter", "serving", 18,
         "block-table allocations (one per admitted request)"),
     _ii("kvcache.frees", "counter", "serving", 18,
@@ -1170,6 +1193,10 @@ INSTRUMENTS = [
         "the same a pool (full, window), where the cache has window "
         "layers beside full ones: a full layer's tables and the window "
         "layers' rings"),
+    _ii("kvcache.state_rows_in_use", "gauge", "serving", 38,
+        "state rows held by live sequences, where the cache has state "
+        "layers (one a sequence, the same in every state layer; not "
+        "counted in kvcache.blocks_in_use)"),
     _ii("kvcache.fragmentation", "gauge", "serving", 18,
         "unused fraction of allocated KV blocks (internal "
         "fragmentation; at worst one partial block per sequence)"),

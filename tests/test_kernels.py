@@ -55,6 +55,9 @@ CELL_SHAPES = {
     # experts of 2,304 x 896
     "grouped_matmul": (dict(groups=64, k=2304, n=896),
                        dict(groups=0, k=2304, n=896), "positive"),
+    # kimi_linear_serve_closed128: 32 heads of (128, 128) float32 state
+    "kda_decode": (dict(heads=32, key=128, value=128),
+                   dict(heads=32, key=72, value=128), "128-lane"),
 }
 KERNEL_NAMES = sorted(CELL_SHAPES)
 
@@ -77,7 +80,7 @@ def _situate(monkeypatch, backend, has_pallas=True):
     monkeypatch.setattr(kreg, "_has_pallas", lambda: has_pallas)
 
 
-def test_registry_lists_the_four_kernels():
+def test_registry_lists_the_five_kernels():
     assert kernels.list_kernels() == KERNEL_NAMES
 
 

@@ -15,6 +15,7 @@ from jax import export
 from mxnet_tpu.ops.pallas.flash_attention import (
     flash_attention_bwd_pallas, flash_attention_fwd_pallas)
 from mxnet_tpu.ops.pallas.grouped_matmul import grouped_matmul_pallas
+from mxnet_tpu.ops.pallas.kda_decode import kda_decode_pallas
 from mxnet_tpu.ops.pallas.mla_paged_attention import (
     mla_paged_attention_pallas)
 from mxnet_tpu.ops.pallas.paged_attention import paged_attention_pallas
@@ -125,6 +126,15 @@ def test_paged_attention_ouro_decode_buckets(slots):
             q, k, v, bt + 3 * 81, cl, scale=128 ** -0.5),
         S((slots, 16, 128), BF16), slab, slab,
         S((slots, 8), I32), S((slots, 1), I32))
+
+
+@pytest.mark.parametrize("slots", [64, 128])
+def test_kda_decode_kimi_linear_decode_buckets(slots):
+    # kimi_linear_serve_closed128: 32 heads of 128, each slot's whole
+    # float32 state row (2 MiB) a grid step, 128 slots + the scratch row
+    vec = S((slots, 32, 128), F32)
+    _lowers_to_mosaic(kda_decode_pallas, vec, vec, vec, vec, vec,
+                      S((129, 32, 128, 128), F32), S((slots,), I32))
 
 
 _GROUPED = {"gate_up": (2304, 896), "down": (896, 2304)}
@@ -448,3 +458,60 @@ def test_the_loss_over_the_vocabulary_keeps_only_the_bf16_logits(
     else:
         assert not wide, wide
         assert temp < 1.25 * logits_bytes, temp
+
+
+@pytest.mark.parametrize("kind", ["decode", "prefill"])
+def test_a_hybrids_states_and_latent_rows_are_written_in_place_on_the_chip(
+        one_chip, no_compile_cache, monkeypatch, kind):
+    """Kimi Linear's widths, a KDA layer with the dense FFN and a NoPE
+    latent layer holding two of 256 experts: both programs alias every
+    byte of the latent slab, the state array and the convolution's
+    inputs, copy no state array, and a decode step turns the states with
+    the KDA kernel once a KDA layer."""
+    import re
+
+    from mxnet_tpu.kernels import registry
+    from mxnet_tpu.serving.decode import DecodeEngine, LinearLatentMoEDecoder
+    monkeypatch.setattr(registry, "_backend", lambda: "tpu")
+    model = LinearLatentMoEDecoder(
+        vocab_size=2048, hidden_size=2304, num_hidden_layers=2,
+        num_attention_heads=32, kv_lora_rank=512, qk_nope_head_dim=128,
+        qk_rope_head_dim=64, v_head_dim=128, intermediate_size=9216,
+        moe_intermediate_size=1024, n_routed_experts=256,
+        num_experts_per_tok=8, n_shared_experts=1, first_k_dense_replace=1,
+        routed_scaling_factor=2.446, linear_attn_config={
+            "kda_layers": [1], "full_attn_layers": [2], "num_heads": 32,
+            "head_dim": 128, "short_conv_kernel_size": 4},
+        first_expert=0, n_held=2, max_seq=16384)
+    params = {name: S(shape, BF16 if kind_ == "norm" or isinstance(
+                  kind_, int) else F32)
+              for name, (shape, kind_) in model.param_shapes().items()}
+    eng = DecodeEngine(model, params, prefill_buckets=(256,),
+                       decode_buckets=(16,), block_size=64, num_blocks=257,
+                       kv_dtype="bfloat16")
+    assert [a.shape for a in eng.cache.slabs["kda_state"]] \
+        == [(17, 32, 128, 128)]
+    # the convolution's 3 x 3 x 4,096 inputs a row in rows of 128 lanes
+    assert [a.shape for a in eng.cache.slabs["kda_conv"]] == [(17, 288, 128)]
+    slabs = 257 * 64 * 640 * 2 + 17 * 32 * 128 * 128 * 4 \
+        + 17 * 3 * 3 * 4096 * 2
+    assert eng.cache.slab_bytes() == slabs
+    prefill, decode = eng._specs()
+    fn, specs = (eng._decode_impl, decode[16]) if kind == "decode" \
+        else (eng._prefill_impl, prefill[256])
+    specs = jax.tree.map(
+        lambda s: S(s.shape, s.dtype, sharding=one_chip), specs)
+    compiled = jax.jit(fn, donate_argnums=eng._DONATED).lower(
+        *specs).compile()
+    assert compiled.memory_analysis().alias_size_in_bytes == slabs
+    text = compiled.as_text()
+    assert len(re.findall(r"kda_decode_pallas\S* = ", text)) \
+        == (1 if kind == "decode" else 0)
+    assert len(re.findall(r"mla_paged_attention_pallas\S* = ", text)) \
+        == (1 if kind == "decode" else 0)
+    moved = re.findall(r"= f32\[17,32,128,128\]\S* (?:copy|slice)\(", text)
+    assert not moved, moved[:3]
+    # the convolution's inputs are written as whole rows (one scatter),
+    # not by a loop of one-row updates
+    assert not re.findall(r"dynamic-update-slice\S* = bf16\[17,", text) \
+        or kind == "prefill"

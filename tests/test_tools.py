@@ -86,3 +86,38 @@ def test_distributed_init_noop_single_process(monkeypatch):
     import mxnet_tpu as mx
     monkeypatch.delenv("MXNET_TPU_COORDINATOR", raising=False)
     assert mx.distributed_init() is False
+
+
+def test_program_diff_leaves_out_what_names_the_source():
+    """tools/program_diff.py compares instructions: the source tables
+    and an instruction's metadata may differ, an instruction may not."""
+    from tools import program_diff
+
+    def module(line, op="add"):
+        return ("HloModule m\n\nFileNames\n1 \"%s.py\"\n\nStackFrames\n"
+                "1 {file_location_id=1 parent_frame_id=1}\n\n"
+                "ENTRY %%main (x: f32[]) -> f32[] {\n"
+                "  %%x = f32[] parameter(0), metadata={op_name=\"h0/q\" "
+                "source_file=\"%s.py\" source_line=%d}, stack_frame_id=1\n"
+                "  ROOT %%y = f32[] %s(%%x, %%x)\n}\n" % (line, line, len(line),
+                                                         op))
+    same = program_diff._instructions(module("a"))
+    assert program_diff._instructions(module("parent/b")) == same
+    assert "source" not in same and "FileNames" not in same
+    assert program_diff._instructions(module("a", "multiply")) != same
+
+
+def test_program_diff_reads_a_checkout_like_itself(capsys):
+    """A serving cell's programs compiled for a described v5e in this
+    checkout and (a process of its own) in the same one: the same."""
+    from tools import program_diff
+    try:
+        from jax.experimental import topologies
+        topologies.get_topology_desc(platform="tpu",
+                                     topology_name="v5e:2x2")
+    except Exception as e:          # pragma: no cover
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    assert program_diff.main(["--workload", "gpt2m_serve_closed16",
+                              "--other", REPO, "--rehearse"]) == 0
+    lines = capsys.readouterr().out.split("\n")
+    assert [ln.split()[1] for ln in lines if ln] == ["same"] * 3
