@@ -844,10 +844,11 @@ class DecodeEngine:
         ``kv_rows_window``; one that runs its layers several times:
         ``ut_passes``, ``exit_step_sum``, ``exit_early``, ``kv_rows``;
         one with state layers: ``state_rows`` (a decode step) or
-        ``scan_tokens`` (a prefill); empty for a dense one of full layers
-        run once): onto the step's or the prefill's span and the
-        ``decode.moe.*`` / ``decode.kv.*`` / ``decode.ut.*`` /
-        ``decode.linear.*`` counters."""
+        ``scan_tokens`` (a prefill); one with latent-attention layers:
+        ``latent_grid_steps`` (a decode step); empty for a dense one of
+        full layers run once): onto the step's or the prefill's span and
+        the ``decode.moe.*`` / ``decode.kv.*`` / ``decode.ut.*`` /
+        ``decode.linear.*`` / ``decode.latent.*`` counters."""
         if not stats:
             return
         stats = {k: int(v) for k, v in stats.items()}
@@ -861,6 +862,8 @@ class DecodeEngine:
                 _telemetry.hooks.decode_ut_passes(self._label, stats)
             if "state_rows" in stats or "scan_tokens" in stats:
                 _telemetry.hooks.decode_linear(self._label, stats)
+            if "latent_grid_steps" in stats:
+                _telemetry.hooks.decode_latent(self._label, stats)
 
     def _call_failed(self, error, served, dispatched):
         """A prefill or decode call raised.  Before the call took its

@@ -57,7 +57,9 @@ The prefill and decode programs return, beside the token, three counts
 (``stats``): ``moe_assignments`` (live tokens x experts per token x
 expert layers), ``moe_assignments_held`` (those that fell on experts
 held here) and ``moe_expert_tokens_max`` (the largest count one held
-expert of one layer was given).  The engine fetches them with the token.
+expert of one layer was given); a decode step also ``latent_grid_steps``
+(the grid steps of the latent kernel: its slots' live page groups x MLA
+layers).  The engine fetches them with the token.
 """
 from __future__ import annotations
 
@@ -457,6 +459,7 @@ class LatentMoEDecoder:
         nothing is cut out of a slab."""
         import jax
         import jax.numpy as jnp
+        from ...ops.pallas.mla_paged_attention import grid_steps
         latent = list(slabs["latent"])
         x, blk, off, ctx, live = self._decode_prologue(
             params, token_ids, positions, block_tables, block_size, live)
@@ -469,6 +472,9 @@ class LatentMoEDecoder:
         logits = self._head(params, x)
         with jax.named_scope("mx.lm_head"):
             next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        # the latent kernel's schedule is the same in every layer
+        stats = dict(stats, latent_grid_steps=grid_steps(
+            block_tables, ctx, block_size) * self.num_layers)
         return next_token, logits, {"latent": tuple(latent)}, stats
 
     def __repr__(self):

@@ -44,7 +44,8 @@ matmul accumulates in float32.
 
 Beside the token the programs return ``scan_tokens`` (a prefill: the
 prompt's true tokens x KDA layers) or ``state_rows`` (a decode step: the
-live slots x KDA layers), and the expert layers' three counts.
+live slots x KDA layers) and ``latent_grid_steps`` (the latent kernel's
+grid steps x MLA layers), and the expert layers' three counts.
 """
 from __future__ import annotations
 
@@ -447,6 +448,7 @@ class LinearLatentMoEDecoder(LatentMoEDecoder):
         layer."""
         import jax
         import jax.numpy as jnp
+        from ...ops.pallas.mla_paged_attention import grid_steps
         tables = block_tables["full"]
         rows = block_tables["state"][:, 0]
         out = {name: list(arrays) for name, arrays in slabs.items()}
@@ -470,7 +472,9 @@ class LinearLatentMoEDecoder(LatentMoEDecoder):
         with jax.named_scope("mx.lm_head"):
             next_token = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         stats = dict(stats, state_rows=jnp.sum(live.astype(jnp.int32))
-                     * len(self.kda_layers))
+                     * len(self.kda_layers),
+                     latent_grid_steps=grid_steps(tables, ctx, block_size)
+                     * (self.num_layers - len(self.kda_layers)))
         out = (next_token, logits,
                {name: tuple(a) for name, a in out.items()}, stats)
         return out + (tuple(routing),) if with_routing else out

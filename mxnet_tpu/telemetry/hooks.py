@@ -46,6 +46,7 @@ __all__ = [
     "decode_request", "decode_shed", "decode_prefill", "decode_step",
     "decode_ttft", "decode_inter_token", "decode_finish",
     "decode_kv_aliased", "decode_moe", "decode_ut_passes", "decode_linear",
+    "decode_latent",
     "kvcache_alloc", "kvcache_free", "kvcache_alloc_failure",
     "loss_softmax_ce", "moe_combine",
 ]
@@ -506,6 +507,14 @@ def decode_linear(model, stats):
     reg.counter("decode.linear.state_rows").inc(stats.get("state_rows", 0))
     reg.counter("decode.linear.scan_tokens").inc(
         stats.get("scan_tokens", 0))
+
+
+def decode_latent(model, stats):
+    """The count a decode program of a model with latent-attention
+    layers returned beside its token: the grid steps its latent kernel
+    ran, summed over those layers (``latent_grid_steps``)."""
+    _registry().counter("decode.latent.grid_steps").inc(
+        stats.get("latent_grid_steps", 0))
 
 
 def kvcache_alloc_failure():
@@ -1179,6 +1188,13 @@ INSTRUMENTS = [
     _ii("decode.linear.scan_tokens", "counter", "serving", 38,
         "prompt tokens the prefill programs' chunked scans took in: true "
         "prompt tokens x KDA layers (scan_tokens)"),
+    _ii("decode.latent.grid_steps", "counter", "serving", 39,
+        "grid steps the latent paged-attention kernel ran in the decode "
+        "programs of a model with latent-attention layers: one a live "
+        "page group of each slot, summed over the MLA layers "
+        "(latent_grid_steps, which the program returns beside its token); "
+        "against slots x table blocks / pages x MLA layers it is the "
+        "share of the table walked"),
     _ii("kvcache.allocs", "counter", "serving", 18,
         "block-table allocations (one per admitted request)"),
     _ii("kvcache.frees", "counter", "serving", 18,

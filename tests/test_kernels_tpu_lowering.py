@@ -94,6 +94,19 @@ def test_mla_paged_attention_kimi_k2_decode_buckets(slots):
         S((slots, 256), I32), S((slots, 1), I32))
 
 
+@pytest.mark.parametrize("slots", [64, 128])
+def test_mla_paged_attention_kimi_linear_decode_buckets(slots):
+    # Kimi-Linear's NoPE latent row (512 + 64 values in 640 lanes, bf16),
+    # 32 heads, a slab of 32,769 blocks of 64 tokens and tables 256 wide:
+    # at 128 slots the largest schedule the kernel takes, 4,096 steps of
+    # 8 pages scalar-prefetched beside the context lengths
+    _lowers_to_mosaic(
+        lambda q, c, bt, cl: mla_paged_attention_pallas(
+            q, c, bt, cl, v_width=512, scale=0.07),
+        S((slots, 32, 640), BF16), S((32769, 64, 640), BF16),
+        S((slots, 256), I32), S((slots, 1), I32))
+
+
 @pytest.mark.parametrize("window,blocks,table", [(None, 8385, 262),
                                                  (1024, 545, 17)],
                          ids=["full", "window"])

@@ -136,19 +136,93 @@ def test_mla_attention_never_reads_past_the_context():
         mla_paged_attention_pallas, mla_paged_attention_reference)
     import jax.numpy as jnp
     rng = np.random.default_rng(1)
-    q, cache, _bt, _ctx, v = _kernel_case(rng, "float32", 1, [6])
-    bt = jnp.asarray(np.array([[1, 2, 3, 3, 3, 3, 3, 3]], np.int32))
-    ctx = jnp.asarray(np.array([[6]], np.int32))
+    q, cache, _bt, _ctx, v = _kernel_case(rng, "float32", 3, [6, 10, 5])
+    # slot 0 ends inside block 2, slot 1 inside block 3, slot 2 inside
+    # block 5; blocks 6, 7 and 8 are named by a table past its slot's
+    # context.  Read 8 pages a step, slot 1's and slot 2's dead pages
+    # hold what an earlier slot's held; read 2, slot 1 takes two steps
+    # and the second one's dead page holds block 10
+    bt = np.array([[1, 2, 6, 6, 6, 6, 6, 6],
+                   [9, 10, 3, 8, 8, 8, 8, 8],
+                   [4, 5, 7, 7, 7, 7, 7, 7]], np.int32)
+    ctx = jnp.asarray(np.array([[6], [10], [5]], np.int32))
     poisoned = np.asarray(cache).copy()
-    poisoned[2, 2:] = 1e6                # positions 6, 7 of block 2: dead
-    poisoned[3] = 1e6                    # every later block
-    for fn, kw in ((mla_paged_attention_reference, {}),
-                   (mla_paged_attention_pallas, {"interpret": True})):
-        base = fn(q, cache, bt, ctx, v_width=v, scale=0.5, **kw)
-        got = fn(q, jnp.asarray(poisoned), bt, ctx, v_width=v, scale=0.5,
-                 **kw)
-        np.testing.assert_allclose(np.asarray(got), np.asarray(base),
-                                   atol=1e-6)
+    poisoned[2, 2:] = 1e6       # positions 6, 7 of block 2: dead to slot 0
+    poisoned[3, 2:] = 1e6       # positions 10, 11 of block 3
+    poisoned[5, 1:] = 1e6       # positions 5..7 of block 5
+    poisoned[6:9] = 1e6         # every block past a context
+    for width in (8, 6):
+        table = jnp.asarray(bt[:, :width])
+        for fn, kw in ((mla_paged_attention_reference, {}),
+                       (mla_paged_attention_pallas, {"interpret": True})):
+            base = fn(q, cache, table, ctx, v_width=v, scale=0.5, **kw)
+            got = fn(q, jnp.asarray(poisoned), table, ctx, v_width=v,
+                     scale=0.5, **kw)
+            np.testing.assert_allclose(np.asarray(got), np.asarray(base),
+                                       atol=1e-6)
+
+
+@pytest.mark.parametrize("mb", [8, 6, 3], ids=["pages8", "pages2",
+                                                "pages1"])
+def test_mla_grid_is_the_live_page_groups(mb):
+    """One grid step a page group a slot's context reaches (one for a
+    slot of one token), slot by slot and group by group; a live page
+    names its table entry and a dead one what it held a step before."""
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas import mla_paged_attention as mla
+    from mxnet_tpu.ops.pallas.paged_attention import _live_steps
+    assert mla._live_steps is _live_steps       # the GPT-2 kernel's own
+    bs, pages = 4, {8: 8, 6: 2, 3: 1}[mb]
+    span = bs * pages
+    # one token, a block boundary, inside a block, the whole table
+    ctx = np.array([1, 4, 4 * mb - 3, 4 * mb], np.int32)
+    bt = np.arange(1, 4 * mb + 1, dtype=np.int32).reshape(4, mb)
+    steps = int(mla.grid_steps(jnp.asarray(bt), jnp.asarray(ctx[:, None]),
+                               bs))
+    want = [(s, g) for s in range(4)
+            for g in range(max(1, -(-int(ctx[s]) // span)))]
+    assert steps == len(want)
+    assert steps < 4 * mb // pages or mb == 8    # the whole table's grid
+    n, slot, group, blocks = (np.asarray(a) for a in _live_steps(
+        jnp.asarray(bt), jnp.asarray(ctx), bs, pages))
+    assert int(n) == steps
+    assert list(zip(slot[:steps], group[:steps])) == want
+    for i, (s, g) in enumerate(want):
+        for k in range(pages):
+            if (g * pages + k) * bs < ctx[s]:
+                assert blocks[k, i] == bt[s, g * pages + k]
+            elif i:
+                assert blocks[k, i] == blocks[k, i - 1]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("mb", [8, 6, 3], ids=["pages8", "pages2",
+                                                "pages1"])
+def test_the_live_grid_is_the_whole_table_kernel_bit_for_bit(dtype, mb):
+    """Today's kernel against the one whose grid walked every slot's
+    whole table (``tests/_mla_paged_attention_whole_table.py``): the
+    same live groups in the same order over the same rows, a dead
+    page's scores exact zeros, so the same bits."""
+    import importlib.util
+    import os
+    from mxnet_tpu.ops.pallas.mla_paged_attention import (
+        mla_paged_attention_pallas)
+    spec = importlib.util.spec_from_file_location(
+        "mla_paged_attention_whole_table", os.path.join(
+            os.path.dirname(__file__), "_mla_paged_attention_whole_table.py"))
+    before = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(before)
+    rng = np.random.default_rng(5)
+    ctx = [1, 4, 9, 4 * mb - 3, 4 * mb, 2]
+    q, cache, bt, ctx, v = _kernel_case(rng, dtype, 6, ctx, mb=mb)
+    for scale in (0.3, 1.0):
+        np.testing.assert_array_equal(
+            np.asarray(mla_paged_attention_pallas(
+                q, cache, bt, ctx, v_width=v, scale=scale, interpret=True),
+                np.float32),
+            np.asarray(before.mla_paged_attention_pallas(
+                q, cache, bt, ctx, v_width=v, scale=scale, interpret=True),
+                np.float32))
 
 
 def test_mla_paged_attention_is_a_registry_entry():
@@ -414,6 +488,47 @@ def test_the_engine_counts_expert_assignments(engine, params):
                     "n", "bucket"} <= set(s["attrs"])
         assert sum(s["attrs"]["moe_assignments_held"]
                    for ss in spans.values() for s in ss) == held
+    finally:
+        obs.disable_tracing()
+        telemetry.reset("decode.")
+        telemetry.disable()
+
+
+def test_a_decode_step_counts_the_latent_kernels_grid_steps(engine,
+                                                           params):
+    """``latent_grid_steps`` is the schedule's step count times the MLA
+    layers, on the step's span and summed by ``decode.latent.grid_steps``:
+    a table of 16 blocks of 4 is read 8 pages (32 tokens) a step, so a
+    30-token prompt's decode steps reach 31, 32 and 33 tokens, one group,
+    one and two, beside the padded slot's one."""
+    import jax
+    import jax.numpy as jnp
+    from mxnet_tpu.ops.pallas.mla_paged_attention import grid_steps
+    bt = np.full((4, 16), SCRATCH_BLOCK, np.int32)
+    bt[:, :12] = np.arange(1, 49).reshape(4, 12)
+    positions = np.array([40, 3, 31, 0], np.int32)
+    slabs = {"latent": tuple(jnp.zeros((49, 4, 128), jnp.float32)
+                             for _ in range(MODEL.num_layers))}
+    _next, _logits, _slabs, stats = jax.jit(
+        MODEL.decode_logits, static_argnums=(5,))(
+        params, slabs, np.zeros(4, np.int32), positions, bt, 4,
+        np.array([True, True, True, False]))
+    assert int(grid_steps(jnp.asarray(bt), jnp.asarray(positions + 1)
+                          .reshape(4, 1), 4)) == 2 + 1 + 1 + 1
+    assert int(stats["latent_grid_steps"]) == 5 * MODEL.num_layers
+    telemetry.enable()
+    telemetry.reset("decode.")
+    obs.trace.clear()
+    obs.enable_tracing()
+    try:
+        assert len(engine.submit(list(range(1, 31)), 4).tokens()) == 4
+        steps = [s["attrs"]["latent_grid_steps"] for s in obs.spans()
+                 if s["name"] == "mx.decode.step"]
+        assert steps == [2 * 3, 2 * 3, 3 * 3]
+        assert telemetry.registry().counter(
+            "decode.latent.grid_steps").value == sum(steps)
+        assert not any("latent_grid_steps" in s["attrs"] for s in obs.spans()
+                       if s["name"] == "mx.decode.prefill")
     finally:
         obs.disable_tracing()
         telemetry.reset("decode.")

@@ -377,6 +377,11 @@ def test_the_engine_counts_state_rows_and_scan_tokens(engine):
         assert spans["mx.decode.prefill"][0]["attrs"]["scan_tokens"] == 20
         assert [s["attrs"]["state_rows"] for s in spans["mx.decode.step"]] \
             == [4, 4, 4]
+        # one MLA layer; the stream's 6..8 tokens and the padded slot are
+        # one page group each
+        assert [s["attrs"]["latent_grid_steps"]
+                for s in spans["mx.decode.step"]] == [2, 2, 2]
+        assert reg.counter("decode.latent.grid_steps").value == 3 * 2
         assert "moe_assignments" in spans["mx.decode.step"][0]["attrs"]
     finally:
         obs.disable_tracing()
