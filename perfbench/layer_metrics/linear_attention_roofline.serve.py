@@ -24,7 +24,7 @@ def read(run):
     peak_flops, peak_bw = device_peaks(run.stamp["kind"])
     by_flops, by_bytes = flops / peak_flops, nbytes / peak_bw
     kernel_ms = found.recurrence_ms()
-    run.log.measurement("roofline", kernel="kda_decode",
+    run.log.measurement("roofline", kernel="linear_attention",
                         bound="compute" if by_flops >= by_bytes
                         else "memory",
                         least_ms=1e3 * max(by_flops, by_bytes),

@@ -13,6 +13,7 @@ import types
 import numpy as np
 import pytest
 
+import _entries
 from perfbench.families import kimi_k2
 from perfbench.harness import program_trace, xplane
 from perfbench.harness.spec import Cell, SpecError, sized
@@ -118,29 +119,38 @@ def test_the_mix_fits_the_deployment_letter_for_letter():
         <= tiny["max_position_embeddings"]
 
 
-def test_the_cell_reports_every_serving_metric_but_the_open_loops_two():
-    cell = Cell(REPO, CELL)
-    assert cell.chips == 1 and cell.family() is kimi_k2
-    bench = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
+def entries(bench):
+    """What the benchmark holds of the cell, whatever later cells were
+    appended after it."""
     closed = {m["name"] for m in bench["per_layer"]
               if "gpt2m_serve_open_r80" in m.get("workloads", ())}
-    mine = {m["name"] for m in cell.per_layer}
+    mine = _entries.reported(bench, CELL)
     assert closed - mine == {"queue_wait_ms.serve", "ttft_p50_ms.open"}
     assert mine - closed == {"moe_experts_ms.serve",
                              "expert_tokens_per_step.serve",
                              "cache_hit_share.setup"}
-    assert {m["name"] for m in cell.end_to_end} == {"serve_tokens_per_s",
-                                                    "setup_s"}
-    # appended, each at the end of its list
-    assert bench["workloads"][-1]["name"] == CELL
-    assert bench["configs"][-1]["name"] == "kimi-k2-instruct-ep32"
-    assert [m["name"] for m in bench["per_layer"][-2:]] == [
-        "moe_experts_ms.serve", "expert_tokens_per_step.serve"]
+    assert _entries.reported(bench, CELL, "end_to_end") \
+        == {"serve_tokens_per_s", "setup_s"}
+    # appended: cell 4, configuration 3, its two metrics straight after the
+    # open loop's, and in each list behind the cells the benchmark had
+    _entries.entry_at(bench, "workloads", CELL, 4)
+    _entries.entry_at(bench, "configs", "kimi-k2-instruct-ep32", 3)
+    new = ["moe_experts_ms.serve", "expert_tokens_per_step.serve"]
+    _entries.metrics_in_order(bench, ["ttft_p50_ms.open"] + new)
+    _entries.first_of_its_own(bench, CELL, new)
+    _entries.after_earlier_cells(bench, CELL,
+                                 _entries.names(bench["workloads"][:4]))
     # the decode kernel's roofline is the accepted metric, read by the
     # accepted reader: the cell is appended to its list
     roofline = next(m for m in bench["per_layer"]
                     if m["name"] == "paged_attention_roofline.serve")
-    assert roofline["workloads"][-1] == CELL
+    assert CELL in roofline["workloads"]
+
+
+def test_the_cell_reports_every_serving_metric_but_the_open_loops_two():
+    cell = Cell(REPO, CELL)
+    assert cell.chips == 1 and cell.family() is kimi_k2
+    entries(json.load(open(os.path.join(REPO, "BENCHMARK.json"))))
 
 
 # ---------------------------------------------------------------------

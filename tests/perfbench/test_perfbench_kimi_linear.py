@@ -15,6 +15,7 @@ import types
 import numpy as np
 import pytest
 
+import _entries
 from perfbench.families import kimi_linear
 from perfbench.harness import program_trace, xplane
 from perfbench.harness.spec import Cell, SpecError, sized
@@ -178,60 +179,54 @@ def test_the_mix_is_the_issues_letter_for_letter():
     assert sum(outputs) / 96 == pytest.approx(2431, abs=1)
 
 
-def test_the_cell_and_its_two_metrics_are_appended_entries():
-    bench = _bench()
-    cell = Cell(REPO, CELL)
-    assert cell.chips == 1 and cell.family() is kimi_linear
-    assert [w["name"] for w in bench["workloads"]].index(CELL) == 7
-    entry = bench["workloads"][7]
+def entries(bench):
+    """What the benchmark holds of the cell, whatever later cells were
+    appended after it."""
+    entry = _entries.entry_at(bench, "workloads", CELL, 7)
     assert (entry["config"], entry["traffic"], entry["chips"]) \
         == (CONFIG, MIX, 1)
     assert len(entry["why"]) <= 200
-    assert [c["name"] for c in bench["configs"]].index(CONFIG) == 6
-    assert {m["name"] for m in cell.end_to_end} == {"serve_tokens_per_s",
-                                                    "setup_s"}
-    layer = [m["name"] for m in bench["per_layer"]]
+    _entries.entry_at(bench, "configs", CONFIG, 6)
+    assert _entries.reported(bench, CELL, "end_to_end") \
+        == {"serve_tokens_per_s", "setup_s"}
     new = ["linear_attention_roofline.serve", "linear_attention_ms.serve"]
-    at = layer.index(new[0])
-    assert layer[at:at + 2] == new and at + 2 == len(layer)
+    mine = _entries.metrics_in_order(bench, new)
     want = [("%", "higher", "kernel tier (kernels/, ops/pallas/)"),
             ("ms", "lower", "serving engine (serving/decode/engine.py)")]
+    at = _entries.names(bench["per_layer"]).index(new[0])
     names = {m["layer"] for m in bench["per_layer"][:at]}
-    for m, (unit, better, name) in zip(bench["per_layer"][at:], want):
+    for m, (unit, better, name) in zip(mine, want):
         assert (m["unit"], m["better"], m["source"], m["layer"]) \
             == (unit, better, "device_trace", name)
         assert name in names        # one of the layers the file had
-        assert m["workloads"] == [CELL]
         assert m["moves"] == "serve_tokens_per_s"
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-    assert {m["name"] for m in cell.per_layer} == set(new) | {
+    _entries.first_of_its_own(bench, CELL, new)
+    # one of the four device-clock metrics of harness/serve_programs.py:
+    # the fourth's reader keeps the components attention* only, 2 of the
+    # cell's 8 layers, and the second and third read the prefills, of
+    # which the traced window at 16-20 s holds 1 to 4 and on some seeds
+    # none, so they follow the arrivals and not the program
+    assert _entries.reported(bench, CELL) == set(new) | {
         "mfu.serve", "paged_attention_roofline.serve",
         "decode_step_ms.serve", "prefill_ms.serve",
         "device_idle_share.serve", "peak_hbm_gb.serve", "itl_p95_ms.closed",
         "kv_write_ms.serve", "host_loop_ms.serve", "slot_occupancy.serve",
         "moe_experts_ms.serve", "expert_tokens_per_step.serve",
-        "cache_hit_share.setup"}
-    # not the four of harness/serve_programs.py: an accepted test holds
-    # their lists to the four cells that had them (the traced run prints
-    # their line, ``prefill_programs``, all the same), and the fourth's
-    # reader keeps the components attention* only: 2 of 8 layers
-    for m in bench["per_layer"]:
-        if m["name"] in ("decode_device_ms.serve",
-                         "prefill_device_share.serve",
-                         "prefill_us_per_token.serve",
-                         "prefill_attention_us_per_token.serve"):
-            assert CELL not in m["workloads"]
+        "cache_hit_share.setup", "decode_device_ms.serve"}
     # appended to each list: behind every cell the benchmark had
-    had = [w["name"] for w in bench["workloads"][:7]]
-    for m in bench["per_layer"] + bench["end_to_end"]:
-        lists = m.get("workloads", ())
-        if CELL in lists:
-            assert lists[-1] == CELL, m["name"]
-            assert all(c in had for c in lists[:-1]), m["name"]
-    assert [w["name"] for w in bench["workloads"] if w["chips"] == 4] \
-        == ["bert_train_dp4"]
-    for reader in new:
+    _entries.after_earlier_cells(bench, CELL,
+                                 _entries.names(bench["workloads"][:7]))
+    _entries.among_four_chip_cells(bench, "bert_train_dp4")
+
+
+def test_the_cell_and_its_two_metrics_are_appended_entries():
+    cell = Cell(REPO, CELL)
+    assert cell.chips == 1 and cell.family() is kimi_linear
+    entries(_bench())
+    for reader in ("linear_attention_roofline.serve",
+                   "linear_attention_ms.serve"):
         assert callable(cell.layer_reader(reader))
 
 
@@ -714,7 +709,9 @@ def test_linear_attention_roofline_is_the_state_traffic_over_its_time():
     least_ms = 1e3 * max(flops / 197e12, nbytes / 819e9)
     assert read(run) == pytest.approx(100.0 * least_ms / 4.0)
     line = next(ln for ln in lines if ln.get("event") == "roofline")
-    assert line["bound"] == "memory" and line["kernel"] == "kda_decode"
+    # the line names the layer the scope names, whatever family runs it
+    assert line["bound"] == "memory" \
+        and line["kernel"] == "linear_attention"
     assert line["kernel_ms"] == pytest.approx(4.0)
 
 

@@ -13,6 +13,7 @@ import types
 import numpy as np
 import pytest
 
+import _entries
 from perfbench.families import mellum
 from perfbench.harness import program_trace, xplane
 from perfbench.harness.spec import Cell, SpecError, sized
@@ -149,77 +150,55 @@ def test_the_slabs_bytes_are_the_issues_arithmetic():
                                                                 abs=0.01)
 
 
-def test_the_cell_and_its_three_metrics_are_appended_entries():
-    cell = Cell(REPO, CELL)
-    assert cell.chips == 1 and cell.family() is mellum
-    bench = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
-    kimi = {m["name"] for m in bench["per_layer"]
-            if "kimi_k2_serve_closed32" in m.get("workloads", ())
-            or "workloads" not in m}
-    mine = {m["name"] for m in cell.per_layer}
-    new = {"window_attention_roofline.serve", "attention_full_ms.serve",
-           "attention_window_ms.serve"}
+def entries(bench):
+    """What the benchmark holds of the cell, whatever later cells were
+    appended after it."""
+    kimi = _entries.reported(bench, "kimi_k2_serve_closed32")
+    mine = _entries.reported(bench, CELL)
+    new = ["window_attention_roofline.serve", "attention_full_ms.serve",
+           "attention_window_ms.serve"]
     # every serving metric the Kimi cell reports but the one roofline that
     # a sum of contexts cannot feed where a window bounds what is read
     assert kimi - mine == {"paged_attention_roofline.serve"}
-    assert mine - kimi == new
-    assert not hasattr(mellum, "paged_attention_cost")
-    assert {m["name"] for m in cell.end_to_end} == {"serve_tokens_per_s",
-                                                    "setup_s"}
+    assert mine - kimi == set(new)
+    assert _entries.reported(bench, CELL, "end_to_end") \
+        == {"serve_tokens_per_s", "setup_s"}
     # appended: after everything the benchmark had at PR 31, whatever a
     # later PR appends after them in turn
-    cells = [w["name"] for w in bench["workloads"]]
-    assert cells.index(CELL) == 5 and cell.entry["traffic"] \
+    assert _entries.entry_at(bench, "workloads", CELL, 5)["traffic"] \
         == "closed_loop_p16k"
-    configs = [c["name"] for c in bench["configs"]]
-    assert configs.index(CONFIG) == 4
-    assert bench["configs"][4]["reduced"] == _config()["reduced"]
-    layer = [m["name"] for m in bench["per_layer"]]
-    at = layer.index("expert_tokens_per_step.serve") + 1
-    assert layer[at:at + 3] == [
-        "window_attention_roofline.serve", "attention_full_ms.serve",
-        "attention_window_ms.serve"]
-    for m in bench["per_layer"][at:at + 3]:
-        assert m["workloads"][0] == CELL
+    assert _entries.entry_at(bench, "configs", CONFIG, 4)["reduced"] \
+        == _config()["reduced"]
+    for m in _entries.metrics_in_order(
+            bench, ["expert_tokens_per_step.serve"] + new)[1:]:
         assert m["moves"] == "serve_tokens_per_s"
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
+    _entries.first_of_its_own(bench, CELL, new)
     for m in bench["per_layer"] + bench["end_to_end"]:
         lists = m.get("workloads", ())
         if CELL in lists and "kimi_k2_serve_closed32" in lists:
             assert lists.index(CELL) \
                 == lists.index("kimi_k2_serve_closed32") + 1
-    # only one cell of the benchmark takes four chips, as before
-    assert [w["name"] for w in bench["workloads"] if w["chips"] == 4] \
-        == ["bert_train_dp4"]
+    # one cell of the benchmark took four chips when it was appended
+    _entries.among_four_chip_cells(bench, "bert_train_dp4")
+
+
+def test_the_cell_and_its_three_metrics_are_appended_entries():
+    cell = Cell(REPO, CELL)
+    assert cell.chips == 1 and cell.family() is mellum
+    assert not hasattr(mellum, "paged_attention_cost")
+    entries(json.load(open(os.path.join(REPO, "BENCHMARK.json"))))
 
 
 def test_what_the_kimi_cells_own_test_held_of_it_still_holds():
-    """``test_perfbench_kimi_k2.py`` also asserts that Kimi's entries are
-    the LAST of their lists, which an appended cell ends: that one test
-    fails since PR 32 and waits for a ``benchmark`` PR to take its four
-    position asserts out (CHANGES.md, PR 32; PERF.md section 7).  What it
-    held of the cell itself is held here."""
-    from perfbench.families import kimi_k2
+    """The Kimi-K2 cell's entries, as its own file holds them, seen from
+    the file of the cell appended after it."""
+    import test_perfbench_kimi_k2
     cell = Cell(REPO, "kimi_k2_serve_closed32")
-    assert cell.chips == 1 and cell.family() is kimi_k2
-    bench = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
-    closed = {m["name"] for m in bench["per_layer"]
-              if "gpt2m_serve_open_r80" in m.get("workloads", ())}
-    mine = {m["name"] for m in cell.per_layer}
-    assert closed - mine == {"queue_wait_ms.serve", "ttft_p50_ms.open"}
-    assert mine - closed == {"moe_experts_ms.serve",
-                             "expert_tokens_per_step.serve",
-                             "cache_hit_share.setup"}
-    assert {m["name"] for m in cell.end_to_end} == {"serve_tokens_per_s",
-                                                    "setup_s"}
-    names = [w["name"] for w in bench["workloads"]]
-    assert names.index("kimi_k2_serve_closed32") == 4
-    assert [c["name"] for c in bench["configs"]][3] \
-        == "kimi-k2-instruct-ep32"
-    roofline = next(m for m in bench["per_layer"]
-                    if m["name"] == "paged_attention_roofline.serve")
-    assert roofline["workloads"][-1] == "kimi_k2_serve_closed32"
+    assert cell.chips == 1 and cell.family() is test_perfbench_kimi_k2.kimi_k2
+    test_perfbench_kimi_k2.entries(
+        json.load(open(os.path.join(REPO, "BENCHMARK.json"))))
 
 
 # ---------------------------------------------------------------------

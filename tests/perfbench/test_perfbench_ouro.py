@@ -9,6 +9,7 @@ import types
 
 import pytest
 
+import _entries
 from perfbench.families import ouro
 from perfbench.harness import program_trace, xplane
 from perfbench.harness.spec import Cell, SpecError, sized
@@ -118,48 +119,46 @@ def test_the_mix_is_the_issues_letter_for_letter():
     assert sum(prompts) / 96 < 80 < 130 < sum(outputs) / 96
 
 
-def test_the_cell_and_its_two_metrics_are_appended_entries():
-    bench = _bench()
-    cell = Cell(REPO, CELL)
-    assert cell.chips == 1 and cell.family() is ouro
-    assert [w["name"] for w in bench["workloads"]].index(CELL) == 6
-    entry = bench["workloads"][6]
+def entries(bench):
+    """What the benchmark holds of the cell, whatever later cells were
+    appended after it."""
+    entry = _entries.entry_at(bench, "workloads", CELL, 6)
     assert (entry["config"], entry["traffic"], entry["chips"]) \
         == (CONFIG, MIX, 1)
-    assert [c["name"] for c in bench["configs"]].index(CONFIG) == 5
-    assert {m["name"] for m in cell.end_to_end} == {"serve_tokens_per_s",
-                                                    "setup_s"}
-    layer = [m["name"] for m in bench["per_layer"]]
-    at = layer.index("attention_window_ms.serve") + 1
+    _entries.entry_at(bench, "configs", CONFIG, 5)
+    assert _entries.reported(bench, CELL, "end_to_end") \
+        == {"serve_tokens_per_s", "setup_s"}
     new = ["loop_pass_ms.serve", "ut_passes_per_token.serve"]
-    assert layer[at:at + 2] == new
     engine = "serving engine (serving/decode/engine.py)"
     want = [("ms", "lower", "device_trace", engine),
             ("passes", "lower", "program_counter", engine)]
-    for m, (unit, better, source, name) in zip(
-            bench["per_layer"][at:at + 2], want):
+    for m, (unit, better, source, name) in zip(_entries.metrics_in_order(
+            bench, ["attention_window_ms.serve"] + new)[1:], want):
         assert (m["unit"], m["better"], m["source"], m["layer"]) \
             == (unit, better, source, name)
-        assert m["workloads"] == [CELL]
         assert m["moves"] == "serve_tokens_per_s"
         assert set(m) == {"name", "unit", "better", "source", "layer",
                           "moves", "workloads"}
-    assert {m["name"] for m in cell.per_layer} == set(new) | {
+    _entries.first_of_its_own(bench, CELL, new)
+    assert _entries.reported(bench, CELL) == set(new) | {
         "mfu.serve", "paged_attention_roofline.serve",
         "decode_step_ms.serve", "prefill_ms.serve",
         "device_idle_share.serve", "peak_hbm_gb.serve", "itl_p95_ms.closed",
         "kv_write_ms.serve", "host_loop_ms.serve", "slot_occupancy.serve",
-        "attention_full_ms.serve", "cache_hit_share.setup"}
+        "attention_full_ms.serve", "cache_hit_share.setup",
+        "decode_device_ms.serve", "prefill_device_share.serve",
+        "prefill_us_per_token.serve", "prefill_attention_us_per_token.serve"}
     # appended to each list: behind every cell the benchmark had
-    had = [w["name"] for w in bench["workloads"][:6]]
-    for m in bench["per_layer"] + bench["end_to_end"]:
-        lists = m.get("workloads", ())
-        if CELL in lists:
-            assert all(lists.index(c) < lists.index(CELL)
-                       for c in lists if c in had), m["name"]
-    assert [w["name"] for w in bench["workloads"] if w["chips"] == 4] \
-        == ["bert_train_dp4"]
-    for reader in new:
+    _entries.after_earlier_cells(bench, CELL,
+                                 _entries.names(bench["workloads"][:6]))
+    _entries.among_four_chip_cells(bench, "bert_train_dp4")
+
+
+def test_the_cell_and_its_two_metrics_are_appended_entries():
+    cell = Cell(REPO, CELL)
+    assert cell.chips == 1 and cell.family() is ouro
+    entries(_bench())
+    for reader in ("loop_pass_ms.serve", "ut_passes_per_token.serve"):
         assert callable(cell.layer_reader(reader))
 
 
